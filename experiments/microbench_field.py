@@ -13,8 +13,6 @@ import time
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
-                      os.path.join(tempfile.gettempdir(), "jax-ouro-cache"))
 
 import numpy as np  # noqa: E402
 

@@ -21,6 +21,7 @@ Backends:
 """
 from __future__ import annotations
 
+import importlib.util
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
@@ -323,21 +324,25 @@ def default_backend() -> CryptoBackend:
     seconds per batch — the C-speed OpenSSL path is the right default
     there, exactly the libsodium-fallback role from BASELINE.json.
     Without the `cryptography` binding the pure-Python ground truth is
-    the last resort, so the framework stays functional (just slower)."""
+    the last resort, so the framework stays functional (just slower).
+
+    That choice is made on what JAX reports, never on a failure: when
+    JAX reports an accelerator and `JaxBackend()` cannot be built, the
+    error propagates — a node that quietly verifies on the CPU beside an
+    idle chip is the fault this function must not hide."""
     global _default
     if _default is None:
         try:
             import jax
-            if jax.devices()[0].platform == "cpu":
-                raise RuntimeError("cpu platform: use the openssl backend")
+        except ImportError:     # host-only install: no device to hide
+            jax = None
+        if jax is not None and jax.devices()[0].platform != "cpu":
             from .jax_backend import JaxBackend
             _default = JaxBackend()
-        except Exception:   # no jax / no device: CPU fallback
-            import importlib.util
-            if importlib.util.find_spec("cryptography") is not None:
-                _default = OpensslBackend()
-            else:
-                _default = CpuRefBackend()
+        elif importlib.util.find_spec("cryptography") is not None:
+            _default = OpensslBackend()
+        else:
+            _default = CpuRefBackend()
     return _default
 
 

@@ -52,6 +52,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional, Sequence
 
 from .. import simharness as sim
+from ..compile_cache import cache_dir
 from ..observe import metrics as _metrics
 from ..simharness.stm import TVar, retry
 from . import autotune as _autotune
@@ -137,7 +138,7 @@ class BreakEvenTable:
     @staticmethod
     def path_for(device_kind: str) -> str:
         return os.path.join(
-            _autotune.cache_dir(),
+            cache_dir(),
             f"ouro-breakeven-{_autotune.KERNEL_REV}-"
             f"{_autotune._slug(device_kind)}.json")
 

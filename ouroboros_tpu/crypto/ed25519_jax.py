@@ -27,35 +27,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..compile_cache import cache_dir
 from . import edwards as ed
 from . import field_jax as F
 
 
-def _ensure_compile_cache() -> None:
-    """Point JAX's persistent compilation cache somewhere durable.  Every
-    device path imports this module, so the cache is configured before
-    the first compile no matter which entry point ran first (the mesh
-    tests used to miss it — and re-pay 4-minute XLA:CPU compiles every
-    run — because only pallas_kernels configured it).  The env var route
-    (JAX_COMPILATION_CACHE_DIR) silently fails on machines where an
-    accelerator plugin imports jax at interpreter start; config.update
-    always wins."""
-    import os
-    import tempfile
-    try:
-        if jax.config.jax_compilation_cache_dir is not None:
-            # an application already configured a dir — leave its
-            # min-compile-time threshold alone too (ADVICE r4)
-            return
-        d = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
-            tempfile.gettempdir(), "jax-ouro-cache")
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except Exception:
-        pass
-
-
-_ensure_compile_cache()
+# every device path imports this module, so the persistent compilation
+# cache is configured before the first compile whichever entry point ran
+# first (ladder compiles are minutes per shape)
+cache_dir()
 
 L = ed.L
 

@@ -7,11 +7,10 @@ headers being validated) is sharded over a jax.sharding.Mesh axis and each
 chip runs the same branch-free ladder on its shard, with psum aggregation
 over ICI.  No NCCL/MPI analog: collectives are XLA's.
 """
-from .mesh import enable_compile_cache, log_compile_time, make_mesh
+from .mesh import log_compile_time, make_mesh
 from .sharded_verify import (
     ShardedJaxBackend, build_sharded_verifier, sharded_batch_verify,
 )
 
-__all__ = ["ShardedJaxBackend", "enable_compile_cache",
-           "log_compile_time", "make_mesh", "build_sharded_verifier",
-           "sharded_batch_verify"]
+__all__ = ["ShardedJaxBackend", "log_compile_time", "make_mesh",
+           "build_sharded_verifier", "sharded_batch_verify"]

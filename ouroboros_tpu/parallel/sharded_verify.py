@@ -21,13 +21,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..crypto import ed25519_jax as EJ
 from .mesh import WINDOW_AXIS
 
-# jax.shard_map graduated from jax.experimental on newer jax; this tree
-# must run on both (the container jax only ships the experimental name)
-try:
-    _shard_map = jax.shard_map
-except AttributeError:                       # pragma: no cover - jax<0.5
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 @functools.lru_cache(maxsize=8)
 def build_sharded_verifier(mesh: Mesh):
@@ -48,7 +41,7 @@ def build_sharded_verifier(mesh: Mesh):
         total = jax.lax.psum(jnp.sum(ok), axis)
         return ok, total
 
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         step, mesh=mesh,
         in_specs=(spec2, spec1, spec2, spec1, spec2, spec2),
         out_specs=(spec1, P()))
@@ -94,7 +87,7 @@ def build_sharded_vrf(mesh: Mesh):
     axis = mesh.axis_names[0]
     spec2 = P(None, axis)
     spec1 = P(axis)
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         vrf_jax.vrf_verify_core, mesh=mesh,
         in_specs=(spec2, spec1, spec2, spec1, spec2, spec2, spec2, spec2),
         out_specs=P(axis, None))
@@ -105,7 +98,7 @@ def build_sharded_vrf(mesh: Mesh):
 def build_sharded_gamma8(mesh: Mesh):
     from ..crypto import vrf_jax
     axis = mesh.axis_names[0]
-    mapped = _shard_map(
+    mapped = jax.shard_map(
         vrf_jax.gamma8_kernel.__wrapped__, mesh=mesh,
         in_specs=(P(None, axis), P(axis)),
         out_specs=P(axis, None))
@@ -130,7 +123,7 @@ class ShardedJaxBackend(JaxBackend):
     the whole MULTICHIP_r05 rc=124.  The per-shard program here is the
     same split-ladder packed-words program the single-chip path compiles
     in seconds-to-a-minute, and its compiled executable persists in the
-    XLA compile cache across processes (mesh.enable_compile_cache), so a
+    XLA compile cache across processes (compile_cache.cache_dir), so a
     warm container pays no compile at all.
 
     Inheriting the prep also threads the mesh path through the
@@ -149,9 +142,15 @@ class ShardedJaxBackend(JaxBackend):
                          autotune=False)
         self.mesh = mesh
         self.name = f"jax-mesh-{mesh.devices.size}"
+        # report the devices of the MESH, not whatever jax.devices()
+        # lists first
+        dev0 = mesh.devices.flat[0]
+        self.platform = dev0.platform
+        self.device_kind = dev0.device_kind
+        self.device_count = int(mesh.devices.size)
         # buffer donation for the per-window inputs (see JaxBackend):
         # fresh arrays every window, never read back -> donation-safe
-        self._donate = mesh.devices.flat[0].platform in ("tpu", "gpu")
+        self._donate = self.platform in ("tpu", "gpu")
         axis = mesh.axis_names[0]
         self._lane_sharding = NamedSharding(mesh, P(None, axis))
 
@@ -292,8 +291,8 @@ class ShardedJaxBackend(JaxBackend):
                 outs.append(vrf_jax.gamma8_words_core(bGw, bsG2[0]))
             return tuple(outs)
 
-        mapped = _shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
-                            out_specs=tuple(out_specs))
+        mapped = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
+                               out_specs=tuple(out_specs))
 
         def call(ed_args, vrf_args, beta_args, kes_args):
             present = [a for a in (ed_args, vrf_args, beta_args)

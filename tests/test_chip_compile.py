@@ -1,0 +1,230 @@
+"""Ask the chip's compiler, without a chip (rehearsal 3 of the
+on-chip-measurement guide): the programs of ONE 1024-block replay window
+of bench's chain, at their real widths, compiled for a described TPU
+v5e by the compiler installed here.
+
+What interpret mode cannot show, this does: a block not aligned to the
+(8, 128) int32 tiling, a kernel over the fast-memory limit, a program
+the partitioner refuses.  Nothing runs, so it says nothing about results
+or times on the chip — `chip_smoke.py` does that.
+
+The Pallas kernels are compiled as the chip runs them: `interpret=False`
+and the "columns" multiply.  `pallas_kernels._interpret()` reads
+`jax.devices()`, which is the CPU here, so the module fixture steers it;
+the program has no option for this.  The persistent compilation cache is
+off around these tests: a compile for a described device is written to
+it but cannot be read back without a chip.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may load libtpu, and under xdist every worker
+imports every test file.  Keep these tests in this ONE file.
+
+Tier-1 keeps the cases that together take about three minutes here (KES
+hash, gamma8, the Ed25519 split ladder, the fold, the per-key fill).
+The VRF kernel (~250 s alone), the XLA forms, the whole composites are
+`slow`; their seconds are in CHANGES.md (PR 22).
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from ouroboros_tpu.crypto import blake2b_jax as B2
+from ouroboros_tpu.crypto import ed25519_jax as EJ
+from ouroboros_tpu.crypto import jax_backend as JB
+from ouroboros_tpu.crypto import pallas_kernels as PK
+from ouroboros_tpu.crypto import vrf_jax as VJ
+
+# one 1024-block window of bench's chain (bench.py: 2 txs/block, depth-10
+# KES): OCert + KES leaf + 2 witnesses per block, 2 VRF proofs per block,
+# the next-next window's betas, the cold KES hash-path jobs.  Since the
+# hash-path outcomes are cached per (pool, period) a window of this
+# chain ships ~1,200 Blake2b jobs (bucket 2048); a window in which every
+# block opens a new period ships 10 per block (bucket 16384, the shape
+# the last recorded rounds had).
+NE, NV, NB, NK = 4096, 2048, 2048, 2048
+NK_ALL_NEW = 16384
+
+U32, I32, U8 = jnp.uint32, jnp.int32, jnp.uint8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    mp = pytest.MonkeyPatch()
+    mp.setenv("TPU_LOG_DIR", "disabled")    # no compiler logs under /tmp
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:
+        mp.undo()
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # compile what the chip runs, not the CPU interpreter's form
+    mp.setattr(PK, "_interpret", lambda: False)
+    # lower the jitted program itself, not the span wrapper around it
+    mp.setattr(JB, "_compile_span_on_first_call", lambda fn, name: fn)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    assert PK._mul_form() == "columns"
+    yield t
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(s):
+    """ShapeDtypeStruct maker for arguments placed by sharding `s`."""
+    return lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=s)
+
+
+def _ed_args(n, s):
+    S = _spec(s)
+    return ((S((8, n), U32),) * 5 + (S((1, n), I32),)
+            + (S((8, n), U32),) * 2)
+
+
+def _vrf_args(n, s):
+    S = _spec(s)
+    return (S((8, n), U32), S((8, n), U32), S((8, n), U32),
+            S((1, n), I32), S((8, n), U32), S((4, n), U32),
+            S((8, n), U32))
+
+
+def _beta_args(n, s):
+    S = _spec(s)
+    return (S((8, n), U32), S((1, n), I32))
+
+
+def _kes_args(n, s):
+    S = _spec(s)
+    return (S((16, n), U32), S((8, n), U32))
+
+
+# Every jitted callable below is a FRESH lambda: jit caches traces by
+# function identity, and a trace with interpret=False baked in must not
+# be found again by a CPU test in the same worker.
+
+PALLAS = {
+    "kes_hash": (lambda *a: PK._kes_hash_call(*a, NK),
+                 lambda s: _kes_args(NK, s)),
+    "kes_hash_all_new": (lambda *a: PK._kes_hash_call(*a, NK_ALL_NEW),
+                         lambda s: _kes_args(NK_ALL_NEW, s)),
+    "gamma8": (lambda *a: PK._gamma8_call(*a, NB),
+               lambda s: _beta_args(NB, s)),
+    "ed25519_split": (lambda *a: PK._ed25519_split_call(*a, NE),
+                      lambda s: _ed_args(NE, s)),
+    "vrf_verify": (lambda *a: PK._vrf_verify_call(*a, NV),
+                   lambda s: _vrf_args(NV, s)),
+}
+
+
+@pytest.mark.parametrize("kernel", [
+    "kes_hash", "kes_hash_all_new", "gamma8", "ed25519_split",
+    pytest.param("vrf_verify", marks=pytest.mark.slow)])
+def test_pallas_kernel_compiles_for_v5e(kernel, one_chip):
+    """The Mosaic form of each window part, at the window's lane count:
+    accepted by the chip's compiler, and really a Mosaic kernel."""
+    fn, make_args = PALLAS[kernel]
+    compiled = jax.jit(lambda *a: fn(*a)).lower(
+        *make_args(one_chip)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+XLA = {
+    "ed25519_split": (
+        lambda Aw, xa, xw, yw, Rw, sR, sw, kw:
+            EJ.verify_full_split_words_core(Aw, xa, xw, yw, Rw, sR[0],
+                                            sw, kw),
+        lambda s: _ed_args(NE, s)),
+    "vrf_verify": (
+        lambda Yw, xa, Gw, sG, rw, cw, sw:
+            VJ.vrf_verify_words_core(Yw, xa, Gw, sG[0], rw, cw, sw),
+        lambda s: _vrf_args(NV, s)),
+    "gamma8": (lambda Gw, sG: VJ.gamma8_words_core(Gw, sG[0]),
+               lambda s: _beta_args(NB, s)),
+    "kes_hash": (lambda mw, ew: B2.check_block64(mw, ew),
+                 lambda s: _kes_args(NK, s)),
+}
+
+
+@pytest.mark.parametrize("kernel", [
+    pytest.param("ed25519_split", marks=pytest.mark.slow),
+    pytest.param("vrf_verify", marks=pytest.mark.slow),
+    pytest.param("gamma8", marks=pytest.mark.slow),
+    "kes_hash"])
+def test_xla_form_compiles_for_v5e(kernel, one_chip):
+    """The op-by-op XLA form of each part: what the autotuner also
+    compiles on the chip the first time it sees a window shape."""
+    fn, make_args = XLA[kernel]
+    compiled = jax.jit(lambda *a: fn(*a)).lower(
+        *make_args(one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+
+
+def _backend(pallas: bool) -> JB.JaxBackend:
+    be = JB.JaxBackend(use_pallas=pallas, autotune=False)
+    be._donate = True       # as on the chip (off on the CPU it sees here)
+    return be
+
+
+@pytest.mark.parametrize("nb,nk", [(NB, NK), (0, NK)])
+def test_fold_program_compiles_for_v5e(nb, nk, one_chip):
+    """The verdict fold (device SHA-512 challenge check + first-bad min)
+    over one window's packed buffer, for both shapes a replay meets:
+    windows that carry betas and the last two that do not."""
+    S = _spec(one_chip)
+    fold = _backend(False)._fold_program(NE, NV, nb, nk)
+    fold.lower(
+        S((NE + 130 * NV + 33 * nb + nk,), U8), S((NE,), I32),
+        S((NV,), I32), S((NV, 32), U8), S((NV, 16), U8)).compile()
+
+
+def test_key_fill_compiles_for_v5e(one_chip):
+    """The per-key fill the precompute cache dispatches on a cold key
+    (decompress + 128 doublings), at its smallest bucket."""
+    S = _spec(one_chip)
+    jax.jit(lambda y, s: EJ.a128_core(y, s)).lower(
+        S((20, 128), I32), S((128,), I32)).compile()
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("pallas", [True, False])
+def test_window_composite_compiles_for_v5e(pallas, one_chip):
+    """One whole fused window composite, homogeneous form: what ONE new
+    window shape costs a cold start (jax_backend._window_composite
+    records that a MIXED composite took over an hour; the homogeneous
+    ones take minutes)."""
+    comp = _backend(pallas)._window_composite(NE, NV, NB, NK, pallas)
+    compiled = comp.lower(
+        _ed_args(NE, one_chip), _vrf_args(NV, one_chip),
+        _beta_args(NB, one_chip), _kes_args(NK, one_chip)).compile()
+    assert compiled.as_text().count("tpu_custom_call") == \
+        (4 if pallas else 0)
+
+
+@pytest.mark.slow
+def test_sharded_composite_compiles_for_four_v5e(topo):
+    """`chip_smoke.py --mesh 4`'s program: the sharded window composite
+    over a mesh of the four described chips, each holding a quarter of
+    the lanes."""
+    from ouroboros_tpu.parallel import ShardedJaxBackend
+    from ouroboros_tpu.parallel.mesh import WINDOW_AXIS
+    mesh = Mesh(np.array(topo.devices[:4]), (WINDOW_AXIS,))
+    lanes = NamedSharding(mesh, P(None, WINDOW_AXIS))
+    sb = ShardedJaxBackend(mesh)
+    assert sb._donate and sb.device_count == 4 and sb.platform == "tpu"
+    comp = sb._window_composite(NE, NV, NB, 0, False)
+    compiled = comp.lower(
+        _ed_args(NE, lanes), _vrf_args(NV, lanes), _beta_args(NB, lanes),
+        None).compile()
+    per_dev = compiled.memory_analysis()
+    assert per_dev.argument_size_in_bytes < 2 ** 30

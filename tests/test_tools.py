@@ -4,6 +4,7 @@ import json
 import os
 import subprocess
 import sys
+import types
 
 import pytest
 
@@ -172,8 +173,8 @@ def test_bench_smoke_parity_gate():
     assert 0 < q["p50"] <= q["p95"] <= q["p99"]
     assert res["perfgate_ok"]
     # ISSUE 11: the sharded parity probe either ran green or recorded
-    # WHY it was skipped (experimental-only shard_map: a sharded
-    # composite compiles for minutes on this container's XLA:CPU)
+    # WHY it was skipped (host-platform devices: a sharded composite
+    # compiles for minutes on XLA:CPU)
     sh = res["sharded_replay_smoke"]
     assert sh["ok"] is True
     assert sh.get("skipped") or sh["producer_threads_leaked"] == 0
@@ -220,10 +221,63 @@ def test_bench_cli_flags_exist():
 # perfgate: the BENCH trajectory as an enforced gate (ISSUE 9)
 # ---------------------------------------------------------------------------
 
-def test_perfgate_passes_on_committed_trajectory():
-    """Acceptance: rc 0 over the real recorded BENCH_r01..rNN rounds."""
-    import glob
-    rounds = sorted(glob.glob(os.path.join(REPO, "BENCH_r*.json")))
+def _write_bench_history(d) -> list:
+    """A short synthetic BENCH trajectory r01..r05 in the shape bench.py
+    prints and tools/perfgate.py reads, harness-wrapped like a recorded
+    round.  (The committed rounds were deleted in PR 22: they were
+    measured on a device that no longer exists; git history holds them.)
+    The early rounds lack the spread/overlap sections, as early recorded
+    rounds did, and no round carries phases/variance/serve/stream."""
+    rounds = [
+        {"vs_baseline": 2.0},
+        {"vs_baseline": 5.3, "blocks_per_sec": 1100.0,
+         "state_hash_parity": True},
+        {"vs_baseline": 2.6, "blocks_per_sec": 500.0,
+         "state_hash_parity": True},
+        {"vs_baseline": 5.7, "reps": 5, "spread": 0.55,
+         "state_hash_parity": True,
+         "replay_secs": {"median": 8.0, "min": 6.0, "max": 10.4}},
+        {"vs_baseline": 12.1, "reps": 5, "spread": 0.285,
+         "state_hash_parity": True, "blocks_per_sec": 2100.0,
+         "cpu_baseline_proofs_per_sec": 1060.0,
+         "replay_secs": {"median": 4.7, "min": 4.5, "max": 5.8},
+         "cpu_replay_secs": {"median": 56.5, "spread": 0.256},
+         "breakdown": {"device_secs": 3.8, "host_secs": 0.9},
+         "kernel_choices": {"ed@4096": "xla", "vrf@2048": "pallas",
+                            "win@4096@2048@2048@16384": "pallas"},
+         "primitives": {"ed25519_batch_per_sec": 19000.0,
+                        "ed25519_spread": 0.167,
+                        "vrf_batch_per_sec": 8500.0, "vrf_spread": 0.45,
+                        "kes_batch_per_sec": 11000.0,
+                        "kes_spread": 0.294}},
+    ]
+    paths = []
+    for n, fields in enumerate(rounds, 1):
+        doc = {"metric": "shelley_replay_proofs_per_sec",
+               "value": 1000.0 * fields["vs_baseline"],
+               "unit": "proofs/s", **fields}
+        path = d / f"BENCH_r{n:02d}.json"
+        path.write_text(json.dumps({"n": n, "rc": 0, "parsed": doc}))
+        paths.append(str(path))
+    return paths
+
+
+@pytest.fixture(scope="module")
+def history(tmp_path_factory):
+    """Synthetic recorded rounds (`.bench` and `.multichip` paths), the
+    five of each that predate every later section of the gates — the
+    MULTICHIP ones green with no MULTICHIP_OBS line, the last a red
+    rc=124."""
+    d = tmp_path_factory.mktemp("rounds")
+    bench = _write_bench_history(d)
+    multichip = [_multichip_round(d, n, 124 if n == 5 else 0)
+                 for n in range(1, 6)]
+    return types.SimpleNamespace(bench=bench, multichip=multichip)
+
+
+def test_perfgate_passes_on_committed_trajectory(history):
+    """Acceptance: rc 0 over a recorded-shape BENCH_r01..r05 history."""
+    rounds = history.bench
     assert len(rounds) >= 5
     r = _run("-m", "tools.perfgate", "--check", *rounds)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -234,12 +288,9 @@ def test_perfgate_passes_on_committed_trajectory():
 
 
 def _regressed_round(tmp_path, **fields):
-    import glob
-    import shutil
     d = tmp_path / "traj"
     d.mkdir()
-    for p in sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json"))):
-        shutil.copy(p, d)
+    _write_bench_history(d)
     doc = {"metric": "shelley_replay_proofs_per_sec", "value": 5000.0,
            "unit": "proofs/s", **fields}
     (d / "BENCH_r06.json").write_text(
@@ -278,7 +329,7 @@ def test_perfgate_single_check_failure_and_thresholds(tmp_path):
     assert r2.returncode == 0, r2.stdout
 
 
-def test_perfgate_tightened_spread_binds_from_r06(tmp_path):
+def test_perfgate_tightened_spread_binds_from_r06(tmp_path, history):
     """ISSUE 12 satellite: the rep-spread bound tightened 0.45 -> 0.35
     now that the GC-discipline fix (PR 8) and the ('vrff', m) autotune
     key (PR 11) landed.  A 0.40-spread r06 — fine under the old bound —
@@ -292,9 +343,7 @@ def test_perfgate_tightened_spread_binds_from_r06(tmp_path):
     assert results["rep_spread"] == "FAIL"
     assert results["vs_baseline"] == "pass"
     # history alone (latest = r05) still passes under the legacy bound
-    import glob
-    rounds = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
-    r2 = _run("-m", "tools.perfgate", "--check", *rounds)
+    r2 = _run("-m", "tools.perfgate", "--check", *history.bench)
     assert r2.returncode == 0, r2.stdout + r2.stderr
 
 
@@ -329,13 +378,12 @@ _GREEN_OBS = {"n_devices": 8, "prewarm_compile_secs": 201.3,
                                  "state_hash_parity": True}}
 
 
-def test_perfgate_multichip_tolerates_presharded_history():
-    """The committed MULTICHIP_r01..r05 rounds predate the sharded
-    replay (r05 is a red rc=124 with no MULTICHIP_OBS at all): the gate
-    reports every check skipped and passes — tier-1 must not fail
-    retroactively on history the gate could never have enforced."""
-    import glob
-    rounds = sorted(glob.glob(os.path.join(REPO, "MULTICHIP_r*.json")))
+def test_perfgate_multichip_tolerates_presharded_history(history):
+    """MULTICHIP_r01..r05-shaped rounds that predate the sharded replay
+    (r05 a red rc=124 with no MULTICHIP_OBS at all): the gate reports
+    every check skipped and passes — tier-1 must not fail retroactively
+    on history the gate could never have enforced."""
+    rounds = history.multichip
     assert len(rounds) >= 5
     r = _run("-m", "tools.perfgate", "--multichip", *rounds)
     assert r.returncode == 0, r.stdout + r.stderr
@@ -387,12 +435,10 @@ def test_perfgate_multichip_fails_lost_parity(tmp_path):
                        "sharded_replay_parity": "FAIL"}
 
 
-def test_perfgate_bench_and_multichip_combined(tmp_path):
+def test_perfgate_bench_and_multichip_combined(tmp_path, history):
     """--check and --multichip compose: one verdict, ok only when both
     trajectories pass."""
-    import glob
-    bench_rounds = sorted(glob.glob(os.path.join(REPO,
-                                                 "BENCH_r0*.json")))
+    bench_rounds = history.bench
     mc = [_multichip_round(tmp_path, 6, 0, obs=_GREEN_OBS),
           _multichip_round(tmp_path, 7, 124)]
     r = _run("-m", "tools.perfgate", "--check", *bench_rounds,
@@ -418,12 +464,11 @@ _GREEN_SERVE = {"seed": 7, "deadline_secs": 0.05,
                               "p95_within_deadline": True}}
 
 
-def test_perfgate_serve_skips_on_preservice_history():
-    """ISSUE 14 satellite: the committed r01-r05 rounds predate the
-    serve section — every serve check reports skipped and the gate
-    passes (same binding pattern as --multichip)."""
-    import glob
-    rounds = sorted(glob.glob(os.path.join(REPO, "BENCH_r0*.json")))
+def test_perfgate_serve_skips_on_preservice_history(history):
+    """ISSUE 14 satellite: r01-r05-shaped rounds predate the serve
+    section — every serve check reports skipped and the gate passes
+    (same binding pattern as --multichip)."""
+    rounds = history.bench
     r = _run("-m", "tools.perfgate", "--serve", *rounds)
     assert r.returncode == 0, r.stdout + r.stderr
     sv = json.loads(r.stdout)["serve"]
@@ -495,7 +540,7 @@ def test_obsreport_renders_mesh_section(tmp_path):
         in r.stdout
 
 
-def test_obsreport_renders_overlap_section(tmp_path):
+def test_obsreport_renders_overlap_section(tmp_path, history):
     """Regression (ISSUE 9 satellite): a BENCH_r06-shaped round — the
     ISSUE 8 `overlap` section with per-rep attributions and medians —
     renders the hidden-fraction/producer-stall medians instead of being
@@ -528,12 +573,12 @@ def test_obsreport_renders_overlap_section(tmp_path):
         assert "producer permit stalls" in r.stdout and "0.05" in r.stdout
         assert "88% of the host sequential pass" in r.stdout
     # pre-ISSUE-8 rounds say so instead of rendering nothing
-    r = _run("-m", "tools.obsreport", "BENCH_r05.json")
+    r = _run("-m", "tools.obsreport", history.bench[-1])
     assert r.returncode == 0
     assert "no 'overlap' section" in r.stdout
 
 
-def test_obsreport_renders_serve_section(tmp_path):
+def test_obsreport_renders_serve_section(tmp_path, history):
     """ISSUE 12 satellite: a round carrying the ``serve`` section (the
     adaptive batching service bench) renders the latency-quantile
     table, the coalesced-batch-size histogram and the fallback /
@@ -586,12 +631,12 @@ def test_obsreport_renders_serve_section(tmp_path):
     assert "verdict parity vs CpuRefBackend on every leg: True" \
         in r.stdout
     # a round without the section renders unchanged
-    r2 = _run("-m", "tools.obsreport", "BENCH_r05.json")
+    r2 = _run("-m", "tools.obsreport", history.bench[-1])
     assert r2.returncode == 0
     assert "verification service" not in r2.stdout
 
 
-def test_obsreport_renders_stream_section(tmp_path):
+def test_obsreport_renders_stream_section(tmp_path, history):
     """ISSUE 15 satellite: a round carrying the ``stream`` section (the
     disk->decode->verify engine leg) renders the read-ahead hiding
     accounting and the snapshot/restart timings; rounds without one
@@ -626,7 +671,7 @@ def test_obsreport_renders_stream_section(tmp_path):
     assert "restart probe" in r.stdout and "0.0340" in r.stdout
     assert "state-hash parity True" in r.stdout
     # a round without the section renders unchanged
-    r2 = _run("-m", "tools.obsreport", "BENCH_r05.json")
+    r2 = _run("-m", "tools.obsreport", history.bench[-1])
     assert r2.returncode == 0
     assert "streaming replay" not in r2.stdout
 
@@ -717,7 +762,7 @@ def test_obsreport_flight_renderer(tmp_path):
     assert r2.returncode == 2 and "cannot read flight dump" in r2.stderr
 
 
-def test_obsreport_cli(tmp_path):
+def test_obsreport_cli(tmp_path, history):
     """`python -m tools.obsreport` renders a bench JSON (raw or
     harness-wrapped) as the phase/variance/cache summary table, and
     reports pre-observability rounds' sections as absent."""
@@ -746,12 +791,12 @@ def test_obsreport_cli(tmp_path):
         assert "largest cross-rep spread: 'device'" in r.stdout
         assert "*device" in r.stdout and "precompute.hits" in r.stdout
     # historic rounds (no phases/variance/metrics) still render
-    r = _run("-m", "tools.obsreport", "BENCH_r05.json")
+    r = _run("-m", "tools.obsreport", history.bench[-1])
     assert r.returncode == 0, r.stderr
     assert "no 'variance' section" in r.stdout
-    # a MULTICHIP round renders the mesh section since ISSUE 11 — the
-    # committed red r05 has no MULTICHIP_OBS in its tail, and says so
-    r = _run("-m", "tools.obsreport", "MULTICHIP_r05.json")
+    # a MULTICHIP round renders the mesh section since ISSUE 11 — a
+    # red r05 with no MULTICHIP_OBS in its tail says so
+    r = _run("-m", "tools.obsreport", history.multichip[-1])
     assert r.returncode == 0, r.stderr
     assert "8 devices, rc=124 (RED)" in r.stdout
     assert "no MULTICHIP_OBS line" in r.stdout

@@ -207,7 +207,15 @@ def analysis_validate(db, rules, decode, backend_name: str, mode: str,
                       window: int, out, hdr_proofs: int = 2,
                       db_dir: str = None, snapshot_every: int = 0,
                       resume: bool = False, read_ahead: int = 4):
-    backend = make_backend(backend_name) if mode == "full" else None
+    # `backend_name` is a make_backend name, or a CryptoBackend a caller
+    # already built (chip_smoke.py replays twice on ONE instance: the
+    # compiled window programs live in the backend)
+    backend = None
+    if mode == "full":
+        if isinstance(backend_name, str):
+            backend = make_backend(backend_name)
+        else:
+            backend, backend_name = backend_name, backend_name.name
     hdr_count = hdr_proofs if callable(hdr_proofs) \
         else (lambda b, n=hdr_proofs: n)
     ext = rules.initial_state()
@@ -258,6 +266,12 @@ def analysis_validate(db, rules, decode, backend_name: str, mode: str,
     out.write(json.dumps({
         "analysis": "validate", "mode": mode,
         "backend": backend_name if mode == "full" else "n/a",
+        # a device backend says where it really ran: "jax" alone reads
+        # the same on the chip and on XLA:CPU
+        **({"backend_name": backend.name, "platform": backend.platform,
+            "device_kind": backend.device_kind,
+            "device_count": backend.device_count}
+           if hasattr(backend, "platform") else {}),
         "window": window if mode == "full" else None,
         "blocks": blocks, "proofs": proofs,
         "secs": round(secs, 3),
