@@ -22,6 +22,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Optional, Sequence
 
 from ..crypto.backend import CryptoBackend, default_backend
+from ..observe import spans as _spans
 from .header_validation import (
     HeaderError, HeaderState, validate_envelope, revalidate_header,
 )
@@ -138,20 +139,30 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
                     b: Any) -> tuple[list, ExtLedgerState]:
     """One block of the sequential pass: envelope + cheap checks + proof
     extraction + optimistic reapply.  Shared by the synchronous and the
-    pipelined drivers.  Raises on any sequential failure."""
+    pipelined drivers.  Raises on any sequential failure.
+
+    The header rules run in `seq.header` spans and the ledger pass in
+    `seq.body` spans.  The statements keep their order, since which
+    error a bad block raises first depends on it, so each name opens
+    more than once a block; a reader sums them by name."""
     header = getattr(b, "header", b)
-    view = ledger.forecast_view(st.ledger, header.slot)
-    validate_envelope(header, st.header, protocol)
-    ticked_dep = protocol.tick_chain_dep_state(
-        st.header.chain_dep_state, view, header.slot)
-    protocol.sequential_checks(ticked_dep, header, view)
-    ticked_ledger = ledger.tick(st.ledger, b.slot)
-    ledger.sequential_checks(ticked_ledger, b)
-    reqs = (protocol.extract_proofs(ticked_dep, header, view)
-            + ledger.extract_proofs(ticked_ledger, b))
-    return reqs, ExtLedgerState(
-        ledger.reapply_block(ticked_ledger, b),
-        revalidate_header(protocol, view, header, st.header))
+    with _spans.span("seq.header", cat="host-seq"):
+        view = ledger.forecast_view(st.ledger, header.slot)
+        validate_envelope(header, st.header, protocol)
+        ticked_dep = protocol.tick_chain_dep_state(
+            st.header.chain_dep_state, view, header.slot)
+        protocol.sequential_checks(ticked_dep, header, view)
+    with _spans.span("seq.body", cat="host-seq"):
+        ticked_ledger = ledger.tick(st.ledger, b.slot)
+        ledger.sequential_checks(ticked_ledger, b)
+    with _spans.span("seq.header", cat="host-seq"):
+        reqs = protocol.extract_proofs(ticked_dep, header, view)
+    with _spans.span("seq.body", cat="host-seq"):
+        reqs = reqs + ledger.extract_proofs(ticked_ledger, b)
+        ledger_state = ledger.reapply_block(ticked_ledger, b)
+    with _spans.span("seq.header", cat="host-seq"):
+        header_state = revalidate_header(protocol, view, header, st.header)
+    return reqs, ExtLedgerState(ledger_state, header_state)
 
 
 def validate_blocks_batched(

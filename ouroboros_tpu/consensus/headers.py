@@ -16,6 +16,7 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Optional, Sequence
 
 from ..chain.block import GENESIS_HASH
+from ..observe import spans as _spans
 from ..utils import cbor
 
 
@@ -130,30 +131,37 @@ class ProtocolBlock:
         tx_body_elems: when set, each tx item is a list whose first
         tx_body_elems elements form the tx BODY (ShelleyTx: 6 body
         fields + witnesses) — the body encoding is assembled from spans
-        and stashed in the tx's _cache for txid."""
-        obj = cbor.loads(raw)
-        block = cls.decode(obj, tx_decode=tx_decode)
-        try:
-            outer = cbor.list_spans(raw, 0)          # [header, [txs]]
-            hspan = outer[0]
-            helems = cbor.list_spans(raw, hspan[0])
-            fpairs_sp = cbor.list_spans(raw, helems[5][0])
-            hdr = block.header
-            hdr._cache["bytes"] = raw[hspan[0]:hspan[1]]
-            hdr._cache["spans"] = (
-                raw, helems,
-                list(zip((k for k, _v in hdr.fields), fpairs_sp)))
-            if tx_body_elems is not None and block.body:
-                for tx, tsp in zip(block.body,
-                                   cbor.list_spans(raw, outer[1][0])):
-                    telems = cbor.list_spans(raw, tsp[0])
-                    body_raw = (cbor._head(4, tx_body_elems) + raw[
-                        telems[0][0]:telems[tx_body_elems - 1][1]])
-                    cache = getattr(tx, "_cache", None)
-                    if cache is not None:
-                        cache["body_bytes"] = body_raw
-        except (cbor.CBORError, IndexError):
-            pass        # spans are an optimisation; decode stands alone
+        and stashed in the tx's _cache for txid.
+
+        Its three stages each run in a `disk` span a block
+        (decode.parse, decode.build, decode.slices), children of the
+        prefetcher's `stream.decode` in a streamed replay."""
+        with _spans.span("decode.parse", cat="disk"):
+            obj = cbor.loads(raw)
+        with _spans.span("decode.build", cat="disk"):
+            block = cls.decode(obj, tx_decode=tx_decode)
+        with _spans.span("decode.slices", cat="disk"):
+            try:
+                outer = cbor.list_spans(raw, 0)          # [header, [txs]]
+                hspan = outer[0]
+                helems = cbor.list_spans(raw, hspan[0])
+                fpairs_sp = cbor.list_spans(raw, helems[5][0])
+                hdr = block.header
+                hdr._cache["bytes"] = raw[hspan[0]:hspan[1]]
+                hdr._cache["spans"] = (
+                    raw, helems,
+                    list(zip((k for k, _v in hdr.fields), fpairs_sp)))
+                if tx_body_elems is not None and block.body:
+                    for tx, tsp in zip(block.body,
+                                       cbor.list_spans(raw, outer[1][0])):
+                        telems = cbor.list_spans(raw, tsp[0])
+                        body_raw = (cbor._head(4, tx_body_elems) + raw[
+                            telems[0][0]:telems[tx_body_elems - 1][1]])
+                        cache = getattr(tx, "_cache", None)
+                        if cache is not None:
+                            cache["body_bytes"] = body_raw
+            except (cbor.CBORError, IndexError):
+                pass    # spans are an optimisation; decode stands alone
         return block
 
     @property

@@ -57,6 +57,16 @@ thread — see precompute._insert / VrfBetaCache._store.  Span trees are per-thr
 ``window.host_seq``/``window.submit`` roots and the consumer's
 ``window.drain`` roots overlap in wall time — which is the point — and
 bench.py's ``overlap`` section measures exactly that hiding.
+``window.host_seq`` and ``pipeline.drain`` carry the window's index in
+the replay (``window=k``), so one window can be followed across both
+threads.
+
+The hand-offs are timed by three counters of whole microseconds, never
+by spans (observe/spans.py says why): ``producer_wait_blocks_us`` (the
+producer inside ``next_window()``, waiting for decoded blocks),
+``consumer_wait_us`` (the caller's thread with no submitted window to
+drain) and ``first_submit_us`` (producer start to the first submit,
+once a replay: the head during which the device has had nothing).
 """
 from __future__ import annotations
 
@@ -92,6 +102,14 @@ _STALLS = _metrics.counter("pipeline.producer_stalls")
 # hot-loop calls per window.
 _SUBMIT_DRAIN = _metrics.latency_histogram("pipeline.submit_drain_secs")
 _WINDOW_BLOCKS = _metrics.histogram("pipeline.window_blocks")
+# hand-off waits, whole microseconds on monotonic_now() (measured, so
+# unstable): what each thread of the replay spent with nothing to do
+_WAIT_BLOCKS_US = _metrics.counter("pipeline.producer_wait_blocks_us",
+                                   stable=False)
+_CONSUMER_WAIT_US = _metrics.counter("pipeline.consumer_wait_us",
+                                     stable=False)
+_FIRST_SUBMIT_US = _metrics.counter("pipeline.first_submit_us",
+                                    stable=False)
 
 # replay progress gauges (rendered live by tools/obsreport.py --live via
 # the scrape endpoint).  blocks_done / windows_in_flight / total are
@@ -109,11 +127,11 @@ _P_HIDDEN = _metrics.gauge("replay.progress.hidden_frac", stable=False)
 # live scrape of a sharded replay names its mesh
 _P_DEVICES = _metrics.gauge("replay.progress.devices")
 _P_PAD_WASTE = _metrics.gauge("replay.progress.padding_waste_frac")
-# streaming-replay disk overlap (ISSUE 15): disk+decode seconds the
-# prefetch thread spent while >= 1 window was in flight on device —
-# published live so a scrape of a streaming replay shows whether the
-# read-ahead is actually hiding the storage layer
-_S_HIDDEN = _metrics.gauge("replay.stream.hidden_frac", stable=False)
+
+
+def _us_since(t0: float) -> int:
+    """Whole microseconds on `monotonic_now()` since the reading `t0`."""
+    return int((_spans.monotonic_now() - t0) * 1e6)
 
 
 class ProgressTracker:
@@ -217,7 +235,6 @@ class ProgressTracker:
             self.blocks += n_blocks
             blocks, inflight = self.blocks, self._inflight
             host, hidden = self.host_secs, self.hidden_secs
-            disk, disk_hidden = self.disk_secs, self.disk_hidden_secs
         elapsed = now - self.t0
         rate = blocks / elapsed if elapsed > 0 else 0.0
         _P_BLOCKS.set(blocks)
@@ -226,8 +243,6 @@ class ProgressTracker:
         if self.total and rate > 0:
             _P_ETA.set(round(max(0, self.total - blocks) / rate, 3))
         _P_HIDDEN.set(round(hidden / host, 4) if host > 0 else 0.0)
-        if disk > 0:
-            _S_HIDDEN.set(round(disk_hidden / disk, 4))
 
 
 class _Shared:
@@ -241,7 +256,8 @@ class _Shared:
 
     def __init__(self):
         self.cond = threading.Condition()
-        # (start, sub, reqs, owner, n_seq, t_submit, state_after, point)
+        # (start, sub, reqs, owner, n_seq, t_submit, state_after, point,
+        #  window index)
         self.pending: deque = deque()
         self.progress: Optional[ProgressTracker] = None
         self.submitted = 0
@@ -260,9 +276,13 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
     window, permit-gated to the beta-carry depth."""
     protocol, ledger = ext_rules.protocol, ext_rules.ledger
     submit = backend.submit_window
+    # producer start; None once the first submit is made
+    t_first: Optional[float] = _spans.monotonic_now()
 
     def next_window():
+        t = _spans.monotonic_now()
         w = list(itertools.islice(block_iter, window))
+        _WAIT_BLOCKS_US.inc(_us_since(t))
         return w or None
 
     try:
@@ -277,10 +297,12 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
         if ahead:
             # windows 0 and 1 ride a plain prefetch; window w's device
             # call then carries window w+2's betas
-            protocol.prefetch_window(
-                [h for hs, _w in list(ahead)[:2] for h in hs], backend)
+            with _spans.span("pipeline.beta_prefetch", cat="device"):
+                protocol.prefetch_window(
+                    [h for hs, _w in list(ahead)[:2] for h in hs], backend)
 
         st = ext_state
+        k = -1                          # index of the window in the replay
         while ahead:
             with shared.cond:
                 if not (shared.stop
@@ -292,6 +314,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                             shared.submitted - shared.drained < DEPTH)
                 if shared.stop:
                     return
+            k += 1
             headers_w, blk_window = ahead.popleft()
             nxt = next_window()
             if nxt is not None:
@@ -304,7 +327,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             progress = shared.progress
             if progress is not None:
                 progress.host_begin()
-            with _spans.span("window.host_seq", cat="host-seq"):
+            with _spans.span("window.host_seq", cat="host-seq", window=k):
                 for i, b in enumerate(blk_window):
                     try:
                         rs, st = _seq_block_step(protocol, ledger, st, b)
@@ -330,6 +353,9 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                            if len(ahead) > 1 and seq_error is None else ())
             next_proofs = [p for p in next_proofs
                            if p not in GLOBAL_BETA_CACHE]
+            if t_first is not None:
+                _FIRST_SUBMIT_US.inc(_us_since(t_first))
+                t_first = None
             sub = (submit(reqs, next_proofs, fold=True) if fold
                    else submit(reqs, next_proofs))
             _WINDOWS.inc()
@@ -353,7 +379,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             with shared.cond:
                 shared.pending.append(
                     (shared.seq_done, sub, reqs, owner, n_seq_w,
-                     _spans.monotonic_now(), st, pt))
+                     _spans.monotonic_now(), st, pt, k))
                 shared.submitted += 1
                 shared.seq_done += n_seq_w
                 shared.cond.notify_all()
@@ -373,7 +399,7 @@ def _drain(backend, entry) -> tuple:
     """Finish one window's device call; install its carried betas.
     Returns (error, n_valid): error None when every proof held, else
     n_valid is the global index of the first bad block."""
-    start, sub, reqs, owner, n_seq_w, t_submit, _st, _pt = entry
+    start, sub, reqs, owner, n_seq_w, t_submit, _st, _pt, k = entry
     # named distinctly from jax_backend's inner "window.drain" span:
     # bench._rep_overlap pairs submits and drains positionally by name,
     # and a second same-named interval per drain would break the zip.
@@ -381,7 +407,7 @@ def _drain(backend, entry) -> tuple:
     # recorder must show drains even on stub/CPU backends); phase
     # totals stay correct because self-time attribution subtracts the
     # nested inner span.
-    with _spans.span("pipeline.drain", cat="device"):
+    with _spans.span("pipeline.drain", cat="device", window=k):
         ok, betas = backend.finish_window(sub)
     _SUBMIT_DRAIN.observe(_spans.monotonic_now() - t_submit)
     if betas:
@@ -455,8 +481,10 @@ def replay_threaded(ext_rules, blocks, ext_state, backend,
     try:
         while True:
             with shared.cond:
+                t_wait = _spans.monotonic_now()
                 shared.cond.wait_for(
                     lambda: shared.pending or shared.done)
+                _CONSUMER_WAIT_US.inc(_us_since(t_wait))
                 if not shared.pending:
                     break               # done and fully drained
                 entry = shared.pending.popleft()

@@ -587,7 +587,12 @@ class JaxBackend(CryptoBackend):
         WindowVerdict scalar pair instead of the boolean vector.  The
         big ladder composite is SHARED between both modes (same program,
         same autotuned choice, same compile), so a fold caller costs one
-        extra small compile, not a second composite."""
+        extra small compile, not a second composite.
+
+        `window.submit` holds one span a stage: submit.split,
+        submit.pack_ed (key tables included), submit.pack_vrf (beta
+        words included), submit.pack_kes, submit.dispatch (the choice
+        and the composite call) and, folding, submit.fold."""
         with _spans.span("window.submit", cat="dispatch"):
             return self._submit_window(reqs, next_beta_proofs, fold)
 
@@ -595,42 +600,49 @@ class JaxBackend(CryptoBackend):
                        fold: bool = False):
         from . import vrf_jax
         _WINDOWS.inc()
-        (ed_reqs, ed_owner, vrf_reqs, vrf_owner,
-         kes_msgs, kes_expects, kes_checks, n) = \
-            self._split_mixed_device(reqs)
-        beta_proofs = list(dict.fromkeys(next_beta_proofs))
+        with _spans.span("submit.split", cat="dispatch"):
+            (ed_reqs, ed_owner, vrf_reqs, vrf_owner,
+             kes_msgs, kes_expects, kes_checks, n) = \
+                self._split_mixed_device(reqs)
+            beta_proofs = list(dict.fromkeys(next_beta_proofs))
         ed_state = vrf_state = beta_state = None
         ne = nv = nb = nk = 0
         ed_args = vrf_args = beta_args = kes_args = None
-        if ed_reqs:
-            ne = self._pad(len(ed_reqs))
-            ed_args, parse_ok = self._prep_ed(ed_reqs, ne)
-            ed_state = (None, parse_ok)
-        if vrf_reqs:
-            nv = self._pad(len(vrf_reqs))
-            vrf_args, masks = self._prep_vrf(vrf_reqs, nv)
-            vrf_state = (None,) + masks
-        if beta_proofs:
-            nb = self._pad(len(beta_proofs))
-            padded = beta_proofs + [b"\x00" * 80] * (nb - len(beta_proofs))
-            (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(padded)
-            beta_state = (decode_ok,)
-            beta_args = (self._dev(Gw),
-                         self._dev(signG.reshape(1, -1)))
-        if kes_msgs:
-            nk = self._pad(len(kes_msgs))
-            kes_args = self._prep_kes_hash(kes_msgs, kes_expects, nk)
+        with _spans.span("submit.pack_ed", cat="dispatch"):
+            if ed_reqs:
+                ne = self._pad(len(ed_reqs))
+                ed_args, parse_ok = self._prep_ed(ed_reqs, ne)
+                ed_state = (None, parse_ok)
+        with _spans.span("submit.pack_vrf", cat="dispatch"):
+            if vrf_reqs:
+                nv = self._pad(len(vrf_reqs))
+                vrf_args, masks = self._prep_vrf(vrf_reqs, nv)
+                vrf_state = (None,) + masks
+            if beta_proofs:
+                nb = self._pad(len(beta_proofs))
+                padded = beta_proofs + [b"\x00" * 80] * (
+                    nb - len(beta_proofs))
+                (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(
+                    padded)
+                beta_state = (decode_ok,)
+                beta_args = (self._dev(Gw),
+                             self._dev(signG.reshape(1, -1)))
+        with _spans.span("submit.pack_kes", cat="dispatch"):
+            if kes_msgs:
+                nk = self._pad(len(kes_msgs))
+                kes_args = self._prep_kes_hash(kes_msgs, kes_expects, nk)
         self._note_padding(
             len(ed_reqs) + len(vrf_reqs) + len(beta_proofs) + len(kes_msgs),
             ne + nv + nb + nk)
-        if (ed_args is None and vrf_args is None and beta_args is None
-                and kes_args is None):
-            packed = None
-        else:
-            allp = self._window_choice(ne, nv, nb, nk, ed_args, vrf_args,
-                                       beta_args, kes_args)
-            packed = self._window_composite(ne, nv, nb, nk, allp)(
-                ed_args, vrf_args, beta_args, kes_args)
+        with _spans.span("submit.dispatch", cat="dispatch"):
+            if (ed_args is None and vrf_args is None and beta_args is None
+                    and kes_args is None):
+                packed = None
+            else:
+                allp = self._window_choice(ne, nv, nb, nk, ed_args,
+                                           vrf_args, beta_args, kes_args)
+                packed = self._window_composite(ne, nv, nb, nk, allp)(
+                    ed_args, vrf_args, beta_args, kes_args)
         state = {"packed": packed, "n": n,
                  "ed": ed_state, "ed_owner": ed_owner, "ne": ne,
                  "vrf": vrf_state, "vrf_owner": vrf_owner,
@@ -639,7 +651,8 @@ class JaxBackend(CryptoBackend):
                  "kes_checks": kes_checks, "nk": nk,
                  "kes_n": len(kes_msgs)}
         if fold:
-            self._attach_fold(state, reqs)
+            with _spans.span("submit.fold", cat="dispatch"):
+                self._attach_fold(state, reqs)
         return state
 
     def _attach_fold(self, state, reqs) -> None:

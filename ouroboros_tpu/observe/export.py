@@ -7,8 +7,8 @@ Three formats, one per audience:
   dot->underscore mangled, sorted — deterministic for a fixed registry
   state).
 - `chrome_trace(spans)` — span trees as chrome://tracing / Perfetto
-  `trace_event` JSON ("X" complete events, microsecond timestamps).
-  Load via chrome://tracing "Load" or ui.perfetto.dev.
+  `trace_event` JSON ("X" complete events, microsecond timestamps, one
+  row per thread).  Load via chrome://tracing "Load" or ui.perfetto.dev.
 - `events_jsonl(events)` — typed utils/tracer.py events as JSON lines:
   one object per event carrying the dataclass type name and its fields
   (bytes hex-encoded), so a log pipeline gets the TYPED schema instead
@@ -26,6 +26,8 @@ from .metrics import Histogram, MetricsRegistry, quantile_from_buckets
 from .spans import Span
 
 PROM_PREFIX = "ouro_"
+#: chrome-trace row of a span that names no thread (built by hand)
+NO_THREAD = "(no thread)"
 
 
 def _split_labels(name: str) -> tuple:
@@ -151,15 +153,18 @@ def prom_histogram_quantiles(parsed: dict, base: str,
 # --- chrome://tracing -------------------------------------------------------
 
 def chrome_trace(spans: Iterable[Span], pid: int = 1) -> dict:
-    """`trace_event` JSON for a forest of span trees.  Each category gets
-    its own tid row so the five replay phases render as parallel tracks;
-    timestamps are the spans' monotonic clock readings in microseconds
-    (chrome only cares about relative position)."""
+    """`trace_event` JSON for a forest of span trees.  Each THREAD that
+    opened a span gets its own tid row, named after it, so the streamed
+    replay's prefetcher, producer and consumer render as three parallel
+    tracks; a span's category goes to the event's `cat` field and its
+    `meta` (the window's index) to `args`.  Timestamps are the spans'
+    monotonic clock readings in microseconds (chrome only cares about
+    relative position)."""
     events: List[dict] = []
     tids: dict = {}
 
     def emit(sp: Span):
-        tid = tids.setdefault(sp.cat, len(tids) + 1)
+        tid = tids.setdefault(sp.thread or NO_THREAD, len(tids) + 1)
         ev = {"name": sp.name, "cat": sp.cat, "ph": "X",
               "ts": round(sp.t0 * 1e6, 3),
               "dur": round(sp.duration * 1e6, 3),
@@ -173,7 +178,7 @@ def chrome_trace(spans: Iterable[Span], pid: int = 1) -> dict:
     for sp in spans:
         emit(sp)
     meta = [{"name": "thread_name", "ph": "M", "pid": pid, "tid": tid,
-             "args": {"name": cat}} for cat, tid in sorted(
+             "args": {"name": thread}} for thread, tid in sorted(
                  tids.items(), key=lambda kv: kv[1])]
     return {"traceEvents": meta + events, "displayTimeUnit": "ms"}
 

@@ -11,10 +11,12 @@ pipelined replay's producer and consumer record concurrently without a
 lock), and a failure path dumps the ring as
 
 - ``flight.trace.json`` — the span entries as chrome://tracing
-  `trace_event` JSON (load via chrome://tracing or ui.perfetto.dev);
+  `trace_event` JSON, one row per thread (load via chrome://tracing or
+  ui.perfetto.dev);
 - ``flight.jsonl``      — every ring entry in arrival order, one JSON
   object per line, ``kind`` ∈ {span, metric, event} (a header line
-  leads with the dump reason and entry count).
+  leads with the dump reason and entry count); a span line names the
+  thread that opened it and, under ``args``, its window's index.
 
 Cost model: DISARMED is one attribute read per instrument write and per
 span close (`flight is None`); ARMED adds one tuple build + deque
@@ -105,7 +107,8 @@ class FlightRecorder:
     # -- recording hooks (called from metrics/spans while armed) -------------
     def span(self, sp: _spans.Span) -> None:
         self._ring.append(
-            (sp.t1, "span", sp.name, sp.cat, sp.t0, sp.t1))
+            (sp.t1, "span", sp.name, sp.cat, sp.t0, sp.t1, sp.thread,
+             sp.meta))
 
     def metric(self, name: str, op: str, v) -> None:
         self._ring.append((_spans.monotonic_now(), "metric", name, op, v))
@@ -135,7 +138,7 @@ class FlightRecorder:
         out = []
         for e in entries:
             if e[1] == "span":
-                sp = _spans.Span(e[2], e[3], e[4])
+                sp = _spans.Span(e[2], e[3], e[4], e[6], e[7])
                 sp.t1 = e[5]
                 out.append(sp)
         return out
@@ -167,8 +170,12 @@ class FlightRecorder:
     def _record(e: tuple) -> dict:
         t, kind = round(e[0], 9), e[1]
         if kind == "span":
-            return {"t": t, "kind": kind, "name": e[2], "cat": e[3],
-                    "t0": round(e[4], 9), "t1": round(e[5], 9)}
+            rec = {"t": t, "kind": kind, "name": e[2], "cat": e[3],
+                   "t0": round(e[4], 9), "t1": round(e[5], 9),
+                   "thread": e[6]}
+            if e[7]:
+                rec["args"] = e[7]
+            return rec
         if kind == "metric":
             return {"t": t, "kind": kind, "name": e[2], "op": e[3],
                     "v": e[4]}

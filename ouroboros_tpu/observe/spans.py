@@ -40,6 +40,38 @@ keeps one open-span stack PER THREAD (a producer's `window.host_seq`
 must never adopt the consumer's `window.drain` as a child just because
 they overlap in wall time).  Completed roots land in one shared,
 lock-guarded list so a drain sees both threads' trees.
+
+Who and which: every span carries `thread`, the name of the thread that
+opened it (the streamed replay's three are `ouro-stream-prefetch`,
+`ouro-replay-producer` and the caller's), and `span(..., window=k)`
+puts keyword arguments into `meta`.  `window.host_seq` (producer) and
+`pipeline.drain` (consumer) carry the window's index in the replay, so
+one window's work can be followed across both threads;
+`window.submit` and `stream.snapshot` follow theirs on the same
+thread.  `export.chrome_trace` draws one row per thread and shows
+`meta` as the event's `args`; the flight recorder's dump keeps both.
+
+The streamed replay's stage spans (each nests under the pipeline-stage
+span named first; cat in brackets):
+
+    stream.decode   decode.parse, decode.build, decode.slices [disk],
+                    one each a block (consensus/headers.py)
+    window.host_seq seq.header, seq.body [host-seq], the header rules
+                    and the ledger pass of one block, interleaved as
+                    `_seq_block_step` runs them (consensus/batch.py)
+    window.submit   submit.split, submit.pack_ed, submit.pack_vrf,
+                    submit.pack_kes, submit.dispatch, submit.fold
+                    [dispatch], one each a window (crypto/jax_backend.py)
+    (a root)        pipeline.beta_prefetch [device], the beta round
+                    trip before window 0 (consensus/pipeline.py)
+
+Waits are counters, not spans: `pipeline.producer_wait_blocks_us`,
+`pipeline.consumer_wait_us` and `pipeline.first_submit_us`
+(consensus/pipeline.py) hold whole microseconds.  A consumer of spans
+that gives a piece of device idle time to the open span that started
+last would hand it to a wait span opened after the work it waits on,
+and take it from that work; as gated registry counters the waits also
+reach the scrape endpoint with span recording off.
 """
 from __future__ import annotations
 
@@ -76,17 +108,22 @@ def device_fence() -> None:
 class Span:
     """One completed (or in-flight) interval.  `t0`/`t1` are clock
     readings from `monotonic_now`; `children` are spans closed while
-    this one was the innermost open span."""
+    this one was the innermost open span; `thread` is the name of the
+    thread that opened it; `meta` holds the keyword arguments `span()`
+    was given (`window=k`), or None."""
 
-    __slots__ = ("name", "cat", "t0", "t1", "children", "meta")
+    __slots__ = ("name", "cat", "t0", "t1", "children", "meta", "thread")
 
-    def __init__(self, name: str, cat: str, t0: float):
+    def __init__(self, name: str, cat: str, t0: float,
+                 thread: Optional[str] = None,
+                 meta: Optional[dict] = None):
         self.name = name
         self.cat = cat
         self.t0 = t0
         self.t1: Optional[float] = None
         self.children: List["Span"] = []
-        self.meta: Optional[dict] = None
+        self.meta = meta
+        self.thread = thread
 
     @property
     def duration(self) -> float:
@@ -119,20 +156,21 @@ _NULL = _NullSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_cat", "_fence", "_span")
+    __slots__ = ("_rec", "_name", "_cat", "_fence", "_meta", "_span")
 
     def __init__(self, rec: "SpanRecorder", name: str, cat: str,
-                 fence: bool):
+                 fence: bool, meta: Optional[dict] = None):
         self._rec = rec
         self._name = name
         self._cat = cat
         self._fence = fence
+        self._meta = meta
         self._span: Optional[Span] = None
 
     def __enter__(self) -> Span:
         if self._fence:
             device_fence()
-        self._span = self._rec._open(self._name, self._cat)
+        self._span = self._rec._open(self._name, self._cat, self._meta)
         return self._span
 
     def __exit__(self, *exc):
@@ -182,12 +220,14 @@ class SpanRecorder:
         return st
 
     # -- the public surface ------------------------------------------------
-    def span(self, name: str, cat: str = "host-seq", fence: bool = False):
-        """Context manager timing one interval.  Near-free when the
-        recorder is disabled (returns a shared null CM)."""
+    def span(self, name: str, cat: str = "host-seq", fence: bool = False,
+             **meta):
+        """Context manager timing one interval; keyword arguments land
+        in the span's `meta`.  Near-free when the recorder is disabled
+        (returns a shared null CM)."""
         if not self.enabled:
             return _NULL
-        return _LiveSpan(self, name, cat, fence)
+        return _LiveSpan(self, name, cat, fence, meta or None)
 
     def enable(self) -> None:
         self.enabled = True
@@ -209,8 +249,10 @@ class SpanRecorder:
             self.dropped = 0
 
     # -- recording ---------------------------------------------------------
-    def _open(self, name: str, cat: str) -> Span:
-        sp = Span(name, cat, monotonic_now())
+    def _open(self, name: str, cat: str,
+              meta: Optional[dict] = None) -> Span:
+        thread = threading.current_thread().name
+        sp = Span(name, cat, monotonic_now(), thread, meta)
         self._stack.append(sp)
         return sp
 
@@ -262,13 +304,13 @@ def recorder() -> SpanRecorder:
     return RECORDER
 
 
-def span(name: str, cat: str = "host-seq", fence: bool = False):
+def span(name: str, cat: str = "host-seq", fence: bool = False, **meta):
     """observe.spans.span("window.drain", cat="device") — module-level
     convenience over the process-wide recorder."""
     rec = RECORDER
     if not rec.enabled:
         return _NULL
-    return _LiveSpan(rec, name, cat, fence)
+    return _LiveSpan(rec, name, cat, fence, meta or None)
 
 
 def enabled() -> bool:

@@ -18,6 +18,7 @@ import io
 import json
 import os
 import sys
+import threading
 
 import pytest
 
@@ -289,18 +290,22 @@ def _golden_registry() -> MetricsRegistry:
 
 
 def _golden_spans():
-    rep = Span("rep", "host-seq", 0.0)
+    """A consumer-thread root with a drain that carries its window's
+    index, and a producer-thread root with a compile inside."""
+    rep = Span("rep", "host-seq", 0.0, "MainThread")
     rep.t1 = 10.0
-    sub = Span("window.submit", "dispatch", 1.0)
+    sub = Span("window.submit", "dispatch", 1.0, "ouro-replay-producer")
     sub.t1 = 3.0
-    comp = Span("window.composite(8,8,2,0)", "compile", 1.5)
+    comp = Span("window.composite(8,8,2,0)", "compile", 1.5,
+                "ouro-replay-producer")
     comp.t1 = 2.5
     comp.meta = {"ne": 8}
-    drain = Span("window.drain", "device", 3.0)
+    drain = Span("pipeline.drain", "device", 3.0, "MainThread",
+                 {"window": 0})
     drain.t1 = 6.0
     sub.children.append(comp)
-    rep.children.extend([sub, drain])
-    return [rep]
+    rep.children.append(drain)
+    return [rep, sub]
 
 
 def _golden_events():
@@ -342,6 +347,12 @@ def test_prometheus_exposition_golden_and_roundtrip():
     assert parsed['ouro_batch_size_bucket{le="4"}'] == 3.0
 
 
+def _rows(doc) -> dict:
+    """tid -> row name of a chrome-trace document."""
+    return {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+            if e["ph"] == "M"}
+
+
 def test_chrome_trace_golden_and_structure():
     doc = export.chrome_trace(_golden_spans())
     _check_golden("spans.trace.json",
@@ -349,16 +360,78 @@ def test_chrome_trace_golden_and_structure():
     events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     names = {e["name"] for e in events}
     assert names == {"rep", "window.submit", "window.composite(8,8,2,0)",
-                     "window.drain"}
-    # one tid row per category so phases render as parallel tracks
-    by_cat = {e["cat"]: e["tid"] for e in events}
-    assert len(set(by_cat.values())) == len(by_cat)
-    meta = [e for e in doc["traceEvents"] if e["ph"] == "M"]
-    assert {m["args"]["name"] for m in meta} == set(by_cat)
-    comp = next(e for e in events
-                if e["name"] == "window.composite(8,8,2,0)")
+                     "pipeline.drain"}
+    # one tid row per THREAD, so the replay's threads render as parallel
+    # tracks; the category is the event's own `cat`
+    rows = _rows(doc)
+    assert sorted(rows.values()) == ["MainThread", "ouro-replay-producer"]
+    by_name = {e["name"]: e for e in events}
+    assert {n: rows[e["tid"]] for n, e in by_name.items()} == {
+        "rep": "MainThread", "pipeline.drain": "MainThread",
+        "window.submit": "ouro-replay-producer",
+        "window.composite(8,8,2,0)": "ouro-replay-producer"}
+    assert {n: e["cat"] for n, e in by_name.items()} == {
+        "rep": "host-seq", "pipeline.drain": "device",
+        "window.submit": "dispatch",
+        "window.composite(8,8,2,0)": "compile"}
+    comp = by_name["window.composite(8,8,2,0)"]
     assert comp["ts"] == 1.5e6 and comp["dur"] == 1e6
     assert comp["args"] == {"ne": 8}
+    assert by_name["pipeline.drain"]["args"] == {"window": 0}
+    assert "args" not in by_name["rep"]
+
+
+@pytest.mark.parametrize("threads, want_rows", [
+    # every span names its thread: a row each, in order of first sight
+    (("a", "b", "c"), {"x": "a", "y": "b", "z": "c"}),
+    # two categories on one thread share its row (rows were by category)
+    (("a", "a", "b"), {"x": "a", "y": "a", "z": "b"}),
+    # spans built by hand name none: they share the row of the unnamed
+    ((None, None, "b"), {"x": export.NO_THREAD, "y": export.NO_THREAD,
+                         "z": "b"}),
+])
+def test_chrome_trace_rows_by_thread(threads, want_rows):
+    x = Span("x", "host-seq", 0.0, threads[0])
+    y = Span("y", "dispatch", 1.0, threads[1])
+    z = Span("z", "device", 2.0, threads[2])
+    for sp in (x, y, z):
+        sp.t1 = sp.t0 + 1.0
+    x.children.append(y)
+    doc = export.chrome_trace([x, z])
+    rows = _rows(doc)
+    got = {e["name"]: rows[e["tid"]] for e in doc["traceEvents"]
+           if e["ph"] == "X"}
+    assert got == want_rows
+    assert len(set(rows.values())) == len(rows) == len(
+        set(want_rows.values()))
+    assert {e["name"]: e["cat"] for e in doc["traceEvents"]
+            if e["ph"] == "X"} == {"x": "host-seq", "y": "dispatch",
+                                   "z": "device"}
+
+
+def test_recorder_stamps_thread_and_meta_only_when_recording():
+    rec = SpanRecorder(enabled=True)
+    seen = {}
+
+    def work():
+        with rec.span("w", cat="disk", window=3) as sp:
+            seen["sp"] = sp
+
+    t = threading.Thread(target=work, name="ouro-test-thread")
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    with rec.span("m") as main_sp:
+        pass
+    assert seen["sp"].thread == "ouro-test-thread"
+    assert seen["sp"].meta == {"window": 3}
+    assert main_sp.thread == threading.current_thread().name
+    assert main_sp.meta is None
+    rec.disable()
+    with rec.span("off", window=1) as nothing:
+        pass
+    assert nothing is None                 # the shared null span
+    assert [r.name for r in rec.drain()] == ["w", "m"]
 
 
 def test_events_jsonl_golden_and_typed_schema():
@@ -688,10 +761,15 @@ def test_flight_ring_is_bounded():
     fl.disarm()
 
 
-def test_flight_dump_golden_and_byte_identical_replay(tmp_path):
+def test_flight_dump_golden_and_byte_identical_replay(tmp_path,
+                                                      monkeypatch):
     """A seeded sim failure dumps byte-identical flight files on every
     replay — virtual timestamps only.  Golden regen:
     OURO_REGEN_GOLDEN=1 pytest tests/test_observe.py"""
+    # a span line names its thread: whatever runs this test is the main
+    # thread of the golden bytes
+    monkeypatch.setattr(threading.current_thread(), "name", "MainThread")
+
     def one_run(d):
         fl, reg, rec = _private_flight()
         fl.arm()
@@ -700,7 +778,7 @@ def test_flight_dump_golden_and_byte_identical_replay(tmp_path):
             with rec.span("window.host_seq", cat="host-seq"):
                 await sim.sleep(1.5)
             reg.counter("replay.windows").inc()
-            with rec.span("window.drain", cat="device"):
+            with rec.span("pipeline.drain", cat="device", window=0):
                 await sim.sleep(0.25)
             fl.note(TraceForgeEvent(slot=7, outcome="error"))
 
@@ -720,8 +798,17 @@ def test_flight_dump_golden_and_byte_identical_replay(tmp_path):
     _check_golden("flight.jsonl", text1)
     # the chrome dump loads as a trace_event document
     doc = json.load(open(out1["trace"]))
-    names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
-    assert names == {"window.host_seq", "window.drain"}
+    events = {e["name"]: e for e in doc["traceEvents"]
+              if e.get("ph") == "X"}
+    assert set(events) == {"window.host_seq", "pipeline.drain"}
+    # the dump keeps who opened a span and which window it was
+    assert _rows(doc) == {1: "MainThread"}
+    assert events["pipeline.drain"]["args"] == {"window": 0}
+    spans_l = [json.loads(ln) for ln in text1.splitlines()[1:]
+               if json.loads(ln)["kind"] == "span"]
+    assert [(ln["name"], ln["thread"], ln.get("args")) for ln in spans_l] \
+        == [("window.host_seq", "MainThread", None),
+            ("pipeline.drain", "MainThread", {"window": 0})]
     # header line carries the reason + count
     head = json.loads(text1.splitlines()[0])
     assert head["kind"] == "flight" and "forced failure" in head["reason"]

@@ -1,0 +1,410 @@
+"""The streamed replay measured from inside (ISSUE 25): the stage spans
+of decode, host pass and submit, the wait counters at the hand-offs, the
+thread and window fields of a span, and the benchmark's readers of them.
+
+One tiny Shelley chain on disk, streamed through the real three threads
+(storage/stream.py prefetcher -> consensus/pipeline.py producer ->
+caller) and the real `JaxBackend.submit_window` host path.  Only the two
+device programs are stand-ins computed on the host (a window composite
+costs minutes of XLA:CPU tracing, see test_replay_pipeline.py), so every
+span of the submit path opens where the chip run opens it.
+"""
+import glob
+import importlib.util
+import json
+import os
+import shutil
+import subprocess
+import sys
+import threading
+
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+pytest.importorskip("jax")
+
+from ouroboros_tpu import observe                                # noqa: E402
+from ouroboros_tpu.consensus import pipeline                      # noqa: E402
+from ouroboros_tpu.crypto import edwards, vrf_ref                 # noqa: E402
+from ouroboros_tpu.crypto.backend import (                        # noqa: E402
+    GLOBAL_BETA_CACHE, OpensslBackend,
+)
+from ouroboros_tpu.crypto.jax_backend import FOLD_SENT, JaxBackend  # noqa: E402
+from ouroboros_tpu.crypto.precompute import (                     # noqa: E402
+    GLOBAL_PRECOMPUTE_CACHE,
+)
+from ouroboros_tpu.observe import spans as spans_mod              # noqa: E402
+from ouroboros_tpu.storage import (                               # noqa: E402
+    DiskPolicy, IoFS, StreamConfig, StreamingReplayEngine,
+)
+from ouroboros_tpu.storage.stream import THREAD_NAME as PREFETCH   # noqa: E402
+
+PRODUCER = "ouro-replay-producer"
+BLOCKS, WINDOW = 24, 8
+N_WINDOWS = BLOCKS // WINDOW
+
+# table 1 of the issue: span -> (cat, parent, thread)
+STAGES = {
+    "decode.parse": ("disk", "stream.decode", PREFETCH),
+    "decode.build": ("disk", "stream.decode", PREFETCH),
+    "decode.slices": ("disk", "stream.decode", PREFETCH),
+    "seq.header": ("host-seq", "window.host_seq", PRODUCER),
+    "seq.body": ("host-seq", "window.host_seq", PRODUCER),
+    "submit.split": ("dispatch", "window.submit", PRODUCER),
+    "submit.pack_ed": ("dispatch", "window.submit", PRODUCER),
+    "submit.pack_vrf": ("dispatch", "window.submit", PRODUCER),
+    "submit.pack_kes": ("dispatch", "window.submit", PRODUCER),
+    "submit.dispatch": ("dispatch", "window.submit", PRODUCER),
+    "submit.fold": ("dispatch", "window.submit", PRODUCER),
+    "pipeline.beta_prefetch": ("device", None, PRODUCER),
+}
+WAITS = ("pipeline.producer_wait_blocks_us", "pipeline.consumer_wait_us",
+         "pipeline.first_submit_us")
+
+
+class HostProgramsBackend(JaxBackend):
+    """JaxBackend with its two device programs (window composite, fold)
+    computed on the host by the OpenSSL reference.  `submit_window`,
+    `_submit_window`, the split, the three packers, the choice and
+    `_attach_fold` are the real ones; so is `finish_window`."""
+
+    def __init__(self):
+        super().__init__(min_bucket=16, use_pallas=False, autotune=False)
+        self._cpu = OpensslBackend()
+        self._asked = ([], [])
+        self.vrf_betas_batch = self._cpu.vrf_betas_batch
+
+    def submit_window(self, reqs, next_beta_proofs=(), fold=False):
+        self._asked = (list(reqs), list(dict.fromkeys(next_beta_proofs)))
+        return super().submit_window(reqs, next_beta_proofs, fold)
+
+    def _window_composite(self, ne, nv, nb, nk, pallas):
+        return lambda *_args: self._asked
+
+    def _fold_program(self, ne, nv, nb, nk):
+        def fold(asked, _ed_own, _vrf_own, _gamma_b, _c_b):
+            reqs, proofs = asked
+            ok = self._cpu.verify_mixed(reqs)
+            bad = ok.index(False) if False in ok else FOLD_SENT
+            rows = np.zeros((nb, 33), np.uint8)
+            for j, pi in enumerate(proofs):
+                gamma = vrf_ref.decode_proof(pi)[0]
+                rows[j, :32] = np.frombuffer(edwards.compress(
+                    edwards.scalar_mult(8, gamma)), np.uint8)
+                rows[j, 32] = 1
+            return np.concatenate([
+                np.frombuffer(bad.to_bytes(4, "little"), np.uint8),
+                np.ones(nk, np.uint8), rows.reshape(-1)])
+        return fold
+
+
+@pytest.fixture(scope="module")
+def chain_dir(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("tracedb"))
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "db_synth.py"),
+         "--out", d, "--protocol", "shelley", "--blocks", str(BLOCKS),
+         "--txs-per-block", "3", "--pools", "2", "--f", "4/5",
+         "--epoch-length", "500", "--kes-depth", "4", "--chunk-size", "6"],
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return d
+
+
+@pytest.fixture()
+def host_tables(monkeypatch):
+    """The per-key tables are a device fill too (`a128_kernel`): a
+    stand-in marks every key known, and the global caches this replay
+    fills with it are put back as they were."""
+    from ouroboros_tpu.crypto import ed25519_jax as EJ
+    from ouroboros_tpu.crypto import field_jax as F
+
+    def a128(yA, _signA):
+        n = np.asarray(yA).shape[1]
+        zero = F.pack([0] * n)
+        return zero, zero, zero, np.ones(n, bool)
+
+    monkeypatch.setattr(EJ, "a128_kernel", a128)
+    cache = GLOBAL_PRECOMPUTE_CACHE
+    saved = (cache._c.copy(), cache._kes.copy())
+    try:
+        yield
+    finally:
+        cache.clear()
+        cache._c.update(saved[0])
+        cache._kes.update(saved[1])
+
+
+def _counters() -> dict:
+    return {i.name: i.value for i in observe.REGISTRY.instruments()
+            if i.kind == "counter"}
+
+
+def _replay(chain_dir, db_dir, backend):
+    """One streamed replay as `db_analyser --analysis validate` makes it
+    (cold caches, snapshots on); (stats, state hash, counter deltas)."""
+    from tools import db_analyser
+    shutil.copytree(chain_dir, db_dir)
+    db, rules, decode, _cfg = db_analyser.load_db(db_dir)
+    GLOBAL_BETA_CACHE.clear()
+    GLOBAL_PRECOMPUTE_CACHE.clear()
+    engine = StreamingReplayEngine(
+        IoFS(db_dir), db, rules, decode, backend=backend,
+        config=StreamConfig(window=WINDOW, read_ahead=4, resume=False,
+                            policy=DiskPolicy(snapshot_interval_slots=10)))
+    c0 = _counters()
+    res = engine.replay()
+    c1 = _counters()
+    assert res.all_valid, res.error
+    assert res.n_valid == BLOCKS
+    return (res.stats, res.final_state.ledger.state_hash(),
+            {k: c1[k] - c0.get(k, 0) for k in c1})
+
+
+@pytest.fixture()
+def traced(chain_dir, tmp_path, host_tables):
+    """(roots, stats, state hash, counter deltas) of one replay with
+    span recording on."""
+    rec = spans_mod.RECORDER
+    assert not rec.enabled
+    rec.drain()
+    rec.enable()
+    try:
+        stats, state_hash, delta = _replay(
+            chain_dir, str(tmp_path / "on"), HostProgramsBackend())
+    finally:
+        rec.disable()
+    return rec.drain(), stats, state_hash, delta
+
+
+def _with_parents(roots):
+    """(span, parent or None) over a forest."""
+    def walk(sp, parent):
+        yield sp, parent
+        for c in sp.children:
+            yield from walk(c, sp)
+    for r in roots:
+        yield from walk(r, None)
+
+
+def test_every_stage_span_under_its_parent_on_its_thread(traced):
+    roots, _stats, _hash, _delta = traced
+    pairs = list(_with_parents(roots))
+    by_name: dict = {}
+    for sp, parent in pairs:
+        by_name.setdefault(sp.name, []).append((sp, parent))
+    for name, (cat, parent_name, thread) in STAGES.items():
+        assert name in by_name, f"no span {name}"
+        for sp, parent in by_name[name]:
+            assert sp.cat == cat
+            assert sp.thread == thread
+            assert (parent.name if parent else None) == parent_name
+    # how many: one a block, one a window, one a replay (seq.* open more
+    # than once a block: the statements keep their order)
+    for name in ("decode.parse", "decode.build", "decode.slices"):
+        assert len(by_name[name]) == BLOCKS
+    assert len(by_name["seq.header"]) == 3 * BLOCKS
+    assert len(by_name["seq.body"]) == 2 * BLOCKS
+    for name in ("submit.split", "submit.pack_ed", "submit.pack_vrf",
+                 "submit.pack_kes", "submit.dispatch", "submit.fold",
+                 "window.submit", "window.host_seq", "pipeline.drain"):
+        assert len(by_name[name]) == N_WINDOWS, name
+    assert len(by_name["pipeline.beta_prefetch"]) == 1
+    # the spans the benchmark already read keep name, cat and thread
+    caller = threading.current_thread().name
+    for name, cat, thread in (
+            ("stream.read", "disk", PREFETCH),
+            ("stream.decode", "disk", PREFETCH),
+            ("window.host_seq", "host-seq", PRODUCER),
+            ("window.submit", "dispatch", PRODUCER),
+            ("pipeline.drain", "device", caller),
+            ("stream.snapshot", "disk", caller)):
+        assert {(sp.cat, sp.thread) for sp, _p in by_name[name]} \
+            == {(cat, thread)}, name
+    # no span of cat compile: the benchmark counts those in its window
+    assert not [sp for sp, _p in pairs if sp.cat == "compile"]
+
+
+def test_children_never_longer_than_their_parent(traced):
+    roots, _stats, _hash, _delta = traced
+    for sp, parent in _with_parents(roots):
+        assert sp.t1 is not None and sp.t1 >= sp.t0
+        if parent is not None:
+            assert parent.t0 <= sp.t0 and sp.t1 <= parent.t1
+    for root in roots:
+        for sp in root.walk():
+            assert sum(c.duration for c in sp.children) \
+                <= sp.duration + 1e-9
+
+
+def test_window_index_on_both_threads(traced):
+    roots, _stats, _hash, _delta = traced
+    spans = [sp for r in roots for sp in r.walk()]
+    for name in ("window.host_seq", "pipeline.drain"):
+        got = [sp.meta["window"] for sp in spans if sp.name == name]
+        assert got == list(range(N_WINDOWS)), name
+    # only those two carry it
+    assert {sp.name for sp in spans if sp.meta} \
+        == {"window.host_seq", "pipeline.drain"}
+
+
+def test_wait_counters_count_and_first_submit_rises_once(
+        chain_dir, tmp_path, host_tables, monkeypatch):
+    """Each counter gets what its hand-off measured; `first_submit_us`
+    is written once a replay, the other two once a `next_window()` and
+    once a wait of the caller's thread."""
+    class Spy:
+        def __init__(self, inst, seen):
+            self.inst, self.seen = inst, seen
+
+        def inc(self, n=1):
+            self.seen.append(n)
+            self.inst.inc(n)
+
+    incs: dict = {n: [] for n in WAITS}
+    for attr, name in (("_WAIT_BLOCKS_US", WAITS[0]),
+                       ("_CONSUMER_WAIT_US", WAITS[1]),
+                       ("_FIRST_SUBMIT_US", WAITS[2])):
+        inst = getattr(pipeline, attr)
+        assert inst.name == name and inst.kind == "counter"
+        assert not inst.always and not inst.stable
+        monkeypatch.setattr(pipeline, attr, Spy(inst, incs[name]))
+    _stats, _hash, delta = _replay(chain_dir, str(tmp_path / "db"),
+                                   HostProgramsBackend())
+    for name in WAITS:
+        assert delta[name] == sum(incs[name]) > 0, name
+        assert all(isinstance(n, int) and n >= 0 for n in incs[name])
+    assert len(incs["pipeline.first_submit_us"]) == 1
+    # three windows read ahead, then one more pull a window
+    assert len(incs["pipeline.producer_wait_blocks_us"]) == 3 + N_WINDOWS
+    assert len(incs["pipeline.consumer_wait_us"]) == N_WINDOWS + 1
+    # the head of the replay holds the whole wait for the first blocks
+    assert incs["pipeline.first_submit_us"][0] \
+        >= incs["pipeline.producer_wait_blocks_us"][0]
+
+
+def test_recording_off_allocates_no_span_and_counters_still_count(
+        chain_dir, tmp_path, host_tables, traced, monkeypatch):
+    _roots, _stats, hash_on, _delta = traced
+    made = []
+    real_init = spans_mod.Span.__init__
+
+    def counting_init(self, *a, **kw):
+        made.append(a[0])
+        real_init(self, *a, **kw)
+
+    monkeypatch.setattr(spans_mod.Span, "__init__", counting_init)
+    assert not spans_mod.RECORDER.enabled
+    _stats, hash_off, delta = _replay(chain_dir, str(tmp_path / "off"),
+                                      HostProgramsBackend())
+    assert made == []
+    assert spans_mod.RECORDER.drain() == []
+    for name in WAITS:
+        assert delta[name] > 0, name
+    # recording changes nothing the replay computes
+    assert hash_off == hash_on
+
+
+def test_chrome_trace_of_a_replay_has_a_row_per_thread(traced):
+    roots, _stats, _hash, _delta = traced
+    doc = observe.export.chrome_trace(roots)
+    rows = {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+            if e["ph"] == "M"}
+    assert set(rows.values()) == {PREFETCH, PRODUCER,
+                                  threading.current_thread().name}
+    events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
+    assert {rows[e["tid"]] for e in events
+            if e["name"].startswith("decode.")} == {PREFETCH}
+    assert {rows[e["tid"]] for e in events
+            if e["name"].startswith(("seq.", "submit."))} == {PRODUCER}
+    drains = [e for e in events if e["name"] == "pipeline.drain"]
+    assert [e["args"]["window"] for e in drains] == list(range(N_WINDOWS))
+    assert {e["cat"] for e in drains} == {"device"}
+
+
+# -- the benchmark's readers of these spans and counters ----------------------
+
+BENCH = os.path.join(REPO, "benchmarks")
+NEW_METRICS = (
+    "decode_parse_us_per_block", "decode_build_us_per_block",
+    "decode_slices_us_per_block", "decode_mb_per_s", "read_ms_per_chunk",
+    "host_header_us_per_block", "host_body_us_per_block",
+    "producer_wait_blocks_ms_per_window", "submit_pack_ms_per_window",
+    "submit_dispatch_ms_per_window", "consumer_wait_ms_per_window",
+    "first_submit_share")
+
+
+def _facts(roots, stats, delta) -> dict:
+    """Facts as `gather_facts` in benchmarks/run.py gathers them from
+    one replay: span seconds and counts by name, counter deltas, the
+    window's totals."""
+    span_seconds: dict = {}
+    span_count: dict = {}
+    for root in roots:
+        for sp in root.walk():
+            span_seconds[sp.name] = span_seconds.get(sp.name, 0.0) \
+                + sp.duration
+            span_count[sp.name] = span_count.get(sp.name, 0) + 1
+    return {
+        "window": {"replays": 1, "blocks": BLOCKS,
+                   "windows": delta["jax_backend.windows_submitted"],
+                   "replay_seconds": stats["replay_secs"]},
+        "span_seconds": span_seconds, "span_count": span_count,
+        "counter": delta,
+        "stream": {k: v for k, v in stats.items()
+                   if isinstance(v, (int, float))},
+    }
+
+
+def _reader_module():
+    spec = importlib.util.spec_from_file_location(
+        "bench_readers", os.path.join(BENCH, "harness", "readers.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_the_new_metric_files_are_these():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
+        listed = [m["name"] for m in json.load(fh)["per_layer"]]
+    assert listed[-len(NEW_METRICS):] == list(NEW_METRICS)
+    files = {os.path.basename(p)[:-5] for p in glob.glob(
+        os.path.join(BENCH, "layer_metrics", "*.json"))}
+    assert files == set(listed)
+
+
+@pytest.mark.parametrize("metric", NEW_METRICS)
+def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
+    """A renamed span or counter fails here, not in a chip run."""
+    roots, stats, _hash, delta = traced
+    with open(os.path.join(BENCH, "layer_metrics", metric + ".json")) as fh:
+        doc = json.load(fh)
+    facts = _facts(roots, stats, delta)
+    for ns, key in doc["reader"]["num"] + doc["reader"].get("den", []):
+        assert key in facts[ns], f"{metric}: no fact {ns}/{key}"
+    value = _reader_module().read(doc["reader"], facts)
+    assert value is not None and value > 0
+    if metric.endswith("_share"):
+        assert value <= 100.0
+    source = {"span_seconds": "program_span",
+              "counter": "program_counter"}[doc["reader"]["num"][0][0]]
+    assert doc["source"] == source
+
+
+def test_stages_never_exceed_their_outer_span(traced):
+    """The reconciliation PERF.md makes on the chip, as far as a tiny
+    chain on a shared CPU can hold it: the stages of decode, of the host
+    pass and of submit are all inside the span around them, so they add
+    up to no more than it."""
+    roots, stats, _hash, delta = traced
+    sec = _facts(roots, stats, delta)["span_seconds"]
+    for outer, stages in (
+            ("stream.decode", ("decode.parse", "decode.build",
+                               "decode.slices")),
+            ("window.host_seq", ("seq.header", "seq.body")),
+            ("window.submit", ("submit.split", "submit.pack_ed",
+                               "submit.pack_vrf", "submit.pack_kes",
+                               "submit.dispatch", "submit.fold"))):
+        assert 0 < sum(sec[s] for s in stages) <= sec[outer], outer
