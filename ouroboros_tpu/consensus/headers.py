@@ -16,8 +16,14 @@ from dataclasses import dataclass, field, replace
 from typing import Any, Optional, Sequence
 
 from ..chain.block import GENESIS_HASH
+from ..observe import metrics as _metrics
 from ..observe import spans as _spans
 from ..utils import cbor
+
+
+# blocks whose cached header bytes, header spans and tx body bytes all
+# came from the offsets of the one walk (ProtocolBlock.from_bytes)
+_ONE_WALK = _metrics.counter("replay.decode.one_walk_blocks")
 
 
 @dataclass(frozen=True)
@@ -125,48 +131,72 @@ class ProtocolBlock:
     @classmethod
     def from_bytes(cls, raw: bytes, tx_decode=None,
                    tx_body_elems: int | None = None) -> "ProtocolBlock":
-        """Decode AND retain raw-byte spans so the hot sequential pass
-        (header hash, KES signing bytes, tx ids) never re-encodes.
+        """Decode AND retain raw-byte slices so the hot sequential pass
+        (header hash, KES signing bytes, tx ids) never re-encodes.  The
+        bytes are walked once: the parse keeps the offsets of the list
+        elements down to a transaction's, and the slices are cut there.
 
         tx_body_elems: when set, each tx item is a list whose first
         tx_body_elems elements form the tx BODY (ShelleyTx: 6 body
-        fields + witnesses) — the body encoding is assembled from spans
-        and stashed in the tx's _cache for txid.
+        fields + witnesses) — the body encoding is assembled from the
+        offsets and stashed in the tx's _cache for txid.
 
-        Its three stages each run in a `disk` span a block
-        (decode.parse, decode.build, decode.slices), children of the
+        An item not shaped so (a header that is not a 6-list, a tx of
+        fewer elements) keeps an empty cache and is re-encoded when
+        asked; `replay.decode.one_walk_blocks` counts the blocks where
+        no item was.
+
+        Its three stages each run in a `disk` span a block (decode.parse
+        around the one walk, decode.build around `decode`, decode.slices
+        around the cutting of the cached slices), children of the
         prefetcher's `stream.decode` in a streamed replay."""
         with _spans.span("decode.parse", cat="disk"):
-            obj = cbor.loads(raw)
+            obj, spans = cbor.loads_spans(raw, depth=2)
         with _spans.span("decode.build", cat="disk"):
             block = cls.decode(obj, tx_decode=tx_decode)
         with _spans.span("decode.slices", cat="disk"):
-            try:
-                outer = cbor.list_spans(raw, 0)          # [header, [txs]]
-                hspan = outer[0]
-                helems = cbor.list_spans(raw, hspan[0])
-                fpairs_sp = cbor.list_spans(raw, helems[5][0])
-                hdr = block.header
-                hdr._cache["bytes"] = raw[hspan[0]:hspan[1]]
-                hdr._cache["spans"] = (
-                    raw, helems,
-                    list(zip((k for k, _v in hdr.fields), fpairs_sp)))
-                if tx_body_elems is not None and block.body:
-                    for tx, tsp in zip(block.body,
-                                       cbor.list_spans(raw, outer[1][0])):
-                        telems = cbor.list_spans(raw, tsp[0])
-                        body_raw = (cbor._head(4, tx_body_elems) + raw[
-                            telems[0][0]:telems[tx_body_elems - 1][1]])
-                        cache = getattr(tx, "_cache", None)
-                        if cache is not None:
-                            cache["body_bytes"] = body_raw
-            except (cbor.CBORError, IndexError):
-                pass    # spans are an optimisation; decode stands alone
+            if _cache_slices(block, raw, spans, tx_body_elems):
+                _ONE_WALK.inc()
         return block
 
     @property
     def bytes(self) -> bytes:
         return cbor.dumps(self.encode())
+
+
+def _cache_slices(block: ProtocolBlock, raw: bytes, spans,
+                  tx_body_elems: int | None) -> bool:
+    """Fill the header's and the transactions' caches from the offsets
+    `cbor.loads_spans(raw, depth=2)` kept of `[header, [tx, ...]]`; True
+    when every item was shaped as expected, so every cache is filled."""
+    if spans is None:
+        return False
+    bounds, subs = spans
+    whole = True
+    hsp = subs[0]
+    if hsp is not None and len(hsp[1]) == 6 and hsp[1][5] is not None:
+        hb, fb = hsp[0], hsp[1][5][0]
+        cache = block.header._cache
+        cache["bytes"] = raw[bounds[0]:bounds[1]]
+        cache["spans"] = (
+            raw, list(zip(hb, hb[1:])),
+            list(zip((k for k, _v in block.header.fields),
+                     zip(fb, fb[1:]))))
+    else:
+        whole = False
+    if tx_body_elems is not None and block.body:
+        if subs[1] is None:
+            return False
+        head = cbor._head(4, tx_body_elems)
+        for tx, tsp in zip(block.body, subs[1][1]):
+            cache = getattr(tx, "_cache", None)
+            if (cache is None or tsp is None
+                    or len(tsp[0]) <= tx_body_elems):
+                whole = False
+                continue
+            tb = tsp[0]
+            cache["body_bytes"] = head + raw[tb[0]:tb[tx_body_elems]]
+    return whole
 
 
 def body_hash_of(body: Sequence) -> bytes:

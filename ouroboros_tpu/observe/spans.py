@@ -55,7 +55,10 @@ The streamed replay's stage spans (each nests under the pipeline-stage
 span named first; cat in brackets):
 
     stream.decode   decode.parse, decode.build, decode.slices [disk],
-                    one each a block (consensus/headers.py)
+                    one each a block (consensus/headers.py): the one
+                    walk of the bytes that keeps the list offsets, the
+                    block built from the parsed object, the cached raw
+                    slices cut at those offsets
     window.host_seq seq.header, seq.body [host-seq], the header rules
                     and the ledger pass of one block, interleaved as
                     `_seq_block_step` runs them (consensus/batch.py)

@@ -11,7 +11,8 @@ from __future__ import annotations
 import struct
 from typing import Any
 
-__all__ = ["dumps", "loads", "CBORError", "CBORTruncated", "Tag"]
+__all__ = ["dumps", "loads", "loads_spans", "CBORError", "CBORTruncated",
+           "Tag"]
 
 
 class CBORError(ValueError):
@@ -107,57 +108,131 @@ def dumps(obj: Any) -> bytes:
     return bytes(out)
 
 
-class _Decoder:
-    def __init__(self, data: bytes):
-        self.data = data
-        self.pos = 0
+_TRUNCATED = "truncated CBOR"
 
-    def _take(self, n: int) -> bytes:
-        if self.pos + n > len(self.data):
-            raise CBORTruncated("truncated CBOR")
-        b = self.data[self.pos:self.pos + n]
-        self.pos += n
-        return b
 
-    def _arg(self, info: int) -> int:
-        if info < 24:
-            return info
+def _walk(data, rec: int) -> tuple:
+    """One walk of `data` from offset 0: (object, end offset, spans).
+
+    `rec` is how many levels of list nesting have their elements'
+    offsets kept (0: none; `loads_spans` says what `spans` holds).
+
+    This is the decode's inner loop (a replay spends a third of its
+    host time here), so a head is read in place: no slice for a byte,
+    no call for an argument under 24, and inside a list the byte
+    strings, small integers and empty lists are taken without a call
+    at all.  A read past the end is an IndexError, caught once."""
+    if type(data) is not bytes:
+        data = bytes(data)
+    size = len(data)
+    pos = 0
+    node = None          # spans of the list item() last returned, if kept
+
+    def long_arg(info):
+        """The argument after a head whose additional info is >= 24."""
+        nonlocal pos
         if info == 24:
-            return self._take(1)[0]
-        if info == 25:
-            return int.from_bytes(self._take(2), "big")
-        if info == 26:
-            return int.from_bytes(self._take(4), "big")
-        if info == 27:
-            return int.from_bytes(self._take(8), "big")
-        raise CBORError(f"unsupported additional info {info}")
+            pos += 1
+            return data[pos - 1]
+        if info > 27:
+            raise CBORError(f"unsupported additional info {info}")
+        width = 1 << (info - 24)
+        pos += width
+        if pos > size:
+            raise CBORTruncated(_TRUNCATED)
+        return int.from_bytes(data[pos - width:pos], "big")
 
-    def decode(self) -> Any:
-        b = self._take(1)[0]
-        major, info = b >> 5, b & 0x1F
-        if major == 0:
-            return self._arg(info)
-        if major == 1:
-            return -1 - self._arg(info)
-        if major == 2:
-            return bytes(self._take(self._arg(info)))
-        if major == 3:
-            return self._take(self._arg(info)).decode("utf-8")
+    def item(rec):
+        nonlocal pos, node
+        b = data[pos]
+        pos += 1
+        if b < 0x18:
+            return b
+        major = b >> 5
+        info = b & 0x1F
         if major == 4:
-            if info == 31:                     # indefinite-length array
-                items = []
-                while True:
-                    if self.data[self.pos:self.pos + 1] == b"\xff":
-                        self.pos += 1
-                        return items
-                    items.append(self.decode())
-            return [self.decode() for _ in range(self._arg(info))]
-        if major == 5:
-            n = self._arg(info)
-            out = {}
+            out = []
+            if info < 24:
+                n = info
+            elif info == 31:
+                n = -1                         # indefinite length
+            else:
+                n = long_arg(info)
+            if n < 0 or rec > 1:
+                # every element through item(): the levels above the
+                # deepest one kept, and indefinite length (rare)
+                sub = rec - 1 if rec else 0
+                bounds = [pos]
+                subs = []
+                if n < 0:
+                    while data[pos] != 0xFF:
+                        node = None
+                        out.append(item(sub))
+                        bounds.append(pos)
+                        subs.append(node)
+                    pos += 1
+                else:
+                    for _ in range(n):
+                        node = None
+                        out.append(item(sub))
+                        bounds.append(pos)
+                        subs.append(node)
+                if rec:
+                    node = (bounds, subs if sub else None)
+                return out
+            append = out.append
+            if rec:
+                bounds = [pos]
             for _ in range(n):
-                k = self.decode()
-                v = self.decode()
+                b = data[pos]
+                if b == 0x58:                  # bytes, one-byte length
+                    start = pos + 2
+                    pos = start + data[pos + 1]
+                    if pos > size:
+                        raise CBORTruncated(_TRUNCATED)
+                    append(data[start:pos])
+                elif b < 0x18:
+                    pos += 1
+                    append(b)
+                elif b == 0x80:
+                    pos += 1
+                    append([])
+                elif 0x40 <= b < 0x58:         # bytes shorter than 24
+                    start = pos + 1
+                    pos += b - 0x3F
+                    if pos > size:
+                        raise CBORTruncated(_TRUNCATED)
+                    append(data[start:pos])
+                else:
+                    append(item(0))
+                if rec:
+                    bounds.append(pos)
+            if rec:
+                node = (bounds, None)
+            return out
+        if major == 2:
+            n = info if info < 24 else long_arg(info)
+            start = pos
+            pos += n
+            if pos > size:
+                raise CBORTruncated(_TRUNCATED)
+            return data[start:pos]
+        if major == 0:
+            return long_arg(info)
+        if major == 1:
+            return -1 - (info if info < 24 else long_arg(info))
+        if major == 3:
+            n = info if info < 24 else long_arg(info)
+            start = pos
+            pos += n
+            if pos > size:
+                raise CBORTruncated(_TRUNCATED)
+            return str(data[start:pos], "utf-8")
+        if major == 5:
+            out = {}
+            for _ in range(info if info < 24 else long_arg(info)):
+                k = item(0)
+                v = item(0)
                 if isinstance(k, list):
                     # array map keys (Shelley tx bodies use them) become
                     # tuples so the dict stays usable; _encode re-emits
@@ -172,7 +247,7 @@ class _Decoder:
                 out[k] = v
             return out
         if major == 6:
-            return Tag(self._arg(info), self.decode())
+            return Tag(info if info < 24 else long_arg(info), item(0))
         # major 7
         if info == 20:
             return False
@@ -180,15 +255,25 @@ class _Decoder:
             return True
         if info == 22 or info == 23:
             return None
-        if info == 25:
-            # half float
-            h = int.from_bytes(self._take(2), "big")
-            return _decode_half(h)
-        if info == 26:
-            return struct.unpack(">f", self._take(4))[0]
-        if info == 27:
-            return struct.unpack(">d", self._take(8))[0]
+        if 25 <= info <= 27:
+            width = 1 << (info - 24)
+            pos += width
+            if pos > size:
+                raise CBORTruncated(_TRUNCATED)
+            raw = data[pos - width:pos]
+            if info == 25:
+                return _decode_half(int.from_bytes(raw, "big"))
+            return struct.unpack(">f" if info == 26 else ">d", raw)[0]
         raise CBORError(f"unsupported simple value {info}")
+
+    try:
+        return item(rec), pos, node
+    except IndexError:
+        raise CBORTruncated(_TRUNCATED) from None
+    finally:
+        # item refers to itself through this cell: emptied, the closure
+        # is freed now and not at the collector's next pass
+        item = None
 
 
 def _freeze(obj):
@@ -210,11 +295,29 @@ def _decode_half(h: int) -> float:
 
 
 def loads(data: bytes, allow_trailing: bool = False):
-    dec = _Decoder(data)
-    obj = dec.decode()
-    if not allow_trailing and dec.pos != len(data):
-        raise CBORError(f"trailing bytes after CBOR value at {dec.pos}")
+    obj, end, _spans = _walk(data, 0)
+    if not allow_trailing and end != len(data):
+        raise CBORError(f"trailing bytes after CBOR value at {end}")
     return obj
+
+
+def loads_spans(data: bytes, depth: int) -> tuple:
+    """`loads(data)` and, from the same walk, where the elements of its
+    lists lie: (object, spans), so a caller that keeps raw slices of
+    sub-items (header bytes, tx bodies) never walks or re-encodes them.
+
+    `spans` is None unless the item is a list; for a list it is
+    `(bounds, subs)`.  `bounds` holds one offset more than the list has
+    elements: element i is `data[bounds[i]:bounds[i + 1]]`.  `subs[i]` is
+    the spans of element i where that is a list too, else None.  The
+    outermost list is at depth 0 and a list's elements one deeper;
+    lists deeper than `depth` are not kept, so at `depth` itself `subs`
+    is None.  Only lists reached through lists are kept (not one inside
+    a map or a tag)."""
+    obj, end, spans = _walk(data, depth + 1)
+    if end != len(data):
+        raise CBORError(f"trailing bytes after CBOR value at {end}")
+    return obj, spans
 
 
 def unwrap_tag24(obj):
@@ -229,96 +332,5 @@ def unwrap_tag24(obj):
 
 def loads_prefix(data: bytes) -> tuple[Any, int]:
     """Decode one CBOR item, returning (value, bytes_consumed)."""
-    dec = _Decoder(data)
-    obj = dec.decode()
-    return obj, dec.pos
-
-
-# ---------------------------------------------------------------------------
-# Structural span scanning: walk items WITHOUT building objects, so decode
-# paths can keep raw-byte slices of sub-items (header bytes, tx bodies) and
-# the hot sequential pass never re-encodes what it just decoded (re-encoding
-# was 40% of the replay's host pass in the r5 profile).
-# ---------------------------------------------------------------------------
-
-def skip_item(data: bytes, pos: int) -> int:
-    """End offset of the CBOR item starting at `pos` (no object built)."""
-    b = data[pos]
-    major, info = b >> 5, b & 0x1F
-    pos += 1
-    if info < 24:
-        arg = info
-    elif info == 24:
-        arg = data[pos]
-        pos += 1
-    elif info == 25:
-        arg = int.from_bytes(data[pos:pos + 2], "big")
-        pos += 2
-    elif info == 26:
-        arg = int.from_bytes(data[pos:pos + 4], "big")
-        pos += 4
-    elif info == 27:
-        arg = int.from_bytes(data[pos:pos + 8], "big")
-        pos += 8
-    elif info == 31 and major in (2, 3, 4, 5):
-        # indefinite length: scan children to the break byte
-        while data[pos] != 0xFF:
-            pos = skip_item(data, pos)
-            if major == 5:
-                pos = skip_item(data, pos)
-        return pos + 1
-    else:
-        if major == 7 and info in (20, 21, 22, 23):
-            return pos
-        raise CBORError(f"unsupported additional info {info}")
-    if major in (0, 1):
-        return pos
-    if major in (2, 3):
-        return pos + arg
-    if major == 4:
-        for _ in range(arg):
-            pos = skip_item(data, pos)
-        return pos
-    if major == 5:
-        for _ in range(2 * arg):
-            pos = skip_item(data, pos)
-        return pos
-    if major == 6:
-        return skip_item(data, pos)
-    # major 7 with numeric arg encodings (float16/32/64 handled via info)
-    return pos
-
-
-def list_spans(data: bytes, pos: int = 0) -> list:
-    """(start, end) spans of each element of the LIST item at `pos`."""
-    b = data[pos]
-    major, info = b >> 5, b & 0x1F
-    if major != 4:
-        raise CBORError(f"list_spans: item at {pos} is major {major}")
-    pos += 1
-    if info < 24:
-        n = info
-    elif info == 24:
-        n = data[pos]
-        pos += 1
-    elif info == 25:
-        n = int.from_bytes(data[pos:pos + 2], "big")
-        pos += 2
-    elif info == 26:
-        n = int.from_bytes(data[pos:pos + 4], "big")
-        pos += 4
-    elif info == 31:
-        spans = []
-        while data[pos] != 0xFF:
-            end = skip_item(data, pos)
-            spans.append((pos, end))
-            pos = end
-        return spans
-    else:
-        raise CBORError(f"unsupported list length info {info}")
-    spans = []
-    for _ in range(n):
-        end = skip_item(data, pos)
-        spans.append((pos, end))
-        pos = end
-    return spans
+    obj, end, _spans = _walk(data, 0)
+    return obj, end

@@ -333,7 +333,7 @@ NEW_METRICS = (
     "host_header_us_per_block", "host_body_us_per_block",
     "producer_wait_blocks_ms_per_window", "submit_pack_ms_per_window",
     "submit_dispatch_ms_per_window", "consumer_wait_ms_per_window",
-    "first_submit_share")
+    "first_submit_share", "decode_one_walk_share")
 
 
 def _facts(roots, stats, delta) -> dict:
