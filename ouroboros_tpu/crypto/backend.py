@@ -158,7 +158,7 @@ class CryptoBackend:
         the outcome.  The sharded mesh backend threads its windows
         through this (the single-chip JaxBackend goes further and runs
         cold paths as device Blake2b jobs — jax_backend.py)."""
-        from .precompute import GLOBAL_PRECOMPUTE_CACHE
+        from .precompute import GLOBAL_PRECOMPUTE_CACHE, KES_HOST_WALKS
         cache = cache if cache is not None else GLOBAL_PRECOMPUTE_CACHE
 
         def kes_leaf(r):
@@ -168,6 +168,7 @@ class CryptoBackend:
                 return None           # structurally invalid
             ent = cache.kes_get(key)
             if ent is None:
+                KES_HOST_WALKS.inc()
                 sig = kes_mod.KesSig.from_bytes(r.depth, r.sig_bytes)
                 prep = kes_mod.verify_prepare(r.depth, r.vk, r.period,
                                               sig)

@@ -54,6 +54,11 @@ from ..observe import spans as _spans
 _BAD = object()
 _MISSING = object()
 
+# Sum-KES hash paths walked on the HOST (CryptoBackend.split_mixed_cached,
+# a cache miss there; the mesh backend's windows).  The one-chip path runs
+# cold paths as device Blake2b jobs and never counts here.
+KES_HOST_WALKS = _metrics.counter("precompute.kes_host_walks")
+
 
 class _Stripe:
     """One namespace's lock with contention accounting.

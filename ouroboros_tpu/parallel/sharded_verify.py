@@ -19,7 +19,15 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..crypto import ed25519_jax as EJ
+from ..observe import metrics as _metrics
+from ..observe import spans as _spans
 from .mesh import WINDOW_AXIS
+
+# what the mesh adds to a window, counted on the mesh path only (the
+# one-chip JaxBackend never reaches these): bytes handed to the sharded
+# device_put, and the padded lanes ONE shard carries (handles pre-bound)
+_SHARD_PUT_BYTES = _metrics.counter("jax_backend.shard_put_bytes")
+_SHARD_LANES_PADDED = _metrics.counter("jax_backend.shard_lanes_padded")
 
 
 @functools.lru_cache(maxsize=8)
@@ -135,7 +143,14 @@ class ShardedJaxBackend(JaxBackend):
 
     The legacy bit-rows mesh API (sharded_batch_verify / verify_*_batch
     overrides below) is kept for the standalone-batch surface and its
-    tests; the replay hot path never touches it."""
+    tests.  The served replay touches ONE piece of it: `vrf_betas_batch`,
+    the bit-rows `gamma8_kernel` under shard_map, is what the producer's
+    beta prefetch (VrfBetaCache.prefetch) calls for the first TWO
+    windows' betas, all there are on a two-window chain; window w+2's
+    ride in window w's composite as packed words (`gamma8_words_core`).
+    So the mesh keeps a second gamma8 form, and host KES, where the
+    one-chip path has packed words and device Blake2b (PERF.md section
+    7, "Also open")."""
 
     def __init__(self, mesh: Mesh, min_bucket: int = 128):
         super().__init__(min_bucket=min_bucket, use_pallas=False,
@@ -169,7 +184,14 @@ class ShardedJaxBackend(JaxBackend):
 
     def _dev(self, a):
         # every window input is lane-axis-last: shard the lane axis
-        return jax.device_put(np.asarray(a), self._lane_sharding)
+        a = np.asarray(a)
+        _SHARD_PUT_BYTES.inc(a.nbytes)
+        with _spans.span("submit.shard_put", cat="dispatch"):
+            return jax.device_put(a, self._lane_sharding)
+
+    def _note_padding(self, used: int, padded: int) -> None:
+        super()._note_padding(used, padded)
+        _SHARD_LANES_PADDED.inc(padded // self.n_shards)
 
     def _split_mixed_device(self, reqs):
         """Mesh windows reduce KES hash paths on host — through the
