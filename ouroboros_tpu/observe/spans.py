@@ -68,6 +68,13 @@ span named first; cat in brackets):
     (a root)        pipeline.beta_prefetch [device], the beta round
                     trip before window 0 (consensus/pipeline.py)
 
+The cyclic collector has counters, not spans (storage/stream.py):
+`replay.gc.pause_us` (whole microseconds inside collections of any
+generation, on whichever thread tripped one: a span there would land
+inside whatever stage span happened to be open), `replay.gc.full_passes`,
+`replay.gc.freezes` and `replay.gc.frozen_objects`, all counted only
+while a replay runs.
+
 Waits are counters, not spans: `pipeline.producer_wait_blocks_us`,
 `pipeline.consumer_wait_us` and `pipeline.first_submit_us`
 (consensus/pipeline.py) hold whole microseconds.  A consumer of spans
