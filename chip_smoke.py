@@ -15,7 +15,7 @@ benchmark: the rates it prints are smoke readings.
                                       every phase, tiny, on the CPU
 
 Phases (one JSON line each, then the contract's last line):
-  synth      db_synth child: bench's chain (shelley, 2 pools, f=4/5,
+  synth      db_synth child: a small chain (shelley, 2 pools, f=4/5,
              2 txs/block, 600-slot epochs, depth-10 KES), fixed seed
   reference  the DB through analysis_validate on a CPU backend
   device     the same DB through analysis_validate on the device backend,
@@ -76,9 +76,9 @@ SEED = "chip-smoke-22"
 SYNTH = ("--protocol", "shelley", "--pools", "2", "--f", "4/5",
          "--txs-per-block", "2", "--epoch-length", "600",
          "--kes-depth", "10")
-# rehearsal 1 runs at the shapes `bench --smoke` compiles (depth-4 KES,
-# empty bodies, min_bucket 16, XLA form): a new composite shape costs
-# minutes of XLA:CPU compile
+# rehearsal 1 runs at the shapes tests/test_served_replay.py compiles
+# (depth-4 KES, empty bodies, min_bucket 16, XLA form): a new composite
+# shape costs minutes of XLA:CPU compile
 REHEARSE_SYNTH = ("--protocol", "shelley", "--pools", "2", "--f", "4/5",
                   "--txs-per-block", "0", "--epoch-length", "500",
                   "--kes-depth", "4")

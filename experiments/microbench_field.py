@@ -59,16 +59,6 @@ def bench_e2e():
 
     arrays, _ok = EJ.prepare_bytes_batch(vks, msgs, sigs)
     yA, signA, yR, signR, s_bits, k_bits = arrays
-    dev = [jnp.asarray(a) for a in
-           (yA, signA.reshape(1, -1), yR, signR.reshape(1, -1),
-            s_bits, k_bits)]
-
-    def run_pallas():
-        return np.asarray(PK._ed25519_verify_jit(*dev, n))
-
-    med, lo, hi = timed(run_pallas)
-    report(f"ed pallas device n={n}", med, lo, hi,
-           per=f"{n/med:.0f}/s")
 
     # transfer cost: host->device of the same arrays
     def xfer():

@@ -282,8 +282,8 @@ def replay_blocks_pipelined(
     cores over the mesh, and the fold verdict's min-reduction already
     spans shards — first-error-wins is preserved because the failing
     request INDEX, not a per-shard flag, is what crosses the link.
-    `bench.py --mesh N` and the multichip dryrun are the measured
-    entry points.
+    The benchmark's `sync-mesh4` cell and the multichip dryrun are the
+    measured entry points.
 
     `on_window(state, n_done, point)` fires after each window is FULLY
     verified — the streaming engine's snapshot seam (identical contract

@@ -4,11 +4,10 @@ The r5 software pipeline (consensus/batch.py) kept two windows in
 flight, but the sequential pass, request packing, and dispatch all ran
 on ONE Python thread: while that thread sat inside a blocking drain
 (the packed result transfer plus result folding), no host-sequential
-work advanced, so host-seq and device time simply ADDED in the bench
-breakdown (BENCH_r05: 0.87s + 3.79s).  SURVEY.md hard parts #3 says the
-split is legal — nonce evolution is sequential, but proofs are
-state-independent once seeds are derived — so this module puts the host
-half on its own thread:
+work advanced, so host-seq and device time simply ADDED.  SURVEY.md
+hard parts #3 says the split is legal — nonce evolution is sequential,
+but proofs are state-independent once seeds are derived — so this
+module puts the host half on its own thread:
 
     producer (background thread)      consumer (caller thread)
     ------------------------------    ------------------------------
@@ -55,8 +54,7 @@ recomputes; the caches' LRU bookkeeping (recency touches, capacity
 eviction) additionally tolerates a concurrent eviction from the other
 thread — see precompute._insert / VrfBetaCache._store.  Span trees are per-thread (observe/spans.py): the producer's
 ``window.host_seq``/``window.submit`` roots and the consumer's
-``window.drain`` roots overlap in wall time — which is the point — and
-bench.py's ``overlap`` section measures exactly that hiding.
+``window.drain`` roots overlap in wall time — which is the point.
 ``window.host_seq`` and ``pipeline.drain`` carry the window's index in
 the replay (``window=k``), so one window can be followed across both
 threads.
@@ -88,8 +86,8 @@ from .ledger import LedgerError, OutsideForecastRange
 DEPTH = 2
 
 # load-bearing thread accounting (always on): a replay that returns with
-# started != finished leaked its producer — bench --smoke asserts the
-# pair equal after the pipelined parity probe
+# started != finished leaked its producer
+# (tests/test_served_replay.py::test_producer_ran_and_is_gone)
 _STARTED = _metrics.counter("pipeline.producers_started", always=True)
 _FINISHED = _metrics.counter("pipeline.producers_finished", always=True)
 # observational: windows through the pipeline / producer permit stalls
@@ -400,13 +398,10 @@ def _drain(backend, entry) -> tuple:
     Returns (error, n_valid): error None when every proof held, else
     n_valid is the global index of the first bad block."""
     start, sub, reqs, owner, n_seq_w, t_submit, _st, _pt, k = entry
-    # named distinctly from jax_backend's inner "window.drain" span:
-    # bench._rep_overlap pairs submits and drains positionally by name,
-    # and a second same-named interval per drain would break the zip.
-    # This outer span exists for EVERY async backend (the flight
-    # recorder must show drains even on stub/CPU backends); phase
-    # totals stay correct because self-time attribution subtracts the
-    # nested inner span.
+    # named distinctly from jax_backend's inner "window.drain" span,
+    # so a reader that pairs submits with drains by name sees one
+    # interval a drain.  This outer span exists for EVERY async backend
+    # (the flight recorder must show drains even on stub/CPU backends).
     with _spans.span("pipeline.drain", cat="device", window=k):
         ok, betas = backend.finish_window(sub)
     _SUBMIT_DRAIN.observe(_spans.monotonic_now() - t_submit)

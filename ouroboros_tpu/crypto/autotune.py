@@ -3,9 +3,9 @@
 The r5 `_pick` voted with a median-of-3 timed inline — no fence before a
 rep (so a timed rep inherited whatever async dispatches were still in
 flight), no persistence of the window-composite vote, and measurements
-could run INSIDE a benchmark's timed region when a shape first appeared
-there.  BENCH_r05 showed the cost: the VRF primitive regressed 0.83x
-with a 45% spread and the pallas/xla choice flip-flopping between runs.
+could run INSIDE a timed region when a shape first appeared there: the
+VRF primitive once regressed 0.83x with a 45% spread and the pallas/xla
+choice flip-flopping between runs.
 
 This module replaces it with one process-wide tuner per device kind:
 
@@ -17,12 +17,11 @@ This module replaces it with one process-wide tuner per device kind:
 - persistence: choices (including derived window-composite votes) are
   stored per (kernel revision, device kind) in a JSON file next to the
   XLA compilation cache, so every later process starts pinned and two
-  consecutive bench runs emit byte-identical `kernel_choices`.
+  consecutive runs report byte-identical `kernel_choices`.
 - fencing of timed regions: `freeze()` turns any further `_store_choice`
-  into a `FrozenAutotunerError`; benchmarks freeze all tuners before a
-  timed rep, making "a retune happened mid-measurement" a loud failure
-  instead of a silent 45% spread.  `--retune` (OURO_RETUNE=1) drops the
-  persisted file and re-measures from scratch.
+  into a `FrozenAutotunerError`, making "a retune happened
+  mid-measurement" a loud failure instead of a silent 45% spread.
+  OURO_RETUNE=1 drops the persisted file and re-measures from scratch.
 """
 from __future__ import annotations
 
@@ -48,8 +47,8 @@ KERNEL_REV = "r8-fold-1"
 WARMUP_REPS = 1
 TIMED_REPS = 3
 
-# registry counters (ISSUE 7).  frozen_writes is load-bearing (bench
-# asserts it stays 0 across timed regions) -> always.  measurements and
+# registry counters (ISSUE 7).  frozen_writes is load-bearing (it must
+# stay 0 across a frozen region) -> always.  measurements and
 # stores depend on what an earlier process persisted, so they are
 # excluded from the deterministic snapshot (stable=False) but still
 # exported to Prometheus.
@@ -159,7 +158,7 @@ class Autotuner:
         return self._choices.get(key)
 
     def choices_snapshot(self) -> dict:
-        """Stable-ordered {key tuple: use_pallas} copy (bench JSON)."""
+        """Stable-ordered {key tuple: use_pallas} copy."""
         return {k: self._choices[k] for k in sorted(self._choices)}
 
     # -- writes --------------------------------------------------------------
@@ -249,18 +248,3 @@ def tuner_for(device_kind: str) -> Autotuner:
             t.invalidate()
         _TUNERS[device_kind] = t
     return t
-
-
-def freeze_all() -> None:
-    """Pin every instantiated tuner (call before a timed region)."""
-    for t in _TUNERS.values():
-        t.freeze()
-
-
-def thaw_all() -> None:
-    for t in _TUNERS.values():
-        t.thaw()
-
-
-def frozen_write_count() -> int:
-    return sum(t.writes_while_frozen for t in _TUNERS.values())

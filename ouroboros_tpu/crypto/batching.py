@@ -40,8 +40,8 @@ Metrics (namespace ``service.*``): queue-depth gauge, coalesced
 batch-size + bucket histograms, time-in-queue and request-latency
 histograms, deadline-miss / fallback / device-dispatch / back-pressure
 counters.  ``device_batches`` and ``fallback_requests`` are
-``always=True`` — the serve smoke gates on them (light load ⇒ ZERO
-device dispatches).
+``always=True`` — tests/test_batching.py gates on them (light load ⇒
+ZERO device dispatches).
 """
 from __future__ import annotations
 
@@ -61,7 +61,7 @@ from .backend import (
 )
 
 __all__ = [
-    "BackPressure", "BreakEvenTable", "ModeledBackend", "PrecheckedBackend",
+    "BackPressure", "BreakEvenTable", "PrecheckedBackend",
     "ServiceConfig", "ServiceStopped", "VerifyFuture", "VerifyService",
     "calibrate_break_even", "validate_headers_coalesced",
 ]
@@ -172,7 +172,7 @@ class BreakEvenTable:
             return None
 
     def snapshot(self) -> dict:
-        """Stable-ordered copy for bench JSON / obsreport."""
+        """Stable-ordered copy (a saved table reloads to the same one)."""
         return {"device_kind": self.device_kind,
                 "entries": {k: self.entries[k]
                             for k in sorted(self.entries)}}
@@ -340,10 +340,9 @@ class VerifyService:
         # the queue is an immutable tuple in ONE TVar: each admission
         # copies it (O(depth)), which is deliberate — rollback stays
         # free, the flusher's deadline scan needs the whole view anyway,
-        # and at the measured saturated regime (bench --serve: 10k
-        # req/s, depth <= max_batch most of the time) the copies are
-        # ~2% of wall.  If a profile ever shows this hot, the TQueue
-        # two-stack representation is the drop-in upgrade.
+        # and while depth <= max_batch most of the time the copies are
+        # small.  If a profile ever shows this hot, the TQueue two-stack
+        # representation is the drop-in upgrade.
         self._pending_tv = TVar((), label="service-pending")
         self._stop_tv = TVar(False, label="service-stopping")
         self._task = None
@@ -351,15 +350,14 @@ class VerifyService:
         # deadline-driven flush instant backs off by this much
         self._flush_latency = self.cfg.initial_latency
         # local tallies mirrored into service.* (readable without the
-        # registry in tests/bench)
+        # registry in tests)
         self.stats = {"submitted": 0, "device_batches": 0,
                       "device_requests": 0, "fallback_batches": 0,
                       "fallback_requests": 0, "deadline_misses": 0,
                       "flushes": 0, "rejected": 0,
                       "backpressure_waits": 0}
         # coalesced-batch-size tally {size: flushes} — the per-service
-        # view of the shared service.batch_size histogram (bench --serve
-        # embeds it; obsreport renders it)
+        # view of the shared service.batch_size histogram
         self.batch_sizes: dict = {}
 
     # -- lifecycle -----------------------------------------------------------
@@ -531,8 +529,9 @@ class VerifyService:
 
     async def _call(self, b: CryptoBackend, method: str, reqs: list):
         """One backend call; prefers an async variant when the backend
-        provides one (ModeledBackend charges runtime-clock latency
-        there), else the plain synchronous batch API."""
+        provides one (testing.modeled.ModeledBackend charges
+        runtime-clock latency there), else the plain synchronous batch
+        API."""
         fn = getattr(b, method + "_async", None)
         if fn is not None:
             return await fn(reqs)
@@ -694,56 +693,3 @@ async def validate_headers_coalesced(protocol, headers, header_state,
     ok = await service.verify_many(proofs, deadline) if proofs else []
     return _merge_header_verdicts(headers, states, proofs, owner, ok,
                                   seq_error, n_seq)
-
-
-# -- modeled backend (serve bench / service tests) --------------------------
-
-class ModeledBackend(CryptoBackend):
-    """`inner`'s verdicts + a latency model charged to the RUNTIME
-    clock: ``verify_*_batch_async`` sleeps ``setup_secs + per_req_secs *
-    n`` before answering — exact virtual seconds under the sim harness,
-    real sleeps under io_run.
-
-    This is how `bench --serve` runs device-shaped serving dynamics in
-    deterministic sim time on a container with no accelerator: the cost
-    PARAMETERS come from measurement (the break-even calibration file
-    when one exists, documented defaults otherwise), the DYNAMICS
-    (coalescing, queueing, deadlines, back-pressure) play out in virtual
-    time, and every verdict still comes from `inner` (CpuRefBackend by
-    default — or a PrecheckedBackend over CpuRef-computed verdicts, so
-    a big trace does not re-run pure-Python EC math per arrival), so
-    parity gates stay byte-exact."""
-
-    def __init__(self, setup_secs: float, per_req_secs: float,
-                 inner: Optional[CryptoBackend] = None,
-                 name: str = "modeled"):
-        self.setup_secs = setup_secs
-        self.per_req_secs = per_req_secs
-        self.inner = inner if inner is not None else CpuRefBackend()
-        self.name = name
-        self.calls = 0
-
-    # sync forms delegate straight through (no latency to charge: the
-    # runtime clock only advances inside a thread that sleeps)
-    def verify_ed25519_batch(self, reqs):
-        return self.inner.verify_ed25519_batch(reqs)
-
-    def verify_vrf_batch(self, reqs):
-        return self.inner.verify_vrf_batch(reqs)
-
-    def verify_kes_batch(self, reqs):
-        return self.inner.verify_kes_batch(reqs)
-
-    async def _charged(self, method, reqs):
-        self.calls += 1
-        await sim.sleep(self.setup_secs + self.per_req_secs * len(reqs))
-        return getattr(self.inner, method)(reqs)
-
-    async def verify_ed25519_batch_async(self, reqs):
-        return await self._charged("verify_ed25519_batch", reqs)
-
-    async def verify_vrf_batch_async(self, reqs):
-        return await self._charged("verify_vrf_batch", reqs)
-
-    async def verify_kes_batch_async(self, reqs):
-        return await self._charged("verify_kes_batch", reqs)

@@ -55,7 +55,7 @@ _FOLD_WINDOWS = _metrics.counter("jax_backend.fold_windows")
 # lane occupancy: real requests vs padded bucket lanes per window — the
 # mesh backend's padding additionally rounds to a mesh multiple, so the
 # waste fraction (1 - used/padded) is the per-shard occupancy cost the
-# MULTICHIP_OBS / bench --mesh artifacts report (ISSUE 11)
+# MULTICHIP_OBS line and the benchmark's `lane_pad_share` report
 _LANES_USED = _metrics.counter("jax_backend.lanes_used")
 _LANES_PADDED = _metrics.counter("jax_backend.lanes_padded")
 
@@ -202,7 +202,7 @@ class JaxBackend(CryptoBackend):
         ``waste_frac`` is the fraction of padded lanes that carried no
         real request — on the mesh backend the same fraction per shard,
         since sharding splits the padded batch evenly.  The MULTICHIP
-        dryrun and ``bench --mesh`` embed this dict.  Pass a previously
+        dryrun and chip_smoke.py embed this dict.  Pass a previously
         returned dict as `since` to get the delta (one replay's windows
         instead of the instance lifetime)."""
         used, padded = self._lanes_used, self._lanes_padded
@@ -246,7 +246,7 @@ class JaxBackend(CryptoBackend):
     @property
     def kernel_choices(self) -> dict:
         """Stable {shape key tuple: use_pallas} of every pinned choice
-        this backend can run with (bench emits it as `kernel_choices`)."""
+        this backend can run with (chip_smoke.py prints it)."""
         if self._tuner is not None:
             return self._tuner.choices_snapshot()
         return {k: self._static_choice[k]

@@ -25,10 +25,6 @@ multi-verify of Shelley/Ledger/Ledger.hs:279-284, batched per SURVEY.md §7.
 """
 from __future__ import annotations
 
-import functools
-
-import numpy as np
-
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -57,25 +53,8 @@ def _mul_form() -> str:
     return "shifted" if _interpret() else "columns"
 
 
-def _pt_add(p, q, n):
-    return EJ.pt_add(p, q, n)
-
-
 def _pt_double(p):
     return EJ.pt_double(p)
-
-
-def _select_bit(table, idx):
-    """4-entry point-table select by 2-bit index (N,) — where-chain, no
-    one-hot multiply (cheaper on the VPU than the 4-way one-hot sum)."""
-    out = []
-    for c in range(4):
-        t = table[0][c]
-        t = jnp.where((idx == 1)[None, :], table[1][c], t)
-        t = jnp.where((idx == 2)[None, :], table[2][c], t)
-        t = jnp.where((idx == 3)[None, :], table[3][c], t)
-        out.append(t)
-    return tuple(out)
 
 
 def _select16(table, idx):
@@ -100,77 +79,6 @@ def _select16(table, idx):
         t = jnp.where((hi == 3)[None, :], groups[3], t)
         out.append(t)
     return tuple(out)
-
-
-def _ed25519_verify_kernel(yA_ref, signA_ref, yR_ref, signR_ref,
-                           s_bits_ref, k_bits_ref, ok_ref):
-    """One TILE of full Ed25519 verification: decompress A and R, run the
-    windowed (w=2, 128-iteration) dual-scalar ladder Q = [s]B + [k](-A)
-    over a 16-entry joint table, compare vs R."""
-    n = TILE
-    yA = yA_ref[:]
-    yR = yR_ref[:]
-    signA = signA_ref[0, :]
-    signR = signR_ref[0, :]
-    xA, okA = EJ.device_decompress(yA, signA)
-    xR, okR = EJ.device_decompress(yR, signR)
-    one = F.const_batch(1, n)
-    nax = F.sub(yA * 0, xA)
-    negA = (nax, yA, one, F.mul(nax, yA))
-    gx, gy = ed.to_affine(ed.BASE)
-    ident = EJ._identity_like(yA)
-    Bs = EJ._const_smalls(gx, gy, n, ident)
-    As = EJ._smalls_of(negA, n, ident)
-    table = EJ.joint_table_16(Bs, As, n)      # T[4j+i] = [i]B + [j](-A)
-
-    def body(i, Q):
-        Q = _pt_double(_pt_double(Q))
-        idx = (2 * s_bits_ref[2 * i, :] + s_bits_ref[2 * i + 1, :]) \
-            + 4 * (2 * k_bits_ref[2 * i, :] + k_bits_ref[2 * i + 1, :])
-        return _pt_add(Q, _select16(table, idx), n)
-
-    Q = lax.fori_loop(0, 128, body, ident)
-    X, Y, Z, _ = Q
-    d1 = F.sub(F.mul(xR, Z), X)
-    d2 = F.sub(F.mul(yR, Z), Y)
-    ok = jnp.logical_and(jnp.logical_and(okA, okR),
-                         jnp.logical_and(F.is_zero(d1), F.is_zero(d2)))
-    ok_ref[0, :] = ok.astype(jnp.int32)
-
-
-def _ed25519_verify_call(yA, signA2d, yR, signR2d, s_bits, k_bits, n: int):
-    grid = n // TILE
-    lane = lambda i: (0, i)     # block index along the lane axis
-    limb_spec = pl.BlockSpec((F.NLIMBS, TILE), lane,
-                             memory_space=pltpu.VMEM)
-    sign_spec = pl.BlockSpec((1, TILE), lane, memory_space=pltpu.VMEM)
-    bits_spec = pl.BlockSpec((256, TILE), lane, memory_space=pltpu.VMEM)
-    with F.mul_impl(_mul_form()):
-        return pl.pallas_call(
-            _ed25519_verify_kernel,
-            grid=(grid,),
-            in_specs=[limb_spec, sign_spec, limb_spec, sign_spec,
-                      bits_spec, bits_spec],
-            out_specs=pl.BlockSpec((1, TILE), lane,
-                                   memory_space=pltpu.VMEM),
-            out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
-            interpret=_interpret(),
-        )(yA, signA2d, yR, signR2d, s_bits, k_bits)
-
-
-# always jitted: an un-jitted pallas_call re-lowers and re-compiles on
-# EVERY invocation (tens of seconds to minutes per kernel for the chip —
-# tests/test_chip_compile.py records them), and jit-of-interpret
-# compiles the interpreted kernel into one XLA:CPU program off-chip
-_ed25519_verify_jit = jax.jit(_ed25519_verify_call,
-                              static_argnames=("n",))
-
-
-def ed25519_verify_pallas(yA, signA, yR, signR, s_bits, k_bits, n: int):
-    """Batched Ed25519 verify, pallas path.  Inputs as in
-    ed25519_jax.verify_full_core; n must be a multiple of TILE."""
-    return _ed25519_verify_jit(yA, signA.reshape(1, -1), yR,
-                               signR.reshape(1, -1), s_bits, k_bits, n)
 
 
 # ---------------------------------------------------------------------------
@@ -479,22 +387,3 @@ _kes_hash_jit = jax.jit(_kes_hash_call, static_argnames=("n",))
 def kes_hash_pallas(mw, ew):
     """(16, N) message words + (8, N) expected digests -> (1, N) ok."""
     return _kes_hash_jit(jnp.asarray(mw), jnp.asarray(ew), mw.shape[1])
-
-
-def batch_verify_ed25519(vks, msgs, sigs) -> list[bool]:
-    """End-to-end pallas-batched verify (host prep identical to the XLA
-    path; padding to a TILE multiple)."""
-    n = len(vks)
-    if n == 0:
-        return []
-    m = ((n + TILE - 1) // TILE) * TILE
-    vks = list(vks) + [b"\x00" * 32] * (m - n)
-    msgs = list(msgs) + [b""] * (m - n)
-    sigs = list(sigs) + [b"\x00" * 64] * (m - n)
-    arrays, parse_ok = EJ.prepare_bytes_batch(vks, msgs, sigs)
-    yA, signA, yR, signR, s_bits, k_bits = arrays
-    ok = np.asarray(ed25519_verify_pallas(
-        jnp.asarray(yA), jnp.asarray(signA), jnp.asarray(yR),
-        jnp.asarray(signR), jnp.asarray(s_bits), jnp.asarray(k_bits),
-        m))[0]
-    return [bool(o) and bool(p) for o, p in zip(ok[:n], parse_ok[:n])]

@@ -20,7 +20,7 @@ bytes (points) or the KES hash-path identity (kes.hash_path_key), with
 counters (`device_fills`, `filled_keys`, `hits`, `misses`, `evictions`)
 so the warm-path guarantee — a cache-warm window does ZERO per-key
 decompression/table-build device calls — is assertable in tests and
-readable in bench logs.
+readable in a run's counters.
 
 Unlike the r5 A128Cache, undecodable keys are cached too (as negative
 entries): a bad key repeated across windows used to re-dispatch the fill
@@ -33,10 +33,10 @@ ed25519_jax lazily inside `_fill`.
 Counters live in the observability registry (ISSUE 7): the process-wide
 cache registers its hit/miss/device_fill/eviction counters under the
 `precompute.*` namespace so metrics snapshots, the Prometheus
-exposition and the bench JSON all read ONE source of truth — while the
-original attribute names (`cache.hits`, `cache.device_fills += 1`, ...)
-keep working as read/write property aliases, so every existing
-assertion and call site is untouched.  Per-instance caches (tests)
+exposition and the benchmark's counters all read ONE source of truth —
+while the original attribute names (`cache.hits`,
+`cache.device_fills += 1`, ...) keep working as read/write property
+aliases, so every existing assertion and call site is untouched.  Per-instance caches (tests)
 carry private unregistered counters with the same API.
 """
 from __future__ import annotations
@@ -121,7 +121,7 @@ class PrecomputeCache:
         # counters: the warm-path contract is `device_fills`/`filled_keys`
         # flat across a warm window (zero per-key device work).  They are
         # `always` instruments — load-bearing program state asserted by
-        # bench/tests, counted whether or not observation is enabled —
+        # tests, counted whether or not observation is enabled —
         # and only the process-wide cache binds them into the global
         # registry (per-instance caches in tests stay private).
         mk = ((lambda n, **kw: _metrics.counter(n, always=True, **kw))

@@ -183,7 +183,8 @@ class Mux:
         # per-peer traffic accounting (ISSUE 14), built lazily on the
         # first ENABLED write: with observation off the per-SDU cost is
         # exactly one flag read — no label formatting, no instrument
-        # writes (the bench --smoke disabled-observation probe)
+        # writes (tests/test_netobs.py::
+        # test_mux_disabled_observation_is_free)
         self._io: Optional[_net.MuxIO] = None
 
     def _io_acct(self) -> _net.MuxIO:

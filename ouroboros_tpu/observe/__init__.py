@@ -29,12 +29,13 @@ Three parts, one seam (ISSUE 7):
 
 Defaults: metric writes are ON (an enabled counter bump is one flag
 read plus an int add) and span recording is OFF (spans allocate and
-read clocks; the bench/tests enable them around regions they study).
+read clocks; the benchmark and tests enable them around regions they
+study).
 Both layers are near-free when off — `spans.span()` returns a shared
 null context manager, a gated metric write is a single flag read — and
 `enable()/disable()` flip them together.  The migrated precompute/
 autotune counters are `always=True`: they are load-bearing program
-state (bench and tests assert on them) that the registry exports, not
+state (tests assert on them) that the registry exports, not
 observation that the flag may drop.
 """
 from __future__ import annotations
@@ -46,7 +47,7 @@ from .flight import FLIGHT, FlightRecorder
 from .metrics import REGISTRY, Counter, Gauge, Histogram, MetricsRegistry
 from .netmetrics import peer_label
 from .propagation import FleetTelemetry, PropagationTracker
-from .spans import RECORDER, Span, SpanRecorder, phase_totals, span
+from .spans import RECORDER, Span, SpanRecorder, span
 
 # NOTE: observe.scrape is deliberately NOT imported here — it pulls in
 # the network stack (snocket/mux), which itself imports observe.metrics;
@@ -58,7 +59,7 @@ __all__ = [
     "MetricsRegistry", "PropagationTracker", "Span", "SpanRecorder",
     "adapter", "counting_node_tracers", "disable", "enable", "enabled",
     "export", "flight", "metrics", "metrics_node_tracers", "netmetrics",
-    "peer_label", "phase_totals", "propagation", "span", "spans",
+    "peer_label", "propagation", "span", "spans",
 ]
 
 

@@ -97,7 +97,7 @@ def prometheus_text(reg: MetricsRegistry,
 def parse_prometheus_text(text: str) -> dict:
     """Minimal exposition parser: {metric_name: float} for plain sample
     lines (bucketed samples keep their label suffix as part of the key).
-    Used by the bench smoke gate to assert the exporter round-trips."""
+    Used by the tests to assert the exporter round-trips."""
     out: dict = {}
     for line in text.splitlines():
         line = line.strip()

@@ -1,8 +1,8 @@
 """Flight recorder — a bounded ring of the last moments before a failure.
 
 The reference stack keeps an always-on tracer seam precisely so a crash
-leaves evidence (SURVEY.md §5); the bench-scoped observe/ layer from
-ISSUE 7 cannot play that role — spans are drained per rep and metric
+leaves evidence (SURVEY.md §5); the observe/ layer from ISSUE 7
+cannot play that role — spans are drained per run and metric
 history is a point-in-time snapshot.  This module is the crash-proof
 analog: while ARMED, every span close, every instrument write and any
 `note()`d typed event lands in one process-wide ring

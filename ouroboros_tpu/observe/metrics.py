@@ -4,27 +4,27 @@ deterministic snapshots.
 The reference threads a contravariant `Tracer m a` through every
 constructor but ships no metrics layer; our reproduction had outgrown
 its ad-hoc equivalents (private counters in crypto/precompute.py and
-crypto/autotune.py, one-off breakdowns printed by bench.py).  This
+crypto/autotune.py, one-off breakdowns printed by scripts).  This
 module is the one seam they all migrate into.
 
 Design constraints, in order:
 
 1. **Near-free when disabled.**  Every observational write goes through
    one flag read (`registry.enabled`); a disabled registry performs NO
-   instrument writes at all — asserted by the bench --smoke probe via
-   `data_writes`, which counts gated writes that actually landed.
+   instrument writes at all — asserted through `data_writes`, which
+   counts gated writes that actually landed
+   (tests/test_served_replay.py::test_observation_off_writes_nothing).
 2. **Deterministic snapshots.**  `snapshot()` returns instruments in
    sorted name order with values that are pure functions of the workload
-   at a fixed seed (counts, not wall times), so two bench runs emit
-   byte-identical `metrics` sections and the output stays diffable.
+   at a fixed seed (counts, not wall times), so two runs emit
+   byte-identical snapshots and the output stays diffable.
    Instruments that hold measured durations or other run-varying values
    are created with `stable=False` and excluded from `snapshot()`
    (they still appear in the Prometheus exposition, which is allowed to
    vary run to run).
 3. **Functional counters stay functional.**  The migrated precompute /
-   autotune counters are *load-bearing* — tests and bench assertions
-   gate on them (warm windows do zero fills; frozen tuners reject
-   writes).  Those are created with `always=True`: they count whether or
+   autotune counters are *load-bearing* — tests gate on them (warm
+   windows do zero fills; frozen tuners reject writes).  Those are created with `always=True`: they count whether or
    not observation is enabled, and their writes are not charged to
    `data_writes` (they are program state that happens to be exported,
    not observation).
@@ -260,9 +260,8 @@ class MetricsRegistry:
 
     def snapshot(self, include_unstable: bool = False) -> dict:
         """{name: value} in sorted name order.  Only `stable` instruments
-        by default — the deterministic, diffable subset (bench emits this
-        verbatim into its JSON).  Histograms render as nested dicts with
-        repr'd bucket edges."""
+        by default — the deterministic, diffable subset.  Histograms
+        render as nested dicts with repr'd bucket edges."""
         out = {}
         for name in sorted(self._instruments):
             inst = self._instruments[name]
@@ -315,7 +314,7 @@ def latency_histogram(name: str) -> Histogram:
     """A duration histogram on the shared LATENCY_BUCKETS vocabulary.
     Measured seconds vary run to run, so latency instruments are always
     `stable=False` — exported live (scrape/Prometheus) but excluded from
-    the deterministic snapshot bench embeds.  Bind the handle ONCE at
+    the deterministic snapshot.  Bind the handle ONCE at
     module/init scope: `observe()` through a fresh registry lookup on a
     hot path is the OBS002 lint."""
     return REGISTRY.histogram(name, buckets=LATENCY_BUCKETS, stable=False)

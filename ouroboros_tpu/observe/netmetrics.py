@@ -23,14 +23,14 @@ ouro-lint rule OBS003 enforces the seam: a metric name built by
 f-string/concat from runtime values anywhere else in the package is a
 finding — route it through here instead.
 
-Cost discipline (the bench --smoke disabled-observation probe): every
-label resolution bumps :data:`LABEL_FORMATS` (an ``always`` counter, so
-it counts even while observation is off) — call sites like the mux hot
-path must therefore guard on ``registry.enabled`` BEFORE touching this
-module, and the probe asserts the counter stayed flat with observation
-disabled.  Labeled series are ``stable=False``: peer sets vary run to
+Cost discipline (tests/test_netobs.py::
+test_mux_disabled_observation_is_free): every label resolution bumps
+:data:`LABEL_FORMATS` (an ``always`` counter, so it counts even while
+observation is off) — call sites like the mux hot path must therefore
+guard on ``registry.enabled`` BEFORE touching this module, and the test
+asserts the counter stayed flat with observation disabled.  Labeled series are ``stable=False``: peer sets vary run to
 run, so they live in the live exposition, never the deterministic
-snapshot bench embeds.
+snapshot.
 
 :class:`MuxIO` is the mux's per-connection traffic accounting: registry
 series per (peer, protocol-number) plus plain-int local totals that

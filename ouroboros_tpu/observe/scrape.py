@@ -16,7 +16,8 @@ uses — which buys three properties for free:
   so tests see exact virtual emission times;
 - **clean shutdown**: server/emitter are runtime threads with explicit
   `stop()` — cancel-and-join on every exit path, no leaked threads
-  (asserted by tests and the bench --smoke scrape probe).
+  (asserted by tests/test_scrape.py and, after a real replay, by
+  tests/test_served_replay.py).
 
 Wire format (protocol number 0x7A50, outside every mini-protocol's
 range): the client sends one SDU whose payload is ``GET /metrics``; the

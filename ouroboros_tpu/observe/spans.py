@@ -1,9 +1,8 @@
 """Hierarchical timing spans with explicit device fencing.
 
 A span is one named, categorised interval on the host timeline; spans
-nest, forming one tree per top-level region (a replay window, a bench
-rep, a compile).  Categories are the replay phase vocabulary the bench
-attributes time to:
+nest, forming one tree per top-level region (a replay window, a
+compile).  Categories are the replay's phase vocabulary:
 
     host-seq   the sequential host pass (nonce evolution, envelope
                checks, proof extraction)
@@ -26,7 +25,7 @@ steps, and sim tests must see exact virtual durations.
 
 Fencing: a span created with `fence=True` drains the async dispatch
 queue (`jax.block_until_ready` on a dummy transfer — the same fence the
-autotuner and bench use) at BOTH edges, so the measured interval covers
+autotuner uses) at BOTH edges, so the measured interval covers
 exactly the work dispatched inside it and inherits nothing in flight.
 The fence is skipped when jax was never imported — host-only flows must
 not pull in the device stack just by timing themselves.
@@ -92,10 +91,6 @@ from typing import List, Optional
 
 from ..simharness import runtime as _runtime
 from . import metrics as _metrics
-
-PHASES = ("host-seq", "dispatch", "device", "compile", "sync", "stall",
-          "disk")
-
 
 def monotonic_now() -> float:
     """Virtual monotonic time under an active sim/IO runtime, host
@@ -271,7 +266,7 @@ class SpanRecorder:
             # already stamped: this span was adopted as a child by an
             # earlier out-of-order close (or its CM exited twice);
             # recording it again would attach it under a second
-            # parent/root and double-count it in phase_totals
+            # parent/root and count it twice
             return
         sp.t1 = monotonic_now()
         fl = self.flight
@@ -325,72 +320,3 @@ def span(name: str, cat: str = "host-seq", fence: bool = False, **meta):
 
 def enabled() -> bool:
     return RECORDER.enabled
-
-
-def intervals_of(spans_: List[Span], cat: Optional[str] = None,
-                 name: Optional[str] = None) -> list:
-    """(t0, t1) intervals of every completed span in the forest matching
-    `cat` and/or `name` (None = match all).  Inputs for overlap math —
-    the bench's host-under-device attribution."""
-    out = []
-    for root in spans_:
-        for sp in root.walk():
-            if sp.t1 is None:
-                continue
-            if cat is not None and sp.cat != cat:
-                continue
-            if name is not None and sp.name != name:
-                continue
-            out.append((sp.t0, sp.t1))
-    return out
-
-
-def merge_intervals(intervals: list) -> list:
-    """Union of intervals as a sorted, disjoint list."""
-    merged: list = []
-    for t0, t1 in sorted(intervals):
-        if merged and t0 <= merged[-1][1]:
-            merged[-1] = (merged[-1][0], max(merged[-1][1], t1))
-        else:
-            merged.append((t0, t1))
-    return merged
-
-
-def overlap_seconds(a: list, b: list) -> float:
-    """Total seconds where the union of `a` intersects the union of `b`
-    — e.g. host-seq time HIDDEN under in-flight device time.  The two
-    forests' clocks must be comparable (same monotonic_now source)."""
-    a, b = merge_intervals(a), merge_intervals(b)
-    i = j = 0
-    total = 0.0
-    while i < len(a) and j < len(b):
-        lo = max(a[i][0], b[j][0])
-        hi = min(a[i][1], b[j][1])
-        if hi > lo:
-            total += hi - lo
-        if a[i][1] <= b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return total
-
-
-def phase_totals(spans_: List[Span]) -> dict:
-    """Seconds per category over a forest of span trees.
-
-    Each span contributes its SELF time (duration minus its children's
-    durations) to its own category, so a dispatch span containing a
-    compile span attributes the compile seconds to `compile`, never
-    twice.  Categories outside PHASES aggregate under their own name."""
-    totals: dict = {}
-
-    def add(sp: Span):
-        inner = sum(c.duration for c in sp.children)
-        totals[sp.cat] = totals.get(sp.cat, 0.0) + max(
-            0.0, sp.duration - inner)
-        for c in sp.children:
-            add(c)
-
-    for sp in spans_:
-        add(sp)
-    return totals

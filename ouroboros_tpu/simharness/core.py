@@ -512,7 +512,7 @@ def run_trace(main: Coroutine, seed: int = 0,
 def leaked_threads(trace: Trace) -> set:
     """Tids forked during the run that never reached a terminal event
     (stop/cancelled/fail) — the shared thread-leak gate (chaos sweeps,
-    scrape-endpoint shutdown tests, bench --smoke).  One definition of
+    scrape-endpoint shutdown tests).  One definition of
     "terminal" so a future event kind cannot silently skew one copy."""
     forked = {e.tid for e in trace if e.kind == "fork"}
     ended = {e.tid for e in trace

@@ -5,7 +5,7 @@ LedgerDB from the newest on-disk snapshot (LedgerDB/OnDisk.hs:277) and
 streams ImmutableDB chunks through iterators (Impl/Iterator.hs) instead
 of materialising the chain; DiskPolicy decides when replay checkpoints
 (DiskPolicy.hs).  Our replay so far loaded every block into memory and
-started from genesis — fine for a bench chain, not for a million-block
+started from genesis — fine for a test chain, not for a million-block
 mainnet DB.
 
 This module closes that gap with a third pipeline stage in front of the
@@ -25,8 +25,7 @@ Disk + decode seconds hide behind device verify exactly the way the
 host sequential pass does: the prefetcher feeds a third on/off signal
 into the shared ProgressTracker ({prefetch busy} ∩ {≥1 window in
 flight} accumulates O(1) into ``disk_hidden_secs``), and its work is
-span-recorded under the ``disk`` phase so bench/obsreport attribute it
-beside host-seq/device.
+span-recorded under the ``disk`` phase, beside host-seq/device.
 
 Era discipline: the engine is protocol-agnostic — a Cardano-composed
 DB (eras/cardano.py) replays Byron EBBs through the Shelley translation
@@ -489,8 +488,8 @@ class StreamReplayResult:
 
 class StreamingReplayEngine:
     """One replay of one on-disk chain DB: restore, stream, verify,
-    checkpoint.  Construct per run (`db_analyser --resume`, bench's
-    stream leg, the kill/resume tests); the heavyweight state — key
+    checkpoint.  Construct per run (`db_analyser --resume`, the
+    benchmark's replays, the kill/resume tests); the heavyweight state — key
     caches, compiled programs — lives in the backend and survives
     across engines.
 

@@ -38,11 +38,11 @@ from ouroboros_tpu.crypto.backend import (
     CpuRefBackend, Ed25519Req, KesReq, VrfReq,
 )
 from ouroboros_tpu.crypto.batching import (
-    BreakEvenTable, ModeledBackend, PrecheckedBackend, ServiceConfig,
-    ServiceStopped, VerifyService, calibrate_break_even,
-    validate_headers_coalesced,
+    BreakEvenTable, PrecheckedBackend, ServiceConfig, ServiceStopped,
+    VerifyService, calibrate_break_even, validate_headers_coalesced,
 )
 from ouroboros_tpu.ledgers import MockLedger, TxOut, make_tx
+from ouroboros_tpu.testing.modeled import ModeledBackend
 
 _leaked = sim.leaked_threads
 

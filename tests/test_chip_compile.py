@@ -1,6 +1,6 @@
 """Ask the chip's compiler, without a chip (rehearsal 3 of the
 on-chip-measurement guide): the programs of ONE 1024-block replay window
-of bench's chain, at their real widths, compiled for a described TPU
+of chip_smoke.py's chain, at their real widths, compiled for a described TPU
 v5e by the compiler installed here.
 
 What interpret mode cannot show, this does: a block not aligned to the
@@ -38,8 +38,8 @@ from ouroboros_tpu.crypto import jax_backend as JB
 from ouroboros_tpu.crypto import pallas_kernels as PK
 from ouroboros_tpu.crypto import vrf_jax as VJ
 
-# one 1024-block window of bench's chain (bench.py: 2 txs/block, depth-10
-# KES): OCert + KES leaf + 2 witnesses per block, 2 VRF proofs per block,
+# one 1024-block window of chip_smoke.py's chain (SYNTH: 2 txs/block,
+# depth-10 KES): OCert + KES leaf + 2 witnesses per block, 2 VRF proofs per block,
 # the next-next window's betas, the cold KES hash-path jobs.  Since the
 # hash-path outcomes are cached per (pool, period) a window of this
 # chain ships ~1,200 Blake2b jobs (bucket 2048); a window in which every

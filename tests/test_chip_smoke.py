@@ -205,8 +205,8 @@ def test_chip_smoke_refuses_to_run_off_the_chip():
 
 
 def test_db_synth_never_imports_jax(tmp_path):
-    """One process per chip: chip_smoke.py and bench.py hold the chip
-    and run db_synth as a child, which is safe only while a whole synth
+    """One process per chip: chip_smoke.py holds the chip and runs
+    db_synth as a child, which is safe only while a whole synth
     run — the shelley path the smoke uses — leaves JAX unimported."""
     prog = (
         "import runpy, sys\n"

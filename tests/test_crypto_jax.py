@@ -91,8 +91,9 @@ def test_point_add_double_match_python():
 
 
 # slow: ~27s tracing this test's own ed25519 batch shape; valid +
-# tampered ed25519 verdicts vs the reference are tier-1-gated by bench
-# --smoke's verdict-parity mixed batch (which includes a bad-sig req)
+# tampered ed25519 verdicts vs the reference are tier-1-gated by
+# test_served_replay.py::test_mixed_verdict_equals_reference (cases
+# ed25519-good and ed25519-bad-signature)
 @pytest.mark.slow
 def test_batch_verify_valid_and_tampered():
     n = 12
@@ -116,8 +117,9 @@ def test_batch_verify_valid_and_tampered():
 
 
 # slow: ~26s tracing a second ed25519 bucket shape just for the padding
-# probe; bench --smoke's replay + verdict-parity already run padded
-# buckets (10 reqs in a 16-lane bucket) with verdict parity in tier-1
+# probe; test_served_replay.py's replay and mixed batch already run
+# padded buckets (10 reqs in a 16-lane bucket) with verdict parity in
+# tier-1
 @pytest.mark.slow
 def test_batch_verify_padding_hits_same_result():
     sk = hashlib.sha256(b"pad").digest()
@@ -128,7 +130,8 @@ def test_batch_verify_padding_hits_same_result():
 
 # slow: ~55s tracing this test's own composite shape; the VRF+KES
 # verify_mixed path (valid + corrupted, vs CpuRefBackend) is
-# tier-1-gated at a shared shape by bench --smoke's verdict-parity
+# tier-1-gated at a shared shape by test_served_replay.py::
+# test_mixed_verdict_equals_reference (the vrf-* and kes-* cases)
 @pytest.mark.slow
 def test_jax_backend_vrf_and_kes():
     from ouroboros_tpu.crypto.jax_backend import JaxBackend
@@ -167,9 +170,8 @@ def test_vrf_batch_autotunes_under_its_own_key(monkeypatch):
     piggyback on (test_jax_backend_vrf_and_kes) moved to the slow lane
     in ISSUE 14, leaving this test paying its own ~45s fold-program
     trace in tier-1; the vrf fold path itself stays tier-1-gated by
-    bench --smoke's fold-verdict parity + fenced vrf-spread probes, and
-    the key separation is re-asserted on every hardware bench round
-    (kernel_choices are emitted from the tuner, keyed)."""
+    test_served_replay.py::test_vrf_batch_fold_form_equals_reference
+    and ::test_fold_verdict_names_the_first_bad_request."""
     from ouroboros_tpu.crypto import vrf_ref
     from ouroboros_tpu.crypto.backend import VrfReq
     from ouroboros_tpu.crypto.jax_backend import JaxBackend
@@ -190,8 +192,9 @@ def test_vrf_batch_autotunes_under_its_own_key(monkeypatch):
 
 
 # slow: ~35s tracing this test's own vrf batch shape; beta correctness
-# is tier-1-gated through bench --smoke's state-hash parity (betas feed
-# the nonce evolution) and the fold-verdict parity probe
+# is tier-1-gated through test_served_replay.py::
+# test_device_replay_state_hash_equals_reference (betas feed the nonce
+# evolution)
 @pytest.mark.slow
 def test_vrf_jax_batch_parity_and_betas():
     """batch_verify_vrf + batch_betas vs the pure-Python oracle, incl.

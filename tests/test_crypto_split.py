@@ -163,7 +163,8 @@ def test_y_canonical_mask():
 @pytest.mark.device
 # slow: ~26s tracing the split-words program at this test's own shape;
 # bit-exactness of the packed-words cores stays covered nightly, and
-# the end-to-end verdict path is tier-1-gated by bench --smoke parity
+# the end-to-end verdict path is tier-1-gated by test_served_replay.py::
+# test_mixed_verdict_equals_reference
 @pytest.mark.slow
 def test_split_words_verify_bit_exact_vs_reference():
     n = 128
@@ -220,7 +221,8 @@ def test_jax_backend_mixed_window_with_kes_device_hashes():
     slow: ~75s of per-process composite tracing for this test's own
     window shape (no persistent cache avoids tracing — the PR 8
     discipline); tier-1 gates the same mixed cold-KES window with
-    tampered hash paths via bench --smoke's verdict-parity probe."""
+    tampered hash paths in test_served_replay.py::
+    test_mixed_verdict_equals_reference[kes-tampered-merkle-node]."""
     from ouroboros_tpu.crypto import vrf_ref
     from ouroboros_tpu.crypto.backend import (
         CpuRefBackend, Ed25519Req, KesReq, VrfReq,

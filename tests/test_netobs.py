@@ -10,8 +10,7 @@ Acceptance gates covered here:
 - mux byte accounting matches the traffic a test injects exactly on a
   fault-free link;
 - with observation disabled the mux hot path performs zero per-peer
-  instrument writes and zero label formats (the bench --smoke probe's
-  unit form);
+  instrument writes and zero label formats;
 - the scrape endpoint sheds fault-injected connections without leaking
   handlers or stalling the PeriodicEmitter.
 """
@@ -147,7 +146,8 @@ def test_mux_accounting_matches_injected_traffic():
 def test_mux_disabled_observation_is_free():
     """With the registry disabled the mux hot path performs zero gated
     writes, zero label formats, and never builds the accounting object
-    — the tier-1 bench --smoke probe's unit form."""
+    (the served replay's counterpart is test_served_replay.py::
+    test_observation_off_writes_nothing)."""
     om.REGISTRY.disable()
     writes0 = om.REGISTRY.data_writes
     formats0 = net.LABEL_FORMATS.value

@@ -200,7 +200,6 @@ def test_span_sim_clock_exact_virtual_durations():
     assert rep.duration == 3.75
     (drain,) = rep.children
     assert drain.duration == 1.25
-    assert spans.phase_totals([rep]) == {"host-seq": 2.5, "device": 1.25}
 
 
 def test_fenced_span_fences_both_edges(monkeypatch):
@@ -220,17 +219,6 @@ def test_device_fence_never_imports_jax(monkeypatch):
     monkeypatch.delitem(sys.modules, "jax", raising=False)
     spans.device_fence()                   # must be a pure no-op
     assert "jax" not in sys.modules
-
-
-def test_phase_totals_attributes_self_time_once():
-    outer = Span("submit", "dispatch", 0.0)
-    outer.t1 = 10.0
-    inner = Span("composite", "compile", 2.0)
-    inner.t1 = 7.0
-    outer.children.append(inner)
-    totals = spans.phase_totals([outer])
-    assert totals == {"dispatch": 5.0, "compile": 5.0}
-    assert sum(totals.values()) == outer.duration   # nothing counted twice
 
 
 def test_out_of_order_close_reparents_and_closes_survivors():
@@ -500,9 +488,8 @@ def test_precompute_counters_live_in_global_registry():
 
 
 # ---------------------------------------------------------------------------
-# ISSUE 8: per-thread span stacks + interval/overlap math (the pipelined
-# replay's producer and consumer record concurrently; bench's `overlap`
-# section is computed from these primitives)
+# ISSUE 8: per-thread span stacks (the pipelined replay's producer and
+# consumer record concurrently)
 # ---------------------------------------------------------------------------
 
 def test_spans_per_thread_stacks_never_cross_adopt():
@@ -557,30 +544,6 @@ def test_spans_concurrent_closes_are_recorded_without_loss():
     assert len(roots) == 200
     assert len({r.name for r in roots}) == 200
     assert rec.dropped == 0
-
-
-def test_interval_and_overlap_math():
-    a = [(0.0, 1.0), (0.5, 2.0), (3.0, 4.0)]
-    assert spans.merge_intervals(a) == [(0.0, 2.0), (3.0, 4.0)]
-    # host [0,2]u[3,4]; device [1.5, 3.5] -> overlap 0.5 + 0.5
-    assert spans.overlap_seconds(a, [(1.5, 3.5)]) == pytest.approx(1.0)
-    assert spans.overlap_seconds([], [(0, 1)]) == 0.0
-    assert spans.overlap_seconds([(0, 1)], [(2, 3)]) == 0.0
-
-
-def test_intervals_of_filters_by_cat_and_name():
-    rec = SpanRecorder(enabled=True)
-    with rec.span("window.host_seq", cat="host-seq"):
-        pass
-    with rec.span("window.drain", cat="device"):
-        pass
-    with rec.span("producer.stall", cat="stall"):
-        pass
-    roots = rec.drain()
-    assert len(spans.intervals_of(roots, name="window.drain")) == 1
-    assert len(spans.intervals_of(roots, cat="stall")) == 1
-    assert len(spans.intervals_of(roots)) == 3
-    assert spans.intervals_of(roots, cat="compile") == []
 
 
 # ---------------------------------------------------------------------------

@@ -14,8 +14,8 @@ The interpret runs use field_jax's small shifted-multiplication trace
 these three tests cost ~18 minutes of XLA:CPU compile+interpret per
 suite run (VERDICT r3 weak #7); shifted brings them to ~2.5 minutes with
 identical semantics (both forms are field-parity-tested).  On a real TPU
-the column-form kernels compile through Mosaic and are exercised by the
-flagship bench and the autotuned backend.
+the column-form kernels compile through Mosaic and are exercised by
+chip_smoke.py.
 """
 import hashlib
 
@@ -25,7 +25,7 @@ jax = pytest.importorskip("jax")
 
 import numpy as np  # noqa: E402
 
-from ouroboros_tpu.crypto import ed25519_ref, vrf_ref  # noqa: E402
+from ouroboros_tpu.crypto import vrf_ref  # noqa: E402
 from ouroboros_tpu.crypto import pallas_kernels as PK  # noqa: E402
 
 pytestmark = pytest.mark.device
@@ -144,26 +144,10 @@ def test_triple_ladder_matches_xla_form_and_reference():
 #    pallas interpreter stepping the ladders.
 # ---------------------------------------------------------------------------
 
-# slow: ~26s tracing the interpret-mode ed25519 kernel; gamma8 below
-# stays as the tier-1 pallas-interpret representative, and the ed25519
-# verdict path is tier-1-gated by bench --smoke parity
-@pytest.mark.slow
-def test_ed25519_pallas_interpret_bit_exact():
-    sk = hashlib.sha256(b"pallas-test").digest()
-    vk = ed25519_ref.public_key(sk)
-    n = 16                                  # 2 grid steps at TILE=8
-    msgs = [b"m%d" % i for i in range(n)]
-    sigs = [ed25519_ref.sign(sk, m) for m in msgs]
-    bad = {3, 9}
-    sigs = [bytes([s[0] ^ 1]) + s[1:] if i in bad else s
-            for i, s in enumerate(sigs)]
-    ok = PK.batch_verify_ed25519([vk] * n, msgs, sigs)
-    assert ok == [i not in bad for i in range(n)]
-
-
-# slow: ~57s tracing the interpret-mode VRF kernel; the ed25519 and
-# gamma8 interpret tests below keep pallas bit-exactness in tier-1,
-# and the VRF verdict path is tier-1-gated by bench --smoke parity
+# slow: ~57s tracing the interpret-mode VRF kernel; the gamma8
+# interpret test below keeps pallas bit-exactness in tier-1, and the VRF
+# verdict path is tier-1-gated by test_served_replay.py::
+# test_mixed_verdict_equals_reference
 @pytest.mark.slow
 def test_vrf_pallas_interpret_bit_exact():
     from ouroboros_tpu.crypto import vrf_jax

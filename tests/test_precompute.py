@@ -4,9 +4,8 @@ fenced autotuner (crypto/autotune.py).
 Host-only partition: LRU/eviction semantics (with a stubbed device
 fill), the KES hash-path outcome namespace, tuner persistence/freezing.
 Device partition: cold-vs-warm parity for every primitive through the
-real XLA kernels (the same contract the bench acceptance asserts: a
-cache-warm window does ZERO per-key fill dispatches and identical
-verdicts/betas).
+real XLA kernels (a cache-warm window does ZERO per-key fill dispatches
+and gives identical verdicts/betas).
 """
 import hashlib
 
@@ -238,7 +237,7 @@ def test_autotuner_persistence_round_trip(tmp_path):
     assert t2.get(("ed", 4096)) is True
     assert t2.get(("win", 16, 16, 0, 32)) is False
     assert t2.get(("vrf", 2048)) is None
-    # stable ordering for byte-identical bench kernel_choices blocks
+    # stable ordering: two runs report byte-identical kernel_choices
     assert list(t2.choices_snapshot()) == sorted(t2.choices_snapshot())
     t2.invalidate()
     assert Autotuner(path, "test-dev").get(("ed", 4096)) is None
@@ -319,13 +318,13 @@ def _mixed_reqs():
 @pytest.mark.device
 @pytest.mark.slow
 def test_cold_vs_warm_window_parity_and_zero_warm_fills():
-    """The bench acceptance contract, in miniature: identical verdicts
+    """The warm-window contract, in miniature: identical verdicts
     and betas cold and warm, with the warm window dispatching ZERO
     per-key fill kernels and ZERO Blake2b hash-path jobs.
 
     slow+device: ~2.5 min of XLA:CPU ladder executions — the tier-1
-    run keeps the same contract through `bench --smoke`
-    (tests/test_tools.py), which shares its window shapes; this test
+    run keeps the same contract through test_served_replay.py::
+    test_warm_batch_needs_no_fill_and_no_kes_hashing; this test
     adds the corrupted-lane beta/verdict sweep and the simple-batch
     cache-sharing checks on top."""
     jax = pytest.importorskip("jax")  # noqa: F841

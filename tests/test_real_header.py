@@ -96,7 +96,8 @@ class TestFullValidation:
     @pytest.mark.device
     # slow: ~65s of composite tracing for this header's window shape;
     # the real-bytes crypto stays tier-1 via test_ref_backend above,
-    # and jax-vs-cpu verdict parity is tier-1-gated by bench --smoke
+    # and jax-vs-cpu verdict parity is tier-1-gated by
+    # test_served_replay.py::test_mixed_verdict_equals_reference
     @pytest.mark.slow
     def test_jax_backend(self):
         from ouroboros_tpu.crypto.jax_backend import JaxBackend

@@ -210,12 +210,12 @@ def test_pipelined_jax_backend_matches(chain):
     """JaxBackend through the threaded+fold pipeline on a longer chain.
     slow: tracing this chain's window-composite/fold shapes costs ~3
     CPU-minutes per process (the persistent cache only skips the XLA
-    compile, not the trace) — tier-1 gates the same path end-to-end via
-    bench --smoke's state-hash parity in test_tools."""
+    compile, not the trace) — tier-1 gates the same path end-to-end in
+    test_served_replay.py::test_device_replay_state_hash_equals_reference."""
     jax = pytest.importorskip("jax")
     from ouroboros_tpu.crypto.jax_backend import JaxBackend
     ext, blocks, final = chain
-    # XLA-only, no autotune (like bench --smoke): the autotuner would
+    # XLA-only, no autotune (like test_served_replay.py): the autotuner would
     # MEASURE pallas+XLA candidates for every window/fold shape here —
     # minutes of AOT pallas compile with no extra coverage (kernel
     # selection has its own tests)
@@ -598,7 +598,7 @@ def test_pipeline_sim_model_race_free_at_k16():
 # The cheap accounting tests run in tier-1; the full mesh parity sweep is
 # slow-marked (one sharded composite costs minutes of XLA:CPU on this
 # container's experimental-shard_map jax) and tier-1 gates the same path
-# through `bench --smoke`'s sharded probe where affordable.
+# in tests/test_sharded_replay.py.
 # ---------------------------------------------------------------------------
 
 
@@ -650,9 +650,8 @@ def test_sharded_threaded_result_identical_to_sync_driver(chain):
     single-device driver on a valid, a tampered, and a truncated chain,
     with zero leaked producer threads and per-shard padding accounted.
     slow: compiles two sharded window composites (~minutes of XLA:CPU
-    each on experimental-shard_map jax); tier-1 gates the same path via
-    bench --smoke's sharded probe on containers where it is
-    affordable."""
+    each on experimental-shard_map jax); tier-1 gates the same path in
+    tests/test_sharded_replay.py."""
     jax = pytest.importorskip("jax")
     if len(jax.devices()) < 2:
         pytest.skip("needs >= 2 XLA devices (conftest forces 8)")

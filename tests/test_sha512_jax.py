@@ -64,9 +64,9 @@ def test_challenge_ok_device_matches_host_verifier():
 
     slow: compiles the full packed-words VRF verify kernel at a shape
     nothing else in the suite uses (~minutes of XLA:CPU).  The tier-1
-    coverage of the same fold path is bench --smoke's
-    fold_verdict_parity gate, which reuses the composite the smoke
-    already compiles."""
+    coverage of the same fold path is test_served_replay.py::
+    test_fold_verdict_names_the_first_bad_request, which reuses the
+    composite that file already compiles."""
     import jax.numpy as jnp
 
     from ouroboros_tpu.crypto import vrf_jax, vrf_ref

@@ -581,7 +581,8 @@ def test_stream_10k_block_multi_era_end_to_end(tmp_path):
     snapshot to a byte-identical final state hash.  slow: the 10k-block
     synth plus three large replays cost minutes of CPU even on the
     native backend; the tier-1 lane gates the same engine path via
-    bench --smoke's streaming probe and the 60-block tests above."""
+    test_served_replay.py's from-disk tests and the 60-block tests
+    above."""
     d = str(tmp_path / "bigdb")
     info = _synth_cardano(d, blocks=10_000, epoch_length=500,
                           chunk_size=100)

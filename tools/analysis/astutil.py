@@ -10,21 +10,14 @@ from . import REPO_ROOT
 
 def iter_py_files(*subdirs: str, exclude: Iterable[str] = (),
                   exclude_dirs: Iterable[str] = ()) -> Iterator[str]:
-    """Yield absolute paths of .py files under repo-relative `subdirs`
-    (an entry may also be a single repo-relative .py FILE, e.g. a
-    top-level script like bench.py), skipping repo-relative paths in
-    `exclude` and whole repo-relative directory prefixes in
-    `exclude_dirs`."""
+    """Yield absolute paths of .py files under repo-relative `subdirs`,
+    skipping repo-relative paths in `exclude` and whole repo-relative
+    directory prefixes in `exclude_dirs`."""
     excluded = {e.replace("/", os.sep) for e in exclude}
     dir_prefixes = tuple(d.rstrip("/").replace("/", os.sep) + os.sep
                          for d in exclude_dirs)
     for sub in subdirs:
         base = os.path.join(REPO_ROOT, sub)
-        if os.path.isfile(base):
-            if base.endswith(".py") and \
-                    os.path.relpath(base, REPO_ROOT) not in excluded:
-                yield base
-            continue
         for dirpath, _dirnames, filenames in os.walk(base):
             for fn in sorted(filenames):
                 if not fn.endswith(".py"):

@@ -22,15 +22,14 @@ retrace hazards inside those bodies:
   can never hit (and a tracer error unless marked static).
 - JAX006 jit-in-loop: jax.jit / shard_map / pallas_call CONSTRUCTED
   lexically inside a for/while loop — a per-window or per-rep kernel
-  rebuild, the retrace hazard behind BENCH_r05's mid-bench retunes.
+  rebuild, a retrace every time round.
   Memoised builders called from loops are fine; building the wrapper in
   the loop body never is.
 
 The traced-set computation is deliberately same-module only: cross-module
 calls (e.g. field_jax helpers) are linted in their own module when they
 are jitted/traced there, which keeps the pass O(files) with no import cost.
-The scan covers crypto/, parallel/ and the top-level bench.py (the
-per-rep loops the JAX006 hazard lives in).
+The scan covers crypto/ and parallel/.
 """
 from __future__ import annotations
 
@@ -40,7 +39,7 @@ from typing import Dict, Iterable, List, Optional, Set
 from . import Finding, register, relpath
 from .astutil import QualnameVisitor, dotted_name, iter_py_files, parse_file
 
-SCAN_DIRS = ("ouroboros_tpu/crypto", "ouroboros_tpu/parallel", "bench.py")
+SCAN_DIRS = ("ouroboros_tpu/crypto", "ouroboros_tpu/parallel")
 
 _JIT_NAMES = {"jax.jit", "jit", "pjit", "jax.pjit"}
 # Calls whose function-valued arguments are traced when invoked.

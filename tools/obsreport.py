@@ -1,42 +1,9 @@
-"""obsreport — human-readable summary of a bench round's observability
-sections, and a live view of a running node's scrape endpoint.
+"""obsreport — a live view of a running node's scrape endpoint, and
+renderers for what a run leaves behind.
 
-    python -m tools.obsreport BENCH_r05.json
-    python -m tools.obsreport MULTICHIP_r06.json
-    python bench.py > out.json && python -m tools.obsreport out.json
     python -m tools.obsreport --live 127.0.0.1:9187 [--interval 5]
     python -m tools.obsreport --fleet fleet.json
     python -m tools.obsreport --flight /tmp/ouro-flight [--tail 20]
-
-Accepts a raw bench JSON object (what `python bench.py` prints), a
-harness record wrapping one under ``parsed`` (the committed
-BENCH_r*.json files), or a MULTICHIP_rNN.json mesh-dryrun record
-(``{n_devices, rc, tail}`` — the MULTICHIP_OBS/MESH_SCALING JSON lines
-are recovered from the stored stdout tail and rendered as a mesh
-section: devices, prewarm/compile attribution, per-shard padding waste,
-and sharded vs single-device replay throughput when both legs are
-recorded).  For a bench round it prints, in order:
-
-- the headline (proofs/s, speedup vs the CPU baseline, rep spread);
-- the per-phase table from the ``variance`` section — median / min /
-  max / absolute and relative spread per replay phase across the timed
-  reps, with the dominant phase (largest absolute spread) starred.
-  This is the attributed form of the old bare "vrf spread 45%" warning:
-  the starred row names WHERE the cross-rep seconds moved;
-- the ``overlap`` section (ISSUE 8): host-seq seconds hidden under
-  in-flight device windows, hidden fraction and producer permit stalls
-  — cross-rep medians;
-- the ``stream`` section (ISSUE 15), when the round ran the streaming
-  disk->decode->verify engine: read-ahead depth, disk+decode seconds
-  hidden under device verify, snapshot write/restore timings and the
-  restart probe — rounds without one render unchanged;
-- the precompute cache stats (hit/miss/device_fill/eviction);
-- the registry metrics snapshot (the deterministic subset bench embeds).
-
-Rounds recorded before the observability layer (ISSUE 7) lack the
-``phases``/``variance``/``metrics`` sections and pre-ISSUE-8 rounds
-lack ``overlap``; each missing section is reported as absent rather
-than failing, so the CLI works across the whole BENCH_r*.json history.
 
 ``--live ADDR`` scrapes a running process's metrics endpoint
 (observe/scrape.py, served over the project's own snocket/SDU
@@ -62,36 +29,6 @@ import json
 import sys
 from typing import List, Optional
 
-from ouroboros_tpu.observe.spans import PHASES  # jax-free
-
-PHASE_ORDER = PHASES + ("other",)
-
-OVERLAP_MEDIANS = (
-    ("host_seq_secs_median", "host-seq total"),
-    ("device_secs_median", "device drains"),
-    ("host_hidden_secs_median", "host-seq hidden under device"),
-    ("hidden_frac_median", "hidden fraction"),
-    ("producer_stall_secs_median", "producer permit stalls"),
-)
-
-
-def load_bench(path: str) -> dict:
-    """The bench result object from `path` — unwraps a harness record's
-    ``parsed`` field and tolerates a list of parsed JSON lines (the
-    replay headline is the dict carrying ``metric``)."""
-    with open(path) as f:
-        doc = json.load(f)
-    if isinstance(doc, dict) and "parsed" in doc and "metric" not in doc:
-        doc = doc["parsed"]
-    if isinstance(doc, list):
-        dicts = [d for d in doc if isinstance(d, dict) and "metric" in d]
-        if not dicts:
-            raise ValueError("no bench result object in JSON list")
-        doc = dicts[-1]
-    if not isinstance(doc, dict) or "metric" not in doc:
-        raise ValueError("not a bench result (no 'metric' field)")
-    return doc
-
 
 def _table(rows: List[List[str]], header: List[str]) -> List[str]:
     widths = [max(len(str(r[i])) for r in [header] + rows)
@@ -104,312 +41,6 @@ def _table(rows: List[List[str]], header: List[str]) -> List[str]:
 
 def _fmt_secs(v) -> str:
     return f"{v:.4f}" if isinstance(v, (int, float)) else "-"
-
-
-def render(doc: dict) -> str:
-    out: List[str] = []
-
-    # -- headline -----------------------------------------------------------
-    out.append(f"{doc.get('metric', '?')}: {doc.get('value', '?')} "
-               f"{doc.get('unit', '')}".rstrip())
-    if "vs_baseline" in doc:
-        out.append(f"  vs CPU baseline: {doc['vs_baseline']}x"
-                   f"  (reps={doc.get('reps', '?')}, "
-                   f"rep spread={doc.get('spread', '?')})")
-    bd = doc.get("breakdown")
-    if bd:
-        out.append(f"  breakdown: device {bd.get('device_secs')}s / "
-                   f"host {bd.get('host_secs')}s")
-
-    # -- phase variance -----------------------------------------------------
-    out.append("")
-    var = doc.get("variance") or {}
-    per_phase = var.get("per_phase")
-    if per_phase:
-        out.append("per-phase seconds across timed reps "
-                   "(* = largest absolute spread):")
-        dom = var.get("dominant_phase")
-        rows = []
-        for ph in PHASE_ORDER:
-            st = per_phase.get(ph)
-            if st is None:
-                continue
-            rows.append([("*" if ph == dom else " ") + ph,
-                         _fmt_secs(st.get("median")),
-                         _fmt_secs(st.get("min")),
-                         _fmt_secs(st.get("max")),
-                         _fmt_secs(st.get("spread_secs")),
-                         st.get("spread_rel", "-")])
-        out += _table(rows, ["phase", "median", "min", "max",
-                             "spread_s", "rel"])
-        if dom is not None:
-            out.append(f"largest cross-rep spread: '{dom}' "
-                       f"({var.get('dominant_spread_secs')}s min->max) — "
-                       f"the phase to blame for rep-to-rep variance")
-    else:
-        out.append("no 'variance' section (round predates the "
-                   "observability layer)")
-
-    # -- host/device overlap (ISSUE 8 section; ISSUE 9 renders it) ----------
-    out.append("")
-    ov = doc.get("overlap") or {}
-    if any(k in ov for k, _ in OVERLAP_MEDIANS):
-        reps = len(ov.get("per_rep") or ())
-        out.append(f"pipelined-replay overlap (medians over "
-                   f"{reps or '?'} reps):")
-        rows = [[label, ov.get(key, "-")] for key, label in
-                OVERLAP_MEDIANS if key in ov]
-        out += _table(rows, ["quantity", "median"])
-        hf = ov.get("hidden_frac_median")
-        if isinstance(hf, (int, float)):
-            out.append(f"{100 * hf:.0f}% of the host sequential pass ran "
-                       f"while a window was in flight on device — the "
-                       f"closer to 100%, the closer host time is to free")
-    else:
-        out.append("no 'overlap' section (round predates the threaded "
-                   "producer/consumer replay attribution)")
-
-    # -- streaming replay section (ISSUE 15) --------------------------------
-    # rounds without one render unchanged: the section only appears once
-    # a bench round ran the disk->decode->verify engine
-    stream = doc.get("stream")
-    if stream:
-        out.append("")
-        out += _render_stream(stream)
-
-    # -- verification-service serve section (ISSUE 12) ----------------------
-    serve = doc.get("serve")
-    if serve:
-        out.append("")
-        out += _render_serve(serve)
-
-    # -- precompute cache ---------------------------------------------------
-    out.append("")
-    pc = doc.get("precompute")
-    if pc:
-        out.append("precompute cache:")
-        out += _table([[k, pc[k]] for k in sorted(pc)],
-                      ["stat", "value"])
-    else:
-        out.append("no 'precompute' section")
-
-    # -- metrics snapshot ---------------------------------------------------
-    out.append("")
-    snap = doc.get("metrics")
-    if snap:
-        out.append("metrics snapshot (deterministic subset):")
-        rows = []
-        for name in sorted(snap):
-            v = snap[name]
-            if isinstance(v, dict):       # histogram
-                v = f"count={v.get('count')} sum={v.get('sum')}"
-            rows.append([name, v])
-        out += _table(rows, ["metric", "value"])
-    else:
-        out.append("no 'metrics' section")
-    return "\n".join(out) + "\n"
-
-
-def _render_stream(st: dict) -> List[str]:
-    """The ``stream`` section of a bench round (ISSUE 15): the
-    disk->decode->verify engine's read-ahead accounting (how many
-    storage seconds hid under device verify), and the snapshot write /
-    restore timings behind `db_analyser --resume`."""
-    out: List[str] = []
-    out.append(f"streaming replay (disk -> decode -> verify, read-ahead "
-               f"{st.get('read_ahead', '?')} windows):")
-    rows = [
-        ["blocks streamed", st.get("blocks", "-")],
-        ["chunks read", st.get("chunks_read", "-")],
-        ["bytes read", st.get("bytes_read", "-")],
-        ["era crossings in-stream", st.get("era_crossings", "-")],
-        ["prefetch stalls (reader ahead)", st.get("prefetch_stalls",
-                                                  "-")],
-        ["disk+decode secs", _fmt_secs(st.get("disk_secs"))],
-        ["  of which hidden under device", _fmt_secs(
-            st.get("disk_hidden_secs"))],
-    ]
-    out += _table(rows, ["quantity", "value"])
-    hf = st.get("disk_hidden_frac")
-    if isinstance(hf, (int, float)):
-        out.append(f"{100 * hf:.0f}% of disk+decode ran while a window "
-                   f"was in flight on device — the read-ahead's hiding "
-                   f"power (same reading as the host-seq overlap above)")
-    snaps = st.get("snapshots_written")
-    if snaps is not None:
-        out.append(f"snapshots: {snaps} written in "
-                   f"{_fmt_secs(st.get('snapshot_write_secs'))}s; "
-                   f"restore scan {_fmt_secs(st.get('restore_secs'))}s"
-                   + (f"; resumed from slot {st['resumed_from_slot']}"
-                      if st.get("resumed_from_slot") is not None
-                      else ""))
-    restart = st.get("restart")
-    if restart:
-        out.append(f"restart probe: reopened from the tip snapshot in "
-                   f"{_fmt_secs(restart.get('restore_secs'))}s, "
-                   f"{restart.get('blocks_replayed', '?')} blocks "
-                   f"re-replayed, state-hash parity "
-                   f"{restart.get('state_hash_parity')}")
-    return out
-
-
-def _render_serve(serve: dict) -> List[str]:
-    """The ``serve`` section of a bench round (ISSUE 12): request-latency
-    quantiles of the coalescing service vs the unbatched CPU baseline,
-    the coalesced-batch-size histogram, and the fallback / deadline-miss
-    / back-pressure accounting across the three trace legs."""
-    out: List[str] = []
-    sat = serve.get("saturated") or {}
-    out.append(f"verification service (seed {serve.get('seed', '?')}, "
-               f"deadline {serve.get('deadline_secs', '?')}s"
-               + (", modeled device costs" if serve.get("modeled_costs")
-                  else ", measured device costs") + "):")
-    if sat:
-        out.append(f"  saturated: {sat.get('requests')} requests, "
-                   f"{sat.get('proofs_per_sec')} proofs/s = "
-                   f"{sat.get('vs_unbatched_cpu')}x the unbatched "
-                   f"per-request CPU baseline "
-                   f"({sat.get('cpu_unbatched_proofs_per_sec')} /s)")
-        lq, cq = sat.get("latency") or {}, \
-            sat.get("cpu_unbatched_latency") or {}
-        rows = [["service", lq.get("p50", "-"), lq.get("p95", "-"),
-                 lq.get("p99", "-")],
-                ["cpu unbatched", cq.get("p50", "-"), cq.get("p95", "-"),
-                 cq.get("p99", "-")]]
-        out += _table(rows, ["request latency (s)", "p50", "p95", "p99"])
-        within = sat.get("p95_within_deadline")
-        out.append(f"  p95 within deadline: {within}; deadline misses "
-                   f"{sat.get('deadline_misses')} "
-                   f"({sat.get('deadline_miss_frac')})")
-        hist = sat.get("batch_size_hist") or {}
-        if hist:
-            out.append("  coalesced batch sizes (size: flushes):")
-            out += _table([[k, hist[k]] for k in
-                           sorted(hist, key=lambda s: int(s))],
-                          ["batch", "count"])
-        svc = sat.get("service") or {}
-        out.append(f"  device batches {svc.get('device_batches')} "
-                   f"({svc.get('device_requests')} reqs) / CPU fallback "
-                   f"{svc.get('fallback_batches')} "
-                   f"({svc.get('fallback_requests')} reqs)")
-    light = serve.get("light_load") or {}
-    if light:
-        out.append(f"  light load: {light.get('requests')} requests, "
-                   f"device batches {light.get('device_batches')} "
-                   f"(break-even n*={light.get('break_even_n')}; 0 = "
-                   f"every flush took the CPU fallback), "
-                   f"{light.get('fallback_requests')} fallback reqs")
-    bp = serve.get("backpressure") or {}
-    if bp:
-        out.append(f"  back-pressure: {bp.get('requests')} requests vs "
-                   f"queue {bp.get('max_queue')}: "
-                   f"{bp.get('backpressure_waits')} blocked submits, "
-                   f"{bp.get('completed')} completed")
-    be = (serve.get("break_even") or {}).get("entries") or {}
-    if be:
-        rows = [[p, be[p].get("n_star"), be[p].get("cpu_secs_per_req"),
-                 be[p].get("device_secs_batch")] for p in sorted(be)]
-        out += _table(rows, ["primitive", "n*", "cpu s/req",
-                             "device s/batch"])
-    parity = all(leg.get("parity") for leg in (sat, light, bp) if leg)
-    out.append(f"  verdict parity vs CpuRefBackend on every leg: "
-               f"{parity}")
-    return out
-
-
-# ---------------------------------------------------------------------------
-# MULTICHIP mesh-dryrun rounds (ISSUE 11)
-# ---------------------------------------------------------------------------
-
-def load_multichip(path: str) -> Optional[dict]:
-    """The multichip harness record from `path`, or None when the file
-    is not one (callers fall through to load_bench).  The MULTICHIP_OBS
-    and MESH_SCALING JSON lines are parsed out of the stored tail under
-    ``obs``/``scaling`` (None when the round died before printing them —
-    the rc says how)."""
-    try:
-        with open(path) as f:
-            doc = json.load(f)
-    except (OSError, json.JSONDecodeError):
-        return None
-    if not isinstance(doc, dict) or "rc" not in doc \
-            or "n_devices" not in doc:
-        return None
-    out = {"n_devices": doc.get("n_devices"), "rc": doc.get("rc"),
-           "ok": doc.get("ok"), "obs": None, "scaling": None}
-    for line in (doc.get("tail") or "").splitlines():
-        for marker, key in (("MULTICHIP_OBS ", "obs"),
-                            ("MESH_SCALING ", "scaling")):
-            i = line.find(marker)
-            if i < 0:
-                continue
-            try:
-                out[key] = json.loads(line[i + len(marker):])
-            except json.JSONDecodeError:
-                pass
-    return out
-
-
-def render_multichip(doc: dict) -> str:
-    """Mesh section of a MULTICHIP round: run identity, compile
-    attribution, the sharded pipelined replay (parity, throughput,
-    per-shard occupancy/padding waste) and the single-device comparison
-    leg when the round recorded one."""
-    out: List[str] = []
-    out.append(f"multichip dryrun: {doc.get('n_devices', '?')} devices, "
-               f"rc={doc.get('rc')} "
-               f"({'green' if doc.get('rc') == 0 else 'RED'})")
-    obs = doc.get("obs")
-    if not obs:
-        out.append("no MULTICHIP_OBS line in the stored tail (the round "
-                   "died before attribution, or predates ISSUE 6)")
-        return "\n".join(out) + "\n"
-
-    compile_rows = [[k, obs[k]] for k in sorted(obs)
-                    if k.endswith("_compile_secs")]
-    if compile_rows:
-        out.append("")
-        out.append("compile attribution (seconds outside timed regions):")
-        out += _table(compile_rows, ["stage", "secs"])
-    if "over_budget_after" in obs:
-        out.append(f"OVER BUDGET after '{obs['over_budget_after']}' "
-                   f"({obs.get('elapsed_secs')}s of "
-                   f"{obs.get('budget_secs')}s)")
-
-    sh = obs.get("sharded_replay")
-    out.append("")
-    if sh:
-        out.append("sharded pipelined replay (the real chain, not the "
-                   "prewarm window):")
-        rows = [[k, sh[k]] for k in sorted(sh) if k != "padding"]
-        out += _table(rows, ["field", "value"])
-        pad = sh.get("padding") or {}
-        if pad:
-            out.append("per-shard occupancy / padding waste:")
-            out += _table([[k, pad[k]] for k in sorted(pad)],
-                          ["stat", "value"])
-        single = obs.get("single_device_replay") or {}
-        sp, dp = (single.get("proofs_per_sec"),
-                  sh.get("proofs_per_sec"))
-        if sp and dp:
-            out.append(f"sharded vs single-device: {dp} vs {sp} proofs/s "
-                       f"({dp / sp:.2f}x on this mesh)")
-        elif dp:
-            out.append("no single-device leg recorded (budget-gated); "
-                       "sharded throughput stands alone")
-    else:
-        out.append("no sharded_replay section (round predates the "
-                   "sharded pipelined replay, ISSUE 11)")
-
-    scaling = doc.get("scaling")
-    if scaling:
-        out.append("")
-        out.append(f"mesh scaling: wall {scaling.get('wall_secs')} / "
-                   f"dispatches per window "
-                   f"{scaling.get('dispatches_per_window')} "
-                   f"(relative n-vs-1: "
-                   f"{scaling.get('relative_wall_n_vs_1')})")
-    return "\n".join(out) + "\n"
 
 
 # ---------------------------------------------------------------------------
@@ -643,9 +274,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     import argparse
     ap = argparse.ArgumentParser(
         prog="python -m tools.obsreport",
-        description="render a bench round's observability sections, or "
-                    "--live: a running node's scrape endpoint")
-    ap.add_argument("path", nargs="?", help="BENCH_rNN.json round file")
+        description="render a running node's scrape endpoint, a fleet "
+                    "report or a flight-recorder dump")
     ap.add_argument("--live", metavar="ADDR",
                     help="scrape host:port (or /unix/path) and render "
                          "replay progress + latency quantiles")
@@ -661,12 +291,12 @@ def main(argv: Optional[List[str]] = None) -> int:
                     help="with --flight: span/event tail length "
                          "(default 20)")
     args = ap.parse_args(sys.argv[1:] if argv is None else argv)
-    modes = [m for m in (args.path, args.live, args.fleet, args.flight)
+    modes = [m for m in (args.live, args.fleet, args.flight)
              if m is not None]
     if len(modes) != 1:
         ap.print_usage(sys.stderr)
-        print("obsreport: give exactly one of PATH, --live ADDR, "
-              "--fleet PATH or --flight DIR", file=sys.stderr)
+        print("obsreport: give exactly one of --live ADDR, --fleet PATH "
+              "or --flight DIR", file=sys.stderr)
         return 2
     if args.fleet:
         try:
@@ -686,36 +316,24 @@ def main(argv: Optional[List[str]] = None) -> int:
             return 2
         sys.stdout.write(render_flight(header, records, tail=args.tail))
         return 0
-    if args.live:
-        from ouroboros_tpu.observe.export import parse_prometheus_text
-        try:
-            while True:
-                sys.stdout.write(
-                    render_live(parse_prometheus_text(
-                        _live_once(args.live))))
-                sys.stdout.flush()
-                if args.interval <= 0:
-                    return 0
-                import time
-                time.sleep(args.interval)
-                sys.stdout.write("\n")
-        except KeyboardInterrupt:
-            return 0
-        except Exception as e:
-            print(f"obsreport: cannot scrape {args.live}: {e}",
-                  file=sys.stderr)
-            return 2
-    mc = load_multichip(args.path)
-    if mc is not None:
-        sys.stdout.write(render_multichip(mc))
-        return 0
+    from ouroboros_tpu.observe.export import parse_prometheus_text
     try:
-        doc = load_bench(args.path)
-    except (OSError, ValueError, json.JSONDecodeError) as e:
-        print(f"obsreport: cannot read {args.path}: {e}", file=sys.stderr)
+        while True:
+            sys.stdout.write(
+                render_live(parse_prometheus_text(
+                    _live_once(args.live))))
+            sys.stdout.flush()
+            if args.interval <= 0:
+                return 0
+            import time
+            time.sleep(args.interval)
+            sys.stdout.write("\n")
+    except KeyboardInterrupt:
+        return 0
+    except Exception as e:
+        print(f"obsreport: cannot scrape {args.live}: {e}",
+              file=sys.stderr)
         return 2
-    sys.stdout.write(render(doc))
-    return 0
 
 
 if __name__ == "__main__":
