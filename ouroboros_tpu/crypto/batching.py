@@ -539,16 +539,11 @@ class VerifyService:
 
     def _bucket_of(self, n: int) -> int:
         """The padded lane count a device flush of n requests occupies:
-        the backend's own bucket ladder when it has one (JaxBackend pads
-        to power-of-two buckets >= min_bucket internally — the service
-        adds NO shapes of its own), else n."""
-        lo = getattr(self.backend, "min_bucket", None)
-        if not lo:
-            return n
-        b = lo
-        while b < n:
-            b *= 2
-        return b
+        what the backend's own `_pad` says when it has one (JaxBackend
+        and the mesh backend; the service adds NO shapes of its own and
+        keeps no copy of their ladder), else n."""
+        pad = getattr(self.backend, "_pad", None)
+        return pad(n) if pad is not None else n
 
     async def _dispatch(self, batch: Sequence[_Pending]) -> None:
         self.stats["flushes"] += 1

@@ -15,8 +15,10 @@ whole 4096-lane Ed25519 dispatch + compute + drain took 14 ms on a TPU
 v5 lite — smoke reading, PR 22, ROADMAP A2); the packed form is kept
 because it is never the larger transfer.
 
-Batch sizes are padded to power-of-two buckets (min 128) so repeated
-calls hit the jit cache instead of recompiling per shape.
+Batch sizes up to ED_TILE lanes are padded to power-of-two buckets (min
+128) so repeated calls hit the jit cache instead of recompiling per
+shape; a wider batch pads to a multiple of ED_TILE, and the window
+composite walks its Ed25519 lanes as ED_TILE-wide tiles (`ed_lanes_core`).
 
 Kernel selection is MEASURED, not assumed: on a TPU the fused pallas
 (Mosaic) kernels and the op-by-op XLA kernels are timed head-to-head
@@ -58,6 +60,9 @@ _FOLD_WINDOWS = _metrics.counter("jax_backend.fold_windows")
 # MULTICHIP_OBS line and the benchmark's `lane_pad_share` report
 _LANES_USED = _metrics.counter("jax_backend.lanes_used")
 _LANES_PADDED = _metrics.counter("jax_backend.lanes_padded")
+# ED_TILE-wide tiles ONE device walks for a window's Ed25519 lanes; a
+# window on the single-bucket path adds 0 (the "does it engage" reading)
+_ED_TILES = _metrics.counter("jax_backend.ed_tiles")
 
 # device-side verdict-fold sentinel: "no failing request".  int32 max so
 # jnp.min over any real request index beats it; request lists are bounded
@@ -82,10 +87,62 @@ def _compile_span_on_first_call(fn, name: str):
 
 
 def _bucket(n: int, lo: int = 128) -> int:
+    """Smallest lo * 2^k that holds n: the shapes of every batch of at
+    most ED_TILE lanes (`JaxBackend._pad`)."""
     m = lo
     while m < n:
         m *= 2
     return m
+
+
+# Width of one Ed25519 tile of the XLA window composite, in lanes (a
+# multiple of pallas_kernels.TILE = 512, so the Pallas grid divides every
+# padded count too).  What a lane costs the XLA ladder is not flat in the
+# width of the program: 3.5 us at 2,048 and 4,096 lanes, 3.9 at 8,192,
+# 5.8 at 16,384, 7.8 at 32,768, 19.0 at 65,536 and 29.1 at 131,072 (a TPU
+# v5e; PERF.md section 6, PR 30, has the sweep and the device operations
+# that grow), and a loop of tiles costs what its tile does alone.  So a
+# device handed more than ED_TILE lanes walks them ED_TILE at a time
+# inside the one program.  Tests reach a small tile by monkeypatching
+# this name.
+ED_TILE = 4096
+
+
+def ed_tiles(lanes: int) -> int:
+    """Tiles `ed_lanes_core` walks for `lanes` lanes on one device: 0 at
+    or under ED_TILE (the single-bucket program)."""
+    return lanes // ED_TILE if lanes > ED_TILE else 0
+
+
+def ed_lanes_core(Aw, xa, xw, yw, Rw, signR2, sw, kw):
+    """The XLA Ed25519 verification of the lanes ONE device is handed, as
+    both window composites trace it (the one-chip program over the whole
+    window, the mesh's over a shard): `verify_full_split_words_core` on
+    all of them at or under ED_TILE lanes — exactly the program of the
+    power-of-two buckets — and above it one ED_TILE-wide tile at a time
+    under `lax.map`, so the body is traced and compiled once whatever
+    the tile count and a tile's working set stays near the core.  Lanes
+    are independent, so the (lanes,) int32 verdicts are the flat
+    program's, lane for lane."""
+    lanes = Aw.shape[-1]
+    tiles = ed_tiles(lanes)
+    if not tiles:
+        return EJ.verify_full_split_words_core(
+            Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
+    assert lanes == tiles * ED_TILE, (lanes, ED_TILE)
+    from jax import lax
+
+    def tile_major(a):            # (rows, lanes) -> (tiles, rows, ED_TILE)
+        return a.reshape(a.shape[0], tiles, ED_TILE).transpose(1, 0, 2)
+
+    def one_tile(t):
+        tAw, txa, txw, tyw, tRw, tsR2, tsw, tkw = t
+        return EJ.verify_full_split_words_core(
+            tAw, txa, txw, tyw, tRw, tsR2[0], tsw, tkw)
+
+    return lax.map(one_tile, tuple(
+        tile_major(a) for a in (Aw, xa, xw, yw, Rw, signR2, sw, kw))
+    ).reshape(-1)
 
 
 def batch_inverse(vals: list[int]) -> list[int]:
@@ -170,9 +227,13 @@ class JaxBackend(CryptoBackend):
 
     # -- subclass seams (ShardedJaxBackend overrides both) -------------------
     def _pad(self, n: int) -> int:
-        """Batch padding: power-of-two buckets here; the mesh backend
-        additionally rounds to a mesh-size multiple."""
-        return _bucket(n, self.min_bucket)
+        """Batch padding: power-of-two buckets from `min_bucket` up to
+        ED_TILE lanes, above that the next multiple of ED_TILE (a
+        window's 90,624 Ed25519 lanes pad to 94,208, not 131,072).  The
+        mesh backend pads to a mesh multiple, and to whole tiles a shard
+        once a shard is wider than ED_TILE."""
+        m = _bucket(n, self.min_bucket)
+        return m if m <= ED_TILE else -(-n // ED_TILE) * ED_TILE
 
     def _dev(self, a):
         """Host array -> device array for a lane-axis-last batch input;
@@ -514,7 +575,12 @@ class JaxBackend(CryptoBackend):
         mixing an op-by-op XLA ladder into a pallas composite made XLA's
         compile of the combined program pathological (>1h at replay
         shapes, vs minutes for either pure form), and only the chosen
-        form is ever compiled."""
+        form is ever compiled.
+
+        The XLA form walks more than ED_TILE Ed25519 lanes as ED_TILE-wide
+        tiles inside the one program (`ed_lanes_core`: same verdicts at
+        the same offsets of the packed buffer); the Pallas form keeps
+        its own 512-lane grid over the tile-multiple `ne`."""
         key = (ne, nv, nb, nk, pallas)
         fn = self._composites.get(key)
         if fn is not None:
@@ -531,9 +597,7 @@ class JaxBackend(CryptoBackend):
                 if pallas:
                     ok = PK._ed25519_split_call(*ed_args, ne)
                 else:
-                    Aw, xa, xw, yw, Rw, signR2, sw, kw = ed_args
-                    ok = EJ.verify_full_split_words_core(
-                        Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
+                    ok = ed_lanes_core(*ed_args)
                 parts.append(ok.reshape(-1).astype(jnp.uint8))
             if vrf_args is not None:
                 if pallas:
@@ -643,6 +707,8 @@ class JaxBackend(CryptoBackend):
                                            vrf_args, beta_args, kes_args)
                 packed = self._window_composite(ne, nv, nb, nk, allp)(
                     ed_args, vrf_args, beta_args, kes_args)
+                if not allp:
+                    _ED_TILES.inc(ed_tiles(ne // self.n_shards))
         state = {"packed": packed, "n": n,
                  "ed": ed_state, "ed_owner": ed_owner, "ne": ne,
                  "vrf": vrf_state, "vrf_owner": vrf_owner,

@@ -114,6 +114,7 @@ def build_sharded_gamma8(mesh: Mesh):
 
 
 from ..crypto.backend import CryptoBackend  # noqa: F401  (re-export)
+from ..crypto import jax_backend as jb
 from ..crypto.jax_backend import JaxBackend
 
 
@@ -170,9 +171,14 @@ class ShardedJaxBackend(JaxBackend):
         self._lane_sharding = NamedSharding(mesh, P(None, axis))
 
     def _pad(self, n: int) -> int:
+        """A mesh multiple of at least `min_bucket` lanes; once a shard
+        is wider than ED_TILE, whole ED_TILE-wide tiles a shard
+        (`ed_lanes_core` walks each shard's lanes a tile at a time)."""
         d = self.mesh.devices.size
-        m = max(self.min_bucket, n)
-        m = ((m + d - 1) // d) * d
+        m = -(-max(self.min_bucket, n) // d) * d
+        if m // d > jb.ED_TILE:
+            step = d * jb.ED_TILE
+            m = -(-n // step) * step
         return m
 
     @property
@@ -267,7 +273,10 @@ class ShardedJaxBackend(JaxBackend):
         SAME packed-words component cores the single-device composite
         fuses, each shard running the identical per-shard program, the
         results stitched into JaxBackend's flat uint8 layout (so
-        finish_window and the fold program are shared verbatim).
+        finish_window and the fold program are shared verbatim).  A
+        shard's Ed25519 lanes go through `ed_lanes_core` as the one-chip
+        window's do: tiled above ED_TILE lanes a shard, today's program
+        at or under it.
 
         Tracing the per-shard body instead of a mesh-wide monolith is
         the compile-budget fix: XLA compiles one shard-sized program +
@@ -297,10 +306,8 @@ class ShardedJaxBackend(JaxBackend):
             i = 0
             outs = []
             if ne:
-                Aw, xa, xw, yw, Rw, signR2, sw, kw = present[i]
+                ok = jb.ed_lanes_core(*present[i])
                 i += 1
-                ok = EJ.verify_full_split_words_core(
-                    Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
                 outs.append(ok.reshape(-1).astype(jnp.uint8))
             if nv:
                 Yw, xa, Gw, sG2, rw, cw, sw_ = present[i]
@@ -324,8 +331,7 @@ class ShardedJaxBackend(JaxBackend):
 
         fn = jax.jit(call, donate_argnums=(0, 1, 2, 3)) if self._donate \
             else jax.jit(call)
-        from ..crypto.jax_backend import _compile_span_on_first_call
-        fn = _compile_span_on_first_call(
+        fn = jb._compile_span_on_first_call(
             fn, f"sharded.composite({ne},{nv},{nb})"
                 f"@mesh{len(self.mesh.devices.flat)}")
         self._composites[key] = fn

@@ -46,6 +46,7 @@ from ouroboros_tpu.crypto import vrf_jax as VJ
 # block opens a new period ships 10 per block (bucket 16384, the shape
 # the last recorded rounds had).
 NE, NV, NB, NK = 4096, 2048, 2048, 2048
+NE_TILED = 23 * JB.ED_TILE
 NK_ALL_NEW = 16384
 
 U32, I32, U8 = jnp.uint32, jnp.int32, jnp.uint8
@@ -153,6 +154,10 @@ XLA = {
                lambda s: _beta_args(NB, s)),
     "kes_hash": (lambda mw, ew: B2.check_block64(mw, ew),
                  lambda s: _kes_args(NK, s)),
+    # a full-body window's Ed25519 lanes (the benchmark's 90,624, padded
+    # to whole tiles): one tile-wide body under a loop, not NE_TILED wide
+    "ed25519_tiles": (lambda *a: JB.ed_lanes_core(*a),
+                      lambda s: _ed_args(NE_TILED, s)),
 }
 
 
@@ -160,6 +165,7 @@ XLA = {
     pytest.param("ed25519_split", marks=pytest.mark.slow),
     pytest.param("vrf_verify", marks=pytest.mark.slow),
     pytest.param("gamma8", marks=pytest.mark.slow),
+    pytest.param("ed25519_tiles", marks=pytest.mark.slow),
     "kes_hash"])
 def test_xla_form_compiles_for_v5e(kernel, one_chip):
     """The op-by-op XLA form of each part: what the autotuner also
@@ -168,6 +174,10 @@ def test_xla_form_compiles_for_v5e(kernel, one_chip):
     compiled = jax.jit(lambda *a: fn(*a)).lower(
         *make_args(one_chip)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
+    if kernel == "ed25519_tiles":
+        # a tile's working set, not the window's: the flat program of
+        # 131,072 lanes asks the compiler for 4 GB of temporaries
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 def _backend(pallas: bool) -> JB.JaxBackend:
