@@ -350,10 +350,12 @@ def a128_core(yA, signA):
     """Per-key precompute: decompress A, then [2^128]A via 128 doublings
     + one batched inversion to canonical affine limbs.  Returns
     (xA, x128, y128, ok) — the key's own affine x AND the shifted point.
-    Rare path (first sighting of a key); results are memoised by
-    A128Cache: steady-state verify kernels then skip the A square root
-    entirely (the r5 probe measured the two pow-chain decompressions at
-    ~40% of the split-ladder kernel)."""
+    Run once a key, at its first sighting, and memoised by the per-key
+    cache (crypto/precompute.py): a header key (a pool's cold, VRF and
+    KES leaf keys) then recurs for thousands of blocks and the verify
+    kernels skip the A square root for good; a witness key of a chain
+    whose wallets take a fresh address a transaction is seen once, so on
+    such a chain this runs for every witness lane of every window."""
     xA, ok = device_decompress(yA, signA)
     one = F.one_like(yA)
     P = (xA, yA, one, F.mul(xA, yA))
@@ -362,7 +364,20 @@ def a128_core(yA, signA):
     return (xA, F.canon(F.mul(P[0], Zi)), F.canon(F.mul(P[1], Zi)), ok)
 
 
-a128_kernel = jax.jit(a128_core)
+def a128_words_core(Aw, signA):
+    """`a128_core` in the packed form the cache keeps and the verify
+    kernels take: Aw (8, N) uint32 words of the compressed keys with the
+    sign bit cleared, signA (N,) int32.  Returns ((24, N) uint32: the
+    words of xA, x([2^128]A), y([2^128]A), eight rows each; ok (N,))."""
+    xA, x128, y128, ok = a128_core(F.limbs_from_words(Aw), signA)
+    return jnp.concatenate([F.words_from_limbs(xA),
+                            F.words_from_limbs(x128),
+                            F.words_from_limbs(y128)]), ok
+
+
+# one program a WIDTH, whatever the count of new keys: the cache calls it
+# a tile at a time (precompute.PrecomputeCache._device_tables)
+a128_words_kernel = jax.jit(a128_words_core)
 
 # filler for padding / undecodable keys: [2^128]B (any valid point works —
 # such entries are masked invalid by parse_ok before the result is read)
@@ -376,6 +391,8 @@ _B128Y_W = _words_of_int(_B128Y)
 
 
 _GX_W = _words_of_int(_GX_AFF)
+# the (24,) table column of a key that does not decode
+_FILLER_COL = np.concatenate([_GX_W, _B128X_W, _B128Y_W])
 
 
 # The per-key [2^128]A cache grew into the cross-window precomputation
@@ -697,6 +714,15 @@ def _y_canonical(arr: np.ndarray) -> np.ndarray:
              & (arr[:, 0] >= 0xED))
 
 
+def _point_words(arr: np.ndarray):
+    """(N, 32) compressed point rows -> ((8, N) uint32 words of y, the
+    sign bit cleared; sign (N,) int32; mask of y < p)."""
+    clear = arr.copy()
+    clear[:, 31] &= 0x7F
+    return (F.words_from_bytes_rows(clear),
+            (arr[:, 31] >> 7).astype(np.int32), _y_canonical(arr))
+
+
 def prepare_words_batch(vks, msgs, sigs):
     """Packed-words host prep for verify_full_split_words_kernel.
 
@@ -706,17 +732,11 @@ def prepare_words_batch(vks, msgs, sigs):
     n = len(vks)
     vk_arr, vk_ok = _bytes_rows(vks, 32)
     sig_arr, sig_ok = _bytes_rows(sigs, 64)
-    signA = (vk_arr[:, 31] >> 7).astype(np.int32)
-    signR = (sig_arr[:, 31] >> 7).astype(np.int32)
-    a_ok = _y_canonical(vk_arr)
-    r_ok = _y_canonical(sig_arr[:, :32])
+    Aw, signA, a_ok = _point_words(vk_arr)
+    Rw, signR, r_ok = _point_words(sig_arr[:, :32])
     s_rows = np.ascontiguousarray(sig_arr[:, 32:])
     s_ok = _scalar_lt_L(s_rows)
     parse_ok = vk_ok & sig_ok & a_ok & r_ok & s_ok
-    vk_clear = vk_arr.copy()
-    vk_clear[:, 31] &= 0x7F
-    r_clear = sig_arr[:, :32].copy()
-    r_clear[:, 31] &= 0x7F
     k_bytes = bytearray()
     for j in range(n):
         if parse_ok[j]:
@@ -726,8 +746,7 @@ def prepare_words_batch(vks, msgs, sigs):
             k = 0
         k_bytes += k.to_bytes(32, "little")
     k_rows = np.frombuffer(bytes(k_bytes), dtype=np.uint8).reshape(n, 32)
-    return ((F.words_from_bytes_rows(vk_clear), signA,
-             F.words_from_bytes_rows(r_clear), signR,
+    return ((Aw, signA, Rw, signR,
              F.words_from_bytes_rows(s_rows),
              F.words_from_bytes_rows(k_rows)), parse_ok)
 

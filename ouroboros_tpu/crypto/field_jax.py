@@ -326,6 +326,26 @@ def limbs_from_words(w):
     return jnp.stack(rows)
 
 
+def words_from_limbs(v):
+    """(NLIMBS, N) int32 CANONICAL limbs (each in [0, 2^13), value
+    < 2^255: what `canon` returns) -> (8, N) uint32 words, the inverse of
+    `limbs_from_words` (device op).  The limbs' bits are disjoint, so a
+    word is the OR of the two to four limbs that overlap it."""
+    u = v.astype(jnp.uint32)
+    rows = []
+    for k in range(8):
+        lo = 32 * k
+        w = None
+        for l in range(NLIMBS):
+            bit = RADIX * l
+            if bit + RADIX <= lo or bit >= lo + 32:
+                continue
+            part = u[l] << (bit - lo) if bit >= lo else u[l] >> (lo - bit)
+            w = part if w is None else w | part
+        rows.append(w)
+    return jnp.stack(rows)
+
+
 def bit_from_words(w, j: int):
     """Bit j (0 = LSB) of each lane's 256-bit value: (N,) int32."""
     return ((w[j // 32] >> (j % 32)) & 1).astype(jnp.int32)

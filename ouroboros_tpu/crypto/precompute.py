@@ -1,11 +1,18 @@
 """Cross-window per-key precomputation cache — the generalized A128Cache.
 
-A chain has few stake pools, so the same verification keys recur in
-every replay window and their expensive per-key precomputation is PURE:
+Which keys recur is a property of the chain, not of the protocol.  A
+block's four HEADER proofs are a stake pool's (cold, VRF and KES leaf
+keys), and a chain has few pools beside its blocks, so those keys recur
+for thousands of blocks.  A block's hundreds of WITNESS keys are payment
+keys: on a chain whose wallets derive a fresh address for every
+transaction (CIP-1852) a syncing node meets nearly every one of them
+once, and on a chain that pays back to two owners' addresses (db_synth's
+default) it meets two.  The per-key precomputation is PURE either way,
+so it is made once a key and kept while the bound allows:
 
 - Ed25519 cold/payment keys: the decompressed affine x of A plus the
   affine coordinates of [2^128]A (the split-ladder table half), computed
-  on device by ed25519_jax.a128_kernel at first sighting;
+  on device by ed25519_jax.a128_words_kernel at first sighting;
 - VRF pool keys: the decompressed affine x of Y that feeds the cached-Y
   packed kernel (vrf_jax.vrf_verify_words_kernel) — the [c](-Y) half of
   the on-device triple table is derived from it per batch, so the cached
@@ -15,12 +22,21 @@ every replay window and their expensive per-key precomputation is PURE:
   per-period subtree check has ONE answer for the thousands of headers
   it signs in that period.
 
-This module holds all three behind one LRU-bounded cache keyed by vk
-bytes (points) or the KES hash-path identity (kes.hash_path_key), with
-counters (`device_fills`, `filled_keys`, `hits`, `misses`, `evictions`)
-so the warm-path guarantee — a cache-warm window does ZERO per-key
+The point tables live in ONE slot table of packed words ((24, slots)
+uint32: xA, x([2^128]A), y([2^128]A)), indexed through a dict from vk
+bytes to slot; a window in which every lane's key is new and a window in
+which every key hits run the same array code, with no per-key Python
+integers, entry objects or locked inserts.  The fill programs have two
+fixed widths (FILL_NARROW lanes for a handful of new keys, tiles of
+jax_backend.ED_TILE lanes for more), so no program is keyed on the count
+of new keys.  KES outcomes keep an OrderedDict of their own.  Both
+namespaces are LRU-bounded, with counters (`device_fills`,
+`filled_keys`, `fill_lanes_padded`, `hits`, `misses`, `evictions`) so
+the warm-path guarantee — a cache-warm window does ZERO per-key
 decompression/table-build device calls — is assertable in tests and
-readable in a run's counters.
+readable in a run's counters.  `hits` counts LANES served from the
+table (and KES lookups that hit); `misses` counts distinct keys that had
+to be filled (and KES lookups that missed).
 
 Unlike the r5 A128Cache, undecodable keys are cached too (as negative
 entries): a bad key repeated across windows used to re-dispatch the fill
@@ -28,7 +44,7 @@ kernel every window just to re-discover it cannot be decompressed.
 
 Import discipline: this module must import WITHOUT jax (backend.py and
 host-only tooling read the KES namespace); the device fill imports
-ed25519_jax lazily inside `_fill`.
+ed25519_jax lazily inside `_device_tables`.
 
 Counters live in the observability registry (ISSUE 7): the process-wide
 cache registers its hit/miss/device_fill/eviction counters under the
@@ -43,16 +59,22 @@ from __future__ import annotations
 
 import threading
 from collections import OrderedDict
+from itertools import repeat
 
 import numpy as np
 
 from ..observe import metrics as _metrics
 from ..observe import spans as _spans
 
-# sentinel stored for keys whose decompression failed: assemble() keeps
-# reporting known=False for them without re-dispatching the fill kernel
-_BAD = object()
-_MISSING = object()
+# Width of the narrow fill program: a window that meets a handful of new
+# keys (a pool's next KES leaf key) fills them in one FILL_NARROW-lane
+# call; a window that meets more walks them as whole tiles of
+# jax_backend.ED_TILE lanes, the width the Ed25519 ladder measured
+# cheapest a lane (PERF.md section 6, PR 30).  Two programs, whatever the
+# count of new keys.
+FILL_NARROW = 128
+# words a key's table takes: xA, x([2^128]A), y([2^128]A), eight each
+_TAB_ROWS = 24
 
 # Sum-KES hash paths walked on the HOST (CryptoBackend.split_mixed_cached,
 # a cache miss there; the mesh backend's windows).  The one-chip path runs
@@ -98,11 +120,11 @@ class PrecomputeCache:
 
     assemble() returns ((8, N) uint32 xA-words, x128-words, y128-words,
     known (N,) bool) for a batch of keys, computing every missing unique
-    key in one a128_kernel call (padded to a power-of-two bucket so
-    repeats hit the jit cache).  `known` is False for keys that failed
-    decompression (not on the curve / bad length) — callers must mask
-    those invalid, since the verify kernels trust the cached x and skip
-    the square-root check entirely.
+    key on the device in programs of two fixed widths (`_device_tables`).
+    `known` is False for keys that failed decompression (not on the
+    curve / bad length) — callers must mask those invalid, since the
+    verify kernels trust the cached x and skip the square-root check
+    entirely.
 
     Eviction is exact LRU per namespace: every hit refreshes the entry,
     and inserts past `max_entries` drop the least-recently-used entry
@@ -112,10 +134,21 @@ class PrecomputeCache:
     # counter names in the registry namespace (ISSUE 7); the attribute
     # aliases below expose each as plain read/write ints
     _COUNTERS = ("hits", "misses", "device_fills", "filled_keys",
-                 "evictions")
+                 "fill_lanes_padded", "evictions")
 
     def __init__(self, max_entries: int = 200_000, register: bool = False):
-        self._c: OrderedDict = OrderedDict()    # vk -> (xa, x128, y128)|_BAD
+        # point entries: vk -> slot of the word table.  A slot holds the
+        # key's table column, whether the key decoded (False = a negative
+        # entry, its column the filler point), its recency stamp (0 = a
+        # free slot) and the key itself, for eviction
+        self._slot: dict = {}
+        self._tab = np.empty((_TAB_ROWS, 0), dtype=np.uint32)
+        self._known = np.empty(0, dtype=bool)
+        self._stamp = np.empty(0, dtype=np.int64)
+        self._key_at = np.empty(0, dtype=object)
+        self._free: list = []     # evicted slots, reused first
+        self._used = 0            # slots ever handed out
+        self._clock = 0           # last recency stamp given
         self._kes: OrderedDict = OrderedDict()  # hash_path_key -> (leaf_vk, ok)
         self.max_entries = max_entries
         # counters: the warm-path contract is `device_fills`/`filled_keys`
@@ -152,94 +185,168 @@ class PrecomputeCache:
     misses = _alias("misses")
     device_fills = _alias("device_fills")
     filled_keys = _alias("filled_keys")
+    fill_lanes_padded = _alias("fill_lanes_padded")
     evictions = _alias("evictions")
     lock_wait = _alias("lock_wait")
     del _alias
 
     def __len__(self):
-        return len(self._c)
+        return len(self._slot)
 
     def __contains__(self, vk: bytes) -> bool:
-        return vk in self._c
+        return vk in self._slot
 
     # -- point entries (Ed25519 A / VRF Y) ----------------------------------
     def assemble(self, vks):
-        # snapshot this batch's entries while scanning: a fill larger than
-        # max_entries may evict keys this very batch hit, and the read
-        # below must still see them (results stay correct under ANY bound)
-        local: dict = {}
-        missing = []
-        with self._lock_c:
-            for vk in vks:
-                if vk in local:
-                    continue
-                ent = self._c.get(vk, _MISSING)
-                if ent is not _MISSING:
-                    try:                # recency touch stays best-effort
-                        self._c.move_to_end(vk)   # (eviction-tolerant:
-                    except KeyError:    # an unlocked legacy caller may
-                        pass            # still race the bookkeeping)
-                    self.hits += 1
-                    local[vk] = ent
-                else:
-                    missing.append(vk)
-                    local[vk] = _BAD   # overwritten by the fill below
-        self.misses += len(missing)
-        if missing:
-            local.update(self._fill(missing))
-        from . import ed25519_jax as EJ
         n = len(vks)
-        xa = np.empty((8, n), dtype=np.uint32)
-        xs = np.empty((8, n), dtype=np.uint32)
-        ys = np.empty((8, n), dtype=np.uint32)
-        known = np.zeros(n, dtype=bool)
-        for j, vk in enumerate(vks):
-            ent = local[vk]
-            if ent is _BAD:
-                # any valid point works: the lane is masked via `known`
-                xa[:, j] = EJ._GX_W
-                xs[:, j] = EJ._B128X_W
-                ys[:, j] = EJ._B128Y_W
-            else:
-                xa[:, j], xs[:, j], ys[:, j] = ent
-                known[j] = True
-        return xa, xs, ys, known
+        out = np.empty((_TAB_ROWS, n), dtype=np.uint32)
+        known = np.empty(n, dtype=bool)
+        # this batch's hits are copied out while the stripe is held: a
+        # fill larger than max_entries may evict keys this very batch
+        # hit, and the lanes must still carry them (results stay correct
+        # under ANY bound)
+        with self._lock_c:
+            slots = self._slots_of(vks)
+            hit = np.flatnonzero(slots >= 0)
+            if hit.size:
+                at = slots[hit]
+                out[:, hit] = self._tab[:, at]
+                known[hit] = self._known[at]
+                self._stamp[at] = self._stamps(hit.size)
+        self.hits += int(hit.size)
+        if hit.size < n:
+            miss = np.flatnonzero(slots < 0)
+            missed = [vks[j] for j in miss.tolist()]
+            # the distinct new keys in first-seen order, and each missed
+            # lane's place among them
+            place = dict.fromkeys(missed)
+            self.misses += len(place)
+            tab, ok = self._fill(list(place))
+            if len(place) < len(missed):      # a new key met twice
+                place = {vk: i for i, vk in enumerate(place)}
+                at = np.array(list(map(place.__getitem__, missed)))
+                tab, ok = tab[:, at], ok[at]
+            out[:, miss] = tab
+            known[miss] = ok
+        return out[0:8], out[8:16], out[16:24], known
 
-    def _fill(self, missing) -> dict:
-        """Batched device fill of every missing key (ONE a128_kernel
-        dispatch, padded to a power-of-two bucket).  Undecodable keys are
-        stored as negative entries so they never refill.  Returns the
-        fresh {vk: entry} map (assemble reads it directly so LRU eviction
-        during the insert loop can never lose this batch's entries)."""
+    def _fill(self, keys):
+        """Tables for the distinct new `keys`, made on the device and
+        stored; returns ((24, k) uint32 columns, ok (k,) bool) so that
+        assemble reads this batch's entries directly (LRU eviction
+        during the store can never lose them).  Undecodable keys are
+        stored as negative entries so they never refill."""
+        self.device_fills += 1
+        self.filled_keys += len(keys)
+        with _spans.span("precompute.fill", cat="device"):
+            tab, ok = self._device_tables(keys)
+            with _spans.span("fill.store", cat="device"):
+                self._store(keys, tab, ok)
+        return tab, ok
+
+    def _device_tables(self, keys):
+        """The device's part of a fill, at fixed program widths: up to
+        FILL_NARROW keys in one narrow call, more as whole ED_TILE-lane
+        tiles of one program called a tile at a time (all dispatched,
+        then all fetched).  Spans: fill.pack (key bytes to words, on the
+        host), fill.dispatch, fill.fetch (the wait for the device and
+        the copy back)."""
         import jax.numpy as jnp
 
         from . import ed25519_jax as EJ
-        from . import field_jax as F
-        m = 128
-        while m < len(missing):
-            m *= 2
-        arr, len_ok = EJ._bytes_rows(missing + [b"\x00" * 32] *
-                                     (m - len(missing)), 32)
-        yA, signA, y_ok = EJ._decode_compressed(arr)
-        self.device_fills += 1
-        self.filled_keys += len(missing)
-        with _spans.span("precompute.fill", cat="device"):
-            xa, x, y, ok = EJ.a128_kernel(jnp.asarray(yA),
-                                          jnp.asarray(signA))
-            xai = F.unpack(np.asarray(xa))
-            xi = F.unpack(np.asarray(x))
-            yi = F.unpack(np.asarray(y))
-        ok = np.asarray(ok) & len_ok & y_ok
-        fresh: dict = {}
-        for j, vk in enumerate(missing):
-            if ok[j]:
-                fresh[vk] = (EJ._words_of_int(xai[j]),
-                             EJ._words_of_int(xi[j]),
-                             EJ._words_of_int(yi[j]))
-            else:
-                fresh[vk] = _BAD
-            self._insert(self._c, vk, fresh[vk])
-        return fresh
+        from . import jax_backend as JB
+        k = len(keys)
+        width = FILL_NARROW if k <= FILL_NARROW else JB.ED_TILE
+        lanes = -(-k // width) * width
+        self.fill_lanes_padded += lanes
+        with _spans.span("fill.pack", cat="device"):
+            arr, len_ok = EJ._bytes_rows(keys, 32)
+            rows = np.zeros((lanes, 32), dtype=np.uint8)
+            rows[:k] = arr
+            Aw, sign, y_ok = EJ._point_words(rows)
+        with _spans.span("fill.dispatch", cat="device"):
+            parts = [EJ.a128_words_kernel(jnp.asarray(Aw[:, o:o + width]),
+                                          jnp.asarray(sign[o:o + width]))
+                     for o in range(0, lanes, width)]
+        with _spans.span("fill.fetch", cat="device"):
+            tab = np.concatenate([np.asarray(t) for t, _ok in parts],
+                                 axis=1)[:, :k]
+            ok = np.concatenate([np.asarray(o) for _t, o in parts])[:k]
+        ok = ok & len_ok & y_ok[:k]
+        # any valid point works for a key that does not decode: its
+        # lanes are masked via `known`
+        tab[:, ~ok] = EJ._FILLER_COL[:, None]
+        return tab, ok
+
+    def _slots_of(self, vks) -> np.ndarray:
+        """Each key's slot, -1 where it has none (under the stripe)."""
+        return np.array(list(map(self._slot.get, vks, repeat(-1))),
+                        dtype=np.int64)
+
+    def _stamps(self, n: int) -> np.ndarray:
+        """The next n recency stamps, oldest first (under the stripe)."""
+        self._clock += n
+        return np.arange(self._clock - n + 1, self._clock + 1)
+
+    def _store(self, keys, tab, ok) -> None:
+        """Insert a fill's entries in order, evicting least-recently-used
+        ones past `max_entries` — as if one by one, so of a fill larger
+        than the bound the LAST `max_entries` keys stay."""
+        with self._lock_c:
+            over = len(keys) - self.max_entries
+            if over > 0:
+                self.evictions += over
+                keys, tab, ok = keys[over:], tab[:, over:], ok[over:]
+            at = self._slots_of(keys)
+            new = np.flatnonzero(at < 0)   # not filled by a racing thread
+            self._evict(len(self._slot) + new.size - self.max_entries,
+                        keep=at[at >= 0])
+            at[new] = self._take_slots(new.size)
+            self._tab[:, at] = tab
+            self._known[at] = ok
+            self._stamp[at] = self._stamps(at.size)
+            self._key_at[at] = keys
+            self._slot.update(zip(keys, at.tolist()))
+
+    def _evict(self, n: int, keep) -> None:
+        """Free the n least recently used slots (never one of `keep`)."""
+        if n <= 0:
+            return
+        stamp = self._stamp[:self._used].copy()
+        stamp[keep] = 0
+        live = np.flatnonzero(stamp > 0)
+        n = min(n, live.size)
+        if not n:
+            return
+        gone = live[np.argpartition(stamp[live], n - 1)[:n]]
+        for vk in self._key_at[gone]:
+            del self._slot[vk]
+        self._stamp[gone] = 0
+        self._key_at[gone] = None
+        self._free.extend(gone.tolist())
+        self.evictions += n
+
+    def _take_slots(self, n: int) -> np.ndarray:
+        """n free slots: evicted ones first, then fresh ones (the arrays
+        double until they hold them, up to the bound)."""
+        reuse = min(n, len(self._free))
+        out = self._free[len(self._free) - reuse:]
+        del self._free[len(self._free) - reuse:]
+        fresh = n - reuse
+        out.extend(range(self._used, self._used + fresh))
+        self._used += fresh
+        room = self._stamp.size
+        if self._used > room:
+            grow = max(self._used, min(max(2 * room, 1024),
+                                       self.max_entries)) - room
+            self._tab = np.concatenate(
+                [self._tab, np.empty((_TAB_ROWS, grow), np.uint32)], axis=1)
+            self._known = np.concatenate([self._known, np.zeros(grow, bool)])
+            self._stamp = np.concatenate(
+                [self._stamp, np.zeros(grow, np.int64)])
+            self._key_at = np.concatenate(
+                [self._key_at, np.empty(grow, object)])
+        return np.asarray(out, dtype=np.int64)
 
     # -- KES hash-path outcomes ---------------------------------------------
     def kes_get(self, key):
@@ -258,19 +365,13 @@ class PrecomputeCache:
             return ent
 
     def kes_put(self, key, leaf_vk, path_ok: bool) -> None:
-        self._insert(self._kes, key, (leaf_vk, bool(path_ok)))
-
-    def kes_len(self) -> int:
-        return len(self._kes)
-
-    # -- plumbing ------------------------------------------------------------
-    def _insert(self, od: OrderedDict, key, value) -> None:
         # under the namespace stripe; every step STILL tolerates a
         # concurrent mutation (the eviction-tolerant semantics from the
         # pipelined-replay era are kept — dict ops are GIL-atomic and a
         # legacy unlocked caller must not corrupt the LRU bookkeeping)
-        with (self._lock_c if od is self._c else self._lock_kes):
-            od[key] = value
+        od = self._kes
+        with self._lock_kes:
+            od[key] = (leaf_vk, bool(path_ok))
             try:
                 od.move_to_end(key)
             except KeyError:
@@ -282,15 +383,25 @@ class PrecomputeCache:
                     break
                 self.evictions += 1
 
+    def kes_len(self) -> int:
+        return len(self._kes)
+
+    # -- plumbing ------------------------------------------------------------
     def clear(self) -> None:
-        self._c.clear()
+        with self._lock_c:
+            self._slot.clear()
+            self._stamp[:] = 0
+            self._key_at[:] = None
+            self._free.clear()
+            self._used = 0
         self._kes.clear()
 
     def stats(self) -> dict:
-        return {"entries": len(self._c), "kes_entries": len(self._kes),
+        return {"entries": len(self._slot), "kes_entries": len(self._kes),
                 "hits": self.hits, "misses": self.misses,
                 "device_fills": self.device_fills,
                 "filled_keys": self.filled_keys,
+                "fill_lanes_padded": self.fill_lanes_padded,
                 "evictions": self.evictions,
                 "lock_wait": self.lock_wait}
 

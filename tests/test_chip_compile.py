@@ -198,12 +198,19 @@ def test_fold_program_compiles_for_v5e(nb, nk, one_chip):
         S((NV,), I32), S((NV, 32), U8), S((NV, 16), U8)).compile()
 
 
-def test_key_fill_compiles_for_v5e(one_chip):
-    """The per-key fill the precompute cache dispatches on a cold key
-    (decompress + 128 doublings), at its smallest bucket."""
+@pytest.mark.parametrize("width", ["narrow", "tile"])
+def test_key_fill_compiles_for_v5e(width, one_chip):
+    """The per-key fill the precompute cache dispatches for new keys
+    (decompress + 128 doublings, words in and out), at its two widths:
+    the narrow program of a handful of new keys and the ED_TILE-lane
+    tile of a window whose witness keys are all new; a tile's
+    temporaries stay far under the flat 131,072-lane program's 4 GB."""
+    from ouroboros_tpu.crypto import precompute
+    lanes = precompute.FILL_NARROW if width == "narrow" else JB.ED_TILE
     S = _spec(one_chip)
-    jax.jit(lambda y, s: EJ.a128_core(y, s)).lower(
-        S((20, 128), I32), S((128,), I32)).compile()
+    compiled = jax.jit(EJ.a128_words_core).lower(
+        S((8, lanes), U32), S((lanes,), I32)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 27
 
 
 @pytest.mark.slow

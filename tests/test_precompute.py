@@ -23,25 +23,20 @@ from ouroboros_tpu.crypto.precompute import PrecomputeCache
 
 
 def _stub_fill(cache, log=None):
-    """Replace the device fill with a synthetic one (LRU tests must not
-    depend on jax): entry words are derived from the key bytes."""
-    def fill(missing):
+    """Replace the device's part of a fill with a synthetic one (LRU
+    tests must not depend on jax): a key's table words are derived from
+    the key bytes, three copies of its SHA-256."""
+    def tables(keys):
         if log is not None:
-            log.append(list(missing))
-        cache.device_fills += 1
-        cache.filled_keys += len(missing)
-        fresh = {}
-        for vk in missing:
-            if vk.startswith(b"bad"):
-                from ouroboros_tpu.crypto import precompute
-                fresh[vk] = precompute._BAD
-            else:
-                w = np.frombuffer(hashlib.sha256(vk).digest(),
-                                  dtype=np.uint32)
-                fresh[vk] = (w, w, w)
-            cache._insert(cache._c, vk, fresh[vk])
-        return fresh
-    cache._fill = fill
+            log.append(list(keys))
+        tab = np.empty((24, len(keys)), dtype=np.uint32)
+        ok = np.ones(len(keys), dtype=bool)
+        for j, vk in enumerate(keys):
+            tab[:, j] = np.tile(np.frombuffer(hashlib.sha256(vk).digest(),
+                                              dtype=np.uint32), 3)
+            ok[j] = not vk.startswith(b"bad")
+        return tab, ok
+    cache._device_tables = tables
     return cache
 
 
@@ -343,7 +338,7 @@ def test_cold_vs_warm_window_parity_and_zero_warm_fills():
     cache = precompute.GLOBAL_PRECOMPUTE_CACHE
     jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
     # fresh cache: this test owns the global (restore after)
-    saved = (cache._c.copy(), cache._kes.copy())
+    saved = cache._kes.copy()   # point tables refill on demand
     cache.clear()
     try:
         sub = jb.submit_window(reqs, next_beta_proofs=proofs)
@@ -371,8 +366,7 @@ def test_cold_vs_warm_window_parity_and_zero_warm_fills():
         assert cache.device_fills == fills
     finally:
         cache.clear()
-        cache._c.update(saved[0])
-        cache._kes.update(saved[1])
+        cache._kes.update(saved)
 
 
 def test_split_mixed_device_owner_mapping_cold_and_warm():
@@ -389,7 +383,7 @@ def test_split_mixed_device_owner_mapping_cold_and_warm():
             for i in range(3)]
     jb = JaxBackend(use_pallas=False, autotune=False)
     cache = precompute.GLOBAL_PRECOMPUTE_CACHE
-    saved = (cache._c.copy(), cache._kes.copy())
+    saved = cache._kes.copy()   # point tables refill on demand
     cache.clear()
     try:
         (eds, ed_owner, _v, _vo, msgs, _exp, checks, n) = \
@@ -415,5 +409,4 @@ def test_split_mixed_device_owner_mapping_cold_and_warm():
         assert eds3 == [] and msgs3 == [] and checks3 == []
     finally:
         cache.clear()
-        cache._c.update(saved[0])
-        cache._kes.update(saved[1])
+        cache._kes.update(saved)

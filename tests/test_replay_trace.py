@@ -115,26 +115,23 @@ def chain_dir(tmp_path_factory):
 
 @pytest.fixture()
 def host_tables(monkeypatch):
-    """The per-key tables are a device fill too (`a128_kernel`): a
-    stand-in marks every key known, and the global caches this replay
-    fills with it are put back as they were."""
+    """The per-key tables are a device fill too (`a128_words_kernel`):
+    a stand-in marks every key known, and the global caches this replay
+    fills with it are cleared after (the KES outcomes put back)."""
     from ouroboros_tpu.crypto import ed25519_jax as EJ
-    from ouroboros_tpu.crypto import field_jax as F
 
-    def a128(yA, _signA):
-        n = np.asarray(yA).shape[1]
-        zero = F.pack([0] * n)
-        return zero, zero, zero, np.ones(n, bool)
+    def a128(Aw, _signA):
+        n = np.asarray(Aw).shape[1]
+        return np.zeros((24, n), np.uint32), np.ones(n, bool)
 
-    monkeypatch.setattr(EJ, "a128_kernel", a128)
+    monkeypatch.setattr(EJ, "a128_words_kernel", a128)
     cache = GLOBAL_PRECOMPUTE_CACHE
-    saved = (cache._c.copy(), cache._kes.copy())
+    saved = cache._kes.copy()   # point tables refill on demand
     try:
         yield
     finally:
         cache.clear()
-        cache._c.update(saved[0])
-        cache._kes.update(saved[1])
+        cache._kes.update(saved)
 
 
 def _counters() -> dict:
@@ -239,6 +236,27 @@ def test_children_never_longer_than_their_parent(traced):
                 <= sp.duration + 1e-9
 
 
+def test_a_fill_holds_its_four_stages_in_order(traced):
+    """`precompute.fill` is making tables for missed keys, whoever asks
+    (a packer of `window.submit`, the beta prefetch): packing the keys
+    and storing the tables on the host, dispatch and fetch the device's
+    side, and the counters beside it count keys and lanes."""
+    roots, _stats, _hash, delta = traced
+    fills = [sp for r in roots for sp in r.walk()
+             if sp.name == "precompute.fill"]
+    assert fills and len(fills) == delta["precompute.device_fills"]
+    for sp in fills:
+        assert sp.cat == "device" and sp.thread == PRODUCER
+        assert [(c.name, c.cat) for c in sp.children] == [
+            ("fill.pack", "device"), ("fill.dispatch", "device"),
+            ("fill.fetch", "device"), ("fill.store", "device")]
+    # `misses` counts the KES hash paths that missed too
+    assert 0 < delta["precompute.filled_keys"] <= delta["precompute.misses"]
+    assert delta["precompute.filled_keys"] \
+        <= delta["precompute.fill_lanes_padded"]
+    assert delta["precompute.fill_lanes_padded"] % 128 == 0
+
+
 def test_window_index_on_both_threads(traced):
     roots, _stats, _hash, _delta = traced
     spans = [sp for r in roots for sp in r.walk()]
@@ -338,6 +356,10 @@ NEW_METRICS = (
 # a tiny replay may see no collection at all, so these may read 0
 GC_METRICS = ("gc_pause_us_per_block", "gc_full_passes_per_replay",
               "gc_frozen_objects_per_block")
+# the per-key table fill (ISSUE 31), listed after PR 30's
+KEY_METRICS = ("key_fill_ms_per_window", "key_fill_us_per_key",
+               "key_fill_host_share", "key_fill_pad_share",
+               "key_cache_hit_share")
 
 
 def _facts(roots, stats, delta) -> dict:
@@ -374,7 +396,7 @@ def test_the_new_metric_files_are_these():
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         listed = [m["name"] for m in json.load(fh)["per_layer"]]
     # in this order and together; later PRs' metrics follow them
-    for group in (NEW_METRICS, GC_METRICS):
+    for group in (NEW_METRICS, GC_METRICS, KEY_METRICS):
         at = listed.index(group[0])
         assert listed[at:at + len(group)] == list(group)
     files = {os.path.basename(p)[:-5] for p in glob.glob(
@@ -382,7 +404,7 @@ def test_the_new_metric_files_are_these():
     assert files == set(listed)
 
 
-@pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS)
+@pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS)
 def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     """A renamed span or counter fails here, not in a chip run."""
     roots, stats, _hash, delta = traced

@@ -64,21 +64,34 @@ def _clear_caches() -> None:
     GLOBAL_PRECOMPUTE_CACHE.clear()
 
 
-@pytest.fixture(scope="module")
-def chain(tmp_path_factory):
-    """(directory, db, rules, decode, cfg) of the seeded chain, forged
-    with the rehearse block's `synth` arguments."""
-    d = str(tmp_path_factory.mktemp("mesh-chain") / "chain")
+def _forge(tmp_path_factory, name: str, *extra: str) -> tuple:
+    d = str(tmp_path_factory.mktemp(name) / "chain")
     r = subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "db_synth.py"),
          "--out", d, "--blocks", str(BLOCKS), "--seed", str(SEED),
          "--protocol", "shelley", "--pools", "2", "--f", "1/20",
          "--epoch-length", "432000", "--kes-depth", "6",
-         "--txs-per-block", "1"],
+         "--txs-per-block", "1", *extra],
         capture_output=True, text=True, timeout=300,
         env={**os.environ, "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr
     return (d, *dba.load_db(d))
+
+
+@pytest.fixture(scope="module")
+def chain(tmp_path_factory):
+    """(directory, db, rules, decode, cfg) of the seeded chain, forged
+    with the rehearse block's `synth` arguments."""
+    return _forge(tmp_path_factory, "mesh-chain")
+
+
+@pytest.fixture(scope="module")
+def fresh_chain(tmp_path_factory):
+    """The same chain with `--witness-keys fresh` (the rehearse block of
+    `shelley-sync-1chip-freshkeys.json`): the same counts of blocks,
+    transactions and proofs, so the same window shapes and programs."""
+    return _forge(tmp_path_factory, "fresh-chain",
+                  "--witness-keys", "fresh")
 
 
 def _validate(chain, backend, decode=None, cold: bool = True) -> dict:
@@ -104,19 +117,23 @@ def mesh_backend():
 
 
 @pytest.fixture(scope="module")
+def one_chip_backend():
+    return JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
+
+
+@pytest.fixture(scope="module")
 def reference_backend():
     return dba.make_backend("cpp" if shutil.which("g++") else "openssl")
 
 
 @pytest.fixture(scope="module")
-def lines(chain, mesh_backend, reference_backend):
+def lines(chain, mesh_backend, reference_backend, one_chip_backend):
     """The replay's line by backend, and what the mesh's counters read
     while the OTHER backends replayed (nothing, or the path leaked)."""
     out = {"mesh": _validate(chain, mesh_backend)}
     c0 = _counters()
     out["cpp"] = _validate(chain, reference_backend)
-    out["one-chip"] = _validate(chain, JaxBackend(
-        min_bucket=16, use_pallas=False, autotune=False))
+    out["one-chip"] = _validate(chain, one_chip_backend)
     c1 = _counters()
     out["leak"] = {k: c1[k] - c0.get(k, 0) for k in MESH_COUNTERS}
     return out
@@ -219,6 +236,119 @@ def test_a_bad_lane_in_any_shard_stops_where_the_reference_stops(
     # and the tampered window ran the clean chain's composite
     assert metrics_mod.counter(
         "jax_backend.composite_builds").value == builds
+
+
+# -- a chain on which every witness key is new (ISSUE 31) --------------------
+
+_COMPILES: list = []      # backend compiles of this process, as they end
+
+
+def _witness_keys(chain) -> list:
+    _d, db, _rules, decode, _cfg = chain
+    return [vk for _e, raw in db.stream() for tx in decode(raw).body
+            for vk, _sig in tx.witnesses]
+
+
+@pytest.fixture(scope="module")
+def fresh_lines(fresh_chain, lines, mesh_backend, one_chip_backend,
+                reference_backend):
+    """The fresh chain's replay by backend, AFTER `lines` compiled every
+    program on the pool-key chain; beside each device replay what the
+    per-key cache was asked (`assemble` spied on) and what it counted."""
+    if not _COMPILES:
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **_kw: _COMPILES.append(event)
+            if event.endswith("backend_compile_duration") else None)
+    cache = GLOBAL_PRECOMPUTE_CACHE
+    real = cache.assemble
+    out = {"cpp": _validate(fresh_chain, reference_backend)}
+    for name, backend in (("mesh", mesh_backend),
+                          ("one-chip", one_chip_backend)):
+        asked = []     # (keys, which were cached, lanes `hits` rose by)
+
+        def spy(vks):
+            cached = [vk in cache for vk in vks]
+            h0 = cache.hits
+            res = real(vks)
+            asked.append((list(vks), cached, cache.hits - h0))
+            return res
+
+        compiles = len(_COMPILES)
+        builds = metrics_mod.counter("jax_backend.composite_builds").value
+        cache.assemble = spy
+        try:
+            c0 = _counters()
+            out[name] = _validate(fresh_chain, backend)
+            c1 = _counters()
+        finally:
+            del cache.assemble
+        out[name + "-facts"] = {
+            "asked": asked,
+            "delta": {k: c1[k] - c0.get(k, 0) for k in c1},
+            "compiles": len(_COMPILES) - compiles,
+            "builds": metrics_mod.counter(
+                "jax_backend.composite_builds").value - builds}
+    return out
+
+
+def test_no_witness_key_signs_twice_on_the_fresh_chain(chain, fresh_chain):
+    fresh = _witness_keys(fresh_chain)
+    assert len(fresh) == len(set(fresh)) == BLOCKS
+    assert len(set(_witness_keys(chain))) == 2      # the pools' two
+
+
+@pytest.mark.parametrize("what", ["state_hash", "blocks", "proofs"])
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_fresh_chain_replay_equals_the_reference(fresh_lines, backend,
+                                                 what):
+    assert fresh_lines[backend][what] == fresh_lines["cpp"][what]
+    assert fresh_lines["cpp"]["blocks"] == BLOCKS
+
+
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_fresh_chain_fills_each_witness_key_once_and_hits_header_keys(
+        fresh_chain, fresh_lines, backend):
+    facts = fresh_lines[backend + "-facts"]
+    witness = set(_witness_keys(fresh_chain))
+    seen: set = set()
+    lanes_hit = 0
+    for vks, cached, rose in facts["asked"]:
+        # a lane hits exactly when its key was in the cache, and no
+        # witness key ever was: each is met once, as a miss
+        assert rose == sum(cached)
+        assert not any(c for vk, c in zip(vks, cached) if vk in witness)
+        lanes_hit += rose
+        seen.update(vks)
+    assert witness <= seen
+    delta = facts["delta"]
+    # every distinct key filled once: the witness keys and the header
+    # keys (cold, VRF and KES leaf keys; the pad lanes' zero key)
+    assert delta["precompute.filled_keys"] == len(seen)
+    assert len(witness) < len(seen) <= len(witness) + 4 + BLOCKS + 1
+    assert lanes_hit > 0           # window 1 meets window 0's pool keys
+    assert delta["precompute.fill_lanes_padded"] \
+        == 128 * delta["precompute.device_fills"]
+
+
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_fresh_chain_compiles_nothing_the_pool_chain_did_not(
+        fresh_lines, backend):
+    """The fill's programs are keyed on no count of new keys: the chain
+    of new keys runs what the chain of two keys compiled."""
+    facts = fresh_lines[backend + "-facts"]
+    assert facts["compiles"] == 0 and facts["builds"] == 0
+    assert facts["delta"]["jax_backend.windows_submitted"] == N_WINDOWS
+
+
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_a_flipped_witness_on_the_fresh_chain_stops_both_at_its_block(
+        fresh_chain, fresh_lines, mesh_backend, one_chip_backend,
+        reference_backend, backend):
+    device = mesh_backend if backend == "mesh" else one_chip_backend
+    at = (WINDOW + 2,)
+    assert _stop(fresh_chain, device, at) \
+        == _stop(fresh_chain, reference_backend, at) \
+        == (WINDOW + 2, "Ed25519Req")
 
 
 # -- what the mesh adds: one span, three counters ---------------------------

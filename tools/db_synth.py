@@ -16,6 +16,19 @@ Two chain flavours:
 
 Usage: python tools/db_synth.py --out DIR [--protocol shelley] [--blocks N]
        [--txs-per-block M] [--pools P] [--f NUM/DEN]
+       [--witness-keys pool|fresh]
+
+--witness-keys (shelley) says who signs the transactions.  `pool` (the
+default, the chain every earlier version forged, byte for byte): every
+transaction pays to, and is signed by, its pool owner's one payment key,
+so a chain has as many witness keys as pools.  `fresh`: every
+transaction pays to the public key of a secret derived from (seed,
+running index), and the next transaction of that chain of spends is
+signed by it — the HD-wallet key distribution (CIP-1852: one payment key
+an address, a fresh address a transaction), in which no witness key
+occurs twice in the chain.  An address IS its key here, so transaction
+and block sizes are the same in both; the fresh addresses are not
+delegated, which moves nothing before the first epoch boundary.
 """
 from __future__ import annotations
 
@@ -186,7 +199,7 @@ def synth_shelley(args) -> dict:
     body)."""
     from ouroboros_tpu.consensus.headers import ProtocolBlock, make_header
     from ouroboros_tpu.consensus.ledger import ExtLedgerRules
-    from ouroboros_tpu.crypto import kes as kes_mod
+    from ouroboros_tpu.crypto import ed25519_ref, kes as kes_mod
     from ouroboros_tpu.eras.shelley import (
         TPraosConfig, forge_tpraos_fields, make_shelley_tx,
         shelley_genesis_setup,
@@ -233,6 +246,11 @@ def synth_shelley(args) -> dict:
     gen_order = sorted(p["addr"] for p in pools)
     spendable = {i: [(GEN, gen_order.index(p["addr"]), 100_000)]
                  for i, p in enumerate(pools)}
+    # who may spend a chain of spends' one open output: the owner's
+    # payment key, for good (`pool`) or until the first spend (`fresh`)
+    fresh = args.witness_keys == "fresh"
+    holder_sk = {i: p["keys"].addr_sk for i, p in enumerate(pools)}
+    n_tx = 0
 
     prev = None
     slot = 0
@@ -259,10 +277,20 @@ def synth_shelley(args) -> dict:
             if not spendable[owner]:
                 continue
             txid, ix, amount = spendable[owner].pop(0)
-            op = pools[owner]
+            if fresh:
+                # pay to a key no earlier block has seen; it signs this
+                # chain of spends' next transaction and nothing else
+                next_sk = hashlib.blake2b(
+                    b"fresh-witness:%s:%d" % (args.seed.encode(), n_tx),
+                    digest_size=32).digest()
+                pay_to = ed25519_ref.public_key(next_sk)
+            else:
+                next_sk, pay_to = holder_sk[owner], pools[owner]["addr"]
             tx = make_shelley_tx(
-                inputs=[(txid, ix)], outputs=[(op["addr"], amount)],
-                certs=[], signing_keys=[op["keys"].addr_sk])
+                inputs=[(txid, ix)], outputs=[(pay_to, amount)],
+                certs=[], signing_keys=[holder_sk[owner]])
+            holder_sk[owner] = next_sk
+            n_tx += 1
             spendable[owner].append((tx.txid, 0, amount))
             body.append(tx)
         hdr = make_header(prev, slot, body, issuer=0)
@@ -470,6 +498,12 @@ def main() -> None:
                     help="cardano era span: the full "
                          "Byron->Shelley->Allegra->Mary ladder, or stop "
                          "at Shelley (the streaming-replay e2e shape)")
+    ap.add_argument("--witness-keys", default="pool",
+                    choices=["pool", "fresh"],
+                    help="shelley: who signs the transactions: each "
+                         "pool owner's one payment key, or a key the "
+                         "chain has not seen before for every "
+                         "transaction (HD-wallet addresses)")
     ap.add_argument("--seed", default="db-synth")
     args = ap.parse_args()
 
