@@ -164,6 +164,21 @@ class ProtocolBlock:
         return cbor.dumps(self.encode())
 
 
+@dataclass(frozen=True)
+class BlockDecoder:
+    """`ProtocolBlock.from_bytes` with its two arguments bound: a DB's
+    decoder as an importable, picklable callable (a closure over them
+    could not be sent to the streamed replay's decode worker processes,
+    storage/decode_pool.py).  `tx_decode` has to pickle too: a
+    module-level function or a classmethod (`ShelleyTx.decode`)."""
+    tx_decode: Any = None
+    tx_body_elems: Optional[int] = None
+
+    def __call__(self, raw: bytes) -> ProtocolBlock:
+        return ProtocolBlock.from_bytes(raw, tx_decode=self.tx_decode,
+                                        tx_body_elems=self.tx_body_elems)
+
+
 def _cache_slices(block: ProtocolBlock, raw: bytes, spans,
                   tx_body_elems: int | None) -> bool:
     """Fill the header's and the transactions' caches from the offsets

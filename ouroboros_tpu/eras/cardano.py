@@ -25,6 +25,7 @@ from ..consensus.hardfork import Era, EraParams, hard_fork_rules
 from ..consensus.hardfork.combinator import ERA_FIELD
 from ..consensus.headers import ProtocolBlock, ProtocolHeader
 from ..crypto import ed25519_ref
+from ..utils import cbor
 from .byron import (
     ByronLedger, ByronLedgerState, ByronPBft, ByronTx,
     byron_genesis_setup, byron_transition_epoch,
@@ -177,3 +178,11 @@ def cardano_block_decode(obj) -> ProtocolBlock:
     tx_decode = ByronTx.decode if era == BYRON else ShelleyTx.decode
     body = tuple(tx_decode(t) for t in obj[1])
     return ProtocolBlock(header, body)
+
+
+def cardano_block_from_bytes(raw: bytes) -> ProtocolBlock:
+    """`cardano_block_decode` of a stored block's bytes: the decoder of
+    a Cardano-composed DB (`tools/db_analyser.load_db`), a module-level
+    function so that a streamed replay can ship it to its decode worker
+    processes (storage/decode_pool.py)."""
+    return cardano_block_decode(cbor.loads(raw))

@@ -57,7 +57,13 @@ span named first; cat in brackets):
                     one each a block (consensus/headers.py): the one
                     walk of the bytes that keeps the list offsets, the
                     block built from the parsed object, the cached raw
-                    slices cut at those offsets
+                    slices cut at those offsets.  Where a decode worker
+                    process did them (storage/decode_pool.py) they are
+                    its readings of the same clock, adopted (`adopt`)
+                    with `thread` the worker's name, and lie before the
+                    `stream.decode` they hang under: that span is then
+                    the prefetch thread's wait for the chunk's reply and
+                    decode.unpack [disk], the reply turned into blocks
     window.host_seq seq.header, seq.body [host-seq], the header rules
                     and the ledger pass of one block, interleaved as
                     `_seq_block_step` runs them (consensus/batch.py)
@@ -320,3 +326,17 @@ def span(name: str, cat: str = "host-seq", fence: bool = False, **meta):
 
 def enabled() -> bool:
     return RECORDER.enabled
+
+
+def adopt(parent: Span, rows, thread: str) -> None:
+    """Spans timed in another process become closed children of
+    `parent`: `rows` of `(name, cat, t0, t1)` read there from
+    `time.perf_counter()` (on Linux the system-wide monotonic clock, so
+    the readings are on this recorder's clock), `thread` the name that
+    process goes by.  They lie where the other process did the work,
+    which can be before `parent` opened (storage/decode_pool.py: a
+    worker decodes a chunk ahead of the prefetch thread asking for it)."""
+    for name, cat, t0, t1 in rows:
+        sp = Span(name, cat, t0, thread)
+        sp.t1 = t1
+        parent.children.append(sp)

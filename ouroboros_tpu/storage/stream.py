@@ -21,6 +21,12 @@ producer/consumer replay (consensus/pipeline.py):
        blocks when `depth`
        batches are waiting)
 
+Where the decoding runs: in decode worker PROCESSES where the decoder
+can be shipped to one (storage/decode_pool.py: the prefetcher sends a
+chunk's bytes out and unpickles the built blocks, so the decode leaves
+the interpreter lock the three threads share), else on the prefetch
+thread itself.  The blocks are the same either way.
+
 Disk + decode seconds hide behind device verify exactly the way the
 host sequential pass does: the prefetcher feeds a third on/off signal
 into the shared ProgressTracker ({prefetch busy} ∩ {≥1 window in
@@ -58,6 +64,7 @@ custom CBOR codec plugs into the same two arguments).
 """
 from __future__ import annotations
 
+import contextlib
 import gc
 import pickle
 import threading
@@ -70,6 +77,7 @@ from ..consensus.pipeline import ProgressTracker
 from ..observe import flight as _flight
 from ..observe import metrics as _metrics
 from ..observe import spans as _spans
+from .decode_pool import POOL, Lease, decode_blocks
 from .ledgerdb import DiskPolicy, LedgerDB
 
 #: header field carrying the hard-fork era tag (combinator.ERA_FIELD —
@@ -256,13 +264,33 @@ class BlockPrefetcher:
     I/O; DBs without the chunk API (the reference-format read view)
     fall back to the per-block iterator, same thread, same bounds.
 
+    Where decoding runs is read off the decoder, by no option: one that
+    pickles here and loads in a decode worker (`decode_pool.POOL.lease`:
+    `db_analyser.load_db`'s do) has its chunks decoded in the worker
+    processes, dispatched ahead and collected in chain order, and this
+    thread only unpickles the built blocks; any other (a closure, a
+    lambda, a stateful decoder), a DB without the chunk API, and a
+    second replay while one holds the pool, decode on this thread.
+    Both run `decode_pool.decode_blocks` with the same decoder, so the
+    blocks, their cached slices and a decode error's type are the same.
+    Blocks in flight at the workers count as read ahead: no chunk goes
+    out while decoded-but-unconsumed blocks plus those in flight would
+    pass `depth * window` (one chunk always may, so the stream moves).
+
     Coordination: one Condition guards {batches, stop, eof, error}.
     The thread blocks while `depth` batches are queued (back-pressure),
     the consumer blocks while none are; `close()` wakes and joins the
     thread — the engine calls it in a finally, so an aborted replay
     (first-error-wins, a snapshot-hook kill) never leaks it.  A read or
-    decode failure parks on `error` and re-raises on the consumer after
-    the already-queued batches drain.
+    decode failure (a worker's too: it arrives as the exception the
+    worker raised) parks on `error` and re-raises on the consumer after
+    the already-queued batches drain.  `close()` mid-stream drops what
+    is in flight and gives the workers back idle; a worker that dies
+    fails the replay with `DecodeWorkerDied`.
+
+    `on_decoded(blocks)`, if given, sees every chunk's blocks on this
+    thread as they arrive (db_analyser counts blocks and proofs there:
+    a count inside the decoder would stay in a worker).
 
     The cyclic collector: after each decoded chunk, on this thread and
     outside the Condition, the prefetcher offers the chunk to the
@@ -273,13 +301,15 @@ class BlockPrefetcher:
     def __init__(self, db, decode: Callable[[bytes], Any],
                  window: int = 512, depth: int = 4,
                  tracker: Optional[ProgressTracker] = None,
-                 after_hash: Optional[bytes] = None):
+                 after_hash: Optional[bytes] = None,
+                 on_decoded: Optional[Callable[[list], None]] = None):
         self.db = db
         self.decode = decode
         self.window = max(1, window)
         self.depth = max(1, depth)
         self.tracker = tracker
         self.after_hash = after_hash
+        self.on_decoded = on_decoded
         # exact per-instance accounting (engine stats read these; the
         # registry instruments mirror them for live observers)
         self.chunks_read = 0
@@ -288,6 +318,7 @@ class BlockPrefetcher:
         self.era_crossings = 0
         self.stalls = 0
         self._last_era: Optional[int] = None
+        self._taken = 0            # blocks the consumer has popped
         self._cond = threading.Condition()
         self._batches: deque = deque()
         self._stop = False
@@ -311,10 +342,10 @@ class BlockPrefetcher:
             self._thread.join()
 
     # -- the reading thread --------------------------------------------------
-    def _decode_batch(self, pairs) -> list:
-        out = []
-        for _entry, raw in pairs:
-            b = self.decode(raw)
+    def _account(self, blocks: list) -> list:
+        """A chunk's decoded blocks, wherever they were decoded: era
+        crossings, counts, the caller's hook."""
+        for b in blocks:
             hdr = getattr(b, "header", b)
             era = hdr.get(ERA_FIELD) if hasattr(hdr, "get") else None
             if era is not None:
@@ -322,44 +353,101 @@ class BlockPrefetcher:
                     self.era_crossings += 1
                     _ERAS.inc()
                 self._last_era = era
-            out.append(b)
-        self.blocks_decoded += len(out)
-        _BLOCKS.inc(len(out))
-        return out
+        self.blocks_decoded += len(blocks)
+        _BLOCKS.inc(len(blocks))
+        if self.on_decoded is not None:
+            self.on_decoded(blocks)
+        return blocks
+
+    @contextlib.contextmanager
+    def _disk(self, span_name: str):
+        """The disk signal (tracker + a `disk`-phase span) around work
+        of this thread: a read, a decode, the wait for a worker's
+        reply; never the queue wait."""
+        tracker = self.tracker
+        if tracker is not None:
+            tracker.disk_begin()
+        try:
+            with _spans.span(span_name, cat="disk") as sp:
+                yield sp
+        finally:
+            if tracker is not None:
+                tracker.disk_end()
+
+    def _note_read(self, pairs) -> None:
+        nbytes = sum(len(raw) for _e, raw in pairs)
+        self.chunks_read += 1
+        self.bytes_read += nbytes
+        _CHUNKS.inc()
+        _BYTES.inc(nbytes)
+
+    def _decode_batch(self, pairs) -> list:
+        """Decode on this thread (a decoder that does not ship)."""
+        with self._disk("stream.decode"):
+            return self._account(decode_blocks(
+                self.decode, [raw for _entry, raw in pairs]))
+
+    def _collect(self, lease: Lease) -> Optional[list]:
+        """The oldest chunk out at the workers, as blocks; None when the
+        consumer closed the stream meanwhile."""
+        with self._disk("stream.decode") as sp:
+            blocks = lease.collect(lambda: self._stop, into=sp)
+            return None if blocks is None else self._account(blocks)
+
+    def _read_chunks(self) -> Iterator[list]:
+        """(entry, raw) pairs a chunk, from the resume cursor on."""
+        cursor = self.db.start_after(self.after_hash)
+        if cursor is None:
+            return
+        n0, i0 = cursor
+        for n in self.db.chunk_numbers():
+            if n < n0:
+                continue
+            with self._disk("stream.read"):
+                pairs = self.db.chunk_blocks(
+                    n, from_index=i0 if n == n0 else 0)
+            self._note_read(pairs)
+            yield pairs
 
     def _read_decoded(self) -> Iterator[list]:
-        """Decoded blocks in chain order, one chunk's worth per step —
-        the disk signal (tracker + `disk`-phase spans) brackets exactly
-        the read+decode work, never the queue wait."""
-        tracker = self.tracker
-        chunk_api = hasattr(self.db, "chunk_blocks")
-        if chunk_api:
-            cursor = self.db.start_after(self.after_hash)
-            if cursor is None:
-                return
-            n0, i0 = cursor
-            for n in self.db.chunk_numbers():
-                if n < n0:
-                    continue
-                if tracker is not None:
-                    tracker.disk_begin()
-                try:
-                    with _spans.span("stream.read", cat="disk"):
-                        pairs = self.db.chunk_blocks(
-                            n, from_index=i0 if n == n0 else 0)
-                    self.chunks_read += 1
-                    self.bytes_read += sum(len(raw) for _e, raw in pairs)
-                    _CHUNKS.inc()
-                    _BYTES.inc(sum(len(raw) for _e, raw in pairs))
-                    with _spans.span("stream.decode", cat="disk"):
-                        blocks = self._decode_batch(pairs)
-                finally:
-                    if tracker is not None:
-                        tracker.disk_end()
-                yield blocks
+        """Decoded blocks in chain order, one chunk's worth per step."""
+        if not hasattr(self.db, "chunk_blocks"):
+            yield from self._read_decoded_per_block()
             return
-        # generic fallback: per-block iterator (reference-format views);
-        # `after_hash` skips the already-replayed prefix
+        lease = POOL.lease(self.decode)
+        if lease is None:              # the decoder stays in this process
+            for pairs in self._read_chunks():
+                yield self._decode_batch(pairs)
+            return
+        made = 0                       # blocks this generator has yielded
+        bound = self.depth * self.window
+        try:
+            for pairs in self._read_chunks():
+                # the oldest chunks come back first, until a worker is
+                # free and the read-ahead bound has room for this one
+                while lease.blocks_in_flight and (
+                        not lease.idle
+                        or made - self._taken + lease.blocks_in_flight
+                        + len(pairs) > bound):
+                    blocks = self._collect(lease)
+                    if blocks is None:
+                        return
+                    made += len(blocks)
+                    yield blocks
+                if pairs:
+                    lease.dispatch([raw for _entry, raw in pairs])
+            while lease.blocks_in_flight:
+                blocks = self._collect(lease)
+                if blocks is None:
+                    return
+                yield blocks
+        finally:
+            lease.release()
+
+    def _read_decoded_per_block(self) -> Iterator[list]:
+        """Generic fallback: the per-block iterator (reference-format
+        views), decoded on this thread a window's worth at a time;
+        `after_hash` skips the already-replayed prefix."""
         skipping = self.after_hash is not None
         buf_pairs: list = []
         for entry, raw in self.db.stream():
@@ -371,7 +459,8 @@ class BlockPrefetcher:
                 continue
             buf_pairs.append((entry, raw))
             if len(buf_pairs) >= self.window:
-                yield self._fallback_decode(buf_pairs)
+                self._note_read(buf_pairs)     # one read burst ≈ one chunk
+                yield self._decode_batch(buf_pairs)
                 buf_pairs = []
         if skipping:
             # the resume point never appeared: yielding nothing would
@@ -380,27 +469,14 @@ class BlockPrefetcher:
                 "resume point is not on the streamed chain (snapshot "
                 "outlived the DB?)")
         if buf_pairs:
-            yield self._fallback_decode(buf_pairs)
-
-    def _fallback_decode(self, pairs) -> list:
-        tracker = self.tracker
-        if tracker is not None:
-            tracker.disk_begin()
-        try:
-            self.chunks_read += 1          # one read burst ≈ one chunk
-            self.bytes_read += sum(len(raw) for _e, raw in pairs)
-            _CHUNKS.inc()
-            _BYTES.inc(sum(len(raw) for _e, raw in pairs))
-            with _spans.span("stream.decode", cat="disk"):
-                return self._decode_batch(pairs)
-        finally:
-            if tracker is not None:
-                tracker.disk_end()
+            self._note_read(buf_pairs)
+            yield self._decode_batch(buf_pairs)
 
     def _run(self) -> None:
+        decoded = self._read_decoded()
         try:
             buf: list = []
-            for blocks in self._read_decoded():
+            for blocks in decoded:
                 # the chunk just decoded leaves the cyclic collector's
                 # sight (inside an engine's replay; alone, nothing)
                 _COLLECTOR.freeze()
@@ -416,6 +492,7 @@ class BlockPrefetcher:
                 self._error = e
                 self._cond.notify_all()
         finally:
+            decoded.close()        # the workers go back before the join
             _P_FINISHED.inc()
             with self._cond:
                 self._eof = True
@@ -447,6 +524,7 @@ class BlockPrefetcher:
                     or self._error is not None or self._stop)
                 if self._batches:
                     batch = self._batches.popleft()
+                    self._taken += len(batch)
                     _DEPTH.set(len(self._batches))
                     self._cond.notify_all()
                 elif self._error is not None:
@@ -462,7 +540,10 @@ class StreamConfig:
     """Engine knobs.  `read_ahead` is the prefetch bound in windows —
     together with the pipeline's DEPTH it fixes the peak number of
     decoded blocks alive at once to (read_ahead + ~3) * window,
-    independent of chain length.  `policy` drives both the snapshot
+    independent of chain length: chunks out at the decode workers
+    count toward it (`BlockPrefetcher`), as do the batches queued.
+    Nothing here says where blocks are decoded: the prefetcher reads
+    that off the decoder it is given.  `policy` drives both the snapshot
     cadence during replay and the trim count
     (storage/ledgerdb.DiskPolicy); `take_snapshots=False` makes the
     run read-only on the DB directory (plain validation)."""
@@ -491,7 +572,10 @@ class StreamingReplayEngine:
     checkpoint.  Construct per run (`db_analyser --resume`, the
     benchmark's replays, the kill/resume tests); the heavyweight state — key
     caches, compiled programs — lives in the backend and survives
-    across engines.
+    across engines, and so do the decode worker processes
+    (storage/decode_pool.py), which belong to the process.  `decode` is
+    shipped to them where it can be (`BlockPrefetcher`); `on_decoded`
+    is the prefetcher's hook of that name.
 
     For the length of `replay()` the engine owns the cyclic collector's
     permanent generation (`_ReplayCollector`): it is entered before the
@@ -506,11 +590,13 @@ class StreamingReplayEngine:
     def __init__(self, fs, db, rules, decode: Callable[[bytes], Any],
                  backend=None, config: Optional[StreamConfig] = None,
                  encode_state: Callable[[Any], Any] = pickle_encode,
-                 decode_state: Callable[[Any], Any] = pickle_decode):
+                 decode_state: Callable[[Any], Any] = pickle_decode,
+                 on_decoded: Optional[Callable[[list], None]] = None):
         self.fs = fs
         self.db = db
         self.rules = rules
         self.decode = decode
+        self.on_decoded = on_decoded
         self.backend = backend
         self.cfg = config if config is not None else StreamConfig()
         self._enc = encode_state
@@ -591,7 +677,8 @@ class StreamingReplayEngine:
 
         pre = BlockPrefetcher(self.db, self.decode, window=cfg.window,
                               depth=cfg.read_ahead, tracker=tracker,
-                              after_hash=after_hash)
+                              after_hash=after_hash,
+                              on_decoded=self.on_decoded)
         with _COLLECTOR:
             pre.start()
             t0 = _spans.monotonic_now()

@@ -1,0 +1,490 @@
+"""Decode worker processes (ISSUE 32, storage/decode_pool.py): the
+prefetcher ships a chunk's bytes out and takes the built blocks back.
+
+What has to hold: the blocks are the ones the prefetch thread would
+have decoded, cached slices included; they arrive in chain order; an
+error, a closed stream and a dead worker end a replay as loudly and as
+cleanly as before; a decoder that cannot be shipped decodes in-thread;
+and a worker can neither reach the chip nor outlive its parent.
+
+One small forged Shelley chain of many chunks, no crypto backend, no
+JAX; every test carries its own time limit (`limit`), because what it
+guards against is a wait that never ends.
+"""
+import functools
+import importlib
+import os
+import pickle
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+from ouroboros_tpu import observe                              # noqa: E402
+from ouroboros_tpu.eras.shelley import KES_FIELD               # noqa: E402
+from ouroboros_tpu.storage import decode_pool                  # noqa: E402
+from ouroboros_tpu.storage.decode_pool import (                # noqa: E402
+    POOL, DecodeWorkerDied,
+)
+from ouroboros_tpu.storage.stream import (                     # noqa: E402
+    BlockPrefetcher, prefetcher_threads_alive,
+)
+from tools import db_analyser                                  # noqa: E402
+
+BLOCKS, TXS, WINDOW = 48, 5, 8
+WORKER_BLOCKS = "replay.decode.worker_blocks"
+
+
+def limit(seconds: int):
+    """The test fails, and does not hang, once `seconds` have passed
+    (SIGALRM: pytest and xdist run tests on the main thread)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def run(*a, **kw):
+            def late(_sig, _frame):
+                raise TimeoutError(f"{fn.__name__}: over {seconds} s")
+            old = signal.signal(signal.SIGALRM, late)
+            signal.alarm(seconds)
+            try:
+                return fn(*a, **kw)
+            finally:
+                signal.alarm(0)
+                signal.signal(signal.SIGALRM, old)
+        return run
+    return deco
+
+
+@pytest.fixture(scope="module")
+def chain_dir(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("workersdb"))
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "db_synth.py"),
+         "--out", d, "--protocol", "shelley", "--blocks", str(BLOCKS),
+         "--txs-per-block", str(TXS), "--pools", "2", "--f", "4/5",
+         "--epoch-length", "500", "--kes-depth", "4", "--chunk-size", "3"],
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return d
+
+
+@pytest.fixture(scope="module")
+def loaded(chain_dir):
+    db, _rules, decode, _cfg = db_analyser.load_db(chain_dir)
+    assert len(db) == BLOCKS
+    # more chunks than a process ever has workers: order is then the
+    # prefetcher's doing, not an accident of one chunk a worker
+    assert len(db.chunk_numbers()) > decode_pool.WORKER_CAP
+    return db, decode
+
+
+def in_thread(decode):
+    """The same decoder as a closure: it cannot be shipped."""
+    return lambda raw: decode(raw)
+
+
+def worker_blocks() -> int:
+    return observe.REGISTRY.get(WORKER_BLOCKS).value
+
+
+def stream(db, decode, window=WINDOW, depth=2) -> list:
+    pre = BlockPrefetcher(db, decode, window=window, depth=depth).start()
+    try:
+        return list(pre)
+    finally:
+        pre.close()
+        assert prefetcher_threads_alive() == 0
+
+
+class Tampered:
+    """A chunked DB whose block number `at` (in stream order) reads back
+    with its last byte cut off; everything else is the DB's."""
+
+    def __init__(self, db, at: int, before_chunk=None):
+        self._db, self._at, self._seen = db, at, 0
+        self._before_chunk = before_chunk
+
+    def __getattr__(self, name):
+        return getattr(self._db, name)
+
+    def chunk_blocks(self, n, from_index=0):
+        if self._before_chunk is not None:
+            self._before_chunk(n)
+        pairs = self._db.chunk_blocks(n, from_index=from_index)
+        out = []
+        for entry, raw in pairs:
+            out.append((entry, raw[:-1] if self._seen == self._at else raw))
+            self._seen += 1
+        return out
+
+
+# -- the blocks are the same ---------------------------------------------------
+
+@limit(120)
+def test_blocks_from_workers_equal_blocks_decoded_in_thread(loaded):
+    db, decode = loaded
+    w0 = worker_blocks()
+    shipped = stream(db, decode)
+    assert worker_blocks() - w0 == BLOCKS
+    local = stream(db, in_thread(decode))
+    assert worker_blocks() - w0 == BLOCKS        # the closure shipped none
+    assert len(shipped) == BLOCKS and shipped == local
+    for a, b in zip(shipped, local):
+        # the cached slices crossed intact: the header's bytes and
+        # spans, every transaction's body bytes
+        assert a.header._cache.keys() == b.header._cache.keys() \
+            >= {"bytes", "spans"}
+        assert a.header._cache["bytes"] == b.header._cache["bytes"]
+        assert a.header._cache["spans"] == b.header._cache["spans"]
+        assert a.hash == b.hash
+        assert a.header.bytes_dropping(KES_FIELD) \
+            == b.header.bytes_dropping(KES_FIELD)
+        assert len(a.body) == TXS
+        for ta, tb in zip(a.body, b.body):
+            assert ta._cache["body_bytes"] == tb._cache["body_bytes"]
+            assert ta.txid == tb.txid
+    # and they are what the stored bytes say, read with no cache at all
+    for blk, (_entry, raw) in zip(shipped, db.stream()):
+        plain = type(blk).decode(
+            importlib.import_module("ouroboros_tpu.utils.cbor").loads(raw),
+            tx_decode=decode.tx_decode)
+        assert plain == blk and plain.hash == blk.hash
+        assert [t.txid for t in plain.body] == [t.txid for t in blk.body]
+
+
+@limit(120)
+def test_chain_order_with_more_chunks_than_workers(loaded):
+    db, decode = loaded
+    want = [entry.hash for entry, _raw in db.stream()]
+    # depth 1 and a window of two blocks: the read-ahead bound throttles
+    # the dispatch all the way, and order still holds
+    for window, depth in ((WINDOW, 2), (2, 1), (BLOCKS, 4)):
+        got = stream(db, decode, window=window, depth=depth)
+        assert [b.hash for b in got] == want
+        assert [b.block_no for b in got] == sorted(b.block_no for b in got)
+
+
+@limit(120)
+def test_cardano_decoder_ships_and_counts_era_crossings(tmp_path):
+    import types
+    spec = importlib.util.spec_from_file_location(
+        "db_synth", os.path.join(REPO, "tools", "db_synth.py"))
+    dbs = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(dbs)
+    d = str(tmp_path / "cardano")
+    dbs.synth_cardano(types.SimpleNamespace(
+        out=d, protocol="cardano", blocks=40, txs_per_block=1, nodes=2,
+        pools=2, f="4/5", epoch_length=10, kes_depth=5, chunk_size=5,
+        format="native", seed="workers-test", eras="byron-shelley"))
+    db, _rules, decode, _cfg = db_analyser.load_db(d)
+    crossings = []
+    for dec in (decode, in_thread(decode)):
+        w0 = worker_blocks()
+        pre = BlockPrefetcher(db, dec, window=WINDOW, depth=2).start()
+        try:
+            blocks = list(pre)
+        finally:
+            pre.close()
+        crossings.append((pre.era_crossings, pre.blocks_decoded,
+                          [b.hash for b in blocks], worker_blocks() - w0))
+    assert crossings[0][:3] == crossings[1][:3]
+    assert crossings[0][0] >= 1
+    assert crossings[0][3] == len(db) and crossings[1][3] == 0
+
+
+# -- errors, closing, a dead worker -------------------------------------------------
+
+@limit(120)
+@pytest.mark.parametrize("at", [0, 21, BLOCKS - 1])
+def test_a_corrupt_block_raises_as_in_thread_after_the_same_batches(
+        loaded, at):
+    db, decode = loaded
+    seen = {}
+    for name, dec in (("in-thread", in_thread(decode)), ("workers", decode)):
+        pre = BlockPrefetcher(Tampered(db, at), dec, window=WINDOW,
+                              depth=2).start()
+        got = []
+        try:
+            with pytest.raises(Exception) as err:
+                for b in pre:
+                    got.append(b.hash)
+        finally:
+            pre.close()
+        assert prefetcher_threads_alive() == 0
+        seen[name] = (type(err.value), str(err.value), got)
+    assert seen["workers"] == seen["in-thread"]
+    kind, _msg, got = seen["workers"]
+    assert kind is not DecodeWorkerDied
+    # whole batches from before the bad block's chunk, in order
+    want = [entry.hash for entry, _raw in db.stream()]
+    assert got == want[:len(got)] and len(got) <= at
+    assert len(got) % WINDOW == 0
+    # the workers are none the worse for it
+    assert len(stream(db, decode)) == BLOCKS
+
+
+@limit(120)
+def test_close_mid_stream_then_a_second_replay_on_the_same_workers(loaded):
+    db, decode = loaded
+    stream(db, decode)                 # the pool is up
+    pids = sorted(POOL.pids())
+    assert pids
+    pre = BlockPrefetcher(db, decode, window=2, depth=1).start()
+    it = iter(pre)
+    first = next(it)
+    pre.close()                        # chunks are out at the workers
+    assert prefetcher_threads_alive() == 0
+    assert pre.blocks_decoded < BLOCKS
+    w0 = worker_blocks()
+    again = stream(db, decode)
+    assert again[0] == first and len(again) == BLOCKS
+    assert worker_blocks() - w0 == BLOCKS
+    assert sorted(POOL.pids()) == pids         # the same processes
+
+
+@limit(120)
+def test_a_killed_worker_fails_the_replay_with_its_exit_status(loaded):
+    db, decode = loaded
+    stream(db, decode)
+    before = sorted(POOL.pids())
+
+    def kill_all(n, at=db.chunk_numbers()[3]):
+        if n == at:
+            for pid in POOL.pids():
+                os.kill(pid, signal.SIGKILL)
+
+    pre = BlockPrefetcher(Tampered(db, -1, before_chunk=kill_all), decode,
+                          window=WINDOW, depth=2).start()
+    t0 = time.monotonic()
+    try:
+        with pytest.raises(DecodeWorkerDied) as err:
+            list(pre)
+    finally:
+        pre.close()
+    assert time.monotonic() - t0 < 30
+    assert prefetcher_threads_alive() == 0
+    assert "exit status -9" in str(err.value)
+    assert decode_pool.WORKER_NAME in str(err.value)
+    # the next replay starts new workers and is whole
+    assert len(stream(db, decode)) == BLOCKS
+    after = sorted(POOL.pids())
+    assert after and not set(after) & set(before)
+
+
+# -- which decoders ship -----------------------------------------------------------
+
+class Unloadable:
+    """Pickles here, cannot be loaded in a worker (its module is not
+    there): the replay has to find that out and decode in-thread."""
+
+    def __init__(self, decode):
+        self.decode = decode
+
+    def __call__(self, raw):
+        return self.decode(raw)
+
+    def __reduce__(self):
+        return (importlib.import_module, ("no_such_module_for_a_worker",))
+
+
+class Stateful:
+    """The benchmark's `tampering_decode` in small: it counts the blocks
+    it has seen, which only means something in one process."""
+
+    def __init__(self, decode):
+        self.decode, self.seen, self._lock = decode, 0, threading.Lock()
+
+    def __call__(self, raw):
+        with self._lock:               # a lock does not pickle
+            self.seen += 1
+        return self.decode(raw)
+
+
+@limit(120)
+@pytest.mark.parametrize("kind", ["closure", "lambda", "stateful",
+                                  "unloadable", "shipped"])
+def test_only_a_decoder_that_ships_goes_to_the_workers(loaded, kind):
+    db, decode = loaded
+    dec = {"closure": in_thread(decode),
+           "lambda": lambda raw, d=decode: d(raw),
+           "stateful": Stateful(decode),
+           "unloadable": Unloadable(decode),
+           "shipped": decode}[kind]
+    if kind == "unloadable":
+        pickle.dumps(dec)              # it does pickle: the worker refuses
+    want = stream(db, in_thread(decode))
+    w0 = worker_blocks()
+    assert stream(db, dec) == want
+    assert worker_blocks() - w0 == (BLOCKS if kind == "shipped" else 0)
+    if kind == "stateful":
+        assert dec.seen == BLOCKS
+
+
+@limit(120)
+def test_a_second_replay_at_once_decodes_in_thread(loaded):
+    db, decode = loaded
+    held = POOL.lease(decode)
+    assert held is not None
+    try:
+        assert POOL.lease(decode) is None
+        w0 = worker_blocks()
+        assert len(stream(db, decode)) == BLOCKS
+        assert worker_blocks() == w0
+    finally:
+        held.release()
+    w0 = worker_blocks()
+    assert len(stream(db, decode)) == BLOCKS
+    assert worker_blocks() - w0 == BLOCKS
+
+
+@limit(180)
+def test_replays_on_more_threads_than_cores_share_the_pool(loaded):
+    """Whoever gets the pool ships, the others decode in-thread; every
+    replay is whole and the lease is never held twice or left behind."""
+    db, decode = loaded
+    want = [b.hash for b in stream(db, in_thread(decode))]
+    results, errors = [], []
+
+    def one():
+        try:
+            for _ in range(3):
+                results.append([b.hash for b in stream_no_leak_check(
+                    db, decode)])
+        except BaseException as e:     # read back below
+            errors.append(e)
+
+    def stream_no_leak_check(db, decode):
+        pre = BlockPrefetcher(db, decode, window=WINDOW, depth=2).start()
+        try:
+            return list(pre)
+        finally:
+            pre.close()
+
+    w0 = worker_blocks()
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=one)
+                   for _ in range(2 * (os.cpu_count() or 4))]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=150)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors, errors
+    assert len(results) == 3 * len(threads)
+    assert all(r == want for r in results)
+    assert (worker_blocks() - w0) % BLOCKS == 0
+    assert prefetcher_threads_alive() == 0
+    lease = POOL.lease(decode)         # nobody kept it
+    assert lease is not None
+    lease.release()
+
+
+def test_worker_count_follows_the_cores(monkeypatch):
+    for cores, want in ((1, 1), (3, 1), (4, 1), (5, 2), (8, 5),
+                        (13, decode_pool.WORKER_CAP),
+                        (30, decode_pool.WORKER_CAP)):
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda _pid, n=cores: set(range(n)))
+        assert decode_pool.worker_count() == want
+    assert decode_pool.WORKER_CAP == 8 and decode_pool.REPLAY_THREADS == 3
+
+
+# -- what a worker is ---------------------------------------------------------------
+
+class Probe:
+    """Loads in a worker as a function that answers every block with
+    what the worker's interpreter holds (the one way to run a question
+    there: the worker imports nothing of the tests')."""
+    SRC = ("lambda raw: (sorted(k for k, v in __import__('sys').modules"
+           ".items() if v is not None), "
+           "__import__('sys').modules['__main__'].__spec__.name, "
+           "__import__('sys').argv[1], __import__('os').getpid())")
+
+    def __reduce__(self):
+        return (eval, (self.SRC,))
+
+
+@limit(120)
+def test_a_worker_holds_no_jax_and_not_the_parents_main(loaded):
+    db, _decode = loaded
+    w0 = worker_blocks()
+    answers = stream(db, Probe())
+    assert worker_blocks() - w0 == BLOCKS
+    assert {pid for _m, _s, _n, pid in answers} <= set(POOL.pids())
+    for modules, main_spec, name, _pid in answers:
+        assert not [m for m in modules
+                    if m.split(".")[0] in ("jax", "jaxlib", "numpy")]
+        assert main_spec == decode_pool.WORKER_MODULE
+        assert name.startswith(decode_pool.WORKER_NAME)
+        # pytest is this process's __main__; nothing of it is there
+        assert "pytest" not in modules and "conftest" not in modules
+        assert "ouroboros_tpu.storage.decode_pool" in modules
+
+
+@limit(120)
+def test_a_decoder_that_needs_jax_does_not_load_in_a_worker(loaded):
+    class NeedsJax:
+        def __call__(self, raw):
+            return raw
+
+        def __reduce__(self):
+            return (importlib.import_module, ("jax",))
+
+    db, _decode = loaded
+    w0 = worker_blocks()
+    assert stream(db, NeedsJax()) == [raw for _e, raw in db.stream()]
+    assert worker_blocks() == w0
+
+
+def _gone(pid: int) -> bool:
+    """No such process, or one that has exited and waits to be reaped."""
+    try:
+        with open(f"/proc/{pid}/stat") as fh:
+            return fh.read().rsplit(")", 1)[1].split()[0] == "Z"
+    except OSError:
+        return True
+
+
+@limit(120)
+@pytest.mark.parametrize("ending", ["returns", "killed"])
+def test_no_worker_outlives_its_parent(chain_dir, ending):
+    script = (
+        "import os, sys, time\n"
+        f"sys.path.insert(0, {REPO!r})\n"
+        "from tools import db_analyser\n"
+        "from ouroboros_tpu.storage.decode_pool import POOL\n"
+        "from ouroboros_tpu.storage.stream import BlockPrefetcher\n"
+        f"db, _r, decode, _c = db_analyser.load_db({chain_dir!r})\n"
+        "pre = BlockPrefetcher(db, decode, window=8, depth=2).start()\n"
+        "n = sum(1 for _b in pre)\n"
+        "pre.close()\n"
+        "print(n, *POOL.pids(), flush=True)\n"
+        + ("time.sleep(600)\n" if ending == "killed" else ""))
+    child = subprocess.Popen([sys.executable, "-c", script],
+                             stdout=subprocess.PIPE, text=True)
+    try:
+        n, *pids = map(int, child.stdout.readline().split())
+        assert n == BLOCKS and pids
+        if ending == "killed":
+            assert not any(_gone(p) for p in pids)
+            child.kill()
+        assert child.wait(timeout=60) == (-9 if ending == "killed" else 0)
+        deadline = time.monotonic() + 30
+        while not all(_gone(p) for p in pids):
+            assert time.monotonic() < deadline, \
+                f"workers {pids} outlived their parent"
+            time.sleep(0.05)
+    finally:
+        if child.poll() is None:
+            child.kill()
+            child.wait()

@@ -39,13 +39,22 @@ from ouroboros_tpu.observe import spans as spans_mod              # noqa: E402
 from ouroboros_tpu.storage import (                               # noqa: E402
     DiskPolicy, IoFS, StreamConfig, StreamingReplayEngine,
 )
+from ouroboros_tpu.storage.decode_pool import WORKER_NAME          # noqa: E402
 from ouroboros_tpu.storage.stream import THREAD_NAME as PREFETCH   # noqa: E402
 
 PRODUCER = "ouro-replay-producer"
 BLOCKS, WINDOW = 24, 8
 N_WINDOWS = BLOCKS // WINDOW
 
-# table 1 of the issue: span -> (cat, parent, thread)
+# where a replay's blocks are decoded (ISSUE 32): `load_db`'s decoder
+# ships to the decode worker processes, the same decoder as a closure
+# stays on the prefetch thread
+BOTH = pytest.mark.parametrize("traced", ["in-workers", "in-thread"],
+                               indirect=True)
+DECODE_STAGES = ("decode.parse", "decode.build", "decode.slices")
+
+# table 1 of the issue: span -> (cat, parent, thread); the three decode
+# stages are on a worker's row where a worker timed them
 STAGES = {
     "decode.parse": ("disk", "stream.decode", PREFETCH),
     "decode.build": ("disk", "stream.decode", PREFETCH),
@@ -139,12 +148,14 @@ def _counters() -> dict:
             if i.kind == "counter"}
 
 
-def _replay(chain_dir, db_dir, backend):
+def _replay(chain_dir, db_dir, backend, decoded="in-workers"):
     """One streamed replay as `db_analyser --analysis validate` makes it
     (cold caches, snapshots on); (stats, state hash, counter deltas)."""
     from tools import db_analyser
     shutil.copytree(chain_dir, db_dir)
-    db, rules, decode, _cfg = db_analyser.load_db(db_dir)
+    db, rules, db_decode, _cfg = db_analyser.load_db(db_dir)
+    decode = db_decode if decoded == "in-workers" \
+        else (lambda raw: db_decode(raw))
     GLOBAL_BETA_CACHE.clear()
     GLOBAL_PRECOMPUTE_CACHE.clear()
     engine = StreamingReplayEngine(
@@ -161,19 +172,26 @@ def _replay(chain_dir, db_dir, backend):
 
 
 @pytest.fixture()
-def traced(chain_dir, tmp_path, host_tables):
+def traced(request, chain_dir, tmp_path, host_tables):
     """(roots, stats, state hash, counter deltas) of one replay with
-    span recording on."""
+    span recording on; decoded in the workers unless a test asks
+    (`BOTH`) for the prefetch thread too."""
     rec = spans_mod.RECORDER
     assert not rec.enabled
     rec.drain()
     rec.enable()
     try:
         stats, state_hash, delta = _replay(
-            chain_dir, str(tmp_path / "on"), HostProgramsBackend())
+            chain_dir, str(tmp_path / "on"), HostProgramsBackend(),
+            getattr(request, "param", "in-workers"))
     finally:
         rec.disable()
     return rec.drain(), stats, state_hash, delta
+
+
+def _adopted(sp) -> bool:
+    """Timed by a decode worker process, on this process's clock."""
+    return sp.thread.startswith(WORKER_NAME)
 
 
 def _with_parents(roots):
@@ -186,8 +204,11 @@ def _with_parents(roots):
         yield from walk(r, None)
 
 
+@BOTH
 def test_every_stage_span_under_its_parent_on_its_thread(traced):
-    roots, _stats, _hash, _delta = traced
+    roots, _stats, _hash, delta = traced
+    in_workers = delta["replay.decode.worker_blocks"] > 0
+    assert delta["replay.decode.worker_blocks"] in (0, BLOCKS)
     pairs = list(_with_parents(roots))
     by_name: dict = {}
     for sp, parent in pairs:
@@ -196,8 +217,18 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
         assert name in by_name, f"no span {name}"
         for sp, parent in by_name[name]:
             assert sp.cat == cat
-            assert sp.thread == thread
+            if in_workers and name in DECODE_STAGES:
+                assert _adopted(sp)
+            else:
+                assert sp.thread == thread
             assert (parent.name if parent else None) == parent_name
+    # the reply turned into blocks: once a chunk, on the prefetch thread
+    unpacks = by_name.get("decode.unpack", [])
+    assert len(unpacks) == (len(by_name["stream.decode"]) if in_workers
+                            else 0)
+    for sp, parent in unpacks:
+        assert (sp.cat, sp.thread, parent.name) \
+            == ("disk", PREFETCH, "stream.decode")
     # how many: one a block, one a window, one a replay (seq.* open more
     # than once a block: the statements keep their order)
     for name in ("decode.parse", "decode.build", "decode.slices"):
@@ -224,16 +255,25 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
     assert not [sp for sp, _p in pairs if sp.cat == "compile"]
 
 
+@BOTH
 def test_children_never_longer_than_their_parent(traced):
+    """But for the spans a worker timed: they hang under the
+    `stream.decode` that collected their chunk and lie where the worker
+    did the work, ahead of it, on the same clock: inside the replay."""
     roots, _stats, _hash, _delta = traced
+    own = [sp for r in roots for sp in r.walk() if not _adopted(sp)]
+    start, end = min(sp.t0 for sp in own), max(sp.t1 for sp in own)
     for sp, parent in _with_parents(roots):
         assert sp.t1 is not None and sp.t1 >= sp.t0
-        if parent is not None:
+        if _adopted(sp):
+            assert sp.name in DECODE_STAGES and not sp.children
+            assert start <= sp.t0 and sp.t1 <= parent.t1 <= end
+        elif parent is not None:
             assert parent.t0 <= sp.t0 and sp.t1 <= parent.t1
     for root in roots:
         for sp in root.walk():
-            assert sum(c.duration for c in sp.children) \
-                <= sp.duration + 1e-9
+            assert sum(c.duration for c in sp.children
+                       if not _adopted(c)) <= sp.duration + 1e-9
 
 
 def test_a_fill_holds_its_four_stages_in_order(traced):
@@ -314,9 +354,13 @@ def test_recording_off_allocates_no_span_and_counters_still_count(
         real_init(self, *a, **kw)
 
     monkeypatch.setattr(spans_mod.Span, "__init__", counting_init)
+    # nor does a worker time anything, or ship a row of it
+    monkeypatch.setattr(spans_mod, "adopt",
+                        lambda *a, **kw: made.append("adopt"))
     assert not spans_mod.RECORDER.enabled
     _stats, hash_off, delta = _replay(chain_dir, str(tmp_path / "off"),
                                       HostProgramsBackend())
+    assert delta["replay.decode.worker_blocks"] == BLOCKS
     assert made == []
     assert spans_mod.RECORDER.drain() == []
     for name in WAITS:
@@ -330,11 +374,14 @@ def test_chrome_trace_of_a_replay_has_a_row_per_thread(traced):
     doc = observe.export.chrome_trace(roots)
     rows = {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
             if e["ph"] == "M"}
-    assert set(rows.values()) == {PREFETCH, PRODUCER,
-                                  threading.current_thread().name}
+    workers = {n for n in rows.values() if n.startswith(WORKER_NAME)}
+    assert workers and set(rows.values()) - workers == {
+        PREFETCH, PRODUCER, threading.current_thread().name}
     events = [e for e in doc["traceEvents"] if e["ph"] == "X"]
     assert {rows[e["tid"]] for e in events
-            if e["name"].startswith("decode.")} == {PREFETCH}
+            if e["name"] in DECODE_STAGES} == workers
+    assert {rows[e["tid"]] for e in events
+            if e["name"] == "decode.unpack"} == {PREFETCH}
     assert {rows[e["tid"]] for e in events
             if e["name"].startswith(("seq.", "submit."))} == {PRODUCER}
     drains = [e for e in events if e["name"] == "pipeline.drain"]
@@ -360,6 +407,10 @@ GC_METRICS = ("gc_pause_us_per_block", "gc_full_passes_per_replay",
 KEY_METRICS = ("key_fill_ms_per_window", "key_fill_us_per_key",
                "key_fill_host_share", "key_fill_pad_share",
                "key_cache_hit_share")
+# the decode worker processes (ISSUE 32), listed after PR 31's; the
+# wait may read 0 (every reply there before it was asked for)
+WORKER_METRICS = ("decode_worker_share", "decode_unpack_us_per_block",
+                  "decode_wait_us_per_block")
 
 
 def _facts(roots, stats, delta) -> dict:
@@ -396,7 +447,7 @@ def test_the_new_metric_files_are_these():
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         listed = [m["name"] for m in json.load(fh)["per_layer"]]
     # in this order and together; later PRs' metrics follow them
-    for group in (NEW_METRICS, GC_METRICS, KEY_METRICS):
+    for group in (NEW_METRICS, GC_METRICS, KEY_METRICS, WORKER_METRICS):
         at = listed.index(group[0])
         assert listed[at:at + len(group)] == list(group)
     files = {os.path.basename(p)[:-5] for p in glob.glob(
@@ -404,7 +455,8 @@ def test_the_new_metric_files_are_these():
     assert files == set(listed)
 
 
-@pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS)
+@pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS
+                         + WORKER_METRICS)
 def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     """A renamed span or counter fails here, not in a chip run."""
     roots, stats, _hash, delta = traced
@@ -415,24 +467,38 @@ def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
         assert key in facts[ns], f"{metric}: no fact {ns}/{key}"
     value = _reader_module().read(doc["reader"], facts)
     assert value is not None and value >= 0
-    assert value > 0 or metric in GC_METRICS[:2]
+    assert value > 0 or metric in GC_METRICS[:2] + WORKER_METRICS[2:]
     if metric.endswith("_share"):
         assert value <= 100.0
+    if metric == "decode_worker_share":
+        assert value == 100.0
     source = {"span_seconds": "program_span",
               "counter": "program_counter"}[doc["reader"]["num"][0][0]]
     assert doc["source"] == source
 
 
+@BOTH
 def test_stages_never_exceed_their_outer_span(traced):
     """The reconciliation PERF.md makes on the chip, as far as a tiny
     chain on a shared CPU can hold it: the stages of decode, of the host
     pass and of submit are all inside the span around them, so they add
-    up to no more than it."""
+    up to no more than it.  Decoded in the workers, `stream.decode`
+    holds the unpacking and the wait, and the three stages are the
+    workers' own seconds, side by side."""
     roots, stats, _hash, delta = traced
-    sec = _facts(roots, stats, delta)["span_seconds"]
+    facts = _facts(roots, stats, delta)
+    sec = facts["span_seconds"]
+    in_workers = delta["replay.decode.worker_blocks"] > 0
+    if not in_workers:
+        for m in WORKER_METRICS[1:]:
+            with open(os.path.join(BENCH, "layer_metrics",
+                                   m + ".json")) as fh:
+                reader = json.load(fh)["reader"]
+            # nothing to read, or nothing read: never a made-up number
+            assert not _reader_module().read(reader, facts)
     for outer, stages in (
-            ("stream.decode", ("decode.parse", "decode.build",
-                               "decode.slices")),
+            ("stream.decode", ("decode.unpack",) if in_workers
+             else DECODE_STAGES),
             ("window.host_seq", ("seq.header", "seq.body")),
             ("window.submit", ("submit.split", "submit.pack_ed",
                                "submit.pack_vrf", "submit.pack_kes",

@@ -16,8 +16,10 @@ per backend, plus the final ledger state hash for replay-parity checks.
 
 Full validation routes through the STREAMING replay engine
 (ouroboros_tpu/storage/stream.py, ISSUE 15): a bounded read-ahead
-prefetcher streams ImmutableDB chunks and decodes them on a background
-thread while earlier windows verify, `--snapshot-every N` checkpoints
+prefetcher streams ImmutableDB chunks on a background thread and has
+them decoded in worker processes (storage/decode_pool.py; `load_db`'s
+decoders are importable objects so that they can be sent there) while
+earlier windows verify, `--snapshot-every N` checkpoints
 the verified ledger state every N slots (crash-consistent LedgerDB
 snapshots), and `--resume` restarts from the newest usable snapshot
 instead of genesis — the db-analyser validate-mainnet path made both
@@ -43,11 +45,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 
 def load_db(db_dir: str):
-    from ouroboros_tpu.consensus.headers import ProtocolBlock
+    from ouroboros_tpu.consensus.headers import BlockDecoder
     from ouroboros_tpu.consensus.ledger import ExtLedgerRules
     from ouroboros_tpu.storage.fs import IoFS
-    from ouroboros_tpu.storage.immutabledb import ImmutableDB
-    from ouroboros_tpu.utils import cbor
 
     with open(os.path.join(db_dir, "config.json")) as fh:
         cfg = json.load(fh)
@@ -70,7 +70,7 @@ def load_db(db_dir: str):
         tx_body_elems = None
     elif cfg["protocol"] == "cardano":
         from ouroboros_tpu.eras.cardano import (
-            cardano_block_decode, cardano_setup,
+            cardano_block_from_bytes, cardano_setup,
         )
         shelley_config = None
         if "slots_per_kes_period" in cfg:
@@ -90,11 +90,7 @@ def load_db(db_dir: str):
             mary_epoch=cfg.get("mary_epoch"))
         fs = IoFS(db_dir)
         db = _open_immutable(fs, cfg)
-
-        def decode_cardano(raw: bytes):
-            return cardano_block_decode(cbor.loads(raw))
-
-        return db, rules, decode_cardano, cfg
+        return db, rules, cardano_block_from_bytes, cfg
     elif cfg["protocol"] == "shelley":
         from fractions import Fraction
 
@@ -124,13 +120,11 @@ def load_db(db_dir: str):
     fs = IoFS(db_dir)
     db = _open_immutable(fs, cfg)
 
-    def decode(raw: bytes, _elems=tx_body_elems) -> ProtocolBlock:
-        # span-retaining decode: header bytes / KES message / tx ids come
-        # from raw slices instead of re-encoding (the replay host pass)
-        return ProtocolBlock.from_bytes(raw, tx_decode=tx_decode,
-                                        tx_body_elems=_elems)
-
-    return db, rules, decode, cfg
+    # span-retaining decode: header bytes / KES message / tx ids come
+    # from raw slices instead of re-encoding (the replay host pass).
+    # An importable object, not a closure: the streamed replay ships it
+    # to its decode worker processes (storage/decode_pool.py)
+    return db, rules, BlockDecoder(tx_decode, tx_body_elems), cfg
 
 
 def _open_immutable(fs, cfg):
@@ -220,34 +214,37 @@ def analysis_validate(db, rules, decode, backend_name: str, mode: str,
         else (lambda b, n=hdr_proofs: n)
     ext = rules.initial_state()
     counts = {"blocks": 0, "proofs": 0}
+
+    def count(blocks) -> None:
+        counts["blocks"] += len(blocks)
+        counts["proofs"] += sum(
+            hdr_count(b) + sum(len(tx.witnesses) for tx in b.body)
+            for b in blocks)
+
     stream_stats = None
     t0 = time.time()
     if mode == "reapply":
         for entry, raw in db.stream():
             b = decode(raw)
-            counts["blocks"] += 1
-            counts["proofs"] += hdr_count(b) + sum(len(tx.witnesses)
-                                                   for tx in b.body)
+            count([b])
             ext = rules.tick_then_reapply(ext, b)
     else:
-        # the streaming engine: disk + decode on a prefetch thread,
-        # DiskPolicy-driven snapshots, resume-from-latest-snapshot
+        # the streaming engine: disk on a prefetch thread, decode in its
+        # worker processes, DiskPolicy-driven snapshots,
+        # resume-from-latest-snapshot
         from ouroboros_tpu.storage import (
             DiskPolicy, IoFS, StreamConfig, StreamingReplayEngine,
         )
 
-        def counting_decode(raw: bytes):
-            b = decode(raw)
-            counts["blocks"] += 1
-            counts["proofs"] += hdr_count(b) + sum(len(tx.witnesses)
-                                                   for tx in b.body)
-            return b
-
+        # `decode` goes to the engine as it came (a wrapper that counts
+        # would be a closure, which no decode worker can be sent); the
+        # counts are taken from the blocks the prefetcher hands on
         policy = DiskPolicy(
             snapshot_interval_slots=snapshot_every
             if snapshot_every > 0 else (1 << 62))
         engine = StreamingReplayEngine(
-            IoFS(db_dir), db, rules, counting_decode, backend=backend,
+            IoFS(db_dir), db, rules, decode, backend=backend,
+            on_decoded=count,
             config=StreamConfig(
                 window=window, read_ahead=read_ahead, policy=policy,
                 resume=bool(resume),
