@@ -59,12 +59,14 @@ thread — see precompute._insert / VrfBetaCache._store.  Span trees are per-thr
 the replay (``window=k``), so one window can be followed across both
 threads.
 
-The hand-offs are timed by three counters of whole microseconds, never
+The hand-offs are timed by four counters of whole microseconds, never
 by spans (observe/spans.py says why): ``producer_wait_blocks_us`` (the
 producer inside ``next_window()``, waiting for decoded blocks),
-``consumer_wait_us`` (the caller's thread with no submitted window to
-drain) and ``first_submit_us`` (producer start to the first submit,
-once a replay: the head during which the device has had nothing).
+``producer_stall_us`` (the producer waiting for a permit: DEPTH windows
+submitted and not drained), ``consumer_wait_us`` (the caller's thread
+with no submitted window to drain) and ``first_submit_us`` (producer
+start to the first submit, once a replay: the head during which the
+device has had nothing).
 """
 from __future__ import annotations
 
@@ -108,6 +110,7 @@ _CONSUMER_WAIT_US = _metrics.counter("pipeline.consumer_wait_us",
                                      stable=False)
 _FIRST_SUBMIT_US = _metrics.counter("pipeline.first_submit_us",
                                     stable=False)
+_STALL_US = _metrics.counter("pipeline.producer_stall_us", stable=False)
 
 # replay progress gauges (rendered live by tools/obsreport.py --live via
 # the scrape endpoint).  blocks_done / windows_in_flight / total are
@@ -306,10 +309,12 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                 if not (shared.stop
                         or shared.submitted - shared.drained < DEPTH):
                     _STALLS.inc()
+                    t_stall = _spans.monotonic_now()
                     with _spans.span("producer.stall", cat="stall"):
                         shared.cond.wait_for(
                             lambda: shared.stop or
                             shared.submitted - shared.drained < DEPTH)
+                    _STALL_US.inc(_us_since(t_stall))
                 if shared.stop:
                     return
             k += 1

@@ -25,7 +25,13 @@ import importlib.util
 from dataclasses import dataclass
 from typing import Optional, Sequence
 
+from ..observe import metrics as _metrics
 from . import ed25519_ref, kes as kes_mod, vrf_ref
+
+# betas the sequential pass had to compute itself, on the host: a proof
+# neither a prefetch nor a drained window's carried rows had delivered
+# (in a pipelined replay: 0, unless the producer ran ahead of the carry)
+_BETA_HOST_COMPUTES = _metrics.counter("beta_cache.host_computes")
 
 
 @dataclass(frozen=True)
@@ -232,6 +238,7 @@ class VrfBetaCache:
         vrf_ref.proof_to_hash does."""
         v = self._cache.get(proof, _MISSING)
         if v is _MISSING:
+            _BETA_HOST_COMPUTES.inc()
             try:
                 v = vrf_ref.proof_to_hash(proof)
             except ValueError:

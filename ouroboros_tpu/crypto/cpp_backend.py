@@ -57,11 +57,16 @@ def load_library():
         ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p]
     lib.ouro_vrf_verify_batch.restype = None
     lib.ouro_vrf_proof_to_hash.restype = ctypes.c_int
-    lib.ouro_scalarmult.restype = ctypes.c_int
-    lib.ouro_scalarmult.argtypes = [ctypes.c_char_p, ctypes.c_char_p,
-                                    ctypes.c_char_p]
     lib.ouro_scalarmult_base.restype = None
     lib.ouro_scalarmult_base.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
+    lib.ouro_vrf_prove.restype = None
+    lib.ouro_vrf_prove.argtypes = [
+        ctypes.c_char_p, ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p]
+    lib.ouro_vrf_prove_from_y.restype = None
+    lib.ouro_vrf_prove_from_y.argtypes = [ctypes.c_char_p] * 3
+    lib.ouro_vrf_prove_batch.restype = None
+    lib.ouro_vrf_output.restype = None
+    lib.ouro_vrf_output.argtypes = lib.ouro_vrf_prove.argtypes
     return lib
 
 
@@ -70,7 +75,8 @@ _CACHED_LIB = None
 
 def shared_library():
     """Build-once, load-once module-level handle (None if the toolchain is
-    unavailable) — the host-side fast path for scalar multiplications."""
+    unavailable) — the host-side fast path of key derivation and the
+    forger's VRF."""
     global _CACHED_LIB
     if _CACHED_LIB is None:
         try:
@@ -80,18 +86,6 @@ def shared_library():
     return _CACHED_LIB or None
 
 
-def scalarmult(pt32: bytes, scalar: int):
-    """[scalar]P for compressed P — compressed result, or None when P does
-    not decode.  C speed; full 256-bit double-and-add ladder, so clamped
-    Ed25519 scalars and mod-L scalars are both fine."""
-    lib = shared_library()
-    if lib is None:
-        return NotImplemented
-    out = ctypes.create_string_buffer(32)
-    ok = lib.ouro_scalarmult(pt32, int.to_bytes(scalar, 32, "little"), out)
-    return out.raw if ok else None
-
-
 def scalarmult_base(scalar: int):
     lib = shared_library()
     if lib is None:
@@ -99,6 +93,38 @@ def scalarmult_base(scalar: int):
     out = ctypes.create_string_buffer(32)
     lib.ouro_scalarmult_base(int.to_bytes(scalar, 32, "little"), out)
     return out.raw
+
+
+def vrf_prove(sk: bytes, alpha: bytes):
+    """The 80-byte ECVRF proof, vrf_ref.prove_pure's bytes."""
+    lib = shared_library()
+    if lib is None:
+        return NotImplemented
+    pi = ctypes.create_string_buffer(80)
+    lib.ouro_vrf_prove(sk, alpha, len(alpha), pi)
+    return pi.raw
+
+
+def vrf_prove_batch(sks: Sequence[bytes], alphas: Sequence[bytes]):
+    """Proofs of n (key, alpha) pairs in one call."""
+    lib = shared_library()
+    if lib is None:
+        return NotImplemented
+    n = len(sks)
+    alens = (ctypes.c_size_t * n)(*[len(a) for a in alphas])
+    pis = ctypes.create_string_buffer(80 * n)
+    lib.ouro_vrf_prove_batch(n, b"".join(sks), b"".join(alphas), alens, pis)
+    return [pis.raw[80 * i:80 * i + 80] for i in range(n)]
+
+
+def vrf_output(sk: bytes, alpha: bytes):
+    """beta = proof_to_hash(prove(sk, alpha)) without making the proof."""
+    lib = shared_library()
+    if lib is None:
+        return NotImplemented
+    beta = ctypes.create_string_buffer(64)
+    lib.ouro_vrf_output(sk, alpha, len(alpha), beta)
+    return beta.raw
 
 
 class CppBackend(CryptoBackend):

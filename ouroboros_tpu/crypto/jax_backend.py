@@ -63,6 +63,12 @@ _LANES_PADDED = _metrics.counter("jax_backend.lanes_padded")
 # ED_TILE-wide tiles ONE device walks for a window's Ed25519 lanes; a
 # window on the single-bucket path adds 0 (the "does it engage" reading)
 _ED_TILES = _metrics.counter("jax_backend.ed_tiles")
+# what a window's two occasional parts held (real work, not padding):
+# windows whose composite carried betas for the window two ahead and the
+# beta rows they carried; windows that scheduled no KES hash-path job
+_BETA_WINDOWS = _metrics.counter("jax_backend.beta_windows")
+_BETA_ROWS = _metrics.counter("jax_backend.beta_rows_carried")
+_KES_EMPTY = _metrics.counter("jax_backend.kes_empty_windows")
 
 # device-side verdict-fold sentinel: "no failing request".  int32 max so
 # jnp.min over any real request index beats it; request lists are bounded
@@ -635,6 +641,30 @@ class JaxBackend(CryptoBackend):
         self._composites[key] = fn
         return fn
 
+    def _occasional_widths(self, ne: int, nv: int, nb: int,
+                           nk: int) -> tuple:
+        """(nb, nk) for a window whose beta and KES parts need `nb` and
+        `nk` lanes: those of the narrowest composite this backend has
+        ALREADY built for the same Ed25519 and VRF widths that holds
+        both, else their own.
+
+        A sync meets these two parts at several widths: betas ride in
+        every window but a chain's last two, KES hash jobs only in the
+        windows that first meet a pool's hash path (and whether window
+        1 does is a race with window 0's drain).  Every (ne, nv, nb, nk)
+        is its own program, a minute or more to trace, lower and build
+        or load, where the empty lanes of a wider part cost the device
+        microseconds (PERF.md section 6, PR 33).  So a window rides a
+        built program that covers it rather than building its own, and
+        a chain's first window, the widest in both parts, fixes the
+        program for the rest."""
+        best = None
+        for e, v, b, k, _pallas in self._composites:
+            if e == ne and v == nv and b >= nb and k >= nk and (
+                    best is None or b + k < best[0] + best[1]):
+                best = (b, k)
+        return best or (nb, nk)
+
     def submit_window(self, reqs, next_beta_proofs=(), fold: bool = False):
         """Dispatch one replay window's whole device workload — the mixed
         Ed25519/VRF/KES verification of `reqs` AND the VRF betas the NEXT
@@ -669,21 +699,26 @@ class JaxBackend(CryptoBackend):
              kes_msgs, kes_expects, kes_checks, n) = \
                 self._split_mixed_device(reqs)
             beta_proofs = list(dict.fromkeys(next_beta_proofs))
+            ne, nv, nb, nk = (self._pad(len(part)) if part else 0
+                              for part in (ed_reqs, vrf_reqs, beta_proofs,
+                                           kes_msgs))
+            nb, nk = self._occasional_widths(ne, nv, nb, nk)
+        if beta_proofs:
+            _BETA_WINDOWS.inc()
+            _BETA_ROWS.inc(len(beta_proofs))
+        if not kes_msgs:
+            _KES_EMPTY.inc()
         ed_state = vrf_state = beta_state = None
-        ne = nv = nb = nk = 0
         ed_args = vrf_args = beta_args = kes_args = None
         with _spans.span("submit.pack_ed", cat="dispatch"):
-            if ed_reqs:
-                ne = self._pad(len(ed_reqs))
+            if ne:
                 ed_args, parse_ok = self._prep_ed(ed_reqs, ne)
                 ed_state = (None, parse_ok)
         with _spans.span("submit.pack_vrf", cat="dispatch"):
-            if vrf_reqs:
-                nv = self._pad(len(vrf_reqs))
+            if nv:
                 vrf_args, masks = self._prep_vrf(vrf_reqs, nv)
                 vrf_state = (None,) + masks
-            if beta_proofs:
-                nb = self._pad(len(beta_proofs))
+            if nb:
                 padded = beta_proofs + [b"\x00" * 80] * (
                     nb - len(beta_proofs))
                 (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(
@@ -692,8 +727,7 @@ class JaxBackend(CryptoBackend):
                 beta_args = (self._dev(Gw),
                              self._dev(signG.reshape(1, -1)))
         with _spans.span("submit.pack_kes", cat="dispatch"):
-            if kes_msgs:
-                nk = self._pad(len(kes_msgs))
+            if nk:
                 kes_args = self._prep_kes_hash(kes_msgs, kes_expects, nk)
         self._note_padding(
             len(ed_reqs) + len(vrf_reqs) + len(beta_proofs) + len(kes_msgs),

@@ -167,16 +167,9 @@ static void fe_sub(fe* o, const fe* a, const fe* b) {
     fe_carry(o);
 }
 
-static void fe_mul(fe* o, const fe* a, const fe* b) {
-    u128 t[5] = {0, 0, 0, 0, 0};
-    for (int i = 0; i < 5; i++) {
-        for (int j = 0; j < 5; j++) {
-            u128 prod = (u128)a->v[i] * b->v[j];
-            int k = i + j;
-            if (k >= 5) { k -= 5; prod *= 19; }
-            t[k] += prod;
-        }
-    }
+// limbs may be uncarried sums (< 2^54): 19*b fits a word, five products
+// of < 2^113 fit the 128-bit accumulator
+static inline void fe_reduce_wide(fe* o, u128 t[5]) {
     u128 c = 0;
     u64 r[5];
     for (int i = 0; i < 5; i++) {
@@ -190,7 +183,40 @@ static void fe_mul(fe* o, const fe* a, const fe* b) {
     memcpy(o->v, r, sizeof r);
 }
 
-static void fe_sq(fe* o, const fe* a) { fe_mul(o, a, a); }
+static void fe_mul(fe* o, const fe* a, const fe* b) {
+    const u64 a0 = a->v[0], a1 = a->v[1], a2 = a->v[2], a3 = a->v[3],
+              a4 = a->v[4];
+    const u64 b0 = b->v[0], b1 = b->v[1], b2 = b->v[2], b3 = b->v[3],
+              b4 = b->v[4];
+    const u64 b1_19 = b1 * 19, b2_19 = b2 * 19, b3_19 = b3 * 19,
+              b4_19 = b4 * 19;
+    u128 t[5];
+    t[0] = (u128)a0 * b0 + (u128)a1 * b4_19 + (u128)a2 * b3_19 +
+           (u128)a3 * b2_19 + (u128)a4 * b1_19;
+    t[1] = (u128)a0 * b1 + (u128)a1 * b0 + (u128)a2 * b4_19 +
+           (u128)a3 * b3_19 + (u128)a4 * b2_19;
+    t[2] = (u128)a0 * b2 + (u128)a1 * b1 + (u128)a2 * b0 +
+           (u128)a3 * b4_19 + (u128)a4 * b3_19;
+    t[3] = (u128)a0 * b3 + (u128)a1 * b2 + (u128)a2 * b1 + (u128)a3 * b0 +
+           (u128)a4 * b4_19;
+    t[4] = (u128)a0 * b4 + (u128)a1 * b3 + (u128)a2 * b2 + (u128)a3 * b1 +
+           (u128)a4 * b0;
+    fe_reduce_wide(o, t);
+}
+
+static void fe_sq(fe* o, const fe* a) {
+    const u64 a0 = a->v[0], a1 = a->v[1], a2 = a->v[2], a3 = a->v[3],
+              a4 = a->v[4];
+    const u64 d0 = a0 * 2, d1 = a1 * 2, d2 = a2 * 2;
+    const u64 a3_19 = a3 * 19, a4_19 = a4 * 19;
+    u128 t[5];
+    t[0] = (u128)a0 * a0 + (u128)d1 * a4_19 + (u128)d2 * a3_19;
+    t[1] = (u128)d0 * a1 + (u128)d2 * a4_19 + (u128)a3 * a3_19;
+    t[2] = (u128)d0 * a2 + (u128)a1 * a1 + (u128)(a3 * 2) * a4_19;
+    t[3] = (u128)d0 * a3 + (u128)d1 * a2 + (u128)a4 * a4_19;
+    t[4] = (u128)d0 * a4 + (u128)d1 * a3 + (u128)a2 * a2;
+    fe_reduce_wide(o, t);
+}
 
 static void fe_frombytes(fe* o, const u8 s[32]) {
     u64 w[4];
@@ -332,6 +358,12 @@ static const u8 D2_BYTES[32] = {
     0x82, 0x9a, 0x14, 0xe0, 0x00, 0x30, 0xd1, 0xf3, 0xee, 0xf2, 0x80,
     0x8e, 0x19, 0xe7, 0xfc, 0xdf, 0x56, 0xdc, 0xd9, 0x06, 0x24};
 
+static fe ge_d2() {
+    fe d2;
+    fe_frombytes(&d2, D2_BYTES);
+    return d2;
+}
+
 static void ge_identity(ge* o) {
     fe_0(&o->X); fe_1(&o->Y); fe_1(&o->Z); fe_0(&o->T);
 }
@@ -339,8 +371,8 @@ static void ge_identity(ge* o) {
 // strongly-unified addition (add-2008-hwcd-3); complete because d is
 // non-square — valid for doubling too
 static void ge_add(ge* o, const ge* p, const ge* q) {
-    fe a, b, c, d_, e, f, g, h, t0, t1, d2;
-    fe_frombytes(&d2, D2_BYTES);
+    fe a, b, c, d_, e, f, g, h, t0, t1;
+    static const fe d2 = ge_d2();
     fe_sub(&t0, &p->Y, &p->X);
     fe_sub(&t1, &q->Y, &q->X);
     fe_mul(&a, &t0, &t1);                       // A=(Y1-X1)(Y2-X2)
@@ -495,18 +527,8 @@ static int sc_less_than_L(const u8 s[32]) {
     return 0;   // equal
 }
 
-// ------------------------------------------------- generic scalar mults
-// (host-side forging/proving helpers: db_synth-scale chains need C-speed
-// [k]P; verification stays in the batch entry points below)
-extern "C" int ouro_scalarmult(const u8 pt[32], const u8 sc[32],
-                               u8 out[32]) {
-    ge P_, R;
-    if (!ge_decompress(&P_, pt)) return 0;
-    ge_scalar_mult(&R, sc, &P_);
-    ge_compress(out, &R);
-    return 1;
-}
-
+// ---------------------------------------------------- [k]B, compressed
+// (key derivation on the host; the forger's proving is further down)
 extern "C" void ouro_scalarmult_base(const u8 sc[32], u8 out[32]) {
     ge B, R;
     ge_base(&B);
@@ -550,6 +572,20 @@ extern "C" void ouro_ed25519_verify_batch(size_t n, const u8* vks,
 }
 
 // ----------------------------------------------------------------- ECVRF
+// H from the compressed Edwards y of the Elligator2 map (what
+// vrf_ref._hash_to_curve_bytes returns): decompress, cofactor cleared
+static void vrf_h_from_y(ge* o, const u8 yb[32]) {
+    ge pt;
+    if (!ge_decompress(&pt, yb)) {
+        ge_base(&pt);                // total fallback (vrf_ref parity)
+    }
+    // clear cofactor: multiply by 8
+    ge_add(&pt, &pt, &pt);
+    ge_add(&pt, &pt, &pt);
+    ge_add(&pt, &pt, &pt);
+    *o = pt;
+}
+
 // Elligator2 hash-to-curve per vrf_ref._hash_to_curve (draft-03 §5.4.1.2)
 static void vrf_hash_to_curve(ge* o, const u8 vk[32], const u8* alpha,
                               size_t alen) {
@@ -598,15 +634,7 @@ static void vrf_hash_to_curve(ge* o, const u8 vk[32], const u8* alpha,
     fe_mul(&y, &num, &di);
     u8 yb[32];
     fe_tobytes(yb, &y);
-    ge pt;
-    if (!ge_decompress(&pt, yb)) {
-        ge_base(&pt);                // total fallback (vrf_ref parity)
-    }
-    // clear cofactor: multiply by 8
-    ge_add(&pt, &pt, &pt);
-    ge_add(&pt, &pt, &pt);
-    ge_add(&pt, &pt, &pt);
-    *o = pt;
+    vrf_h_from_y(o, yb);
 }
 
 static void vrf_challenge(u8 c16[16], const ge* H, const ge* Gamma,
@@ -665,14 +693,10 @@ extern "C" void ouro_vrf_verify_batch(size_t n, const u8* vks,
     }
 }
 
-extern "C" int ouro_vrf_proof_to_hash(const u8 pi[80], u8 beta[64]) {
-    ge Gamma;
-    if (!ge_decompress(&Gamma, pi)) return 0;
-    u8 s[32];
-    memcpy(s, pi + 48, 32);
-    if (!sc_less_than_L(s)) return 0;
+// beta of a Gamma already on the curve: SHA-512(suite | 3 | [8]Gamma)
+static void vrf_gamma_to_hash(u8 beta[64], const ge* Gamma) {
     ge G8;
-    ge_add(&G8, &Gamma, &Gamma);
+    ge_add(&G8, Gamma, Gamma);
     ge_add(&G8, &G8, &G8);
     ge_add(&G8, &G8, &G8);
     u8 gbytes[32];
@@ -683,5 +707,139 @@ extern "C" int ouro_vrf_proof_to_hash(const u8 pi[80], u8 beta[64]) {
     sha512::update(&c, pre, 2);
     sha512::update(&c, gbytes, 32);
     sha512::final(&c, beta);
+}
+
+extern "C" int ouro_vrf_proof_to_hash(const u8 pi[80], u8 beta[64]) {
+    ge Gamma;
+    if (!ge_decompress(&Gamma, pi)) return 0;
+    u8 s[32];
+    memcpy(s, pi + 48, 32);
+    if (!sc_less_than_L(s)) return 0;
+    vrf_gamma_to_hash(beta, &Gamma);
     return 1;
+}
+
+// ------------------------------------------------------------ ECVRF prove
+// The forging half (vrf_ref.prove_pure is the oracle, byte for byte): a
+// chain forger evaluates the leader VRF for every pool in every slot, so
+// db_synth-scale chains need it at C speed.  A key's expansion (clamped
+// scalar x, nonce prefix, Y = [x]B) is kept for the last few keys a
+// thread used: a forger alternates between its pools' keys.
+struct vrf_key { u8 sk[32]; u8 x[32]; u8 prefix[32]; u8 Y[32]; int valid; };
+
+static const vrf_key* vrf_expand(const u8 sk[32]) {
+    static thread_local vrf_key cache[4];
+    static thread_local unsigned next = 0;
+    for (int i = 0; i < 4; i++)
+        if (cache[i].valid && memcmp(cache[i].sk, sk, 32) == 0)
+            return &cache[i];
+    vrf_key* k = &cache[next++ & 3];
+    u8 hash[64];
+    sha512::Ctx c;
+    sha512::init(&c);
+    sha512::update(&c, sk, 32);
+    sha512::final(&c, hash);
+    memcpy(k->sk, sk, 32);
+    memcpy(k->x, hash, 32);
+    k->x[0] &= 248;
+    k->x[31] &= 127;
+    k->x[31] |= 64;
+    memcpy(k->prefix, hash + 32, 32);
+    ouro_scalarmult_base(k->x, k->Y);
+    k->valid = 1;
+    return k;
+}
+
+// s = (k + c x) mod L; c is the 16-byte challenge, x the clamped scalar
+static void sc_muladd(u8 s[32], const u8 c16[16], const u8 x[32],
+                      const u8 k[32]) {
+    u64 cw[2], xw[4], kw[4], r[8] = {0, 0, 0, 0, 0, 0, 0, 0};
+    for (int i = 0; i < 2; i++) {
+        cw[i] = 0;
+        for (int j = 0; j < 8; j++) cw[i] |= (u64)c16[8 * i + j] << (8 * j);
+    }
+    for (int i = 0; i < 4; i++) {
+        xw[i] = kw[i] = 0;
+        for (int j = 0; j < 8; j++) {
+            xw[i] |= (u64)x[8 * i + j] << (8 * j);
+            kw[i] |= (u64)k[8 * i + j] << (8 * j);
+        }
+    }
+    for (int i = 0; i < 2; i++) {
+        u128 carry = 0;
+        for (int j = 0; j < 4; j++) {
+            u128 t = (u128)cw[i] * xw[j] + r[i + j] + carry;
+            r[i + j] = (u64)t;
+            carry = t >> 64;
+        }
+        r[i + 4] += (u64)carry;     // < 2^384: never overflows the word
+    }
+    u128 carry = 0;
+    for (int i = 0; i < 8; i++) {
+        u128 t = (u128)r[i] + (i < 4 ? kw[i] : 0) + carry;
+        r[i] = (u64)t;
+        carry = t >> 64;
+    }
+    u8 wide[64];
+    for (int i = 0; i < 64; i++) wide[i] = (u8)(r[i >> 3] >> (8 * (i & 7)));
+    sc_reduce64(s, wide);
+}
+
+// beta alone (what a slot's leader check reads): Gamma = [x]H, no proof
+extern "C" void ouro_vrf_output(const u8 sk[32], const u8* alpha,
+                                size_t alen, u8 beta[64]) {
+    const vrf_key* key = vrf_expand(sk);
+    ge H, Gamma;
+    vrf_hash_to_curve(&H, key->Y, alpha, alen);
+    ge_scalar_mult(&Gamma, key->x, &H);
+    vrf_gamma_to_hash(beta, &Gamma);
+}
+
+static void vrf_prove_at(const vrf_key* key, const ge* H, u8 pi[80]) {
+    ge Gamma, B, kB, kH;
+    u8 h_string[32];
+    ge_compress(h_string, H);
+    ge_scalar_mult(&Gamma, key->x, H);
+    // k = SHA-512(prefix | h_string) mod L (the RFC 8032-style nonce)
+    u8 hash[64], k[32];
+    sha512::Ctx c;
+    sha512::init(&c);
+    sha512::update(&c, key->prefix, 32);
+    sha512::update(&c, h_string, 32);
+    sha512::final(&c, hash);
+    sc_reduce64(k, hash);
+    ge_base(&B);
+    ge_scalar_mult(&kB, k, &B);
+    ge_scalar_mult(&kH, k, H);
+    ge_compress(pi, &Gamma);
+    vrf_challenge(pi + 32, H, &Gamma, &kB, &kH);
+    sc_muladd(pi + 48, pi + 32, key->x, k);
+}
+
+extern "C" void ouro_vrf_prove(const u8 sk[32], const u8* alpha,
+                               size_t alen, u8 pi[80]) {
+    const vrf_key* key = vrf_expand(sk);
+    ge H;
+    vrf_hash_to_curve(&H, key->Y, alpha, alen);
+    vrf_prove_at(key, &H, pi);
+}
+
+// the proof for a GIVEN Elligator2 output y (vrf_ref._hash_to_curve_bytes):
+// how tests reach the not-on-curve fallback, which no alpha is known to hit
+extern "C" void ouro_vrf_prove_from_y(const u8 sk[32], const u8 yb[32],
+                                      u8 pi[80]) {
+    ge H;
+    vrf_h_from_y(&H, yb);
+    vrf_prove_at(vrf_expand(sk), &H, pi);
+}
+
+// n (key, alpha) pairs; consecutive pairs of one key expand it once
+extern "C" void ouro_vrf_prove_batch(size_t n, const u8* sks,
+                                     const u8* alphas, const size_t* alens,
+                                     u8* pis) {
+    size_t off = 0;
+    for (size_t i = 0; i < n; i++) {
+        ouro_vrf_prove(sks + 32 * i, alphas + off, alens[i], pis + 80 * i);
+        off += alens[i];
+    }
 }

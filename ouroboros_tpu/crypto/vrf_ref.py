@@ -67,28 +67,21 @@ def _hash_to_curve_bytes(vk: bytes, alpha: bytes) -> bytes:
 
 
 def prove(sk: bytes, alpha: bytes) -> bytes:
-    """prove with the four scalar multiplications on the native C ladder
-    when available (identical bytes: the construction is deterministic);
-    prove_pure is the spec and stays the conformance oracle."""
+    """prove in native C (`ouro_vrf_prove`) when the library is there
+    (identical bytes: the construction is deterministic); prove_pure is
+    the spec and stays the conformance oracle."""
     from . import cpp_backend as cpp
-    if cpp.shared_library() is None:
-        return prove_pure(sk, alpha)
-    x, prefix = _secret_expand(sk)
-    Y = cpp.scalarmult_base(x)
-    y_h = _hash_to_curve_bytes(Y, alpha)
-    h_string = cpp.scalarmult(y_h, 8)            # clear cofactor
-    if h_string is None:                         # not-on-curve hash output
-        h_string = cpp.scalarmult_base(8)        # the BASE fallback, [8]B
-    Gamma = cpp.scalarmult(h_string, x)
-    k = ed.sha512_int(prefix, h_string) % L
-    kB = cpp.scalarmult_base(k)
-    kH = cpp.scalarmult(h_string, k)
-    c = int.from_bytes(
-        ed.sha512(SUITE, b"\x02", h_string + Gamma + kB + kH)[:16],
-        "little")
-    s = (k + c * x) % L
-    return Gamma + int.to_bytes(c, 16, "little") \
-        + int.to_bytes(s, 32, "little")
+    pi = cpp.vrf_prove(sk, alpha)
+    return prove_pure(sk, alpha) if pi is NotImplemented else pi
+
+
+def prove_many(sk: bytes, alphas) -> list[bytes]:
+    """One key's proofs for several inputs, in one native call."""
+    from . import cpp_backend as cpp
+    pis = cpp.vrf_prove_batch([sk] * len(alphas), alphas)
+    if pis is NotImplemented:
+        return [prove_pure(sk, a) for a in alphas]
+    return pis
 
 
 def public_key(sk: bytes) -> bytes:
@@ -146,4 +139,11 @@ def proof_to_hash(pi: bytes) -> bytes:
 
 
 def output(sk: bytes, alpha: bytes) -> bytes:
-    return proof_to_hash(prove(sk, alpha))
+    """beta without the proof: Gamma = [x]H alone decides it (a third of
+    a proof's scalar multiplications), which is all a forger's leader
+    check of a slot it does not win ever reads."""
+    from . import cpp_backend as cpp
+    beta = cpp.vrf_output(sk, alpha)
+    if beta is NotImplemented:
+        return proof_to_hash(prove_pure(sk, alpha))
+    return beta

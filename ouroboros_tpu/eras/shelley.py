@@ -425,16 +425,17 @@ class TPraos(ConsensusProtocol):
         pool = ledger_view.get(can_be_leader.pool_id)
         if pool is None:
             return None
-        pi_leader = vrf_ref.prove(
-            can_be_leader.vrf_sk, _vrf_alpha(b"leader", slot, ticked.eta0))
-        beta = vrf_ref.proof_to_hash(pi_leader)
+        # the output alone decides; both proofs only for a slot that wins
+        alpha_leader = _vrf_alpha(b"leader", slot, ticked.eta0)
+        beta = vrf_ref.output(can_be_leader.vrf_sk, alpha_leader)
         from .nonintegral import check_leader_value
         if not check_leader_value(_leader_value(beta),
                                   8 * vrf_ref.OUTPUT_LEN,
                                   pool.sigma, self.config.f):
             return None
-        pi_eta = vrf_ref.prove(
-            can_be_leader.vrf_sk, _vrf_alpha(b"eta", slot, ticked.eta0))
+        pi_eta, pi_leader = vrf_ref.prove_many(
+            can_be_leader.vrf_sk,
+            [_vrf_alpha(b"eta", slot, ticked.eta0), alpha_leader])
         return TPraosIsLeader(eta_proof=pi_eta, leader_proof=pi_leader)
 
     # -- chain ordering ------------------------------------------------------

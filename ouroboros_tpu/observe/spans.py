@@ -81,8 +81,11 @@ inside whatever stage span happened to be open), `replay.gc.full_passes`,
 while a replay runs.
 
 Waits are counters, not spans: `pipeline.producer_wait_blocks_us`,
-`pipeline.consumer_wait_us` and `pipeline.first_submit_us`
-(consensus/pipeline.py) hold whole microseconds.  A consumer of spans
+`pipeline.producer_stall_us` (the permit wait; `producer.stall` is its
+span of cat `stall`), `pipeline.consumer_wait_us` and
+`pipeline.first_submit_us` (consensus/pipeline.py), and the prefetch
+thread's `replay.stream.backpressure_wait_us` (storage/stream.py
+`_put`), hold whole microseconds.  A consumer of spans
 that gives a piece of device idle time to the open span that started
 last would hand it to a wait span opened after the work it waits on,
 and take it from that work; as gated registry counters the waits also

@@ -96,6 +96,10 @@ _BYTES = _metrics.counter("replay.stream.bytes_read")
 _ERAS = _metrics.counter("replay.stream.era_crossings")
 _SNAPS = _metrics.counter("replay.stream.snapshots_written")
 _STALLS = _metrics.counter("replay.stream.prefetch_stalls", stable=False)
+# whole microseconds the prefetch thread spent in those stalls: blocked at
+# the read-ahead bound with a decoded window in hand
+_BACKPRESSURE_US = _metrics.counter("replay.stream.backpressure_wait_us",
+                                    stable=False)
 _DEPTH = _metrics.gauge("replay.stream.prefetch_depth", stable=False)
 _DISK_SECS = _metrics.gauge("replay.stream.disk_secs", stable=False)
 _DISK_HIDDEN = _metrics.gauge("replay.stream.disk_hidden_secs",
@@ -505,9 +509,12 @@ class BlockPrefetcher:
             if len(self._batches) >= self.depth and not self._stop:
                 self.stalls += 1
                 _STALLS.inc()
+                t0 = _spans.monotonic_now()
                 self._cond.wait_for(
                     lambda: self._stop
                     or len(self._batches) < self.depth)
+                _BACKPRESSURE_US.inc(
+                    int((_spans.monotonic_now() - t0) * 1e6))
             if self._stop:
                 return False
             self._batches.append(batch)

@@ -14,9 +14,10 @@ here.
 
 Every step stays on the ONE window-composite shape the first replay
 compiles (minutes of XLA:CPU cold, seconds from the compile cache).
-That is why the fixture starts from cold key caches and clears the KES
-hash-path cache before each later step: a batch whose KES paths are
-warm takes the zero-KES-job shape, a fresh multi-minute compile.
+The fixture starts from cold key caches and clears the KES hash-path
+cache before each later step, so every step packs real KES jobs (a
+batch whose paths are warm would ride the same program with an empty
+KES part since PR 33: `JaxBackend._occasional_widths`).
 """
 import hashlib
 import os

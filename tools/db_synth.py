@@ -16,7 +16,7 @@ Two chain flavours:
 
 Usage: python tools/db_synth.py --out DIR [--protocol shelley] [--blocks N]
        [--txs-per-block M] [--pools P] [--f NUM/DEN]
-       [--witness-keys pool|fresh]
+       [--witness-keys pool|fresh] [--slots-per-kes-period N]
 
 --witness-keys (shelley) says who signs the transactions.  `pool` (the
 default, the chain every earlier version forged, byte for byte): every
@@ -207,8 +207,10 @@ def synth_shelley(args) -> dict:
     from ouroboros_tpu.storage.fs import IoFS
 
     f = Fraction(args.f)
-    # KES periods must cover the whole chain
-    slots_per_period = max(
+    # KES periods must cover the whole chain: the genesis's own length
+    # when it is given (mainnet's 129600 keeps a short chain inside the
+    # first period), else one that spreads the chain over the key's life
+    slots_per_period = getattr(args, "slots_per_kes_period", None) or max(
         1, int(args.blocks * 2 / f)
         // kes_mod.total_periods(args.kes_depth) + 1)
     cfg = TPraosConfig(
@@ -504,6 +506,11 @@ def main() -> None:
                          "pool owner's one payment key, or a key the "
                          "chain has not seen before for every "
                          "transaction (HD-wallet addresses)")
+    ap.add_argument("--slots-per-kes-period", type=int, default=None,
+                    help="shelley: slots a KES period lasts, as the "
+                         "genesis gives it (mainnet: 129600); left out, "
+                         "it is derived from the chain's length so that "
+                         "the chain walks through the key's periods")
     ap.add_argument("--seed", default="db-synth")
     args = ap.parse_args()
 
