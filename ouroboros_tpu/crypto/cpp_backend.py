@@ -16,6 +16,8 @@ import os
 import subprocess
 from typing import Optional, Sequence
 
+import numpy as np
+
 from . import kes as kes_mod
 from .backend import CryptoBackend, Ed25519Req, KesReq, VrfReq
 
@@ -38,9 +40,17 @@ def build_library(force: bool = False) -> str:
         with open(_STAMP) as f:
             if f.read().strip() == digest:
                 return _LIB
-    subprocess.run(
-        ["g++", "-O2", "-shared", "-fPIC", "-o", _LIB, _SRC],
-        check=True, capture_output=True, text=True)
+    # built beside its final name and moved over it: the forge child, the
+    # reference child and the replay process may all find it stale at once
+    tmp = f"{_LIB}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
+            check=True, capture_output=True, text=True)
+        os.replace(tmp, _LIB)
+    finally:
+        if os.path.exists(tmp):
+            os.unlink(tmp)
     with open(_STAMP, "w") as f:
         f.write(digest)
     return _LIB
@@ -67,6 +77,12 @@ def load_library():
     lib.ouro_vrf_prove_batch.restype = None
     lib.ouro_vrf_output.restype = None
     lib.ouro_vrf_output.argtypes = lib.ouro_vrf_prove.argtypes
+    lib.ouro_ed25519_challenge_batch.restype = None
+    lib.ouro_ed25519_challenge_batch.argtypes = [
+        ctypes.c_size_t, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_char_p,
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p]
+    lib.ouro_sc_reduce64_fold.restype = None
+    lib.ouro_sc_reduce64_fold.argtypes = [ctypes.c_char_p, ctypes.c_char_p]
     return lib
 
 
@@ -125,6 +141,33 @@ def vrf_output(sk: bytes, alpha: bytes):
     beta = ctypes.create_string_buffer(64)
     lib.ouro_vrf_output(sk, alpha, len(alpha), beta)
     return beta.raw
+
+
+def ed25519_challenge_rows(r_rows: np.ndarray, a_rows: np.ndarray,
+                           msgs: Sequence[bytes], mask: np.ndarray):
+    """The Ed25519 challenge scalars k = SHA-512(R || A || M) mod L of a
+    whole batch in ONE native call, as (n, 32) little-endian uint8 rows;
+    a lane outside `mask` reads 32 zero bytes.  The interpreter lock is
+    released for the length of the call (a `ctypes.CDLL` handle)."""
+    lib = shared_library()
+    if lib is None:
+        return NotImplemented
+    n = len(msgs)
+    if r_rows.shape != (n, 32) or a_rows.shape != (n, 32) \
+            or mask.shape != (n,):
+        raise ValueError("challenge rows: R and A are (n, 32), mask (n,)")
+    r_rows = np.ascontiguousarray(r_rows, dtype=np.uint8)
+    a_rows = np.ascontiguousarray(a_rows, dtype=np.uint8)
+    lanes = np.ascontiguousarray(mask, dtype=np.uint8)
+    offs = np.zeros(n + 1, dtype=np.uint64)
+    np.cumsum(np.fromiter(map(len, msgs), dtype=np.uint64, count=n),
+              out=offs[1:])
+    joined = b"".join(msgs)
+    k_rows = np.empty((n, 32), dtype=np.uint8)
+    lib.ouro_ed25519_challenge_batch(
+        n, r_rows.ctypes.data, a_rows.ctypes.data, joined,
+        offs.ctypes.data, lanes.ctypes.data, k_rows.ctypes.data)
+    return k_rows
 
 
 class CppBackend(CryptoBackend):

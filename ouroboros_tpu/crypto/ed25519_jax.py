@@ -28,6 +28,9 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..compile_cache import cache_dir
+from ..observe import metrics as _metrics
+from ..observe import spans as _spans
+from . import cpp_backend
 from . import edwards as ed
 from . import field_jax as F
 
@@ -625,6 +628,10 @@ def finalize(d1, d2, valid) -> list[bool]:
 
 
 _LIMB_W = (1 << np.arange(F.RADIX, dtype=np.int64)).astype(np.int32)
+# the challenge stage of the packers: lanes handed to it, and lanes the
+# native batch call computed (equal, or 0 where the pure loop ran)
+_CHALLENGE_LANES = _metrics.counter("ed25519.challenge_lanes")
+_CHALLENGE_NATIVE_LANES = _metrics.counter("ed25519.challenge_native_lanes")
 _L_TOP_ROWS = None  # lazy
 
 
@@ -674,12 +681,41 @@ def _scalar_lt_L(s_rows: np.ndarray) -> np.ndarray:
     return ok
 
 
+def challenge_rows_pure(R_rows, A_rows, msgs, parse_ok) -> np.ndarray:
+    """`challenge_rows` one lane at a time in Python: what runs where the
+    native library cannot be built, and the oracle the tests hold the
+    native call to."""
+    zero = bytes(32)
+    rows = [(ed.sha512_int(R_rows[j].tobytes(), A_rows[j].tobytes(),
+                           msgs[j]) % L).to_bytes(32, "little")
+            if parse_ok[j] else zero for j in range(len(msgs))]
+    return np.frombuffer(b"".join(rows),
+                         dtype=np.uint8).reshape(len(msgs), 32)
+
+
+def challenge_rows(R_rows, A_rows, msgs, parse_ok) -> np.ndarray:
+    """The batch's Ed25519 challenge scalars k = SHA-512(R || A || M) mod L
+    as (N, 32) little-endian uint8 rows; a lane outside parse_ok reads
+    k = 0.  One call into the native library for the whole batch (the
+    interpreter lock is free for its length); whether the library loaded
+    is all that selects, and `ed25519.challenge_native_lanes` says which
+    ran."""
+    n = len(msgs)
+    with _spans.span("pack_ed.challenge", cat="dispatch"):
+        _CHALLENGE_LANES.inc(n)
+        k_rows = cpp_backend.ed25519_challenge_rows(R_rows, A_rows, msgs,
+                                                    parse_ok)
+        if k_rows is NotImplemented:
+            return challenge_rows_pure(R_rows, A_rows, msgs, parse_ok)
+        _CHALLENGE_NATIVE_LANES.inc(n)
+        return k_rows
+
+
 def prepare_bytes_batch(vks, msgs, sigs):
     """Numpy-only host prep for verify_full_kernel.
 
     Returns ((yA, signA, yR, signR, s_bits, k_bits), parse_ok); all per-point
     field math happens on device (device_decompress)."""
-    n = len(vks)
     vk_arr, vk_ok = _bytes_rows(vks, 32)
     sig_arr, sig_ok = _bytes_rows(sigs, 64)
     yA, signA, a_ok = _decode_compressed(vk_arr)
@@ -690,17 +726,9 @@ def prepare_bytes_batch(vks, msgs, sigs):
     s_bits = np.flip(np.unpackbits(sig_arr[:, 32:], axis=1,
                                    bitorder="little"), axis=1)
     s_bits = np.ascontiguousarray(s_bits.T).astype(np.int32)
-    # k = SHA512(R || vk || msg) mod L, per signature (C-speed hashlib)
-    k_bytes = bytearray()
-    for j in range(n):
-        if parse_ok[j]:
-            k = ed.sha512_int(bytes(sig_arr[j, :32]), bytes(vk_arr[j]),
-                              msgs[j]) % L
-        else:
-            k = 0
-        k_bytes += k.to_bytes(32, "big")
-    k_rows = np.frombuffer(bytes(k_bytes), dtype=np.uint8).reshape(n, 32)
-    k_bits = np.unpackbits(k_rows, axis=1, bitorder="big")
+    k_rows = challenge_rows(sig_arr[:, :32], vk_arr, msgs, parse_ok)
+    k_bits = np.flip(np.unpackbits(k_rows, axis=1, bitorder="little"),
+                     axis=1)
     k_bits = np.ascontiguousarray(k_bits.T).astype(np.int32)
     return (yA, signA, yR, signR, s_bits, k_bits), parse_ok
 
@@ -729,7 +757,6 @@ def prepare_words_batch(vks, msgs, sigs):
     Returns ((Aw, signA, Rw, signR, sw, kw), parse_ok): the 256-bit
     inputs as (8, N) uint32 word rows (sign bits cleared out of Aw/Rw
     into the (N,) int32 sign vectors) — the transfer-thin form."""
-    n = len(vks)
     vk_arr, vk_ok = _bytes_rows(vks, 32)
     sig_arr, sig_ok = _bytes_rows(sigs, 64)
     Aw, signA, a_ok = _point_words(vk_arr)
@@ -737,15 +764,7 @@ def prepare_words_batch(vks, msgs, sigs):
     s_rows = np.ascontiguousarray(sig_arr[:, 32:])
     s_ok = _scalar_lt_L(s_rows)
     parse_ok = vk_ok & sig_ok & a_ok & r_ok & s_ok
-    k_bytes = bytearray()
-    for j in range(n):
-        if parse_ok[j]:
-            k = ed.sha512_int(bytes(sig_arr[j, :32]), bytes(vk_arr[j]),
-                              msgs[j]) % L
-        else:
-            k = 0
-        k_bytes += k.to_bytes(32, "little")
-    k_rows = np.frombuffer(bytes(k_bytes), dtype=np.uint8).reshape(n, 32)
+    k_rows = challenge_rows(sig_arr[:, :32], vk_arr, msgs, parse_ok)
     return ((Aw, signA, Rw, signR,
              F.words_from_bytes_rows(s_rows),
              F.words_from_bytes_rows(k_rows)), parse_ok)

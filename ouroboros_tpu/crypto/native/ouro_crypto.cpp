@@ -571,6 +571,114 @@ extern "C" void ouro_ed25519_verify_batch(size_t n, const u8* vks,
     }
 }
 
+// out = in (64 bytes LE) mod L by folding at bit 252: 2^252 = -c (mod L),
+// c = L - 2^252 < 2^125, so x = lo + hi * 2^252 = lo - hi * c, three times
+// over until the high part is gone.  ~100 times faster than the long
+// division above, which stays what the scalar verify and the prover use
+// (so the reference's k never comes from this code) and is what
+// tests/test_ed_challenge.py holds this one to.
+static const u64 SC_C[2] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL};
+static const u64 SC_L[4] = {0x5812631a5cf5d3edULL, 0x14def9dea2f79cd6ULL,
+                            0, 0x1000000000000000ULL};
+static const u64 SC_2L[4] = {0xb024c634b9eba7daULL, 0x29bdf3bd45ef39acULL,
+                             0, 0x2000000000000000ULL};
+static const u64 MASK60 = (1ULL << 60) - 1;
+
+// out[0..na+1] = a[0..na-1] * c
+static void sc_mul_c(u64* out, const u64* a, int na) {
+    for (int i = 0; i < na + 2; i++) out[i] = 0;
+    for (int i = 0; i < na; i++) {
+        u128 carry = 0;
+        for (int j = 0; j < 2; j++) {
+            u128 t = (u128)a[i] * SC_C[j] + out[i + j] + carry;
+            out[i + j] = (u64)t;
+            carry = t >> 64;
+        }
+        out[i + 2] = (u64)carry;     // untouched so far: no carry is lost
+    }
+}
+
+// hi[0..nh-1] = x[0..nx-1] >> 252, then x[0..3] &= 2^252 - 1
+static void sc_split252(u64* hi, int nh, u64* x, int nx) {
+    for (int i = 0; i < nh; i++) {
+        u64 lo = 3 + i < nx ? x[3 + i] >> 60 : 0;
+        u64 up = 4 + i < nx ? x[4 + i] << 4 : 0;
+        hi[i] = lo | up;
+    }
+    x[3] &= MASK60;
+}
+
+// r -= m when r >= m (4 limbs)
+static void sc_cond_sub(u64 r[4], const u64 m[4]) {
+    u64 d[4];
+    u128 borrow = 0;
+    for (int i = 0; i < 4; i++) {
+        u128 t = (u128)r[i] - m[i] - borrow;
+        d[i] = (u64)t;
+        borrow = (t >> 64) & 1;
+    }
+    if (!borrow) memcpy(r, d, sizeof d);
+}
+
+static void sc_reduce64_fold(u8 out[32], const u8 in[64]) {
+    u64 x[8], h1[5], t[7], h2[3], u[5], h3[1], v[3];
+    for (int i = 0; i < 8; i++) {
+        x[i] = 0;
+        for (int j = 0; j < 8; j++) x[i] |= (u64)in[8 * i + j] << (8 * j);
+    }
+    sc_split252(h1, 5, x, 8);        // x < 2^512: hi < 2^260
+    sc_mul_c(t, h1, 5);              // < 2^385
+    sc_split252(h2, 3, t, 7);        // hi < 2^133
+    sc_mul_c(u, h2, 3);              // < 2^258
+    sc_split252(h3, 1, u, 5);        // hi < 2^6
+    sc_mul_c(v, h3, 1);              // < 2^131
+    // r = 2L + x_lo + u_lo - t_lo - v: every low part is under 2^252 < L,
+    // so 0 < r < 4L, and r = in (mod L)
+    u64 r[4];
+    __int128 carry = 0;
+    for (int i = 0; i < 4; i++) {
+        __int128 a = (__int128)SC_2L[i] + x[i] + u[i] - t[i] -
+                     (i < 3 ? v[i] : 0) + carry;
+        r[i] = (u64)a;
+        carry = a >> 64;
+    }
+    sc_cond_sub(r, SC_2L);
+    sc_cond_sub(r, SC_L);
+    for (int i = 0; i < 32; i++) out[i] = (u8)(r[i >> 3] >> (8 * (i & 7)));
+}
+
+// What the device packer needs of a whole batch in one call: the challenge
+// scalars k = SHA-512(R || A || M) mod L of n lanes.  R and A rows are
+// n x 32 bytes, lane i's message is msgs[offs[i] .. offs[i + 1]), and a
+// lane whose mask byte is 0 gets 32 zero bytes.  Rows are little-endian.
+extern "C" void ouro_ed25519_challenge_batch(size_t n, const u8* r_rows,
+                                             const u8* a_rows,
+                                             const u8* msgs,
+                                             const u64* offs,
+                                             const u8* mask, u8* k_rows) {
+    for (size_t i = 0; i < n; i++) {
+        u8* k = k_rows + 32 * i;
+        if (!mask[i]) {
+            memset(k, 0, 32);
+            continue;
+        }
+        u8 hash[64];
+        sha512::Ctx c;
+        sha512::init(&c);
+        sha512::update(&c, r_rows + 32 * i, 32);
+        sha512::update(&c, a_rows + 32 * i, 32);
+        sha512::update(&c, msgs + offs[i], (size_t)(offs[i + 1] - offs[i]));
+        sha512::final(&c, hash);
+        sc_reduce64_fold(k, hash);
+    }
+}
+
+// The fold alone, for the tests' edge values (0, L - 1, L, 2^512 - 1 ...)
+// that no hash will produce on demand
+extern "C" void ouro_sc_reduce64_fold(const u8 in[64], u8 out[32]) {
+    sc_reduce64_fold(out, in);
+}
+
 // ----------------------------------------------------------------- ECVRF
 // H from the compressed Edwards y of the Elligator2 map (what
 // vrf_ref._hash_to_curve_bytes returns): decompress, cofactor cleared

@@ -70,6 +70,9 @@ span named first; cat in brackets):
     window.submit   submit.split, submit.pack_ed, submit.pack_vrf,
                     submit.pack_kes, submit.dispatch, submit.fold
                     [dispatch], one each a window (crypto/jax_backend.py)
+    submit.pack_ed  pack_ed.challenge [dispatch], the Ed25519 challenge
+                    scalars of the window's lanes (crypto/ed25519_jax.py
+                    `challenge_rows`); a root in `verify_ed25519_batch`
     (a root)        pipeline.beta_prefetch [device], the beta round
                     trip before window 0 (consensus/pipeline.py)
 

@@ -63,6 +63,7 @@ STAGES = {
     "seq.body": ("host-seq", "window.host_seq", PRODUCER),
     "submit.split": ("dispatch", "window.submit", PRODUCER),
     "submit.pack_ed": ("dispatch", "window.submit", PRODUCER),
+    "pack_ed.challenge": ("dispatch", "submit.pack_ed", PRODUCER),
     "submit.pack_vrf": ("dispatch", "window.submit", PRODUCER),
     "submit.pack_kes": ("dispatch", "window.submit", PRODUCER),
     "submit.dispatch": ("dispatch", "window.submit", PRODUCER),
@@ -235,7 +236,8 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
         assert len(by_name[name]) == BLOCKS
     assert len(by_name["seq.header"]) == 3 * BLOCKS
     assert len(by_name["seq.body"]) == 2 * BLOCKS
-    for name in ("submit.split", "submit.pack_ed", "submit.pack_vrf",
+    for name in ("submit.split", "submit.pack_ed", "pack_ed.challenge",
+                 "submit.pack_vrf",
                  "submit.pack_kes", "submit.dispatch", "submit.fold",
                  "window.submit", "window.host_seq", "pipeline.drain"):
         assert len(by_name[name]) == N_WINDOWS, name
@@ -411,6 +413,9 @@ KEY_METRICS = ("key_fill_ms_per_window", "key_fill_us_per_key",
 # wait may read 0 (every reply there before it was asked for)
 WORKER_METRICS = ("decode_worker_share", "decode_unpack_us_per_block",
                   "decode_wait_us_per_block")
+# the Ed25519 packer's challenge stage (ISSUE 35), listed after PR 33's
+CHALLENGE_METRICS = ("ed_challenge_ms_per_window",
+                     "ed_challenge_native_share")
 
 
 def _facts(roots, stats, delta) -> dict:
@@ -447,7 +452,8 @@ def test_the_new_metric_files_are_these():
     with open(os.path.join(REPO, "BENCHMARK.json")) as fh:
         listed = [m["name"] for m in json.load(fh)["per_layer"]]
     # in this order and together; later PRs' metrics follow them
-    for group in (NEW_METRICS, GC_METRICS, KEY_METRICS, WORKER_METRICS):
+    for group in (NEW_METRICS, GC_METRICS, KEY_METRICS, WORKER_METRICS,
+                  CHALLENGE_METRICS):
         at = listed.index(group[0])
         assert listed[at:at + len(group)] == list(group)
     files = {os.path.basename(p)[:-5] for p in glob.glob(
@@ -456,7 +462,7 @@ def test_the_new_metric_files_are_these():
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS
-                         + WORKER_METRICS)
+                         + WORKER_METRICS + CHALLENGE_METRICS)
 def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     """A renamed span or counter fails here, not in a chip run."""
     roots, stats, _hash, delta = traced
@@ -470,7 +476,7 @@ def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     assert value > 0 or metric in GC_METRICS[:2] + WORKER_METRICS[2:]
     if metric.endswith("_share"):
         assert value <= 100.0
-    if metric == "decode_worker_share":
+    if metric in ("decode_worker_share", "ed_challenge_native_share"):
         assert value == 100.0
     source = {"span_seconds": "program_span",
               "counter": "program_counter"}[doc["reader"]["num"][0][0]]
