@@ -144,7 +144,10 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
     The header rules run in `seq.header` spans and the ledger pass in
     `seq.body` spans.  The statements keep their order, since which
     error a bad block raises first depends on it, so each name opens
-    more than once a block; a reader sums them by name."""
+    more than once a block; a reader sums them by name.  Inside
+    `seq.body` each call into the `LedgerRules` has a span of its own,
+    once a block: `body.tick`, `body.checks`, `body.extract`,
+    `body.reapply`."""
     header = getattr(b, "header", b)
     with _spans.span("seq.header", cat="host-seq"):
         view = ledger.forecast_view(st.ledger, header.slot)
@@ -153,13 +156,17 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
             st.header.chain_dep_state, view, header.slot)
         protocol.sequential_checks(ticked_dep, header, view)
     with _spans.span("seq.body", cat="host-seq"):
-        ticked_ledger = ledger.tick(st.ledger, b.slot)
-        ledger.sequential_checks(ticked_ledger, b)
+        with _spans.span("body.tick", cat="host-seq"):
+            ticked_ledger = ledger.tick(st.ledger, b.slot)
+        with _spans.span("body.checks", cat="host-seq"):
+            ledger.sequential_checks(ticked_ledger, b)
     with _spans.span("seq.header", cat="host-seq"):
         reqs = protocol.extract_proofs(ticked_dep, header, view)
     with _spans.span("seq.body", cat="host-seq"):
-        reqs = reqs + ledger.extract_proofs(ticked_ledger, b)
-        ledger_state = ledger.reapply_block(ticked_ledger, b)
+        with _spans.span("body.extract", cat="host-seq"):
+            reqs = reqs + ledger.extract_proofs(ticked_ledger, b)
+        with _spans.span("body.reapply", cat="host-seq"):
+            ledger_state = ledger.reapply_block(ticked_ledger, b)
     with _spans.span("seq.header", cat="host-seq"):
         header_state = revalidate_header(protocol, view, header, st.header)
     return reqs, ExtLedgerState(ledger_state, header_state)

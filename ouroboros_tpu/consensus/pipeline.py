@@ -66,7 +66,11 @@ producer inside ``next_window()``, waiting for decoded blocks),
 submitted and not drained), ``consumer_wait_us`` (the caller's thread
 with no submitted window to drain) and ``first_submit_us`` (producer
 start to the first submit, once a replay: the head during which the
-device has had nothing).
+device has had nothing).  What the producer thread itself used, start to
+end, goes to ``replay.thread_cpu_us.producer`` and
+``replay.thread_preempts.producer`` (observe/spans.py says what the three
+threads' readings are for), and ``window.host_seq`` is a ``cpu=True``
+span.
 """
 from __future__ import annotations
 
@@ -92,9 +96,6 @@ DEPTH = 2
 # (tests/test_served_replay.py::test_producer_ran_and_is_gone)
 _STARTED = _metrics.counter("pipeline.producers_started", always=True)
 _FINISHED = _metrics.counter("pipeline.producers_finished", always=True)
-# observational: windows through the pipeline / producer permit stalls
-_WINDOWS = _metrics.counter("pipeline.windows")
-_STALLS = _metrics.counter("pipeline.producer_stalls")
 # queue-latency instrumentation (ISSUE 9): submit→drain covers the full
 # async residence of a window — dispatch queue + device + transfer —
 # the quantity the adaptive batching service will trade off against
@@ -111,6 +112,12 @@ _CONSUMER_WAIT_US = _metrics.counter("pipeline.consumer_wait_us",
 _FIRST_SUBMIT_US = _metrics.counter("pipeline.first_submit_us",
                                     stable=False)
 _STALL_US = _metrics.counter("pipeline.producer_stall_us", stable=False)
+# what the producer thread used, start to end of its part of a replay
+# (observe/spans.py `thread_usage`): CPU time in whole microseconds, and
+# the times the kernel took the core from it
+_CPU_US = _metrics.counter("replay.thread_cpu_us.producer", stable=False)
+_PREEMPTS = _metrics.counter("replay.thread_preempts.producer",
+                             stable=False)
 
 # replay progress gauges (rendered live by tools/obsreport.py --live via
 # the scrape endpoint).  blocks_done / windows_in_flight / total are
@@ -308,12 +315,10 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             with shared.cond:
                 if not (shared.stop
                         or shared.submitted - shared.drained < DEPTH):
-                    _STALLS.inc()
                     t_stall = _spans.monotonic_now()
-                    with _spans.span("producer.stall", cat="stall"):
-                        shared.cond.wait_for(
-                            lambda: shared.stop or
-                            shared.submitted - shared.drained < DEPTH)
+                    shared.cond.wait_for(
+                        lambda: shared.stop or
+                        shared.submitted - shared.drained < DEPTH)
                     _STALL_US.inc(_us_since(t_stall))
                 if shared.stop:
                     return
@@ -330,7 +335,8 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             progress = shared.progress
             if progress is not None:
                 progress.host_begin()
-            with _spans.span("window.host_seq", cat="host-seq", window=k):
+            with _spans.span("window.host_seq", cat="host-seq", cpu=True,
+                             window=k):
                 for i, b in enumerate(blk_window):
                     try:
                         rs, st = _seq_block_step(protocol, ledger, st, b)
@@ -361,7 +367,6 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                 t_first = None
             sub = (submit(reqs, next_proofs, fold=True) if fold
                    else submit(reqs, next_proofs))
-            _WINDOWS.inc()
             _WINDOW_BLOCKS.observe(n_seq_w)
             if progress is not None:
                 progress.window_submitted()
@@ -548,6 +553,7 @@ def replay_threaded(ext_rules, blocks, ext_state, backend,
     return ReplayResult(shared.final_state, shared.seq_done, None)
 
 
+@_spans.thread_usage(_CPU_US, _PREEMPTS)
 def _run_producer(*args) -> None:
     try:
         _produce(*args)

@@ -687,7 +687,7 @@ class JaxBackend(CryptoBackend):
         submit.pack_ed (key tables included), submit.pack_vrf (beta
         words included), submit.pack_kes, submit.dispatch (the choice
         and the composite call) and, folding, submit.fold."""
-        with _spans.span("window.submit", cat="dispatch"):
+        with _spans.span("window.submit", cat="dispatch", cpu=True):
             return self._submit_window(reqs, next_beta_proofs, fold)
 
     def _submit_window(self, reqs, next_beta_proofs=(),

@@ -157,7 +157,9 @@ def chrome_trace(spans: Iterable[Span], pid: int = 1) -> dict:
     opened a span gets its own tid row, named after it, so the streamed
     replay's prefetcher, producer and consumer render as three parallel
     tracks; a span's category goes to the event's `cat` field and its
-    `meta` (the window's index) to `args`.  Timestamps are the spans'
+    `meta` (the window's index) to `args`, beside `cpu_ms` and
+    `off_cpu_ms` where the span read its thread's CPU clock
+    (`span(..., cpu=True)`).  Timestamps are the spans'
     monotonic clock readings in microseconds (chrome only cares about
     relative position)."""
     events: List[dict] = []
@@ -171,6 +173,11 @@ def chrome_trace(spans: Iterable[Span], pid: int = 1) -> dict:
               "pid": pid, "tid": tid}
         if sp.meta:
             ev["args"] = sp.meta
+        if sp.cpu is not None:
+            # a `cpu=True` span: its milliseconds on the CPU and off it
+            ev["args"] = {**ev.get("args", {}),
+                          "cpu_ms": round(sp.cpu * 1e3, 3),
+                          "off_cpu_ms": round(sp.off_cpu * 1e3, 3)}
         events.append(ev)
         for c in sp.children:
             emit(c)

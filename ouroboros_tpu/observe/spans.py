@@ -67,6 +67,13 @@ span named first; cat in brackets):
     window.host_seq seq.header, seq.body [host-seq], the header rules
                     and the ledger pass of one block, interleaved as
                     `_seq_block_step` runs them (consensus/batch.py)
+    seq.body        body.tick, body.checks, body.extract, body.reapply
+                    [host-seq], one each a block: the four calls into
+                    the `LedgerRules` of that pass (`tick`,
+                    `sequential_checks`, `extract_proofs`,
+                    `reapply_block`), whatever the era.  None a
+                    transaction: a span costs about what a light
+                    transaction's share of the pass does
     window.submit   submit.split, submit.pack_ed, submit.pack_vrf,
                     submit.pack_kes, submit.dispatch, submit.fold
                     [dispatch], one each a window (crypto/jax_backend.py)
@@ -84,8 +91,8 @@ inside whatever stage span happened to be open), `replay.gc.full_passes`,
 while a replay runs.
 
 Waits are counters, not spans: `pipeline.producer_wait_blocks_us`,
-`pipeline.producer_stall_us` (the permit wait; `producer.stall` is its
-span of cat `stall`), `pipeline.consumer_wait_us` and
+`pipeline.producer_stall_us` (the permit wait),
+`pipeline.consumer_wait_us` and
 `pipeline.first_submit_us` (consensus/pipeline.py), and the prefetch
 thread's `replay.stream.backpressure_wait_us` (storage/stream.py
 `_put`), hold whole microseconds.  A consumer of spans
@@ -93,13 +100,49 @@ that gives a piece of device idle time to the open span that started
 last would hand it to a wait span opened after the work it waits on,
 and take it from that work; as gated registry counters the waits also
 reach the scrape endpoint with span recording off.
+
+On the CPU and off it: `span(..., cpu=True)` reads the opening thread's
+own CPU clock (`time.thread_time()`) beside the monotonic clock at both
+edges.  The closed span carries `cpu`, the seconds the thread ran
+inside it (None where not asked, where a sim or IO runtime supplies the
+clock, and on adopted rows), and the rest of its length (`off_cpu`,
+clipped to 0 and to the length) goes in whole microseconds to the
+counter `span.off_cpu_us.<name>` (gated, `stable=False`, bound once a
+name).  In a stage that makes no
+blocking call (`window.host_seq`, the unpickling of `decode.unpack`)
+time off the CPU is the wait for the interpreter lock plus whatever the
+kernel took the core away for; in `window.submit`, `stream.read` and
+`stream.snapshot` it also holds the copies to the device and the file
+system.  Asked for at those five, none of them a block: two clock reads
+more a span.
+
+A thread's CPU seconds a replay: `thread_usage(cpu_us, preempts)` around
+a thread's part of a streamed replay adds the thread's CPU time
+(`time.thread_time_ns()`, whole microseconds) and the times the kernel
+took the core from it while it wanted to run
+(`getrusage(RUSAGE_THREAD).ru_nivcsw`; a wait for the interpreter lock
+is a voluntary switch and is not among them) to the two counters it is
+given: `replay.thread_cpu_us.<thread>` and
+`replay.thread_preempts.<thread>` for `prefetch` (storage/stream.py
+`BlockPrefetcher._run`), `producer` (consensus/pipeline.py
+`_run_producer`) and `caller` (`StreamingReplayEngine.replay`).  Always
+read, span recording on or off: the three threads' CPU seconds over the
+replay's length say how many cores' worth of work the host chain did,
+which is 1 where one interpreter lock is taken in turn.
 """
 from __future__ import annotations
 
+import contextlib
 import sys
 import threading
 import time
 from typing import List, Optional
+
+try:
+    import resource
+    _RUSAGE_THREAD = resource.RUSAGE_THREAD
+except (ImportError, AttributeError):      # not Linux: no preempt count
+    _RUSAGE_THREAD = None
 
 from ..simharness import runtime as _runtime
 from . import metrics as _metrics
@@ -127,9 +170,11 @@ class Span:
     readings from `monotonic_now`; `children` are spans closed while
     this one was the innermost open span; `thread` is the name of the
     thread that opened it; `meta` holds the keyword arguments `span()`
-    was given (`window=k`), or None."""
+    was given (`window=k`), or None; `cpu` is the seconds the opening
+    thread ran inside a closed `span(..., cpu=True)`, else None."""
 
-    __slots__ = ("name", "cat", "t0", "t1", "children", "meta", "thread")
+    __slots__ = ("name", "cat", "t0", "t1", "children", "meta", "thread",
+                 "cpu")
 
     def __init__(self, name: str, cat: str, t0: float,
                  thread: Optional[str] = None,
@@ -141,10 +186,20 @@ class Span:
         self.children: List["Span"] = []
         self.meta = meta
         self.thread = thread
+        self.cpu: Optional[float] = None
 
     @property
     def duration(self) -> float:
         return (self.t1 - self.t0) if self.t1 is not None else 0.0
+
+    @property
+    def off_cpu(self) -> Optional[float]:
+        """Seconds of a `cpu=True` span in which its thread was not on
+        a CPU: its length less `cpu`, clipped to 0 and to the length
+        (the two clocks are not read at one instant)."""
+        if self.cpu is None:
+            return None
+        return min(self.duration, max(0.0, self.duration - self.cpu))
 
     def walk(self):
         yield self
@@ -173,26 +228,36 @@ _NULL = _NullSpan()
 
 
 class _LiveSpan:
-    __slots__ = ("_rec", "_name", "_cat", "_fence", "_meta", "_span")
+    __slots__ = ("_rec", "_name", "_cat", "_fence", "_meta", "_cpu",
+                 "_cpu0", "_span")
 
     def __init__(self, rec: "SpanRecorder", name: str, cat: str,
-                 fence: bool, meta: Optional[dict] = None):
+                 fence: bool, meta: Optional[dict] = None,
+                 cpu: bool = False):
         self._rec = rec
         self._name = name
         self._cat = cat
         self._fence = fence
         self._meta = meta
+        self._cpu = cpu
+        self._cpu0: Optional[float] = None   # thread_time() at the open
         self._span: Optional[Span] = None
 
     def __enter__(self) -> Span:
         if self._fence:
             device_fence()
         self._span = self._rec._open(self._name, self._cat, self._meta)
+        # the CPU clock is read inside the monotonic readings at both
+        # edges; a runtime's virtual clock has no CPU clock beside it
+        if self._cpu and _runtime.current_or_none() is None:
+            self._cpu0 = time.thread_time()
         return self._span
 
     def __exit__(self, *exc):
         if self._fence:
             device_fence()
+        if self._cpu0 is not None and self._span.t1 is None:
+            self._span.cpu = time.thread_time() - self._cpu0
         self._rec._close(self._span)
         return False
 
@@ -218,6 +283,16 @@ class SpanRecorder:
         # feeds `latency.phase.<cat>`, so phase p50/p95/p99 are live on
         # the scrape endpoint while a replay runs
         self._phase_hist: dict = {}
+        # `span.off_cpu_us.<name>` counters of the `cpu=True` spans,
+        # bound the same way, once a name
+        self._off_cpu: dict = {}
+
+    def _off_cpu_for(self, name: str):
+        c = self._off_cpu.get(name)
+        if c is None:
+            c = _metrics.counter(f"span.off_cpu_us.{name}", stable=False)
+            self._off_cpu[name] = c
+        return c
 
     def _hist_for(self, cat: str):
         h = self._phase_hist.get(cat)
@@ -238,13 +313,14 @@ class SpanRecorder:
 
     # -- the public surface ------------------------------------------------
     def span(self, name: str, cat: str = "host-seq", fence: bool = False,
-             **meta):
+             cpu: bool = False, **meta):
         """Context manager timing one interval; keyword arguments land
-        in the span's `meta`.  Near-free when the recorder is disabled
-        (returns a shared null CM)."""
+        in the span's `meta`; `cpu=True` also reads the thread's CPU
+        clock (the module text says what for).  Near-free when the
+        recorder is disabled (returns a shared null CM)."""
         if not self.enabled:
             return _NULL
-        return _LiveSpan(self, name, cat, fence, meta or None)
+        return _LiveSpan(self, name, cat, fence, meta or None, cpu)
 
     def enable(self) -> None:
         self.enabled = True
@@ -281,6 +357,8 @@ class SpanRecorder:
             # parent/root and count it twice
             return
         sp.t1 = monotonic_now()
+        if sp.cpu is not None:
+            self._off_cpu_for(sp.name).inc(int(sp.off_cpu * 1e6))
         fl = self.flight
         if fl is not None:
             fl.span(sp)
@@ -321,17 +399,43 @@ def recorder() -> SpanRecorder:
     return RECORDER
 
 
-def span(name: str, cat: str = "host-seq", fence: bool = False, **meta):
+def span(name: str, cat: str = "host-seq", fence: bool = False,
+         cpu: bool = False, **meta):
     """observe.spans.span("window.drain", cat="device") — module-level
     convenience over the process-wide recorder."""
     rec = RECORDER
     if not rec.enabled:
         return _NULL
-    return _LiveSpan(rec, name, cat, fence, meta or None)
+    return _LiveSpan(rec, name, cat, fence, meta or None, cpu)
 
 
 def enabled() -> bool:
     return RECORDER.enabled
+
+
+def _preempts() -> int:
+    """Involuntary context switches of the calling thread so far."""
+    if _RUSAGE_THREAD is None:
+        return 0
+    return resource.getrusage(_RUSAGE_THREAD).ru_nivcsw
+
+
+@contextlib.contextmanager
+def thread_usage(cpu_us, preempts):
+    """`with thread_usage(cpu_us, preempts):`, or `@thread_usage(...)`
+    on a function (a fresh pair of start readings a call), adds what the
+    calling thread used inside to two counters: its CPU time in whole
+    microseconds, and the times the kernel took the core from it while
+    it wanted to run.  Two clock reads and two `getrusage` calls, span
+    recording on or off (the module text names the three threads of a
+    streamed replay that are read so)."""
+    sw0 = _preempts()
+    ns0 = time.thread_time_ns()
+    try:
+        yield
+    finally:
+        cpu_us.inc((time.thread_time_ns() - ns0) // 1000)
+        preempts.inc(_preempts() - sw0)
 
 
 def adopt(parent: Span, rows, thread: str) -> None:

@@ -231,7 +231,7 @@ class Lease:
         _WORKER_WAIT_US.inc(int((_spans.monotonic_now() - t0) * 1e6))
         if not ready:
             return None
-        with _spans.span("decode.unpack", cat="disk"):
+        with _spans.span("decode.unpack", cat="disk", cpu=True):
             status, body = pickle.loads(w.read_reply())
         self._flight.popleft()
         self._idle.append(w)

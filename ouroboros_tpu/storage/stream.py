@@ -118,6 +118,18 @@ _GC_FULL = _metrics.counter("replay.gc.full_passes", stable=False)
 _GC_FREEZES = _metrics.counter("replay.gc.freezes", stable=False)
 _GC_FROZEN = _metrics.counter("replay.gc.frozen_objects", stable=False)
 
+# what the prefetch thread and the caller's thread used, start to end of
+# their part of a replay (observe/spans.py `thread_usage`): CPU time in
+# whole microseconds, and the times the kernel took the core from them
+_PREFETCH_CPU_US = _metrics.counter("replay.thread_cpu_us.prefetch",
+                                    stable=False)
+_PREFETCH_PREEMPTS = _metrics.counter("replay.thread_preempts.prefetch",
+                                      stable=False)
+_CALLER_CPU_US = _metrics.counter("replay.thread_cpu_us.caller",
+                                  stable=False)
+_CALLER_PREEMPTS = _metrics.counter("replay.thread_preempts.caller",
+                                    stable=False)
+
 # load-bearing thread accounting, like the pipeline's producer pair: a
 # replay that returns with started != finished leaked its prefetcher
 _P_STARTED = _metrics.counter("stream.prefetchers_started", always=True)
@@ -364,7 +376,7 @@ class BlockPrefetcher:
         return blocks
 
     @contextlib.contextmanager
-    def _disk(self, span_name: str):
+    def _disk(self, span_name: str, cpu: bool = False):
         """The disk signal (tracker + a `disk`-phase span) around work
         of this thread: a read, a decode, the wait for a worker's
         reply; never the queue wait."""
@@ -372,7 +384,7 @@ class BlockPrefetcher:
         if tracker is not None:
             tracker.disk_begin()
         try:
-            with _spans.span(span_name, cat="disk") as sp:
+            with _spans.span(span_name, cat="disk", cpu=cpu) as sp:
                 yield sp
         finally:
             if tracker is not None:
@@ -407,7 +419,7 @@ class BlockPrefetcher:
         for n in self.db.chunk_numbers():
             if n < n0:
                 continue
-            with self._disk("stream.read"):
+            with self._disk("stream.read", cpu=True):
                 pairs = self.db.chunk_blocks(
                     n, from_index=i0 if n == n0 else 0)
             self._note_read(pairs)
@@ -476,6 +488,7 @@ class BlockPrefetcher:
             self._note_read(buf_pairs)
             yield self._decode_batch(buf_pairs)
 
+    @_spans.thread_usage(_PREFETCH_CPU_US, _PREFETCH_PREEMPTS)
     def _run(self) -> None:
         decoded = self._read_decoded()
         try:
@@ -638,7 +651,7 @@ class StreamingReplayEngine:
     # -- snapshotting ---------------------------------------------------------
     def _take_snapshot(self, point, state) -> None:
         t0 = _spans.monotonic_now()
-        with _spans.span("stream.snapshot", cat="disk"):
+        with _spans.span("stream.snapshot", cat="disk", cpu=True):
             LedgerDB.take_snapshot(self.fs, point.slot, point, state,
                                    self._enc, self.cfg.policy)
         self.snapshots_written += 1
@@ -647,6 +660,7 @@ class StreamingReplayEngine:
         _SNAP_SECS.set(round(self.snapshot_write_secs, 6))
 
     # -- the replay ------------------------------------------------------------
+    @_spans.thread_usage(_CALLER_CPU_US, _CALLER_PREEMPTS)
     def replay(self) -> StreamReplayResult:
         from ..consensus.batch import replay_blocks_pipelined
 

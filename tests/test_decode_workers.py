@@ -271,6 +271,11 @@ def test_a_killed_worker_fails_the_replay_with_its_exit_status(loaded):
     assert prefetcher_threads_alive() == 0
     assert "exit status -9" in str(err.value)
     assert decode_pool.WORKER_NAME in str(err.value)
+    # SIGKILL is asynchronous: a worker not yet gone still looks alive to
+    # the pool, which would load it for the next replay
+    deadline = time.monotonic() + 10
+    while POOL.pids() and time.monotonic() < deadline:
+        time.sleep(0.01)
     # the next replay starts new workers and is whole
     assert len(stream(db, decode)) == BLOCKS
     after = sorted(POOL.pids())

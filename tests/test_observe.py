@@ -19,6 +19,7 @@ import json
 import os
 import sys
 import threading
+import time
 
 import pytest
 
@@ -485,6 +486,209 @@ def test_precompute_counters_live_in_global_registry():
         assert inst.value == before + 1
     finally:
         GLOBAL_PRECOMPUTE_CACHE.hits = before
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 36: a span's seconds on the CPU and off it, a thread's CPU time
+# ---------------------------------------------------------------------------
+
+def _spin(seconds: float) -> int:
+    """Pure Python for `seconds` on the monotonic clock: holds the
+    interpreter lock whenever it runs."""
+    n, end = 0, time.perf_counter() + seconds
+    while time.perf_counter() < end:
+        n += 1
+    return n
+
+
+def _off_cpu_counter(name: str) -> int:
+    inst = metrics.REGISTRY.get("span.off_cpu_us." + name)
+    return inst.value if inst is not None else 0
+
+
+def _attempts(measure, good, tries: int = 4):
+    """`measure()` until `good(reading)`, a few times: the readings are
+    of a CPU this process shares with the other test workers."""
+    for _ in range(tries):
+        got = measure()
+        if good(got):
+            break
+    return got
+
+
+def test_cpu_span_busy_is_on_the_cpu_and_asleep_is_off_it():
+    rec = SpanRecorder(enabled=True)
+    assert metrics.REGISTRY.enabled
+
+    def measure():
+        c0 = _off_cpu_counter("t36.busy"), _off_cpu_counter("t36.asleep")
+        with rec.span("t36.busy", cat="host-seq", cpu=True) as busy:
+            _spin(0.05)
+        with rec.span("t36.asleep", cat="disk", cpu=True) as asleep:
+            time.sleep(0.05)
+        return (busy, asleep, _off_cpu_counter("t36.busy") - c0[0],
+                _off_cpu_counter("t36.asleep") - c0[1])
+
+    busy, asleep, busy_off_us, asleep_off_us = _attempts(
+        measure, lambda r: r[0].cpu > 0.75 * r[0].duration)
+    # ratios of the span's own length, never milliseconds
+    assert busy.cpu > 0.75 * busy.duration
+    assert busy_off_us < 0.25 * busy.duration * 1e6
+    assert 0 <= asleep.cpu < 0.25 * asleep.duration
+    assert 0.75 * asleep.duration * 1e6 < asleep_off_us \
+        <= asleep.duration * 1e6
+    inst = metrics.REGISTRY.get("span.off_cpu_us.t36.asleep")
+    assert inst.kind == "counter" and not inst.stable and not inst.always
+    # bound once a name: the recorder keeps the handle
+    assert rec._off_cpu_for("t36.asleep") is inst
+    # a span that did not ask reads no CPU clock and feeds no counter
+    with rec.span("t36.plain") as plain:
+        pass
+    assert plain.cpu is None
+    assert metrics.REGISTRY.get("span.off_cpu_us.t36.plain") is None
+
+
+def test_two_spinning_threads_share_one_interpreter_lock():
+    """Two threads of pure Python take the lock in turn: each is off the
+    CPU for about half of its span, and their CPU seconds add up to
+    about the wall time, not to twice it.  This is the reading
+    `host_busy_cores` and `host_seq_off_cpu_share` make of a replay."""
+    rec = SpanRecorder(enabled=True)
+
+    def measure():
+        start = threading.Barrier(2)
+        got: dict = {}
+
+        def work(i: int):
+            start.wait(10)
+            with rec.span(f"t36.spin{i}", cat="host-seq", cpu=True) as sp:
+                _spin(0.4)
+            got[i] = sp
+
+        c0 = [_off_cpu_counter(f"t36.spin{i}") for i in (0, 1)]
+        t0 = time.perf_counter()
+        threads = [threading.Thread(target=work, args=(i,)) for i in (0, 1)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30)
+        wall = time.perf_counter() - t0
+        assert not any(t.is_alive() for t in threads)
+        off = [(_off_cpu_counter(f"t36.spin{i}") - c0[i]) / 1e6
+               for i in (0, 1)]
+        return got, off, wall
+
+    def good(r):
+        got, off, wall = r
+        return all(0.25 < off[i] / got[i].duration < 0.75 for i in (0, 1)) \
+            and 0.6 < (got[0].cpu + got[1].cpu) / wall < 1.25
+
+    got, off, wall = _attempts(measure, good)
+    for i in (0, 1):
+        assert 0.25 < off[i] / got[i].duration < 0.75
+        assert 0.25 < got[i].cpu / got[i].duration < 0.75
+    assert 0.6 < (got[0].cpu + got[1].cpu) / wall < 1.25
+
+
+def test_cpu_is_none_under_a_sim_clock_and_on_adopted_rows():
+    """A runtime's virtual clock has no CPU clock beside it, and a row
+    timed in another process brings none."""
+    rec = SpanRecorder(enabled=True)
+    c0 = _off_cpu_counter("t36.sim")
+
+    async def main():
+        with rec.span("t36.sim", cat="host-seq", cpu=True):
+            await sim.sleep(2.5)
+
+    sim.run(main())
+    (sp,) = rec.drain()
+    assert sp.duration == 2.5 and sp.cpu is None
+    assert _off_cpu_counter("t36.sim") == c0 == 0
+    parent = Span("stream.decode", "disk", 1.0, "ouro-stream-prefetch")
+    parent.t1 = 2.0
+    spans.adopt(parent, [("decode.parse", "disk", 0.25, 0.75)],
+                thread="ouro-decode-0")
+    (row,) = parent.children
+    assert row.cpu is None and row.duration == 0.5
+    # neither shows CPU fields in the chrome trace; a span that has them
+    # shows both beside its meta, and they add up to its length
+    doc = export.chrome_trace([sp, parent])
+    assert not [e for e in doc["traceEvents"]
+                if e["ph"] == "X" and "cpu_ms" in e.get("args", {})]
+    timed = Span("window.host_seq", "host-seq", 10.0, "p", {"window": 3})
+    timed.t1, timed.cpu = 10.5, 0.125
+    (ev,) = [e for e in export.chrome_trace([timed])["traceEvents"]
+             if e["ph"] == "X"]
+    assert ev["args"] == {"window": 3, "cpu_ms": 125.0, "off_cpu_ms": 375.0}
+
+
+def test_recording_off_cpu_span_is_the_null_cm_and_no_counter_moves():
+    rec = SpanRecorder(enabled=False)
+    before = {i.name: i.value for i in metrics.REGISTRY.instruments()
+              if i.name.startswith("span.off_cpu_us.")}
+    cm = rec.span("t36.off", cat="disk", cpu=True, window=1)
+    assert cm is rec.span("other") is spans._NULL
+    with cm as nothing:
+        time.sleep(0.001)
+    assert nothing is None
+    was = spans.RECORDER.enabled
+    spans.RECORDER.disable()
+    try:
+        assert spans.span("t36.off", cpu=True) is spans._NULL
+    finally:
+        spans.RECORDER.enabled = was
+    assert metrics.REGISTRY.get("span.off_cpu_us.t36.off") is None
+    assert {i.name: i.value for i in metrics.REGISTRY.instruments()
+            if i.name.startswith("span.off_cpu_us.")} == before
+
+
+def test_cpu_span_with_the_registry_off_times_but_counts_nothing():
+    """The span still carries `cpu` (recording is the recorder's flag);
+    the counter is a gated instrument and the registry's flag drops it."""
+    rec = SpanRecorder(enabled=True)
+    c0 = _off_cpu_counter("t36.gated")
+    was = metrics.REGISTRY.enabled
+    metrics.REGISTRY.disable()
+    try:
+        writes = metrics.REGISTRY.data_writes
+        with rec.span("t36.gated", cat="disk", cpu=True) as sp:
+            time.sleep(0.002)
+        assert metrics.REGISTRY.data_writes == writes
+    finally:
+        metrics.REGISTRY.enabled = was
+    assert sp.cpu is not None and sp.cpu < sp.duration
+    assert _off_cpu_counter("t36.gated") == c0
+
+
+def test_thread_usage_adds_the_calling_threads_cpu_time_once():
+    reg = MetricsRegistry()
+    cpu_us = reg.counter("t36.cpu_us", stable=False)
+    preempts = reg.counter("t36.preempts", stable=False)
+    seen: dict = {}
+
+    def work():
+        with spans.thread_usage(cpu_us, preempts):
+            t0 = time.perf_counter()
+            _spin(0.05)
+            time.sleep(0.05)
+            seen["wall"] = time.perf_counter() - t0
+
+    writes = reg.data_writes
+    t = threading.Thread(target=work)
+    t.start()
+    t.join(30)
+    assert not t.is_alive()
+    assert reg.data_writes == writes + 2          # one inc each
+    assert isinstance(cpu_us.value, int) and isinstance(preempts.value, int)
+    # the sleep is not CPU time and the main thread's work is not this
+    # thread's: more than nothing, less than the block's length
+    assert 0 < cpu_us.value < 0.8 * seen["wall"] * 1e6
+    assert preempts.value >= 0
+    # an exception passes through and the reading is still made
+    with pytest.raises(KeyError):
+        with spans.thread_usage(cpu_us, preempts):
+            raise KeyError("x")
+    assert reg.data_writes == writes + 4
 
 
 # ---------------------------------------------------------------------------

@@ -61,6 +61,10 @@ STAGES = {
     "decode.slices": ("disk", "stream.decode", PREFETCH),
     "seq.header": ("host-seq", "window.host_seq", PRODUCER),
     "seq.body": ("host-seq", "window.host_seq", PRODUCER),
+    "body.tick": ("host-seq", "seq.body", PRODUCER),
+    "body.checks": ("host-seq", "seq.body", PRODUCER),
+    "body.extract": ("host-seq", "seq.body", PRODUCER),
+    "body.reapply": ("host-seq", "seq.body", PRODUCER),
     "submit.split": ("dispatch", "window.submit", PRODUCER),
     "submit.pack_ed": ("dispatch", "window.submit", PRODUCER),
     "pack_ed.challenge": ("dispatch", "submit.pack_ed", PRODUCER),
@@ -70,8 +74,15 @@ STAGES = {
     "submit.fold": ("dispatch", "window.submit", PRODUCER),
     "pipeline.beta_prefetch": ("device", None, PRODUCER),
 }
+BODY_STAGES = ("body.tick", "body.checks", "body.extract", "body.reapply")
 WAITS = ("pipeline.producer_wait_blocks_us", "pipeline.consumer_wait_us",
          "pipeline.first_submit_us")
+# the spans that read their thread's CPU clock too (ISSUE 36), and the
+# thread each is on
+CPU_SPANS = {"window.host_seq": PRODUCER, "window.submit": PRODUCER,
+             "decode.unpack": PREFETCH, "stream.read": PREFETCH,
+             "stream.snapshot": None}           # None: the caller's
+THREADS = ("prefetch", "producer", "caller")
 
 
 class HostProgramsBackend(JaxBackend):
@@ -232,8 +243,8 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
             == ("disk", PREFETCH, "stream.decode")
     # how many: one a block, one a window, one a replay (seq.* open more
     # than once a block: the statements keep their order)
-    for name in ("decode.parse", "decode.build", "decode.slices"):
-        assert len(by_name[name]) == BLOCKS
+    for name in DECODE_STAGES + BODY_STAGES:
+        assert len(by_name[name]) == BLOCKS, name
     assert len(by_name["seq.header"]) == 3 * BLOCKS
     assert len(by_name["seq.body"]) == 2 * BLOCKS
     for name in ("submit.split", "submit.pack_ed", "pack_ed.challenge",
@@ -389,6 +400,78 @@ def test_chrome_trace_of_a_replay_has_a_row_per_thread(traced):
     drains = [e for e in events if e["name"] == "pipeline.drain"]
     assert [e["args"]["window"] for e in drains] == list(range(N_WINDOWS))
     assert {e["cat"] for e in drains} == {"device"}
+    # the operator's view of a `cpu=True` span: its milliseconds on the
+    # CPU and off it, which add up to its length; no other span has them
+    with_cpu = [e for e in events if "cpu_ms" in e.get("args", {})]
+    assert {e["name"] for e in with_cpu} == set(CPU_SPANS)
+    for e in with_cpu:
+        a = e["args"]
+        assert a["cpu_ms"] >= 0 and 0 <= a["off_cpu_ms"] <= e["dur"] / 1e3
+        if a["off_cpu_ms"]:
+            assert a["cpu_ms"] + a["off_cpu_ms"] \
+                == pytest.approx(e["dur"] / 1e3, abs=2e-3)
+    hosts = [e for e in with_cpu if e["name"] == "window.host_seq"]
+    assert [e["args"]["window"] for e in hosts] == list(range(N_WINDOWS))
+
+
+@BOTH
+def test_cpu_spans_carry_cpu_seconds_and_feed_their_counter(traced):
+    """The five `cpu=True` spans of a replay (ISSUE 36) and no other
+    carry `cpu`; what each name's spans were off the CPU is what its
+    counter holds, to the microsecond a span."""
+    roots, _stats, _hash, delta = traced
+    in_workers = delta["replay.decode.worker_blocks"] > 0
+    spans = [sp for r in roots for sp in r.walk()]
+    caller = threading.current_thread().name
+    want = {n: t or caller for n, t in CPU_SPANS.items()
+            if in_workers or n != "decode.unpack"}
+    assert {sp.name for sp in spans if sp.cpu is not None} == set(want)
+    for name, thread in want.items():
+        mine = [sp for sp in spans if sp.name == name]
+        assert all(sp.cpu is not None and sp.cpu >= 0
+                   and sp.thread == thread for sp in mine), name
+        assert all(0 <= sp.off_cpu <= sp.duration for sp in mine)
+        off_us = sum(sp.off_cpu for sp in mine) * 1e6
+        got = delta["span.off_cpu_us." + name]
+        assert off_us - len(mine) <= got <= off_us + 1e-3, name
+        assert got <= sum(sp.duration for sp in mine) * 1e6
+    assert not any(sp.cpu is not None for sp in spans if _adopted(sp))
+
+
+def test_thread_readings_rise_once_a_replay_with_recording_off(
+        chain_dir, tmp_path, host_tables, monkeypatch):
+    """Each of the three threads adds its CPU microseconds and its
+    preempt count once a replay, span recording off, and no thread's
+    CPU time is longer than the replay."""
+    names = [f"replay.thread_{kind}.{t}" for t in THREADS
+             for kind in ("cpu_us", "preempts")]
+    for name in names:
+        inst = observe.REGISTRY.get(name)
+        assert inst.kind == "counter" and not inst.always \
+            and not inst.stable, name
+    read = []
+    real_preempts = spans_mod._preempts
+
+    def counting_preempts():
+        read.append(threading.current_thread().name)
+        return real_preempts()
+
+    monkeypatch.setattr(spans_mod, "_preempts", counting_preempts)
+    assert not spans_mod.RECORDER.enabled
+    stats, _hash, delta = _replay(chain_dir, str(tmp_path / "db"),
+                                  HostProgramsBackend())
+    # two readings a thread: where its part of the replay starts and ends
+    assert sorted(read) == sorted(
+        2 * [PREFETCH, PRODUCER, threading.current_thread().name])
+    for name in names:
+        assert isinstance(delta[name], int) and delta[name] >= 0, name
+    for thread in THREADS:
+        # `replay_secs` starts after the restore and ends before the
+        # tip checkpoint, both of them the caller's: a second of room
+        assert 0 < delta["replay.thread_cpu_us." + thread] \
+            <= (stats["replay_secs"] + 1.0) * 1e6, thread
+    assert not [k for k in delta if k.startswith("span.off_cpu_us.")
+                and delta[k]]
 
 
 # -- the benchmark's readers of these spans and counters ----------------------
@@ -416,6 +499,21 @@ WORKER_METRICS = ("decode_worker_share", "decode_unpack_us_per_block",
 # the Ed25519 packer's challenge stage (ISSUE 35), listed after PR 33's
 CHALLENGE_METRICS = ("ed_challenge_ms_per_window",
                      "ed_challenge_native_share")
+# the host chain from inside (ISSUE 36), listed after PR 35's: the ledger
+# pass in four stages, five spans' time off the CPU, the three threads'
+# time on it.  A tiny replay may read 0 off the CPU in a span and no
+# preempt at all
+HOSTCHAIN_METRICS = (
+    "body_tick_us_per_block", "body_checks_us_per_block",
+    "body_extract_us_per_block", "body_reapply_us_per_block",
+    "host_seq_off_cpu_share", "submit_off_cpu_share",
+    "decode_unpack_off_cpu_share", "read_off_cpu_share",
+    "snapshot_off_cpu_share", "producer_on_cpu_share",
+    "prefetch_on_cpu_share", "caller_on_cpu_share", "host_busy_cores",
+    "host_preempts_per_replay")
+MAY_READ_ZERO = GC_METRICS[:2] + WORKER_METRICS[2:] + tuple(
+    m for m in HOSTCHAIN_METRICS
+    if m.endswith("_off_cpu_share") or m == "host_preempts_per_replay")
 
 
 def _facts(roots, stats, delta) -> dict:
@@ -453,7 +551,7 @@ def test_the_new_metric_files_are_these():
         listed = [m["name"] for m in json.load(fh)["per_layer"]]
     # in this order and together; later PRs' metrics follow them
     for group in (NEW_METRICS, GC_METRICS, KEY_METRICS, WORKER_METRICS,
-                  CHALLENGE_METRICS):
+                  CHALLENGE_METRICS, HOSTCHAIN_METRICS):
         at = listed.index(group[0])
         assert listed[at:at + len(group)] == list(group)
     files = {os.path.basename(p)[:-5] for p in glob.glob(
@@ -462,7 +560,8 @@ def test_the_new_metric_files_are_these():
 
 
 @pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS
-                         + WORKER_METRICS + CHALLENGE_METRICS)
+                         + WORKER_METRICS + CHALLENGE_METRICS
+                         + HOSTCHAIN_METRICS)
 def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     """A renamed span or counter fails here, not in a chip run."""
     roots, stats, _hash, delta = traced
@@ -473,7 +572,7 @@ def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
         assert key in facts[ns], f"{metric}: no fact {ns}/{key}"
     value = _reader_module().read(doc["reader"], facts)
     assert value is not None and value >= 0
-    assert value > 0 or metric in GC_METRICS[:2] + WORKER_METRICS[2:]
+    assert value > 0 or metric in MAY_READ_ZERO
     if metric.endswith("_share"):
         assert value <= 100.0
     if metric in ("decode_worker_share", "ed_challenge_native_share"):
@@ -481,6 +580,27 @@ def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     source = {"span_seconds": "program_span",
               "counter": "program_counter"}[doc["reader"]["num"][0][0]]
     assert doc["source"] == source
+
+
+@pytest.mark.parametrize("traced", ["in-thread"], indirect=True)
+@pytest.mark.parametrize("metric", HOSTCHAIN_METRICS)
+def test_hostchain_reader_with_the_decode_on_the_prefetch_thread(traced,
+                                                                 metric):
+    """The same fourteen where no decode worker is used: no reply is
+    unpickled, so that one share reads nothing, never a made-up number;
+    the prefetch thread's CPU time now holds the decode."""
+    roots, stats, _hash, delta = traced
+    assert delta["replay.decode.worker_blocks"] == 0
+    with open(os.path.join(BENCH, "layer_metrics", metric + ".json")) as fh:
+        reader = json.load(fh)["reader"]
+    value = _reader_module().read(reader, _facts(roots, stats, delta))
+    if metric == "decode_unpack_off_cpu_share":
+        assert value is None
+        return
+    assert value is not None and value >= 0
+    assert value > 0 or metric in MAY_READ_ZERO
+    if metric.endswith("_share"):
+        assert value <= 100.0
 
 
 @BOTH
@@ -506,6 +626,7 @@ def test_stages_never_exceed_their_outer_span(traced):
             ("stream.decode", ("decode.unpack",) if in_workers
              else DECODE_STAGES),
             ("window.host_seq", ("seq.header", "seq.body")),
+            ("seq.body", BODY_STAGES),
             ("window.submit", ("submit.split", "submit.pack_ed",
                                "submit.pack_vrf", "submit.pack_kes",
                                "submit.dispatch", "submit.fold"))):

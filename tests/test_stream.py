@@ -164,6 +164,36 @@ def test_stream_engine_matches_cpu_reference(chain_dir, tmp_path,
     assert prefetcher_threads_alive() == 0
 
 
+@pytest.mark.parametrize("killed", [False, True], ids=["clean", "killed"])
+def test_each_thread_reads_its_cpu_time_once_a_replay(chain_dir, tmp_path,
+                                                      killed):
+    """ISSUE 36: prefetcher, producer and caller each add their CPU
+    microseconds (and preempt count) to the registry when their part of
+    a replay ends, with span recording off and on every path out."""
+    from ouroboros_tpu.observe import metrics, spans
+    threads = ("prefetch", "producer", "caller")
+
+    def readings():
+        return {f"{kind}.{t}": metrics.REGISTRY.get(
+                    f"replay.thread_{kind}.{t}").value
+                for t in threads for kind in ("cpu_us", "preempts")}
+
+    assert not spans.RECORDER.enabled and metrics.REGISTRY.enabled
+    d = _fresh_db_dir(chain_dir, tmp_path)
+    GLOBAL_BETA_CACHE.clear()
+    c0 = readings()
+    if killed:
+        with pytest.raises(HardStop):
+            _engine(d, KillBackend(kill_at_window=3)).replay()
+    else:
+        assert _engine(d, AsyncStubBackend()).replay().all_valid
+    c1 = readings()
+    assert prefetcher_threads_alive() == 0
+    for t in threads:
+        assert c1[f"cpu_us.{t}"] > c0[f"cpu_us.{t}"], t
+        assert c1[f"preempts.{t}"] >= c0[f"preempts.{t}"], t
+
+
 def test_stream_crosses_fork_to_shelley(chain_dir, tmp_path,
                                         reference_hash):
     """The final state sits in the Shelley era — the hard-fork
