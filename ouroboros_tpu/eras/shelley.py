@@ -51,6 +51,7 @@ from ..crypto import ed25519_ref, kes as kes_mod, vrf_ref
 from ..crypto.backend import (
     Ed25519Req, GLOBAL_BETA_CACHE, KesReq, VrfReq,
 )
+from ..observe import metrics as _metrics
 from ..utils import cbor
 
 # header protocol-evidence fields (sign-the-header-minus-KES-sig convention)
@@ -635,10 +636,20 @@ class UtxoMap:
     """Persistent UTxO set: immutable view over a shared base dict plus an
     overlay (adds + deletes), so extending the chain by one block is
     O(inputs + outputs) instead of O(|UTxO|) — the tuple-freeze
-    representation made a mainnet-scale replay quadratic.  The overlay is
-    flattened into a fresh base every ~|base|/4 mutations, keeping lookup
-    chains one level deep while old states (LedgerDB's k snapshots) stay
-    valid because bases are never mutated in place.
+    representation made a mainnet-scale replay quadratic.
+
+    The unit of change is the BLOCK: `thaw()` hands the ledger walk one
+    private copy of the overlay, the walk spends from and adds to it for
+    every transaction of the block, and `freeze()` makes the block's one
+    new map from it.  There the overlay is flattened into a fresh base
+    once it has passed ~|base|/4 entries, keeping lookup chains one level
+    deep while old states (LedgerDB's k snapshots, every earlier
+    ExtLedgerState) stay valid: a base is never mutated in place, and an
+    overlay is not touched again once a map holds it.
+
+    The contract is `get` / `in` / `len`, `to_dict()` and iteration.  How
+    the entries are split over `_base` / `_adds` / `_dels` at a given
+    block is not: it depends on where the flatten rule was applied.
 
     Iteration yields sorted (txid, ix, addr, amount, assets) 5-tuples —
     the exact order of the old sorted-tuple representation, so
@@ -667,6 +678,15 @@ class UtxoMap:
         if key in self._dels:
             return default
         return self._base.get(key, default)
+
+    def getter(self):
+        """The cheapest callable that answers `get(key)` for this map:
+        where the overlay is empty (every map a flatten made) that is the
+        base's own `get`.  For a walk that looks up many keys in one
+        map."""
+        if self._adds or self._dels:
+            return self.get
+        return self._base.get
 
     def __contains__(self, key) -> bool:
         if key in self._adds:
@@ -697,25 +717,24 @@ class UtxoMap:
 
     __hash__ = None
 
-    def apply(self, spent, added) -> "UtxoMap":
-        """New map with `spent` keys removed and `added` (key, value)
-        pairs inserted — O(delta) amortized."""
-        adds = dict(self._adds)
-        dels = set(self._dels)
-        for k in spent:
-            # ALWAYS record the delete: popping only the overlay entry
-            # would resurrect a stale base entry if the same outpoint was
-            # deleted, re-created, and spent again
-            adds.pop(k, None)
-            dels.add(k)
-        for k, v in added:
-            adds[k] = v
-            dels.discard(k)
-        if len(adds) + len(dels) > max(64, len(self._base) // 4):
-            base = {k: v for k, v in self._base.items() if k not in dels}
-            base.update(adds)
-            return UtxoMap(base, {}, frozenset())
-        return UtxoMap(self._base, adds, frozenset(dels))
+    def thaw(self) -> tuple[dict, dict, set]:
+        """(base, adds, dels) for one block's walk: the base as it is,
+        shared and read-only, and a private copy of the overlay.  An
+        outpoint is live if it is in `adds`, or in `base` and not in
+        `dels`.  To spend one: `adds.pop(k, None); dels.add(k)` — ALWAYS
+        record the delete: popping only the overlay entry would resurrect
+        a stale base entry if the same outpoint was deleted, re-created,
+        and spent again.  To add one: `adds[k] = v; dels.discard(k)`."""
+        return self._base, dict(self._adds), set(self._dels)
+
+    @classmethod
+    def freeze(cls, base: dict, adds: dict, dels: set) -> "UtxoMap":
+        """The map a block's walk ends in — O(delta) amortized."""
+        if len(adds) + len(dels) > max(64, len(base) // 4):
+            flat = {k: v for k, v in base.items() if k not in dels}
+            flat.update(adds)
+            return cls(flat, {}, frozenset())
+        return cls(base, adds, frozenset(dels))
 
 
 def _freeze_utxo(utxo: dict) -> UtxoMap:
@@ -724,6 +743,14 @@ def _freeze_utxo(utxo: dict) -> UtxoMap:
 
 # Shelley-family eras in order; later eras accept earlier features
 SHELLEY_FAMILY = ("shelley", "allegra", "mary")
+_ALLEGRA = SHELLEY_FAMILY.index("allegra")
+_MARY = SHELLEY_FAMILY.index("mary")
+
+# the ledger walk's transactions, and those of them that carried none of
+# validity interval, mint, withdrawals, certificates or assets, so every
+# guard of the walk fell through; each added to once a block
+_TXS = _metrics.counter("ledger.shelley.txs")
+_LIGHT_TXS = _metrics.counter("ledger.shelley.light_txs")
 
 
 class ShelleyLedger(LedgerRules):
@@ -770,11 +797,11 @@ class ShelleyLedger(LedgerRules):
 
     @property
     def supports_validity(self) -> bool:
-        return self._era_ix >= SHELLEY_FAMILY.index("allegra")
+        return self._era_ix >= _ALLEGRA
 
     @property
     def supports_multiasset(self) -> bool:
-        return self._era_ix >= SHELLEY_FAMILY.index("mary")
+        return self._era_ix >= _MARY
 
     # -- state construction --------------------------------------------------
     def initial_state(self) -> ShelleyLedgerState:
@@ -895,8 +922,16 @@ class ShelleyLedger(LedgerRules):
         return self.ledger_view(self.tick(state, max(slot, state.slot)))
 
     # -- block application ---------------------------------------------------
+    # The unit of the walk is the block, and inside it each rule runs only
+    # for a transaction that carries what the rule is about: ONE walk with
+    # guards on what it can see (an empty tuple on the transaction, the
+    # era), which every transaction takes.  The order of the rules, and so
+    # of the errors a transaction can raise, is the order of the text.
+
     def _check_features(self, tx: ShelleyTx, slot: int) -> None:
-        """Era gating + validity-interval check (cheap, sequential)."""
+        """Era gating + validity-interval check (cheap, sequential).  The
+        walks call it only where it has something to look at: a validity
+        interval, or an era before multi-asset values."""
         if tx.validity:
             if not self.supports_validity:
                 raise LedgerError(
@@ -907,105 +942,148 @@ class ShelleyLedger(LedgerRules):
                 raise LedgerError(
                     f"tx {tx.txid.hex()[:12]} outside validity interval "
                     f"[{before}, {after}] at slot {slot}")
-        if (tx.mint or any(assets for _a, _m, assets in tx.outputs)) \
-                and not self.supports_multiasset:
-            raise LedgerError(
-                f"multi-asset values need mary, era is {self.era}")
+        if self._era_ix < _MARY:
+            carried = tx.mint
+            if not carried:
+                for out in tx.outputs:
+                    if out[2]:
+                        carried = True
+                        break
+            if carried:
+                raise LedgerError(
+                    f"multi-asset values need mary, era is {self.era}")
 
     def _apply_txs(self, state: ShelleyLedgerState,
                    block) -> ShelleyLedgerState:
-        utxo = state.utxo
+        # the block's ONE overlay: spent from and added to by every
+        # transaction, thrown away with the block if a rule raises, and
+        # frozen into the new state's map at the end
+        base, adds, dels = state.utxo.thaw()
+        slot = block.slot
+        gated = not self.supports_multiasset
         delegs = pools = None          # copied lazily: certs are rare
         rewards = retiring = None      # likewise
+        n_light = 0
         for tx in block.body:
-            self._check_features(tx, block.slot)
-            if len(set(tx.inputs)) != len(tx.inputs):
+            light = not tx.validity    # until a guard below finds work
+            if gated or not light:
+                self._check_features(tx, slot)
+            inputs = tx.inputs
+            if len(inputs) > 1 and len(set(inputs)) != len(inputs):
                 raise LedgerError(
                     f"tx {tx.txid.hex()[:12]} has duplicate inputs")
             spent = 0
-            consumed_assets: dict = {}
-            for txid, ix in tx.inputs:
-                entry = utxo.get((txid, ix))
+            consumed_assets = None     # a dict once anything carries assets
+            for key in inputs:
+                entry = adds.get(key)
                 if entry is None:
-                    raise LedgerError(
-                        f"missing input {txid.hex()[:12]}#{ix}")
-                _addr, amount, assets = entry
-                spent += amount
-                for aid, qty in assets:
-                    consumed_assets[aid] = consumed_assets.get(aid, 0) + qty
-            for pid, amount in tx.withdrawals:
+                    entry = None if key in dels else base.get(key)
+                    if entry is None:
+                        txid, ix = key
+                        raise LedgerError(
+                            f"missing input {txid.hex()[:12]}#{ix}")
+                adds.pop(key, None)
+                dels.add(key)
+                spent += entry[1]
+                if entry[2]:
+                    if consumed_assets is None:
+                        consumed_assets = {}
+                    for aid, qty in entry[2]:
+                        consumed_assets[aid] = \
+                            consumed_assets.get(aid, 0) + qty
+            if tx.withdrawals:
+                light = False
                 if rewards is None:
                     rewards = dict(state.rewards)
-                bal = rewards.get(pid, 0)
-                # WDRL: the claim must match the reward balance exactly
-                if amount <= 0 or amount != bal:
-                    raise LedgerError(
-                        f"tx {tx.txid.hex()[:12]}: withdrawal {amount} != "
-                        f"reward balance {bal} of {pid.hex()[:12]}")
-                del rewards[pid]
-                spent += amount
-            for aid, qty in tx.mint:
-                consumed_assets[aid] = consumed_assets.get(aid, 0) + qty
+                for pid, amount in tx.withdrawals:
+                    bal = rewards.get(pid, 0)
+                    # WDRL: the claim must match the reward balance exactly
+                    if amount <= 0 or amount != bal:
+                        raise LedgerError(
+                            f"tx {tx.txid.hex()[:12]}: withdrawal {amount} "
+                            f"!= reward balance {bal} of {pid.hex()[:12]}")
+                    del rewards[pid]
+                    spent += amount
+            if tx.mint:
+                if consumed_assets is None:
+                    consumed_assets = {}
+                for aid, qty in tx.mint:
+                    consumed_assets[aid] = consumed_assets.get(aid, 0) + qty
             produced = 0
-            produced_assets: dict = {}
-            for _addr, amount, assets in tx.outputs:
+            produced_assets = None
+            txid = tx.txid
+            ix = 0
+            for out in tx.outputs:     # (addr, amount, assets), kept as is
                 # Coin is non-negative by construction in the reference
-                if amount < 0:
+                if out[1] < 0:
                     raise LedgerError(
-                        f"tx {tx.txid.hex()[:12]} has a negative output")
-                produced += amount
-                for aid, qty in assets:
-                    if qty <= 0:
-                        raise LedgerError("output asset quantity must be "
-                                          "positive")
-                    produced_assets[aid] = produced_assets.get(aid, 0) + qty
+                        f"tx {txid.hex()[:12]} has a negative output")
+                produced += out[1]
+                if out[2]:
+                    if produced_assets is None:
+                        produced_assets = {}
+                    for aid, qty in out[2]:
+                        if qty <= 0:
+                            raise LedgerError("output asset quantity must "
+                                              "be positive")
+                        produced_assets[aid] = \
+                            produced_assets.get(aid, 0) + qty
+                key = (txid, ix)
+                adds[key] = out
+                dels.discard(key)
+                ix += 1
             if produced > spent:
                 raise LedgerError(
-                    f"tx {tx.txid.hex()[:12]} produces {produced} > "
+                    f"tx {txid.hex()[:12]} produces {produced} > "
                     f"spends {spent}")
-            consumed_assets = {a: q for a, q in consumed_assets.items()
-                               if q != 0}
-            if produced_assets != consumed_assets:
-                raise LedgerError(
-                    f"tx {tx.txid.hex()[:12]}: asset balance mismatch "
-                    f"(consumed+minted != produced)")
-            for kind, a, b in tx.certs:
+            if consumed_assets is not None or produced_assets is not None:
+                light = False
+                consumed_assets = {a: q for a, q
+                                   in (consumed_assets or {}).items()
+                                   if q != 0}
+                if (produced_assets or {}) != consumed_assets:
+                    raise LedgerError(
+                        f"tx {txid.hex()[:12]}: asset balance mismatch "
+                        f"(consumed+minted != produced)")
+            if tx.certs:
+                light = False
                 if pools is None:
                     delegs = dict(state.delegs)
                     pools = dict(state.pools)
-                if kind == CERT_POOL:
-                    pid = pool_id_of(a)
-                    pools[pid] = b
-                    if retiring is None:
-                        retiring = dict(state.retiring)
-                    # re-registration cancels a pending retirement
-                    retiring.pop(pid, None)
-                elif kind == CERT_DELEG:
-                    if b not in pools:
+                for kind, a, b in tx.certs:
+                    if kind == CERT_POOL:
+                        pid = pool_id_of(a)
+                        pools[pid] = b
+                        if retiring is None:
+                            retiring = dict(state.retiring)
+                        # re-registration cancels a pending retirement
+                        retiring.pop(pid, None)
+                    elif kind == CERT_DELEG:
+                        if b not in pools:
+                            raise LedgerError(
+                                f"delegation to unregistered pool "
+                                f"{b.hex()[:12]}")
+                        delegs[a] = b
+                    elif kind == CERT_RETIRE:
+                        pid = pool_id_of(a)
+                        if pid not in pools:
+                            raise LedgerError(
+                                f"retirement of unregistered pool "
+                                f"{pid.hex()[:12]}")
+                        epoch = int.from_bytes(b, "big")
+                        if epoch <= state.epoch:
+                            raise LedgerError(
+                                f"retirement epoch {epoch} not after the "
+                                f"current epoch {state.epoch}")
+                        if retiring is None:
+                            retiring = dict(state.retiring)
+                        retiring[pid] = epoch
+                    else:
                         raise LedgerError(
-                            f"delegation to unregistered pool "
-                            f"{b.hex()[:12]}")
-                    delegs[a] = b
-                elif kind == CERT_RETIRE:
-                    pid = pool_id_of(a)
-                    if pid not in pools:
-                        raise LedgerError(
-                            f"retirement of unregistered pool "
-                            f"{pid.hex()[:12]}")
-                    epoch = int.from_bytes(b, "big")
-                    if epoch <= state.epoch:
-                        raise LedgerError(
-                            f"retirement epoch {epoch} not after the "
-                            f"current epoch {state.epoch}")
-                    if retiring is None:
-                        retiring = dict(state.retiring)
-                    retiring[pid] = epoch
-                else:
-                    raise LedgerError(f"unknown certificate kind {kind!r}")
-            utxo = utxo.apply(
-                tx.inputs,
-                [((tx.txid, ix), (addr, amount, assets))
-                 for ix, (addr, amount, assets) in enumerate(tx.outputs)])
+                            f"unknown certificate kind {kind!r}")
+            n_light += light
+        _TXS.inc(len(block.body))
+        _LIGHT_TXS.inc(n_light)
         # block production accounting for the reward calculation (the
         # BlocksMade map); the mempool's header-less pseudo-blocks skip it
         blocks_made = state.blocks_made
@@ -1018,7 +1096,7 @@ class ShelleyLedger(LedgerRules):
             made[pid] = made.get(pid, 0) + 1
             blocks_made = tuple(sorted(made.items()))
         return _fast_replace(
-            state, utxo=utxo,
+            state, utxo=UtxoMap.freeze(base, adds, dels),
             delegs=state.delegs if delegs is None
             else tuple(sorted(delegs.items())),
             pools=state.pools if pools is None
@@ -1034,11 +1112,18 @@ class ShelleyLedger(LedgerRules):
                            tx: ShelleyTx) -> None:
         """Structural check: every spender, certificate authoriser, and
         minting policy has a witness (validity of the signatures is the
-        batchable proof)."""
-        utxo = state.utxo
-        wit_vks = {vk for vk, _ in tx.witnesses}
-        for txid, ix in tx.inputs:
-            entry = utxo.get((txid, ix))
+        batchable proof).  Reads `state`'s map, the block's start: an
+        input made earlier in the same block is not looked up here."""
+        self._check_witnesses(state.utxo.getter(), tx)
+
+    def _check_witnesses(self, lookup, tx: ShelleyTx) -> None:
+        """`check_tx_witnesses` against the map `lookup` reads."""
+        wits = tx.witnesses
+        # one witness (most of a chain): found by equality, not in a set
+        wit_vks = (wits[0][0],) if len(wits) == 1 \
+            else {vk for vk, _ in wits}
+        for key in tx.inputs:
+            entry = lookup(key)
             if entry is not None and entry[0] not in wit_vks:
                 raise LedgerError(
                     f"tx {tx.txid.hex()[:12]} spends from "
@@ -1053,26 +1138,31 @@ class ShelleyLedger(LedgerRules):
             if kind == CERT_RETIRE and a not in wit_vks:
                 raise LedgerError(
                     "pool retirement without the cold-key witness")
-        # withdrawals: the pool's cold key must witness the claim
-        wit_pids = {pool_id_of(vk) for vk in wit_vks}
-        for pid, _amt in tx.withdrawals:
-            if pid not in wit_pids:
-                raise LedgerError(
-                    f"withdrawal from {pid.hex()[:12]} without the pool "
-                    f"cold-key witness")
-        # minting: asset_id is the key-hash of the policy key, which must
-        # witness the tx (the Mary "policy script = key" base case)
-        policy_hashes = {pool_id_of(vk) for vk in wit_vks}
-        for aid, _qty in tx.mint:
-            if aid not in policy_hashes:
-                raise LedgerError(
-                    f"minting asset {aid.hex()[:12]} without its policy-key "
-                    f"witness")
+        if tx.withdrawals or tx.mint:
+            key_hashes = {pool_id_of(vk) for vk in wit_vks}
+            # withdrawals: the pool's cold key must witness the claim
+            for pid, _amt in tx.withdrawals:
+                if pid not in key_hashes:
+                    raise LedgerError(
+                        f"withdrawal from {pid.hex()[:12]} without the "
+                        f"pool cold-key witness")
+            # minting: asset_id is the key-hash of the policy key, which
+            # must witness the tx (the Mary "policy script = key" base
+            # case)
+            for aid, _qty in tx.mint:
+                if aid not in key_hashes:
+                    raise LedgerError(
+                        f"minting asset {aid.hex()[:12]} without its "
+                        f"policy-key witness")
 
     def sequential_checks(self, ticked: ShelleyLedgerState, block) -> None:
+        slot = block.slot
+        gated = not self.supports_multiasset
+        lookup = ticked.utxo.getter()
         for tx in block.body:
-            self._check_features(tx, block.slot)
-            self.check_tx_witnesses(ticked, tx)
+            if gated or tx.validity:
+                self._check_features(tx, slot)
+            self._check_witnesses(lookup, tx)
 
     def extract_proofs(self, ticked: ShelleyLedgerState, block) -> list:
         """The BBODY Ed25519 witness multi-verify, batched

@@ -511,6 +511,8 @@ HOSTCHAIN_METRICS = (
     "snapshot_off_cpu_share", "producer_on_cpu_share",
     "prefetch_on_cpu_share", "caller_on_cpu_share", "host_busy_cores",
     "host_preempts_per_replay")
+# the ledger walk's transaction counters (ISSUE 37), listed after PR 36's
+LEDGER_TX_METRICS = ("body_light_tx_share", "host_body_us_per_tx")
 MAY_READ_ZERO = GC_METRICS[:2] + WORKER_METRICS[2:] + tuple(
     m for m in HOSTCHAIN_METRICS
     if m.endswith("_off_cpu_share") or m == "host_preempts_per_replay")
@@ -551,7 +553,7 @@ def test_the_new_metric_files_are_these():
         listed = [m["name"] for m in json.load(fh)["per_layer"]]
     # in this order and together; later PRs' metrics follow them
     for group in (NEW_METRICS, GC_METRICS, KEY_METRICS, WORKER_METRICS,
-                  CHALLENGE_METRICS, HOSTCHAIN_METRICS):
+                  CHALLENGE_METRICS, HOSTCHAIN_METRICS, LEDGER_TX_METRICS):
         at = listed.index(group[0])
         assert listed[at:at + len(group)] == list(group)
     files = {os.path.basename(p)[:-5] for p in glob.glob(
@@ -561,7 +563,7 @@ def test_the_new_metric_files_are_these():
 
 @pytest.mark.parametrize("metric", NEW_METRICS + GC_METRICS + KEY_METRICS
                          + WORKER_METRICS + CHALLENGE_METRICS
-                         + HOSTCHAIN_METRICS)
+                         + HOSTCHAIN_METRICS + LEDGER_TX_METRICS)
 def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     """A renamed span or counter fails here, not in a chip run."""
     roots, stats, _hash, delta = traced
@@ -575,7 +577,8 @@ def test_layer_metric_reader_resolves_on_a_tiny_replay(traced, metric):
     assert value > 0 or metric in MAY_READ_ZERO
     if metric.endswith("_share"):
         assert value <= 100.0
-    if metric in ("decode_worker_share", "ed_challenge_native_share"):
+    if metric in ("decode_worker_share", "ed_challenge_native_share",
+                  "body_light_tx_share"):
         assert value == 100.0
     source = {"span_seconds": "program_span",
               "counter": "program_counter"}[doc["reader"]["num"][0][0]]
