@@ -1,7 +1,7 @@
 #!/usr/bin/env python
 """Step 0 of ISSUE 30, on the chip: what an Ed25519 lane costs the XLA
-split ladder by the width of the program, and whether a loop of T-lane
-tiles inside one program keeps the narrow program's cost.
+split ladder by the width of the program, and whether T-lane tiles run
+one after the other keep the narrow program's cost.
 
 Two tables, one JSON line a row (the whole file also lands in
 `chiprun_out/ed_width_sweep.jsonl`):
@@ -10,8 +10,10 @@ Two tables, one JSON line a row (the whole file also lands in
    real signatures (two keys, every message distinct, one lane
    tampered), device microseconds a lane from the profiler's trace and
    the device operations that took most of it.
-2. The same `--lanes` lanes through `jax_backend.ed_lanes_core` (what both
-   window composites trace) with `ED_TILE` set to each of `--tiles`.
+2. The same `--lanes` lanes as back-to-back calls of ONE program as wide
+   as each of `--tiles` (the window path's form since PR 38,
+   `JaxBackend._ed_tile_program`; PR 30 ran them as a loop inside one
+   program).
 
 Each program is compiled ahead of time, run `--reps` times under the
 host clock and once more under the profiler, and its row written before
@@ -149,9 +151,12 @@ def main() -> int:
             comp = jax.jit(flat).lower(*a).compile()
         else:
             a = head(args.lanes)
-            JB.ED_TILE = size           # read while tracing
-            comp = jax.jit(lambda *x: JB.ed_lanes_core(*x)).lower(
-                *a).compile()
+            tile = jax.jit(flat).lower(*head(size)).compile()
+            parts = [tuple(x[..., o:o + size] for x in a)
+                     for o in range(0, args.lanes, size)]
+
+            def comp(*_a, tile=tile, parts=parts):
+                return jax.numpy.concatenate([tile(*p) for p in parts])
         secs = time.perf_counter() - t0
         lanes = a[0].shape[-1]
 

@@ -284,6 +284,9 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
     window, permit-gated to the beta-carry depth."""
     protocol, ledger = ext_rules.protocol, ext_rules.ledger
     submit = backend.submit_window
+    begin = getattr(backend, "begin_replay", None)
+    if begin is not None:
+        begin()                 # what it counts window to window restarts
     # producer start; None once the first submit is made
     t_first: Optional[float] = _spans.monotonic_now()
 

@@ -91,7 +91,7 @@ def _fence() -> None:
 class Autotuner:
     """Measured pallas-vs-XLA choices for one (kernel rev, device kind).
 
-    Keys are tuples like ("vrf", 2048) or ("win", ne, nv, nb, nk); the
+    Keys are tuples like ("vrf", 2048) or ("win", nv, nb, nk); the
     value is True for pallas.  `pick` runners must BLOCK on their result
     (e.g. return np.asarray(...)) so a rep's wall time covers dispatch +
     compute + transfer."""

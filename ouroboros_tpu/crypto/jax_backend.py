@@ -17,8 +17,12 @@ because it is never the larger transfer.
 
 Batch sizes up to ED_TILE lanes are padded to power-of-two buckets (min
 128) so repeated calls hit the jit cache instead of recompiling per
-shape; a wider batch pads to a multiple of ED_TILE, and the window
-composite walks its Ed25519 lanes as ED_TILE-wide tiles (`ed_lanes_core`).
+shape.  A WINDOW's Ed25519 lanes are no program's shape at all: they go
+to the device as whole tiles, one asynchronous call of the ONE tile
+program a tile (`_ed_tile_program`; `ed_tile` lanes, ED_TILE on an
+accelerator), so a replay whose windows hold 1,500 lanes and 90,000
+builds what a replay of equal windows builds, and the device walks only
+the tiles that hold a real lane.
 
 Kernel selection is MEASURED, not assumed: on a TPU the fused pallas
 (Mosaic) kernels and the op-by-op XLA kernels are timed head-to-head
@@ -60,9 +64,16 @@ _FOLD_WINDOWS = _metrics.counter("jax_backend.fold_windows")
 # MULTICHIP_OBS line and the benchmark's `lane_pad_share` report
 _LANES_USED = _metrics.counter("jax_backend.lanes_used")
 _LANES_PADDED = _metrics.counter("jax_backend.lanes_padded")
-# ED_TILE-wide tiles ONE device walks for a window's Ed25519 lanes; a
-# window on the single-bucket path adds 0 (the "does it engage" reading)
+# tiles ONE device walks for a window's Ed25519 lanes (one call of the
+# tile program each), added once a window at dispatch
 _ED_TILES = _metrics.counter("jax_backend.ed_tiles")
+# a window's real Ed25519 lanes, and the lanes of the tiles handed to the
+# device(s) for them (never a capacity: only tiles that hold a real lane);
+# windows whose tile count differs from the previous window's of the same
+# replay (`begin_replay` forgets the previous one)
+_ED_LANES_REAL = _metrics.counter("jax_backend.ed_lanes_real")
+_ED_LANES_WALKED = _metrics.counter("jax_backend.ed_lanes_walked")
+_ED_WIDTH_CHANGES = _metrics.counter("jax_backend.ed_width_changes")
 # what a window's two occasional parts held (real work, not padding):
 # windows whose composite carried betas for the window two ahead and the
 # beta rows they carried; windows that scheduled no KES hash-path job
@@ -101,54 +112,29 @@ def _bucket(n: int, lo: int = 128) -> int:
     return m
 
 
-# Width of one Ed25519 tile of the XLA window composite, in lanes (a
-# multiple of pallas_kernels.TILE = 512, so the Pallas grid divides every
-# padded count too).  What a lane costs the XLA ladder is not flat in the
-# width of the program: 3.5 us at 2,048 and 4,096 lanes, 3.9 at 8,192,
-# 5.8 at 16,384, 7.8 at 32,768, 19.0 at 65,536 and 29.1 at 131,072 (a TPU
-# v5e; PERF.md section 6, PR 30, has the sweep and the device operations
-# that grow), and a loop of tiles costs what its tile does alone.  So a
-# device handed more than ED_TILE lanes walks them ED_TILE at a time
-# inside the one program.  Tests reach a small tile by monkeypatching
-# this name.
+# Width of one Ed25519 tile on an accelerator, in lanes (a multiple of
+# pallas_kernels.TILE = 512, so the Pallas grid divides it).  What a lane
+# costs the XLA ladder is not flat in the width of the program: 3.5 us at
+# 2,048 and 4,096 lanes, 3.9 at 8,192, 5.8 at 16,384, 7.8 at 32,768, 19.0
+# at 65,536 and 29.1 at 131,072 (a TPU v5e; PERF.md section 6, PR 30, has
+# the sweep and the device operations that grow).  So a window's lanes go
+# to the device ED_TILE at a time, one call of the one tile program a
+# tile (`JaxBackend._ed_tile_program`), and the power-of-two buckets
+# of the simple batch entry points top out here.  Tests reach a small
+# tile by monkeypatching this name.
 ED_TILE = 4096
 
 
-def ed_tiles(lanes: int) -> int:
-    """Tiles `ed_lanes_core` walks for `lanes` lanes on one device: 0 at
-    or under ED_TILE (the single-bucket program)."""
-    return lanes // ED_TILE if lanes > ED_TILE else 0
-
-
-def ed_lanes_core(Aw, xa, xw, yw, Rw, signR2, sw, kw):
-    """The XLA Ed25519 verification of the lanes ONE device is handed, as
-    both window composites trace it (the one-chip program over the whole
-    window, the mesh's over a shard): `verify_full_split_words_core` on
-    all of them at or under ED_TILE lanes — exactly the program of the
-    power-of-two buckets — and above it one ED_TILE-wide tile at a time
-    under `lax.map`, so the body is traced and compiled once whatever
-    the tile count and a tile's working set stays near the core.  Lanes
-    are independent, so the (lanes,) int32 verdicts are the flat
-    program's, lane for lane."""
-    lanes = Aw.shape[-1]
-    tiles = ed_tiles(lanes)
-    if not tiles:
-        return EJ.verify_full_split_words_core(
-            Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
-    assert lanes == tiles * ED_TILE, (lanes, ED_TILE)
-    from jax import lax
-
-    def tile_major(a):            # (rows, lanes) -> (tiles, rows, ED_TILE)
-        return a.reshape(a.shape[0], tiles, ED_TILE).transpose(1, 0, 2)
-
-    def one_tile(t):
-        tAw, txa, txw, tyw, tRw, tsR2, tsw, tkw = t
-        return EJ.verify_full_split_words_core(
-            tAw, txa, txw, tyw, tRw, tsR2[0], tsw, tkw)
-
-    return lax.map(one_tile, tuple(
-        tile_major(a) for a in (Aw, xa, xw, yw, Rw, signR2, sw, kw))
-    ).reshape(-1)
+def ed_tile_width(platform: str, narrowest: int) -> int:
+    """Lanes ONE device walks in one call of the Ed25519 tile program.
+    On an accelerator ED_TILE, the width at which the ladder's lane is
+    cheapest (the sweep above).  XLA:CPU has no such width: a lane costs
+    it the same in a 64-lane and a 4,096-lane program (7.0 and 6.5 ms,
+    sandbox), so a pad lane is never cheaper than a real one and the
+    `narrowest` program the backend builds is the tile: tests and CPU
+    rehearsals set it with `min_bucket`."""
+    return ED_TILE if platform in ("tpu", "gpu") else min(ED_TILE,
+                                                          narrowest)
 
 
 def batch_inverse(vals: list[int]) -> list[int]:
@@ -209,8 +195,10 @@ class JaxBackend(CryptoBackend):
             self._pk = PK
             min_bucket = max(min_bucket, PK.TILE)
         self.min_bucket = min_bucket
-        self._composites: dict = {}   # (ne, nv, nb, nk, pallas) -> program
-        self._folds: dict = {}        # (ne, nv, nb, nk) -> fold program
+        self.ed_tile = ed_tile_width(self.platform, self.min_bucket)
+        self._composites: dict = {}   # (nv, nb, nk, pallas) -> program
+        self._folds: dict = {}        # (nv, nb, nk) -> fold program
+        self._ed_tile_programs: dict = {}  # (pallas, fold) -> tile program
         self._pk_vrf_folds: dict = {} # m -> jitted pallas verify+fold
         # donate the window inputs to the composite so a warm-path window
         # reuses the previous window's device buffers instead of
@@ -230,22 +218,59 @@ class JaxBackend(CryptoBackend):
         self._lanes_used = 0
         self._lanes_padded = 0
         self._windows_padded = 0
+        # tiles one device walked for the previous window of this replay
+        self._prev_ed_tiles = None
 
-    # -- subclass seams (ShardedJaxBackend overrides both) -------------------
+    # -- subclass seams (ShardedJaxBackend overrides them) -------------------
     def _pad(self, n: int) -> int:
-        """Batch padding: power-of-two buckets from `min_bucket` up to
-        ED_TILE lanes, above that the next multiple of ED_TILE (a
-        window's 90,624 Ed25519 lanes pad to 94,208, not 131,072).  The
-        mesh backend pads to a mesh multiple, and to whole tiles a shard
-        once a shard is wider than ED_TILE."""
+        """Padding of every batch but a window's Ed25519 lanes (the
+        simple batch entry points; a window's VRF, beta and KES parts):
+        power-of-two buckets from `min_bucket` up to ED_TILE lanes, above
+        that the next multiple of ED_TILE.  The mesh backend pads to a
+        mesh multiple.  A window's Ed25519 lanes pad to whole tiles
+        (`_pad_ed_window`) and are no program's shape."""
         m = _bucket(n, self.min_bucket)
         return m if m <= ED_TILE else -(-n // ED_TILE) * ED_TILE
+
+    def _pad_ed_window(self, n: int) -> int:
+        """Lanes the device(s) walk for a window of `n` Ed25519 lanes:
+        whole tiles of `ed_tile` lanes a device, at least one; 0 for
+        none.  Only tiles that hold a real lane."""
+        step = self.ed_tile * self.n_shards
+        return -(-n // step) * step
 
     def _dev(self, a):
         """Host array -> device array for a lane-axis-last batch input;
         the mesh backend device_puts with the window-axis sharding."""
         import jax.numpy as jnp
         return jnp.asarray(a)
+
+    def _tiles(self, arrays, ne: int) -> list:
+        """A window's Ed25519 lane arrays ((rows, ne) each, lane axis
+        last) cut into one tuple of host arrays a tile call: tile t
+        holds lanes [t * step, (t + 1) * step), step = `ed_tile` lanes a
+        device."""
+        step = self.ed_tile * self.n_shards
+        return [tuple(a[:, off:off + step] for a in arrays)
+                for off in range(0, ne, step)]
+
+    def _dev_tiles(self, arrays, ne: int) -> list:
+        """`_tiles` on the device(s), in ONE device_put (the mesh
+        backend splits each tile's lane axis over the mesh)."""
+        import jax
+        return jax.device_put(self._tiles(arrays, ne))
+
+    def _dev_scalar(self, v: int):
+        """One int32 on the device(s): the fold's running first-bad
+        index before the first tile (the mesh backend replicates it)."""
+        import jax
+        return jax.device_put(np.int32(v))   # a copy, no program
+
+    def begin_replay(self) -> None:
+        """A new sequence of windows starts (the replay driver's
+        producer says so): its first window has no previous window to
+        differ from (`jax_backend.ed_width_changes`)."""
+        self._prev_ed_tiles = None
 
     # -- lane occupancy ------------------------------------------------------
     def _note_padding(self, used: int, padded: int) -> None:
@@ -338,10 +363,11 @@ class JaxBackend(CryptoBackend):
         return self._tuner.measure(key, run_pallas, run_xla)
 
     # -- host prep ----------------------------------------------------------
-    def _prep_ed(self, reqs, m: int):
+    def _pack_ed(self, reqs, m: int):
         """Packed-words prep + A128 assembly for an Ed25519 batch padded
-        to m.  Returns (dev_args, parse_ok); keys the cache could not
-        decompress are masked out of parse_ok (the kernels trust the
+        to m, on the host.  Returns (the eight (rows, m) lane arrays of
+        `verify_full_split_words_core`, parse_ok); keys the cache could
+        not decompress are masked out of parse_ok (the kernels trust the
         cached affine x and skip the A square root)."""
         pad = m - len(reqs)
         vks = [r.vk for r in reqs] + [b"\x00" * 32] * pad
@@ -351,11 +377,14 @@ class JaxBackend(CryptoBackend):
             [r.sig for r in reqs] + [b"\x00" * 64] * pad)
         Aw, _signA, Rw, signR, sw, kw = arrays
         xa, xw, yw, known = EJ.GLOBAL_A128_CACHE.assemble(vks)
-        args = (self._dev(Aw), self._dev(xa),
-                self._dev(xw), self._dev(yw),
-                self._dev(Rw), self._dev(signR.reshape(1, -1)),
-                self._dev(sw), self._dev(kw))
-        return args, parse_ok & known
+        return ((Aw, xa, xw, yw, Rw, signR.reshape(1, -1), sw, kw),
+                parse_ok & known)
+
+    def _prep_ed(self, reqs, m: int):
+        """`_pack_ed` with every array on the device: one program as
+        wide as the batch (the simple batch entry point)."""
+        arrays, parse_ok = self._pack_ed(reqs, m)
+        return tuple(self._dev(a) for a in arrays), parse_ok
 
     def _ed_dispatch(self, args, m: int, use_pallas: bool):
         """Async-dispatch one prepared Ed25519 batch; (m,) int32 handle."""
@@ -566,28 +595,88 @@ class JaxBackend(CryptoBackend):
             return self._pk._kes_hash_jit(mw, ew, m).reshape(-1)
         return B2.check_block64_jit(mw, ew)
 
-    def _window_composite(self, ne: int, nv: int, nb: int, nk: int,
-                          pallas: bool):
-        """One jitted device program for a whole window: Ed25519 verify +
-        VRF verify + next-window gamma8 betas + KES hash checks, results
-        concatenated into the packed flat uint8 buffer on device.  ONE
-        launch per window instead of one per part.  (On a TPU v5 lite a
-        part's whole dispatch + compute + drain took 1-19 ms — smoke
-        reading, PR 22, ROADMAP A2 — so a launch is cheap there, and
-        the price of the fusion is that every window SHAPE is its own
-        multi-minute compile — ROADMAP A9/C5.)
+    def _ed_tile_program(self, pallas: bool, fold: bool):
+        """THE Ed25519 program of the window path: verify one tile of
+        `ed_tile` lanes.  A window of T tiles is T asynchronous calls of
+        it, so the tile COUNT is no program's shape: a replay builds
+        this once whatever widths its windows have, and the device walks
+        only the tiles it is handed.  The XLA form is
+        `verify_full_split_words_core` at the width the power-of-two
+        buckets top out at; the Pallas form its own grid over the same
+        tile.  (What T launches cost the producer: span submit.ed_tiles;
+        PERF.md section 6, PR 38.)
+
+        fold=True: `(first_bad, own, Aw, xa, xw, yw, Rw, signR2, sw, kw)
+        -> first_bad`, the tile's verdicts folded into the running
+        first-bad request index: `own` (1, tile) int32 holds each lane's
+        request index, FOLD_SENT on a pad lane and on a lane the host
+        already knows is bad; each call is handed the scalar the one
+        before returned.  fold=False: `(Aw, ..., kw) -> (tile,) uint8`
+        verdicts.  The inputs are donated: fresh every window, never
+        read again."""
+        fn = self._ed_tile_programs.get((pallas, fold))
+        if fn is not None:
+            return fn
+        import jax
+        return self._keep_ed_tile_program(pallas, fold, jax.jit(
+            self._ed_tile_body(pallas, fold),
+            donate_argnums=self._ed_tile_donated(fold)))
+
+    def _ed_tile_body(self, pallas: bool, fold: bool, across=None):
+        """The tile program before `jit`: what ONE device does with its
+        `ed_tile` lanes.  `across` names the mesh axis the shards'
+        first-bad indexes meet over (the mesh backend's, under
+        shard_map)."""
+        import jax
+        import jax.numpy as jnp
+        tile = self.ed_tile
+        PK = getattr(self, "_pk", None)
+
+        def verify(Aw, xa, xw, yw, Rw, signR2, sw, kw):
+            if pallas:
+                return PK._ed25519_split_call(
+                    Aw, xa, xw, yw, Rw, signR2, sw, kw, tile).reshape(-1)
+            return EJ.verify_full_split_words_core(
+                Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
+
+        def fold_tile(first_bad, own, *lanes):
+            bad = jnp.min(jnp.where(verify(*lanes) != 0, FOLD_SENT, own[0]))
+            if across is not None:
+                bad = jax.lax.pmin(bad, across)
+            return jnp.minimum(first_bad, bad)
+
+        def verdicts(*lanes):
+            return verify(*lanes).astype(jnp.uint8)
+
+        return fold_tile if fold else verdicts
+
+    def _ed_tile_donated(self, fold: bool) -> tuple:
+        """Every input of the tile program: fresh every window, never
+        read again."""
+        return tuple(range(10 if fold else 8)) if self._donate else ()
+
+    def _keep_ed_tile_program(self, pallas: bool, fold: bool, fn):
+        fn = _compile_span_on_first_call(
+            fn, f"window.ed_tile({self.ed_tile},fold={int(fold)})")
+        self._ed_tile_programs[(pallas, fold)] = fn
+        return fn
+
+    def _window_composite(self, nv: int, nb: int, nk: int, pallas: bool):
+        """One jitted device program for the parts of a window whose
+        widths the protocol fixes: VRF verify + next-window gamma8 betas
+        + KES hash checks, results concatenated into the packed flat
+        uint8 buffer on device.  ONE launch instead of one per part.
+        (The Ed25519 lanes, whose count follows the bodies, are not in
+        it: `_ed_tile_program`.  Every composite SHAPE is its own
+        compile of a minute or more, so the three widths are few by
+        construction: `_occasional_widths`.)
 
         The program is HOMOGENEOUS (all ladder parts pallas or all XLA):
         mixing an op-by-op XLA ladder into a pallas composite made XLA's
         compile of the combined program pathological (>1h at replay
         shapes, vs minutes for either pure form), and only the chosen
-        form is ever compiled.
-
-        The XLA form walks more than ED_TILE Ed25519 lanes as ED_TILE-wide
-        tiles inside the one program (`ed_lanes_core`: same verdicts at
-        the same offsets of the packed buffer); the Pallas form keeps
-        its own 512-lane grid over the tile-multiple `ne`."""
-        key = (ne, nv, nb, nk, pallas)
+        form is ever compiled."""
+        key = (nv, nb, nk, pallas)
         fn = self._composites.get(key)
         if fn is not None:
             return fn
@@ -597,14 +686,8 @@ class JaxBackend(CryptoBackend):
         from . import vrf_jax
         PK = getattr(self, "_pk", None)
 
-        def call(ed_args, vrf_args, beta_args, kes_args):
+        def call(vrf_args, beta_args, kes_args):
             parts = []
-            if ed_args is not None:
-                if pallas:
-                    ok = PK._ed25519_split_call(*ed_args, ne)
-                else:
-                    ok = ed_lanes_core(*ed_args)
-                parts.append(ok.reshape(-1).astype(jnp.uint8))
             if vrf_args is not None:
                 if pallas:
                     rows = PK._vrf_verify_call(*vrf_args, nv)
@@ -633,60 +716,64 @@ class JaxBackend(CryptoBackend):
         # them in place — the double-buffered replay (two windows in
         # flight, consensus/batch.py) stops reallocating device memory
         # every window.  CPU ignores donation (warns), hence the gate.
-        fn = jax.jit(call, donate_argnums=(0, 1, 2, 3)) if self._donate \
+        fn = jax.jit(call, donate_argnums=(0, 1, 2)) if self._donate \
             else jax.jit(call)
         _COMPOSITE_BUILDS.inc()
         fn = _compile_span_on_first_call(
-            fn, f"window.composite({ne},{nv},{nb},{nk})")
+            fn, f"window.composite({nv},{nb},{nk})")
         self._composites[key] = fn
         return fn
 
-    def _occasional_widths(self, ne: int, nv: int, nb: int,
-                           nk: int) -> tuple:
-        """(nb, nk) for a window whose beta and KES parts need `nb` and
-        `nk` lanes: those of the narrowest composite this backend has
-        ALREADY built for the same Ed25519 and VRF widths that holds
-        both, else their own.
+    def _occasional_widths(self, nv: int, nb: int, nk: int) -> tuple:
+        """(nv, nb, nk) for a window whose VRF, beta and KES parts need
+        that many lanes: those of the narrowest composite this backend
+        has ALREADY built that holds all three, else their own.
 
-        A sync meets these two parts at several widths: betas ride in
-        every window but a chain's last two, KES hash jobs only in the
-        windows that first meet a pool's hash path (and whether window
-        1 does is a race with window 0's drain).  Every (ne, nv, nb, nk)
-        is its own program, a minute or more to trace, lower and build
-        or load, where the empty lanes of a wider part cost the device
-        microseconds (PERF.md section 6, PR 33).  So a window rides a
-        built program that covers it rather than building its own, and
-        a chain's first window, the widest in both parts, fixes the
-        program for the rest."""
+        A sync meets these parts at several widths: betas ride in every
+        window but a chain's last two, KES hash jobs only in the windows
+        that first meet a pool's hash path (and whether window 1 does is
+        a race with window 0's drain), and a chain's last window, or one
+        cut short at an invalid header, has fewer VRF lanes than the
+        rest.  Every (nv, nb, nk) is its own program, a minute or more
+        to trace, lower and build or load, where the empty lanes of a
+        wider part cost the device microseconds (PERF.md section 6, PR
+        33).  So a window rides a built program that covers it rather
+        than building its own, and a chain's first window, the widest in
+        all three, fixes the program for the rest.  A window with no VRF
+        lane at all (a Byron window) keeps a program of its own."""
         best = None
-        for e, v, b, k, _pallas in self._composites:
-            if e == ne and v == nv and b >= nb and k >= nk and (
-                    best is None or b + k < best[0] + best[1]):
-                best = (b, k)
-        return best or (nb, nk)
+        for v, b, k, _pallas in self._composites:
+            if v >= nv and bool(v) == bool(nv) and b >= nb and k >= nk \
+                    and (best is None or v + b + k < sum(best)):
+                best = (v, b, k)
+        return best or (nv, nb, nk)
 
     def submit_window(self, reqs, next_beta_proofs=(), fold: bool = False):
         """Dispatch one replay window's whole device workload — the mixed
         Ed25519/VRF/KES verification of `reqs` AND the VRF betas the NEXT
-        window's sequential pass will need — as ONE fused device program
-        whose results are packed into ONE flat uint8 array: the
-        latency-bound host<->device link is crossed once per window, and
-        the launch overhead is paid once instead of per kernel.  Returns
-        an opaque state for finish_window.
+        window's sequential pass will need.  The Ed25519 lanes go as T
+        asynchronous calls of the one tile program, the other three
+        parts as ONE fused program whose results are packed into ONE
+        flat uint8 array.  Returns an opaque state for finish_window.
 
-        With fold=True the per-proof verdicts never cross the link: a
-        second tiny device program reduces the composite's packed output
-        to the FIRST failing request index (on-device SHA-512 challenge
-        fold for VRF — sha512_jax), and finish_window returns a
-        WindowVerdict scalar pair instead of the boolean vector.  The
-        big ladder composite is SHARED between both modes (same program,
-        same autotuned choice, same compile), so a fold caller costs one
-        extra small compile, not a second composite.
+        With fold=True the per-proof verdicts never cross the link: each
+        tile call folds its verdicts into a running first-bad index on
+        the device, and a second tiny program reduces the composite's
+        packed output (on-device SHA-512 challenge fold for VRF —
+        sha512_jax) and that index to the FIRST failing request index;
+        finish_window returns a WindowVerdict scalar pair instead of the
+        boolean vector, after ONE transfer.  The composite is SHARED
+        between both modes (same program, same autotuned choice, same
+        compile); without fold the tiles run the bucket program of their
+        width and finish_window fetches their verdicts.
 
         `window.submit` holds one span a stage: submit.split,
-        submit.pack_ed (key tables included), submit.pack_vrf (beta
-        words included), submit.pack_kes, submit.dispatch (the choice
-        and the composite call) and, folding, submit.fold."""
+        submit.pack_ed (key tables and the tiles' copy to the device
+        included), submit.pack_vrf (beta words included),
+        submit.pack_kes, folding submit.fold (the lanes' owner rows,
+        which the tile calls read, and their copy to the device), and
+        submit.dispatch (the choice, submit.ed_tiles = the T tile calls,
+        the composite call and, folding, the fold program's)."""
         with _spans.span("window.submit", cat="dispatch", cpu=True):
             return self._submit_window(reqs, next_beta_proofs, fold)
 
@@ -699,31 +786,39 @@ class JaxBackend(CryptoBackend):
              kes_msgs, kes_expects, kes_checks, n) = \
                 self._split_mixed_device(reqs)
             beta_proofs = list(dict.fromkeys(next_beta_proofs))
-            ne, nv, nb, nk = (self._pad(len(part)) if part else 0
-                              for part in (ed_reqs, vrf_reqs, beta_proofs,
-                                           kes_msgs))
-            nb, nk = self._occasional_widths(ne, nv, nb, nk)
+            ne = self._pad_ed_window(len(ed_reqs))
+            nv, nb, nk = self._occasional_widths(*(
+                self._pad(len(part)) if part else 0
+                for part in (vrf_reqs, beta_proofs, kes_msgs)))
         if beta_proofs:
             _BETA_WINDOWS.inc()
             _BETA_ROWS.inc(len(beta_proofs))
         if not kes_msgs:
             _KES_EMPTY.inc()
-        ed_state = vrf_state = beta_state = None
-        ed_args = vrf_args = beta_args = kes_args = None
+        state = {"packed": None, "n": n, "fold": fold,
+                 "ed": None, "ed_owner": ed_owner, "ne": ne,
+                 "vrf": None, "vrf_owner": vrf_owner,
+                 "vrf_n": len(vrf_reqs), "nv": nv,
+                 "beta": None, "beta_proofs": beta_proofs, "nb": nb,
+                 "kes_checks": kes_checks, "nk": nk,
+                 "kes_n": len(kes_msgs)}
+        vrf_args = beta_args = kes_args = None
+        tiles: list = []
         with _spans.span("submit.pack_ed", cat="dispatch"):
             if ne:
-                ed_args, parse_ok = self._prep_ed(ed_reqs, ne)
-                ed_state = (None, parse_ok)
+                ed_arrays, parse_ok = self._pack_ed(ed_reqs, ne)
+                state["ed"] = (None, parse_ok)
+                tiles = self._dev_tiles(ed_arrays, ne)
         with _spans.span("submit.pack_vrf", cat="dispatch"):
             if nv:
                 vrf_args, masks = self._prep_vrf(vrf_reqs, nv)
-                vrf_state = (None,) + masks
+                state["vrf"] = (None,) + masks
             if nb:
                 padded = beta_proofs + [b"\x00" * 80] * (
                     nb - len(beta_proofs))
                 (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(
                     padded)
-                beta_state = (decode_ok,)
+                state["beta"] = (decode_ok,)
                 beta_args = (self._dev(Gw),
                              self._dev(signG.reshape(1, -1)))
         with _spans.span("submit.pack_kes", cat="dispatch"):
@@ -732,87 +827,103 @@ class JaxBackend(CryptoBackend):
         self._note_padding(
             len(ed_reqs) + len(vrf_reqs) + len(beta_proofs) + len(kes_msgs),
             ne + nv + nb + nk)
-        with _spans.span("submit.dispatch", cat="dispatch"):
-            if (ed_args is None and vrf_args is None and beta_args is None
-                    and kes_args is None):
-                packed = None
-            else:
-                allp = self._window_choice(ne, nv, nb, nk, ed_args,
-                                           vrf_args, beta_args, kes_args)
-                packed = self._window_composite(ne, nv, nb, nk, allp)(
-                    ed_args, vrf_args, beta_args, kes_args)
-                if not allp:
-                    _ED_TILES.inc(ed_tiles(ne // self.n_shards))
-        state = {"packed": packed, "n": n,
-                 "ed": ed_state, "ed_owner": ed_owner, "ne": ne,
-                 "vrf": vrf_state, "vrf_owner": vrf_owner,
-                 "vrf_n": len(vrf_reqs), "nv": nv,
-                 "beta": beta_state, "beta_proofs": beta_proofs, "nb": nb,
-                 "kes_checks": kes_checks, "nk": nk,
-                 "kes_n": len(kes_msgs)}
+        own_tiles = vrf_own = None
         if fold:
             with _spans.span("submit.fold", cat="dispatch"):
-                self._attach_fold(state, reqs)
+                ed_own, vrf_own = self._fold_owners(state)
+                own_tiles = self._dev_tiles((ed_own.reshape(1, -1),), ne)
+        with _spans.span("submit.dispatch", cat="dispatch"):
+            if tiles or nv or nb or nk:
+                allp = self._window_choice(
+                    nv, nb, nk, tiles[0] if tiles else None,
+                    vrf_args, beta_args, kes_args)
+            if tiles:
+                self._note_ed_tiles(len(ed_reqs), ne)
+                run = self._ed_tile_program(allp, fold)
+                with _spans.span("submit.ed_tiles", cat="dispatch"):
+                    if fold:
+                        bad = self._dev_scalar(FOLD_SENT)
+                        for (own,), tile in zip(own_tiles, tiles):
+                            bad = run(bad, own, *tile)
+                        state["ed_bad"] = bad
+                    else:
+                        state["ed_ok"] = [run(*tile) for tile in tiles]
+            if nv or nb or nk:
+                state["packed"] = self._window_composite(
+                    nv, nb, nk, allp)(vrf_args, beta_args, kes_args)
+            if fold and (tiles or state["packed"] is not None):
+                self._attach_fold(state, vrf_own)
         return state
 
-    def _attach_fold(self, state, reqs) -> None:
-        """Reduce the window's packed verdict buffer on device to [first
-        failing request index (4 B LE) | KES job flags | beta rows].
+    def _note_ed_tiles(self, real: int, walked: int) -> None:
+        """Count one window's Ed25519 tiles at dispatch."""
+        tiles = walked // (self.ed_tile * self.n_shards)
+        _ED_TILES.inc(tiles)
+        _ED_LANES_REAL.inc(real)
+        _ED_LANES_WALKED.inc(walked)
+        if self._prev_ed_tiles not in (None, tiles):
+            _ED_WIDTH_CHANGES.inc()
+        self._prev_ed_tiles = tiles
+
+    def _fold_owners(self, state) -> tuple:
+        """The request index each Ed25519 and VRF lane answers for, as
+        the device fold reads it: ((ne,) int32, (nv,) int32), FOLD_SENT
+        on pad lanes.
 
         Host-known failures (undecodable keys/sigs, structurally invalid
         KES, known-bad cached hash paths) never reach the device fold:
         their lanes carry the sentinel owner and their minimum index is
-        kept in `host_first_bad` for finish_window to merge.  The KES
-        job flags still cross the link raw — they exist only on COLD
-        hash paths and the precompute cache must see each path's
-        outcome; warm windows ship zero of them."""
+        kept in `host_first_bad` for finish_window to merge."""
+        n = state["n"]
+        covered = np.zeros(n, dtype=bool)
+        host_bad = FOLD_SENT
+        owns = []
+        for part, lanes in (("ed", state["ne"]), ("vrf", state["nv"])):
+            own = np.full(lanes, FOLD_SENT, np.int32)
+            if state[part] is not None:
+                owner = np.asarray(state[part + "_owner"], np.int32)
+                ok = np.asarray(state[part][1], dtype=bool)[:owner.size]
+                covered[owner] = True
+                own[:owner.size] = np.where(ok, owner, FOLD_SENT)
+                if not ok.all():
+                    host_bad = min(host_bad, int(owner[~ok].min()))
+            owns.append(own)
+        uncovered = np.flatnonzero(~covered)
+        if uncovered.size and uncovered[0] < host_bad:
+            host_bad = int(uncovered[0])
+        state["host_first_bad"] = host_bad
+        return tuple(owns)
+
+    def _attach_fold(self, state, vrf_own) -> None:
+        """Reduce the window's device verdicts to [first failing request
+        index (4 B LE) | KES job flags | beta rows]: the composite's
+        packed buffer and the tiles' running first-bad index through the
+        fold program.  The KES job flags still cross the link raw — they
+        exist only on COLD hash paths and the precompute cache must see
+        each path's outcome; warm windows ship zero of them."""
         import jax.numpy as jnp
         _FOLD_WINDOWS.inc()
-        n = state["n"]
-        ne, nv = state["ne"], state["nv"]
-        covered = np.zeros(max(n, 1), dtype=bool)
-        host_bad = FOLD_SENT
-        ed_own = np.full(ne, FOLD_SENT, np.int32)
-        if state["ed"] is not None:
-            po = np.asarray(state["ed"][1], dtype=bool)
-            for k, i in enumerate(state["ed_owner"]):
-                covered[i] = True
-                if po[k]:
-                    ed_own[k] = i
-                elif i < host_bad:
-                    host_bad = i
-        vrf_own = np.full(nv, FOLD_SENT, np.int32)
+        nv = state["nv"]
         gamma_b = np.zeros((nv, 32), np.uint8)
         c_b = np.zeros((nv, 16), np.uint8)
         if state["vrf"] is not None:
-            _h, parse_ok, _gok, _sok, pf_arr = state["vrf"]
-            pv = np.asarray(parse_ok, dtype=bool)
+            pf_arr = state["vrf"][4]
             gamma_b = np.ascontiguousarray(pf_arr[:, :32])
             c_b = np.ascontiguousarray(pf_arr[:, 32:48])
-            for k, i in enumerate(state["vrf_owner"]):
-                covered[i] = True
-                if pv[k]:
-                    vrf_own[k] = i
-                elif i < host_bad:
-                    host_bad = i
-        uncovered = np.flatnonzero(~covered[:n])
-        if uncovered.size and uncovered[0] < host_bad:
-            host_bad = int(uncovered[0])
-        state["fold"] = True
-        state["host_first_bad"] = host_bad
-        if state["packed"] is not None:
-            state["packed"] = self._fold_program(
-                ne, nv, state["nb"], state["nk"])(
-                    state["packed"], jnp.asarray(ed_own),
-                    jnp.asarray(vrf_own), jnp.asarray(gamma_b),
-                    jnp.asarray(c_b))
+        ed_bad = state.pop("ed_bad", None)
+        if ed_bad is None:
+            ed_bad = self._dev_scalar(FOLD_SENT)
+        state["packed"] = self._fold_program(nv, state["nb"], state["nk"])(
+            state["packed"], ed_bad, jnp.asarray(vrf_own),
+            jnp.asarray(gamma_b), jnp.asarray(c_b))
 
-    def _fold_program(self, ne: int, nv: int, nb: int, nk: int):
-        """Jitted verdict reduction over one window's packed buffer.
-        Output layout: [first-bad index, uint32 LE (FOLD_SENT = none)
-        | nk KES job flags | nb*33 beta rows] — the transfer shrinks
-        from ne + 130*nv + ... to 4 + nk + 33*nb bytes."""
-        key = (ne, nv, nb, nk)
+    def _fold_program(self, nv: int, nb: int, nk: int):
+        """Jitted verdict reduction over one window's packed buffer and
+        the Ed25519 tiles' first-bad index.  Output layout: [first-bad
+        index, uint32 LE (FOLD_SENT = none) | nk KES job flags | nb*33
+        beta rows] — the transfer shrinks from ne + 130*nv + ... to
+        4 + nk + 33*nb bytes."""
+        key = (nv, nb, nk)
         fn = self._folds.get(key)
         if fn is not None:
             return fn
@@ -821,41 +932,38 @@ class JaxBackend(CryptoBackend):
 
         from . import vrf_jax
 
-        def fold(flat, ed_own, vrf_own, gamma_b, c_b):
+        def fold(flat, ed_bad, vrf_own, gamma_b, c_b):
             off = 0
-            m = jnp.int32(FOLD_SENT)
-            if ne:
-                ed_ok = flat[:ne]
-                m = jnp.minimum(m, jnp.min(
-                    jnp.where(ed_ok != 0, FOLD_SENT, ed_own)))
-                off += ne
+            m = ed_bad
             if nv:
-                rows = flat[off:off + nv * 130].reshape(nv, 130)
+                rows = flat[:nv * 130].reshape(nv, 130)
                 ok = vrf_jax.challenge_ok_device(rows, gamma_b, c_b)
                 m = jnp.minimum(m, jnp.min(
                     jnp.where(ok, FOLD_SENT, vrf_own)))
                 off += nv * 130
-            beta_part = flat[off:off + nb * 33]
-            off += nb * 33
-            kes_part = flat[off:off + nk]
             idx = m.astype(jnp.uint32)
-            idx4 = jnp.stack([idx & 0xFF, (idx >> 8) & 0xFF,
-                              (idx >> 16) & 0xFF,
-                              (idx >> 24) & 0xFF]).astype(jnp.uint8)
-            return jnp.concatenate([idx4, kes_part, beta_part])
+            parts = [jnp.stack([idx & 0xFF, (idx >> 8) & 0xFF,
+                                (idx >> 16) & 0xFF,
+                                (idx >> 24) & 0xFF]).astype(jnp.uint8)]
+            if nk:
+                parts.append(flat[off + nb * 33:off + nb * 33 + nk])
+            if nb:
+                parts.append(flat[off:off + nb * 33])
+            return jnp.concatenate(parts)
 
         # the composite's packed output is consumed here and never read
         # again — donate it so the fold reuses its buffer
         fn = jax.jit(fold, donate_argnums=(0,)) if self._donate \
             else jax.jit(fold)
         fn = _compile_span_on_first_call(
-            fn, f"window.fold({ne},{nv},{nb},{nk})")
+            fn, f"window.fold({nv},{nb},{nk})")
         self._folds[key] = fn
         return fn
 
-    def _window_choice(self, ne, nv, nb, nk, ed_args, vrf_args,
+    def _window_choice(self, nv, nb, nk, ed_tile_args, vrf_args,
                        beta_args, kes_args) -> bool:
-        """Homogeneous pallas-vs-XLA choice for one window shape.
+        """Homogeneous pallas-vs-XLA choice for one window shape (the
+        tile program's form with the composite's).
 
         A pinned ("win", ...) choice (persisted by an earlier run, or
         voted earlier in this one) returns with ZERO extra dispatches —
@@ -863,8 +971,9 @@ class JaxBackend(CryptoBackend):
         phase has seen every window shape, its timed reps cannot retune.
         First sighting under autotune measures each present component
         through the fenced tuner (keys shared with the simple-batch
-        paths), votes, and pins the vote persistently."""
-        win_key = ("win", ne, nv, nb, nk)
+        paths; the Ed25519 part on the window's first tile), votes, and
+        pins the vote persistently."""
+        win_key = ("win", nv, nb, nk)
         if not self.autotune:
             self._static_choice[win_key] = self.use_pallas
             return self.use_pallas
@@ -872,11 +981,14 @@ class JaxBackend(CryptoBackend):
         if allp is not None:
             return allp
         use_ed = use_vrf = use_beta = use_kes = False
-        if ed_args is not None:
+        if ed_tile_args is not None:
+            te = self.ed_tile * self.n_shards
             use_ed, _ = self._pick(
-                ("ed", ne),
-                lambda: np.asarray(self._ed_dispatch(ed_args, ne, True)),
-                lambda: np.asarray(self._ed_dispatch(ed_args, ne, False)))
+                ("ed", te),
+                lambda: np.asarray(self._ed_dispatch(ed_tile_args, te,
+                                                     True)),
+                lambda: np.asarray(self._ed_dispatch(ed_tile_args, te,
+                                                     False)))
         if vrf_args is not None:
             use_vrf, _ = self._pick(
                 ("vrf", nv),
@@ -902,7 +1014,7 @@ class JaxBackend(CryptoBackend):
         # faster (see _window_composite on why no mixing); the kes hash
         # kernel is too small to swing the vote
         pallas_votes = [v for v, present in
-                        ((use_ed, ed_args is not None),
+                        ((use_ed, ed_tile_args is not None),
                          (use_vrf, vrf_args is not None),
                          (use_beta, beta_args is not None)) if present]
         allp = any(pallas_votes) if pallas_votes else use_kes
@@ -910,22 +1022,27 @@ class JaxBackend(CryptoBackend):
         return allp
 
     def finish_window(self, state):
-        """Block on a submit_window dispatch (one transfer); returns
-        (ok list aligned with the submitted reqs, {proof: beta} for the
-        requested next-window proofs).  For a fold=True submission the
-        first element is a WindowVerdict instead of the boolean list."""
+        """Block on a submit_window dispatch; returns (ok list aligned
+        with the submitted reqs, {proof: beta} for the requested
+        next-window proofs).  For a fold=True submission (one transfer)
+        the first element is a WindowVerdict instead of the boolean
+        list."""
         if state.get("fold"):
             return self._finish_window_fold(state)
         out = [False] * state["n"]
         betas: dict = {}
-        if state["packed"] is None:
+        if state["packed"] is None and state["ed"] is None:
             return out, betas
         with _spans.span("window.drain", cat="device"):
-            flat = np.asarray(state["packed"])      # THE round trip
+            # the round trip: the composite's buffer and a tile's
+            # verdicts a tile
+            flat = (np.asarray(state["packed"])
+                    if state["packed"] is not None else np.zeros(0, np.uint8))
+            ed_ok = (np.concatenate([np.asarray(t)
+                                     for t in state["ed_ok"]])
+                     if state["ed"] is not None else None)
         off = 0
-        if state["ed"] is not None:
-            ed_ok = flat[off:off + state["ne"]]
-            off += state["ne"]
+        if ed_ok is not None:
             _handle, parse_ok = state["ed"]
             for k, i in enumerate(state["ed_owner"]):
                 out[i] = bool(ed_ok[k]) and bool(parse_ok[k])

@@ -80,6 +80,10 @@ span named first; cat in brackets):
     submit.pack_ed  pack_ed.challenge [dispatch], the Ed25519 challenge
                     scalars of the window's lanes (crypto/ed25519_jax.py
                     `challenge_rows`); a root in `verify_ed25519_batch`
+    submit.dispatch submit.ed_tiles [dispatch], the T asynchronous
+                    calls of the one Ed25519 tile program a window of T
+                    tiles makes (`JaxBackend._ed_tile_program`): what
+                    the launches cost the producer, not device time
     (a root)        pipeline.beta_prefetch [device], the beta round
                     trip before window 0 (consensus/pipeline.py)
 

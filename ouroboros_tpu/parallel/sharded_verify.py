@@ -121,9 +121,12 @@ from ..crypto.jax_backend import JaxBackend
 class ShardedJaxBackend(JaxBackend):
     """JaxBackend over a device mesh: the window path (submit_window /
     finish_window / verify_mixed and the fold=True verdict reduction) is
-    INHERITED — only the fused window composite itself is replaced by a
-    shard_map of the very same packed-words component cores over the
-    window axis, and every batch input lands pre-sharded (`_dev`).
+    INHERITED — only the two window programs (the Ed25519 tile program
+    and the fused composite) are replaced by a shard_map of the very
+    same packed-words component cores over the window axis, and every
+    batch input lands pre-sharded (`_dev`, `_dev_tiles`).  A tile call
+    carries `ed_tile` lanes a shard, so the mesh's tile is `n_shards x
+    ed_tile` lanes wide.
 
     Reusing the single-device composite body per shard is what makes the
     mesh path compile inside the multichip budget: the r5 mesh composite
@@ -167,13 +170,18 @@ class ShardedJaxBackend(JaxBackend):
         # buffer donation for the per-window inputs (see JaxBackend):
         # fresh arrays every window, never read back -> donation-safe
         self._donate = self.platform in ("tpu", "gpu")
+        # `min_bucket` is the mesh's narrowest batch, so a shard's share
+        # of it is the narrowest tile (off an accelerator)
+        self.ed_tile = jb.ed_tile_width(
+            self.platform, max(1, self.min_bucket // mesh.devices.size))
         axis = mesh.axis_names[0]
         self._lane_sharding = NamedSharding(mesh, P(None, axis))
 
     def _pad(self, n: int) -> int:
         """A mesh multiple of at least `min_bucket` lanes; once a shard
-        is wider than ED_TILE, whole ED_TILE-wide tiles a shard
-        (`ed_lanes_core` walks each shard's lanes a tile at a time)."""
+        is wider than ED_TILE, whole ED_TILE-wide tiles a shard.  (A
+        window's Ed25519 lanes pad to whole tiles a shard whatever
+        their count: the inherited `_pad_ed_window`.)"""
         d = self.mesh.devices.size
         m = -(-max(self.min_bucket, n) // d) * d
         if m // d > jb.ED_TILE:
@@ -194,6 +202,17 @@ class ShardedJaxBackend(JaxBackend):
         _SHARD_PUT_BYTES.inc(a.nbytes)
         with _spans.span("submit.shard_put", cat="dispatch"):
             return jax.device_put(a, self._lane_sharding)
+
+    def _dev_tiles(self, arrays, ne: int) -> list:
+        # one sharded device_put for the whole window's tiles: a tile is
+        # n_shards x ed_tile lanes, its lane axis split over the mesh
+        _SHARD_PUT_BYTES.inc(sum(a.nbytes for a in arrays))
+        with _spans.span("submit.shard_put", cat="dispatch"):
+            return jax.device_put(self._tiles(arrays, ne),
+                                  self._lane_sharding)
+
+    def _dev_scalar(self, v: int):
+        return jax.device_put(np.int32(v), NamedSharding(self.mesh, P()))
 
     def _note_padding(self, used: int, padded: int) -> None:
         super()._note_padding(used, padded)
@@ -267,22 +286,38 @@ class ShardedJaxBackend(JaxBackend):
     # submit_window / finish_window / verify_mixed / the fold=True path
     # are inherited from JaxBackend; only the composite is mesh-built.
 
-    def _window_composite(self, ne: int, nv: int, nb: int, nk: int,
-                          pallas: bool):
-        """One jitted mesh program per window shape: shard_map of the
-        SAME packed-words component cores the single-device composite
-        fuses, each shard running the identical per-shard program, the
-        results stitched into JaxBackend's flat uint8 layout (so
-        finish_window and the fold program are shared verbatim).  A
-        shard's Ed25519 lanes go through `ed_lanes_core` as the one-chip
-        window's do: tiled above ED_TILE lanes a shard, today's program
-        at or under it.
+    def _ed_tile_program(self, pallas: bool, fold: bool):
+        """The one-chip tile program's body under shard_map: each shard
+        verifies its `ed_tile` lanes of the tile; folding, the shards'
+        first-bad indexes meet in one `pmin` and the running index stays
+        replicated."""
+        fn = self._ed_tile_programs.get((False, fold))
+        if fn is not None:
+            return fn
+        mesh = self.mesh
+        axis = mesh.axis_names[0]
+        s2 = P(None, axis)
+        body = self._ed_tile_body(False, fold, across=axis)
+        mapped = jax.shard_map(
+            body, mesh=mesh, in_specs=(P(),) + (s2,) * 9,
+            out_specs=P()) if fold else jax.shard_map(
+            body, mesh=mesh, in_specs=(s2,) * 8, out_specs=P(axis))
+        return self._keep_ed_tile_program(False, fold, jax.jit(
+            mapped, donate_argnums=self._ed_tile_donated(fold)))
+
+    def _window_composite(self, nv: int, nb: int, nk: int, pallas: bool):
+        """One jitted mesh program per (VRF, beta) shape: shard_map of
+        the SAME packed-words component cores the single-device
+        composite fuses, each shard running the identical per-shard
+        program, the results stitched into JaxBackend's flat uint8
+        layout (so finish_window and the fold program are shared
+        verbatim).
 
         Tracing the per-shard body instead of a mesh-wide monolith is
         the compile-budget fix: XLA compiles one shard-sized program +
         the SPMD partitioning, not an N-lane super-program."""
         assert nk == 0, "mesh windows reduce KES on host"
-        key = (ne, nv, nb, 0, False)
+        key = (nv, nb, 0, False)
         fn = self._composites.get(key)
         if fn is not None:
             return fn
@@ -292,9 +327,6 @@ class ShardedJaxBackend(JaxBackend):
         s2 = P(None, axis)
         in_specs: list = []
         out_specs: list = []
-        if ne:
-            in_specs.append((s2,) * 8)
-            out_specs.append(P(axis))
         if nv:
             in_specs.append((s2,) * 7)
             out_specs.append(P(axis, None))
@@ -305,10 +337,6 @@ class ShardedJaxBackend(JaxBackend):
         def body(*present):
             i = 0
             outs = []
-            if ne:
-                ok = jb.ed_lanes_core(*present[i])
-                i += 1
-                outs.append(ok.reshape(-1).astype(jnp.uint8))
             if nv:
                 Yw, xa, Gw, sG2, rw, cw, sw_ = present[i]
                 i += 1
@@ -323,16 +351,15 @@ class ShardedJaxBackend(JaxBackend):
         mapped = jax.shard_map(body, mesh=mesh, in_specs=tuple(in_specs),
                                out_specs=tuple(out_specs))
 
-        def call(ed_args, vrf_args, beta_args, kes_args):
-            present = [a for a in (ed_args, vrf_args, beta_args)
-                       if a is not None]
+        def call(vrf_args, beta_args, kes_args):
+            present = [a for a in (vrf_args, beta_args) if a is not None]
             parts = [o.reshape(-1) for o in mapped(*present)]
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
-        fn = jax.jit(call, donate_argnums=(0, 1, 2, 3)) if self._donate \
+        fn = jax.jit(call, donate_argnums=(0, 1, 2)) if self._donate \
             else jax.jit(call)
         fn = jb._compile_span_on_first_call(
-            fn, f"sharded.composite({ne},{nv},{nb})"
+            fn, f"sharded.composite({nv},{nb})"
                 f"@mesh{len(self.mesh.devices.flat)}")
         self._composites[key] = fn
         return fn

@@ -46,7 +46,6 @@ from ouroboros_tpu.crypto import vrf_jax as VJ
 # block opens a new period ships 10 per block (bucket 16384, the shape
 # the last recorded rounds had).
 NE, NV, NB, NK = 4096, 2048, 2048, 2048
-NE_TILED = 23 * JB.ED_TILE
 NK_ALL_NEW = 16384
 
 U32, I32, U8 = jnp.uint32, jnp.int32, jnp.uint8
@@ -154,10 +153,6 @@ XLA = {
                lambda s: _beta_args(NB, s)),
     "kes_hash": (lambda mw, ew: B2.check_block64(mw, ew),
                  lambda s: _kes_args(NK, s)),
-    # a full-body window's Ed25519 lanes (the benchmark's 90,624, padded
-    # to whole tiles): one tile-wide body under a loop, not NE_TILED wide
-    "ed25519_tiles": (lambda *a: JB.ed_lanes_core(*a),
-                      lambda s: _ed_args(NE_TILED, s)),
 }
 
 
@@ -165,7 +160,6 @@ XLA = {
     pytest.param("ed25519_split", marks=pytest.mark.slow),
     pytest.param("vrf_verify", marks=pytest.mark.slow),
     pytest.param("gamma8", marks=pytest.mark.slow),
-    pytest.param("ed25519_tiles", marks=pytest.mark.slow),
     "kes_hash"])
 def test_xla_form_compiles_for_v5e(kernel, one_chip):
     """The op-by-op XLA form of each part: what the autotuner also
@@ -174,27 +168,40 @@ def test_xla_form_compiles_for_v5e(kernel, one_chip):
     compiled = jax.jit(lambda *a: fn(*a)).lower(
         *make_args(one_chip)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
-    if kernel == "ed25519_tiles":
-        # a tile's working set, not the window's: the flat program of
-        # 131,072 lanes asks the compiler for 4 GB of temporaries
-        assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 def _backend(pallas: bool) -> JB.JaxBackend:
     be = JB.JaxBackend(use_pallas=pallas, autotune=False)
     be._donate = True       # as on the chip (off on the CPU it sees here)
+    be.ed_tile = JB.ED_TILE   # likewise: the CPU's tile is `min_bucket`
     return be
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("fold", [True, False])
+def test_ed_tile_program_compiles_for_v5e(fold, one_chip):
+    """THE Ed25519 program of the window path, at the chip's tile: a
+    full-body window (the benchmark's 90,624 lanes) is 23 calls of it,
+    so its temporaries are a tile's: the flat program of 131,072 lanes
+    asks the compiler for 4 GB."""
+    S = _spec(one_chip)
+    run = _backend(False)._ed_tile_program(False, fold)
+    carry = (S((), I32), S((1, JB.ED_TILE), I32)) if fold else ()
+    compiled = run.lower(*carry, *_ed_args(JB.ED_TILE, one_chip)).compile()
+    assert "tpu_custom_call" not in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 29
 
 
 @pytest.mark.parametrize("nb,nk", [(NB, NK), (0, NK)])
 def test_fold_program_compiles_for_v5e(nb, nk, one_chip):
     """The verdict fold (device SHA-512 challenge check + first-bad min)
-    over one window's packed buffer, for both shapes a replay meets:
+    over one window's packed buffer and the Ed25519 tiles' running
+    first-bad index, for both shapes a replay meets:
     windows that carry betas and the last two that do not."""
     S = _spec(one_chip)
-    fold = _backend(False)._fold_program(NE, NV, nb, nk)
+    fold = _backend(False)._fold_program(NV, nb, nk)
     fold.lower(
-        S((NE + 130 * NV + 33 * nb + nk,), U8), S((NE,), I32),
+        S((130 * NV + 33 * nb + nk,), U8), S((), I32),
         S((NV,), I32), S((NV, 32), U8), S((NV, 16), U8)).compile()
 
 
@@ -220,28 +227,34 @@ def test_window_composite_compiles_for_v5e(pallas, one_chip):
     window shape costs a cold start (jax_backend._window_composite
     records that a MIXED composite took over an hour; the homogeneous
     ones take minutes)."""
-    comp = _backend(pallas)._window_composite(NE, NV, NB, NK, pallas)
+    comp = _backend(pallas)._window_composite(NV, NB, NK, pallas)
     compiled = comp.lower(
-        _ed_args(NE, one_chip), _vrf_args(NV, one_chip),
-        _beta_args(NB, one_chip), _kes_args(NK, one_chip)).compile()
+        _vrf_args(NV, one_chip), _beta_args(NB, one_chip),
+        _kes_args(NK, one_chip)).compile()
     assert compiled.as_text().count("tpu_custom_call") == \
-        (4 if pallas else 0)
+        (3 if pallas else 0)
 
 
 @pytest.mark.slow
 def test_sharded_composite_compiles_for_four_v5e(topo):
-    """`chip_smoke.py --mesh 4`'s program: the sharded window composite
-    over a mesh of the four described chips, each holding a quarter of
-    the lanes."""
+    """`chip_smoke.py --mesh 4`'s programs: the sharded window composite
+    and the sharded Ed25519 tile program over a mesh of the four
+    described chips, each holding a quarter of the lanes."""
     from ouroboros_tpu.parallel import ShardedJaxBackend
     from ouroboros_tpu.parallel.mesh import WINDOW_AXIS
     mesh = Mesh(np.array(topo.devices[:4]), (WINDOW_AXIS,))
     lanes = NamedSharding(mesh, P(None, WINDOW_AXIS))
     sb = ShardedJaxBackend(mesh)
     assert sb._donate and sb.device_count == 4 and sb.platform == "tpu"
-    comp = sb._window_composite(NE, NV, NB, 0, False)
+    comp = sb._window_composite(NV, NB, 0, False)
     compiled = comp.lower(
-        _ed_args(NE, lanes), _vrf_args(NV, lanes), _beta_args(NB, lanes),
-        None).compile()
+        _vrf_args(NV, lanes), _beta_args(NB, lanes), None).compile()
     per_dev = compiled.memory_analysis()
     assert per_dev.argument_size_in_bytes < 2 ** 30
+    assert sb.ed_tile == JB.ED_TILE
+    S = _spec(lanes)
+    tile = sb._ed_tile_program(False, True).lower(
+        _spec(NamedSharding(mesh, P()))((), I32),
+        S((1, 4 * JB.ED_TILE), I32),
+        *_ed_args(4 * JB.ED_TILE, lanes)).compile()
+    assert "all-reduce" in tile.as_text()      # the shards' pmin

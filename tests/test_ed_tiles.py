@@ -1,22 +1,23 @@
-"""A window's Ed25519 lanes as fixed-width tiles (ISSUE 30), on the CPU.
+"""A window's Ed25519 lanes as calls of ONE tile program (ISSUEs 30, 38),
+on the CPU.
 
-`jax_backend.ED_TILE` is 4,096 lanes on the chip; here it is monkeypatched
-to 32 (16 on the mesh) with `min_bucket=16`, so seventy requests make a
-window of three tiles on one device and of two tiles a shard on four.
-Four programs compile for the whole file (the tiled composite and its
-fold, the same requests in one 128-lane bucket, the tiled mesh
-composite); one module fixture runs them and every test reads its
-record.
+On an accelerator a tile is `jax_backend.ED_TILE` = 4,096 lanes; off one
+it is the backend's `min_bucket` (`jax_backend.ed_tile_width`), here 16
+lanes (4 a shard on the mesh of four), so seventy requests make a window
+of five tiles on one device and of five tiles a shard on four.  Four
+programs compile for the whole file (the tile program with and without
+the fold, the same requests in one 128-lane bucket, the mesh's tile
+program); one module fixture runs them and every test reads its record.
 
-What is held: the tiled program's verdicts are the single-bucket
-program's lane for lane (a bad signature in the first tile, across a
-tile boundary, in the last tile next to the pad lanes, and a key that
-does not decode); the folded verdict names the unfolded vector's first
+What is held: the tile calls' verdicts are the single-bucket program's
+lane for lane (a bad signature in the first tile, either side of a tile
+boundary, in the last tile next to the pad lanes, and a key that does
+not decode); the folded verdict names the unfolded vector's first
 failure whichever tile holds it, and the host-known failure when that
-comes first; `_pad` is the power-of-two ladder up to a tile and whole
-tiles above it, on one chip and a shard; one composite is built for a
-window of many tiles, and `jax_backend.ed_tiles` counts the tiles one
-device walked.
+comes first; `_pad` is the power-of-two ladder up to ED_TILE and whole
+tiles above it and `_pad_ed_window` whole tiles whatever the count, on
+one chip and a shard; no program is built for a tile count, and
+`jax_backend.ed_tiles` counts the tiles one device walked.
 """
 import hashlib
 from types import SimpleNamespace
@@ -35,13 +36,14 @@ from ouroboros_tpu.parallel import ShardedJaxBackend, make_mesh  # noqa: E402
 
 pytestmark = pytest.mark.device
 
-T = 32                    # one-chip tile of this file
-N = 70                    # requests: 96 lanes = 3 tiles of 32
-SHARDS, MESH_T = 4, 16    # 70 -> 128 lanes = 2 tiles of 16 a shard
+T = 16                    # one-chip tile of this file: the min_bucket
+N = 70                    # requests: 80 lanes = 5 tiles of 16
+TILES = 5
+SHARDS, MESH_T = 4, 4     # 70 -> 80 lanes = 5 tiles of 4 lanes a shard
 # a tampered signature in the first tile, either side of the boundary
 # between tiles 0 and 1, and in the last real lane (its neighbour is the
 # first pad lane); UNDECODABLE carries a key that is no curve point
-BAD_SIGS = (3, 31, 32, 69)
+BAD_SIGS = (3, 15, 16, 69)
 UNDECODABLE = 40
 BAD = sorted(BAD_SIGS + (UNDECODABLE,))
 
@@ -73,6 +75,10 @@ def _counter(name: str) -> int:
     return observe.metrics.counter(name).value
 
 
+COUNTERS = ("jax_backend.ed_tiles", "jax_backend.ed_lanes_real",
+            "jax_backend.ed_lanes_walked", "jax_backend.composite_builds")
+
+
 def _xla_backend() -> JaxBackend:
     return JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
 
@@ -80,11 +86,11 @@ def _xla_backend() -> JaxBackend:
 # first failures the fold is asked for: (tampered lanes, undecodable)
 FOLDS = {
     "tile0": ((3, 69), ()),
-    "before_boundary": ((31, 50), ()),
-    "after_boundary": ((32, 50), ()),
+    "before_boundary": ((15, 50), ()),
+    "after_boundary": ((16, 50), ()),
     "last_tile_pad_neighbour": ((69,), ()),
     "host_known_first": ((69,), (UNDECODABLE,)),
-    "device_before_host_known": ((33,), (UNDECODABLE,)),
+    "device_before_host_known": ((17,), (UNDECODABLE,)),
     "all_good": ((), ()),
 }
 
@@ -94,65 +100,63 @@ def runs():
     reg = observe.metrics.registry()
     was_enabled = reg.enabled
     reg.enable()
-    mp = pytest.MonkeyPatch()
     out = SimpleNamespace(fold={}, unfolded={}, host_first_bad={})
     try:
         reqs = _requests()
-        # today's program: ED_TILE as shipped, one 128-lane bucket
-        tiles0 = _counter("jax_backend.ed_tiles")
-        flat = _xla_backend()
+        # the simple batch entry point: one 128-lane bucket program
+        flat = JaxBackend(use_pallas=False, autotune=False)
         out.flat_ne = flat._pad(N)
-        out.flat = flat.verify_mixed(reqs)
-        out.flat_tiles = _counter("jax_backend.ed_tiles") - tiles0
+        out.flat = flat.verify_ed25519_batch(reqs)
 
-        mp.setattr(JB, "ED_TILE", T)
         tiled = _xla_backend()
-        out.tiled_ne = tiled._pad(N)
-        builds0 = _counter("jax_backend.composite_builds")
-        tiles0 = _counter("jax_backend.ed_tiles")
+        out.tile = tiled.ed_tile
+        out.tiled_ne = tiled._pad_ed_window(N)
+        c0 = {n: _counter(n) for n in COUNTERS}
         out.tiled = tiled.verify_mixed(reqs)
-        out.tiles_one_window = _counter("jax_backend.ed_tiles") - tiles0
+        out.one_window = {n: _counter(n) - c0[n] for n in COUNTERS}
         for name, (sigs, keys) in FOLDS.items():
             rq = _requests(sigs, keys)
             out.unfolded[name] = tiled.verify_mixed(rq)
             st = tiled.submit_window(rq, fold=True)
             out.host_first_bad[name] = st["host_first_bad"]
             out.fold[name] = tiled.finish_window(st)[0]
-        out.tiled_builds = _counter("jax_backend.composite_builds") - builds0
-        out.tiled_programs = sorted(tiled._composites)
+        out.all_windows = {n: _counter(n) - c0[n] for n in COUNTERS}
+        out.tiled_programs = (sorted(tiled._ed_tile_programs),
+                              sorted(tiled._composites),
+                              sorted(tiled._folds))
         out.windows = 1 + 2 * len(FOLDS)
-        out.tiles_all = _counter("jax_backend.ed_tiles") - tiles0
 
         if len(jax.devices()) >= SHARDS:
-            mp.setattr(JB, "ED_TILE", MESH_T)
             mesh = ShardedJaxBackend(make_mesh(SHARDS), min_bucket=16)
-            out.mesh_ne = mesh._pad(N)
+            out.mesh_tile = mesh.ed_tile
+            out.mesh_ne = mesh._pad_ed_window(N)
             tiles0 = _counter("jax_backend.ed_tiles")
             out.mesh = mesh.verify_mixed(reqs)
+            st = mesh.submit_window(_requests(*FOLDS["tile0"]), fold=True)
+            out.mesh_fold = mesh.finish_window(st)[0]
             out.mesh_tiles = _counter("jax_backend.ed_tiles") - tiles0
             out.mesh_stats = mesh.padding_stats()
         else:
             out.mesh = None
     finally:
-        mp.undo()             # the tests below see the shipped ED_TILE
         reg.enabled = was_enabled
     return out
 
 
-# -- the tiled program against the single bucket -----------------------------
+# -- the tile calls against the single bucket ----------------------------------
 
-def test_single_bucket_run_is_todays_program(runs):
-    assert runs.flat_ne == 128 and runs.flat_tiles == 0
+def test_single_bucket_run_is_the_batch_entry_point(runs):
+    assert runs.flat_ne == 128
     assert [i for i, ok in enumerate(runs.flat) if not ok] == BAD
 
 
-@pytest.mark.parametrize("lane", BAD + [0, 30, 33, 68])
+@pytest.mark.parametrize("lane", BAD + [0, 14, 17, 68])
 def test_tiled_verdict_equals_single_bucket(runs, lane):
     assert runs.tiled[lane] == runs.flat[lane] == (lane not in BAD)
 
 
 def test_tiled_verdicts_equal_lane_for_lane(runs):
-    assert runs.tiled_ne == 3 * T
+    assert runs.tile == T and runs.tiled_ne == TILES * T
     assert runs.tiled == runs.flat and len(runs.tiled) == N
 
 
@@ -178,22 +182,38 @@ def test_host_first_bad_is_the_undecodable_key(runs, case):
                                          else JB.FOLD_SENT)
 
 
-# -- one program, counted tiles ----------------------------------------------
+# -- one program whatever the tile count, counted tiles ------------------------
 
-def test_one_composite_for_a_window_of_many_tiles(runs):
-    assert runs.tiled_builds == 1
-    assert runs.tiled_programs == [(3 * T, 0, 0, 0, False)]
+def test_no_program_is_built_for_a_tile_count(runs):
+    """Windows of five tiles ran the two forms of the ONE tile program;
+    a window of Ed25519 lanes alone has no composite, and its fold
+    holds no width."""
+    tile_programs, composites, folds = runs.tiled_programs
+    assert tile_programs == [(False, False), (False, True)]
+    assert composites == [] and folds == [(0, 0, 0)]
+    assert runs.all_windows["jax_backend.composite_builds"] == 0
 
 
 def test_ed_tiles_counts_the_tiles_of_every_window(runs):
-    assert runs.tiles_one_window == runs.tiled_ne // T == 3
-    assert runs.tiles_all == 3 * runs.windows
+    assert runs.one_window["jax_backend.ed_tiles"] == TILES
+    assert runs.all_windows["jax_backend.ed_tiles"] == TILES * runs.windows
 
 
-def test_ed_tiles_helper():
-    T0 = JB.ED_TILE
-    assert [JB.ed_tiles(n) for n in (0, 1, T0, T0 + 1, 2 * T0, 12 * T0)] \
-        == [0, 0, 0, 1, 2, 12]
+def test_real_and_walked_lanes_are_counted_a_window(runs):
+    assert runs.one_window["jax_backend.ed_lanes_real"] == N
+    assert runs.one_window["jax_backend.ed_lanes_walked"] == TILES * T
+    assert runs.all_windows["jax_backend.ed_lanes_walked"] \
+        == TILES * T * runs.windows
+
+
+@pytest.mark.parametrize("platform,narrowest,want", [
+    ("tpu", 128, 4096), ("tpu", 16, 4096), ("gpu", 512, 4096),
+    ("cpu", 16, 16), ("cpu", 128, 128), ("cpu", 8192, 4096)])
+def test_tile_width_by_platform(platform, narrowest, want):
+    """ED_TILE on an accelerator, where the sweep found a cheapest
+    width; the narrowest program of the backend off one."""
+    assert JB.ED_TILE == 4096
+    assert JB.ed_tile_width(platform, narrowest) == want
 
 
 # -- the padding seam ---------------------------------------------------------
@@ -203,8 +223,26 @@ def test_ed_tiles_helper():
     (33, 64), (64, 64), (65, 96), (70, 96), (97, 128), (129, 160)])
 def test_pad_is_the_ladder_to_a_tile_and_whole_tiles_above(monkeypatch, n,
                                                            want):
-    monkeypatch.setattr(JB, "ED_TILE", T)
+    monkeypatch.setattr(JB, "ED_TILE", 32)
     assert _xla_backend()._pad(n) == want
+
+
+@pytest.mark.parametrize("n,want", [
+    (0, 0), (1, 16), (15, 16), (16, 16), (17, 32), (48, 48), (70, 80),
+    (87, 96)])
+def test_window_lanes_pad_to_whole_tiles_whatever_the_count(n, want):
+    assert _xla_backend()._pad_ed_window(n) == want
+
+
+@pytest.mark.parametrize("n,want", [
+    (1, 4096), (1536, 4096), (4096, 4096), (4097, 8192),
+    (23040, 24576), (90624, 94208)])
+def test_window_lanes_at_the_shipped_tile(n, want):
+    """The benchmark's windows: 1,536 lanes of a night window walk one
+    tile, `sync-longchain`'s 23,040 six, `sync-witness`'s 90,624 23."""
+    jb = JaxBackend(use_pallas=False, autotune=False)
+    jb.ed_tile = JB.ED_TILE          # as on the chip
+    assert jb._pad_ed_window(n) == want
 
 
 @pytest.mark.parametrize("n,want", [
@@ -224,7 +262,7 @@ def test_pad_at_the_shipped_tile(n, want):
 def test_mesh_pad_is_whole_tiles_a_shard_above_a_tile(monkeypatch, n, want):
     if len(jax.devices()) < SHARDS:
         pytest.skip(f"needs {SHARDS} XLA devices (conftest forces 8)")
-    monkeypatch.setattr(JB, "ED_TILE", MESH_T)
+    monkeypatch.setattr(JB, "ED_TILE", 16)
     assert ShardedJaxBackend(make_mesh(SHARDS), min_bucket=16)._pad(n) \
         == want
 
@@ -237,6 +275,9 @@ def test_mesh_pad_of_the_benchmarks_window():
     sb = ShardedJaxBackend(make_mesh(SHARDS))
     assert sb._pad(90624) == 4 * 6 * JB.ED_TILE == 98304
     assert sb._pad(512) == 512 and sb._pad(4 * JB.ED_TILE) == 4 * JB.ED_TILE
+    sb.ed_tile = JB.ED_TILE          # as on the chip
+    assert sb._pad_ed_window(90624) == 98304
+    assert sb._pad_ed_window(1536) == 4 * JB.ED_TILE
 
 
 # -- the mesh ------------------------------------------------------------------
@@ -244,17 +285,24 @@ def test_mesh_pad_of_the_benchmarks_window():
 def test_mesh_window_tiles_per_shard_and_agrees(runs):
     if runs.mesh is None:
         pytest.skip(f"needs {SHARDS} XLA devices (conftest forces 8)")
-    assert runs.mesh_ne == SHARDS * 2 * MESH_T
-    assert runs.mesh_tiles == 2            # tiles ONE shard walked
-    assert runs.mesh_stats["lanes_per_shard_per_window"] == 2 * MESH_T
+    assert runs.mesh_tile == MESH_T
+    assert runs.mesh_ne == SHARDS * TILES * MESH_T
+    assert runs.mesh_tiles == 2 * TILES    # tiles ONE shard walked, twice
+    assert runs.mesh_stats["lanes_per_shard_per_window"] == TILES * MESH_T
     assert runs.mesh == runs.tiled == runs.flat
+
+
+def test_mesh_fold_is_the_minimum_over_the_shards(runs):
+    if runs.mesh is None:
+        pytest.skip(f"needs {SHARDS} XLA devices (conftest forces 8)")
+    assert runs.mesh_fold.first_bad == runs.fold["tile0"].first_bad == 3
 
 
 # -- the service's histogram asks the backend ---------------------------------
 
 @pytest.mark.parametrize("n", [1, 17, 33, 70])
 def test_service_bucket_is_the_backends_pad(monkeypatch, n):
-    monkeypatch.setattr(JB, "ED_TILE", T)
+    monkeypatch.setattr(JB, "ED_TILE", 32)
     jb = _xla_backend()
     svc = VerifyService.__new__(VerifyService)
     svc.backend = jb

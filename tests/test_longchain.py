@@ -8,7 +8,7 @@ of the window two ahead in their composite and the host pass of windows
 2-7 reads them from the cache the drain filled; with the genesis's KES
 period a pool's hash path is walked once, so later windows hold no KES
 job; and all of them ride the ONE composite the first window builds
-(`JaxBackend._occasional_widths`).  One module fixture replays the chain
+(`JaxBackend._occasional_widths`) and the one Ed25519 tile program.  One module fixture replays the chain
 three times (clean, clean again, one witness flipped in window 3) and
 records what happened; each test reads one property, so a failure names
 what broke.  The composite is one XLA:CPU compile (minutes cold, seconds
@@ -118,14 +118,18 @@ def longchain(tmp_path_factory):
                "reference": _validate(ctx, cpu),
                "first": _validate(ctx, dev),
                "second": _validate(ctx, dev),
-               "shapes": sorted(k[:4] for k in dev._composites)}
+               "shapes": sorted(k[:3] for k in dev._composites),
+               "tile_programs": sorted(dev._ed_tile_programs),
+               "folds": sorted(dev._folds)}
         blocks = [decode(raw) for _entry, raw in db.stream()]
         blocks[FLIPPED] = _flip_witness(blocks[FLIPPED])
         c0 = _counters()
         rec["device_stop"] = _stop(rules, blocks, dev)
         rec["stop_moved"] = {n: v - c0[n] for n, v in _counters().items()}
         rec["reference_stop"] = _stop(rules, blocks, cpu)
-        rec["shapes_after_stop"] = sorted(k[:4] for k in dev._composites)
+        rec["shapes_after_stop"] = sorted(k[:3] for k in dev._composites)
+        rec["programs_after_stop"] = (sorted(dev._ed_tile_programs),
+                                      sorted(dev._folds))
         rec["producers_alive"] = sum(
             t.name == "ouro-replay-producer" and t.is_alive()
             for t in threading.enumerate())
@@ -158,8 +162,11 @@ def test_one_composite_serves_all_eight_windows(longchain):
     pools' KES hash paths); windows with a narrower or an empty part
     ride its program."""
     assert longchain["first"]["moved"]["jax_backend.composite_builds"] == 1
-    (ne, nv, nb, nk), = longchain["shapes"]
-    assert nb >= 2 * WINDOW and nk >= 2 * 6 and ne and nv
+    (nv, nb, nk), = longchain["shapes"]
+    assert nb >= 2 * WINDOW and nk >= 2 * 6 and nv
+    # and the one folding tile program, and one fold
+    assert longchain["tile_programs"] == [(False, True)]
+    assert longchain["folds"] == [(nv, nb, nk)]
 
 
 def test_second_replay_builds_no_composite(longchain):
@@ -211,6 +218,8 @@ def test_stop_came_with_windows_in_flight(longchain):
     assert 4 <= moved["jax_backend.windows_submitted"] <= 6
     assert moved["jax_backend.composite_builds"] == 0
     assert longchain["shapes_after_stop"] == longchain["shapes"]
+    assert longchain["programs_after_stop"] == (longchain["tile_programs"],
+                                                longchain["folds"])
     assert longchain["producers_alive"] == 0
 
 

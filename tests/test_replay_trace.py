@@ -71,6 +71,7 @@ STAGES = {
     "submit.pack_vrf": ("dispatch", "window.submit", PRODUCER),
     "submit.pack_kes": ("dispatch", "window.submit", PRODUCER),
     "submit.dispatch": ("dispatch", "window.submit", PRODUCER),
+    "submit.ed_tiles": ("dispatch", "submit.dispatch", PRODUCER),
     "submit.fold": ("dispatch", "window.submit", PRODUCER),
     "pipeline.beta_prefetch": ("device", None, PRODUCER),
 }
@@ -86,10 +87,11 @@ THREADS = ("prefetch", "producer", "caller")
 
 
 class HostProgramsBackend(JaxBackend):
-    """JaxBackend with its two device programs (window composite, fold)
-    computed on the host by the OpenSSL reference.  `submit_window`,
-    `_submit_window`, the split, the three packers, the choice and
-    `_attach_fold` are the real ones; so is `finish_window`."""
+    """JaxBackend with its three device programs (Ed25519 tile, window
+    composite, fold) computed on the host by the OpenSSL reference.
+    `submit_window`, `_submit_window`, the split, the three packers, the
+    tiles' copy, the choice, `_fold_owners` and `_attach_fold` are the
+    real ones; so is `finish_window`."""
 
     def __init__(self):
         super().__init__(min_bucket=16, use_pallas=False, autotune=False)
@@ -101,11 +103,14 @@ class HostProgramsBackend(JaxBackend):
         self._asked = (list(reqs), list(dict.fromkeys(next_beta_proofs)))
         return super().submit_window(reqs, next_beta_proofs, fold)
 
-    def _window_composite(self, ne, nv, nb, nk, pallas):
+    def _ed_tile_program(self, pallas, fold):
+        return lambda bad, _own, *_lanes: bad
+
+    def _window_composite(self, nv, nb, nk, pallas):
         return lambda *_args: self._asked
 
-    def _fold_program(self, ne, nv, nb, nk):
-        def fold(asked, _ed_own, _vrf_own, _gamma_b, _c_b):
+    def _fold_program(self, nv, nb, nk):
+        def fold(asked, _ed_bad, _vrf_own, _gamma_b, _c_b):
             reqs, proofs = asked
             ok = self._cpu.verify_mixed(reqs)
             bad = ok.index(False) if False in ok else FOLD_SENT
@@ -249,7 +254,8 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
     assert len(by_name["seq.body"]) == 2 * BLOCKS
     for name in ("submit.split", "submit.pack_ed", "pack_ed.challenge",
                  "submit.pack_vrf",
-                 "submit.pack_kes", "submit.dispatch", "submit.fold",
+                 "submit.pack_kes", "submit.dispatch", "submit.ed_tiles",
+                 "submit.fold",
                  "window.submit", "window.host_seq", "pipeline.drain"):
         assert len(by_name[name]) == N_WINDOWS, name
     assert len(by_name["pipeline.beta_prefetch"]) == 1

@@ -184,7 +184,7 @@ def served(tmp_path_factory):
     out.replay_fills = GLOBAL_PRECOMPUTE_CACHE.device_fills - fills0
 
     # 2. the mixed batch.  Fold mode FIRST, while the KES paths are
-    #    cold: it then has the replay's (ne, nv, nb, nk) shape, as the
+    #    cold: it then has the replay's (nv, nb, nk) shape, as the
     #    cold vector batch after it has once the paths are cold again
     reqs = _mixed_requests()
     out.want = CpuRefBackend().verify_mixed(reqs)
@@ -275,10 +275,11 @@ def test_device_replay_counts_the_reference_blocks_and_proofs(served):
 
 def test_every_step_ran_the_one_composite_shape(served):
     """The replay, both mixed batches, the unobserved batch and the
-    streamed replay: 16 Ed25519 lanes, 16 VRF lanes, no betas, 32 KES
-    jobs.  A second `win` key is minutes of XLA:CPU compile."""
+    streamed replay: 16 VRF lanes, no betas, 32 KES jobs (the 16
+    Ed25519 lanes are one call of the tile program and no part of the
+    key).  A second `win` key is minutes of XLA:CPU compile."""
     assert [k for k in served.kernel_choices if k[0] == "win"] \
-        == [("win", 16, 16, 0, 32)]
+        == [("win", 16, 0, 32)]
 
 
 def test_producer_ran_and_is_gone(served):

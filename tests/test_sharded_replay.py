@@ -49,6 +49,10 @@ N_WINDOWS = BLOCKS // WINDOW
 # a block here is 2 VRF proofs, 1 KES signature (its Ed25519 leaf on the
 # device, its hash path on the host), 1 OCert signature and 1 witness
 ED_LANES, VRF_LANES = 3 * WINDOW, 2 * WINDOW
+# a shard's tile is min_bucket // SHARDS = 4 lanes off an accelerator, so
+# a window's 24 Ed25519 lanes go as two tiles of 16 lanes over the mesh
+ED_TILE_LANES = 16
+ED_WALKED = 2 * ED_TILE_LANES
 MESH_COUNTERS = ("jax_backend.shard_put_bytes",
                  "jax_backend.shard_lanes_padded",
                  "precompute.kes_host_walks")
@@ -204,22 +208,23 @@ def _stop(chain, backend, at_blocks, flipped=None) -> tuple:
 
 
 @pytest.mark.parametrize("at_blocks,shards", [
-    ((1,), {0}), ((2,), {1}), ((5,), {2}), ((7,), {3}),
-    ((6, 3), {1, 3}),              # two shards: the minimum wins
-    ((WINDOW + 4,), {2}),          # a later window
-], ids=["shard0", "shard1", "shard2", "shard3", "shards1and3",
-        "window1-shard2"])
+    ((5,), {0}),                   # in the window's second tile
+    ((1,), {1}), ((2,), {2}), ((4,), {3}),
+    ((6, 3), {1, 2}),              # two shards: the minimum wins
+    ((WINDOW + 4,), {3}),          # a later window
+], ids=["shard0-tile1", "shard1", "shard2", "shard3", "shards1and2",
+        "window1-shard3"])
 def test_a_bad_lane_in_any_shard_stops_where_the_reference_stops(
         chain, mesh_backend, reference_backend, monkeypatch,
         at_blocks, shards):
     packed = []                    # (requests, padded lanes) a window
-    real = mesh_backend._prep_ed
+    real = mesh_backend._pack_ed
 
     def spy(reqs, m):
         packed.append((list(reqs), m))
         return real(reqs, m)
 
-    monkeypatch.setattr(mesh_backend, "_prep_ed", spy)
+    monkeypatch.setattr(mesh_backend, "_pack_ed", spy)
     flipped: list = []
     builds = metrics_mod.counter("jax_backend.composite_builds").value
     got = _stop(chain, mesh_backend, at_blocks, flipped)
@@ -228,10 +233,11 @@ def test_a_bad_lane_in_any_shard_stops_where_the_reference_stops(
     # the flipped signatures really sat in the shards the case names
     hit = set()
     for reqs, m in packed:
-        assert m == ED_LANES and m % SHARDS == 0
+        assert m == ED_WALKED and len(reqs) == ED_LANES
         for lane, r in enumerate(reqs):
             if isinstance(r, Ed25519Req) and r.sig in flipped:
-                hit.add(lane // (m // SHARDS))
+                # a tile's lanes are split four ways, tile after tile
+                hit.add(lane % ED_TILE_LANES // (ED_TILE_LANES // SHARDS))
     assert hit == shards
     # and the tampered window ran the clean chain's composite
     assert metrics_mod.counter(
@@ -241,6 +247,16 @@ def test_a_bad_lane_in_any_shard_stops_where_the_reference_stops(
 # -- a chain on which every witness key is new (ISSUE 31) --------------------
 
 _COMPILES: list = []      # backend compiles of this process, as they end
+_LISTENING: list = []     # set once the listener is registered
+
+
+def _listen_for_compiles() -> None:
+    """Start counting backend compiles, once a process."""
+    if not _LISTENING:
+        _LISTENING.append(True)
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda event, _secs, **_kw: _COMPILES.append(event)
+            if event.endswith("backend_compile_duration") else None)
 
 
 def _witness_keys(chain) -> list:
@@ -255,10 +271,7 @@ def fresh_lines(fresh_chain, lines, mesh_backend, one_chip_backend,
     """The fresh chain's replay by backend, AFTER `lines` compiled every
     program on the pool-key chain; beside each device replay what the
     per-key cache was asked (`assemble` spied on) and what it counted."""
-    if not _COMPILES:
-        jax.monitoring.register_event_duration_secs_listener(
-            lambda event, _secs, **_kw: _COMPILES.append(event)
-            if event.endswith("backend_compile_duration") else None)
+    _listen_for_compiles()
     cache = GLOBAL_PRECOMPUTE_CACHE
     real = cache.assemble
     out = {"cpp": _validate(fresh_chain, reference_backend)}
@@ -351,23 +364,90 @@ def test_a_flipped_witness_on_the_fresh_chain_stops_both_at_its_block(
         == (WINDOW + 2, "Ed25519Req")
 
 
+# -- a chain of unequal windows (ISSUE 38) -------------------------------------
+
+@pytest.fixture(scope="module")
+def mixed_chain(tmp_path_factory):
+    """The chain under arrivals a slot (the rehearse block of
+    `traffic/bodies-mempool-diurnal.json` in small): window 0 holds some
+    tens of transactions, window 1 a few, so the two windows walk
+    different numbers of tiles."""
+    return _forge(tmp_path_factory, "mixed-chain",
+                  "--tx-arrivals-per-slot", "0.6,0.05",
+                  "--tx-arrival-phase-slots", "160")
+
+
+@pytest.fixture(scope="module")
+def mixed_lines(mixed_chain, lines, mesh_backend, one_chip_backend,
+                reference_backend):
+    """The mixed chain's replay by backend, AFTER `lines` compiled every
+    program on the chain of equal windows."""
+    _listen_for_compiles()
+    out = {"cpp": _validate(mixed_chain, reference_backend)}
+    for name, backend in (("mesh", mesh_backend),
+                          ("one-chip", one_chip_backend)):
+        compiles = len(_COMPILES)
+        c0 = _counters()
+        out[name] = _validate(mixed_chain, backend)
+        c1 = _counters()
+        out[name + "-facts"] = {
+            "delta": {k: c1[k] - c0.get(k, 0) for k in c1},
+            "compiles": len(_COMPILES) - compiles}
+    return out
+
+
+@pytest.mark.parametrize("what", ["state_hash", "blocks", "proofs"])
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_mixed_chain_replay_equals_the_reference(mixed_lines, backend,
+                                                 what):
+    assert mixed_lines[backend][what] == mixed_lines["cpp"][what]
+    assert mixed_lines["cpp"]["blocks"] == BLOCKS
+    assert mixed_lines["cpp"]["proofs"] > 5 * BLOCKS
+
+
+@pytest.mark.parametrize("backend", ["mesh", "one-chip"])
+def test_mixed_chain_compiles_nothing_the_equal_chain_did_not(
+        mixed_lines, backend):
+    """A window's tile count is no program's shape, on the mesh as on
+    one chip: windows of other widths run what `lines` compiled."""
+    facts = mixed_lines[backend + "-facts"]
+    delta = facts["delta"]
+    assert facts["compiles"] == 0
+    assert delta["jax_backend.composite_builds"] == 0
+    assert delta["jax_backend.windows_submitted"] == N_WINDOWS
+    assert delta["jax_backend.ed_width_changes"] == 1
+    real = delta["jax_backend.ed_lanes_real"]
+    assert real == mixed_lines["cpp"]["proofs"] - 2 * BLOCKS
+    # whole tiles of 16 lanes (4 a shard on the mesh), none without a
+    # real lane
+    assert delta["jax_backend.ed_lanes_walked"] % ED_TILE_LANES == 0
+    assert real <= delta["jax_backend.ed_lanes_walked"] \
+        < real + N_WINDOWS * ED_TILE_LANES
+    assert delta["jax_backend.ed_tiles"] * ED_TILE_LANES \
+        == delta["jax_backend.ed_lanes_walked"]
+
+
 # -- what the mesh adds: one span, three counters ---------------------------
 
 @pytest.fixture(scope="module")
 def traced(chain, mesh_backend, lines):
-    """One replay with span recording on, `_dev` spied on; then a second
-    with every cache left warm."""
+    """One replay with span recording on, `_dev` and `_dev_tiles` spied
+    on; then a second with every cache left warm."""
     put = []                       # bytes handed to each sharded put
-    real = mesh_backend._dev
+    real, real_tiles = mesh_backend._dev, mesh_backend._dev_tiles
 
     def spy(a):
         put.append(np.asarray(a).nbytes)
         return real(a)
 
+    def spy_tiles(arrays, ne):
+        put.append(sum(a.nbytes for a in arrays))
+        return real_tiles(arrays, ne)
+
     rec = spans_mod.RECORDER
     assert not rec.enabled
     rec.drain()
-    mesh_backend._dev = spy
+    mesh_backend._dev, mesh_backend._dev_tiles = spy, spy_tiles
     rec.enable()
     try:
         c0 = _counters()
@@ -375,7 +455,7 @@ def traced(chain, mesh_backend, lines):
         c1 = _counters()
     finally:
         rec.disable()
-        del mesh_backend._dev
+        del mesh_backend._dev, mesh_backend._dev_tiles
     roots = rec.drain()
     kes_paths = GLOBAL_PRECOMPUTE_CACHE.kes_len()
     _validate(chain, mesh_backend, cold=False)
@@ -393,9 +473,10 @@ def test_traced_replay_is_the_same_replay(traced, lines):
 def test_shard_put_spans_close_inside_the_packing_stages(traced):
     spans = [sp for root in traced["roots"] for sp in root.walk()]
     puts = [sp for sp in spans if sp.name == "submit.shard_put"]
-    # 8 Ed25519 arrays and 7 VRF arrays a window; no beta lanes on a
-    # two-window chain (both windows' betas ride the plain prefetch)
-    assert len(puts) == len(traced["put"]) == 15 * N_WINDOWS
+    # a window's Ed25519 tiles in one put, their owner rows in another,
+    # and 7 VRF arrays; no beta lanes on a two-window chain (both
+    # windows' betas ride the plain prefetch)
+    assert len(puts) == len(traced["put"]) == 9 * N_WINDOWS
     for sp in puts:
         assert sp.t1 is not None and sp.t1 >= sp.t0
         assert sp.cat == "dispatch"
@@ -403,9 +484,10 @@ def test_shard_put_spans_close_inside_the_packing_stages(traced):
     by_stage = {
         stage: sum(c.name == "submit.shard_put" for sp in spans
                    if sp.name == stage for c in sp.children)
-        for stage in ("submit.pack_ed", "submit.pack_vrf")}
-    assert by_stage == {"submit.pack_ed": 8 * N_WINDOWS,
-                        "submit.pack_vrf": 7 * N_WINDOWS}
+        for stage in ("submit.pack_ed", "submit.pack_vrf", "submit.fold")}
+    assert by_stage == {"submit.pack_ed": N_WINDOWS,
+                        "submit.pack_vrf": 7 * N_WINDOWS,
+                        "submit.fold": N_WINDOWS}
 
 
 def test_shard_put_bytes_counts_what_was_handed_over(traced):
@@ -414,7 +496,7 @@ def test_shard_put_bytes_counts_what_was_handed_over(traced):
 
 
 def test_shard_lanes_padded_is_one_shards_share(traced, mesh_backend):
-    per_window = (ED_LANES + VRF_LANES) // SHARDS
+    per_window = (ED_WALKED + VRF_LANES) // SHARDS
     assert traced["delta"]["jax_backend.shard_lanes_padded"] \
         == per_window * N_WINDOWS
     assert traced["delta"]["jax_backend.lanes_padded"] \
@@ -460,7 +542,7 @@ def test_layer_metric_reader_resolves_on_the_mesh_replay(traced, metric,
     assert value is not None and value > 0
     assert doc["source"] == source
     if metric == "shard_lanes_per_window":
-        assert value == (ED_LANES + VRF_LANES) // SHARDS
+        assert value == (ED_WALKED + VRF_LANES) // SHARDS
 
 
 def test_no_span_when_recording_is_off(chain, mesh_backend, lines):

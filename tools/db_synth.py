@@ -17,6 +17,7 @@ Two chain flavours:
 Usage: python tools/db_synth.py --out DIR [--protocol shelley] [--blocks N]
        [--txs-per-block M] [--pools P] [--f NUM/DEN]
        [--witness-keys pool|fresh] [--slots-per-kes-period N]
+       [--tx-arrivals-per-slot R0,R1,... --tx-arrival-phase-slots S]
 
 --witness-keys (shelley) says who signs the transactions.  `pool` (the
 default, the chain every earlier version forged, byte for byte): every
@@ -29,6 +30,18 @@ an address, a fresh address a transaction), in which no witness key
 occurs twice in the chain.  An address IS its key here, so transaction
 and block sizes are the same in both; the fresh addresses are not
 delegated, which moves nothing before the first epoch boundary.
+
+--tx-arrivals-per-slot with --tx-arrival-phase-slots (shelley; both or
+neither, and then `--txs-per-block` says nothing): a block's body is
+what the forger's mempool held.  Transactions ARRIVE every slot, R0 a
+slot for the first S slots, R1 for the next S, the last rate for good; a
+block takes what has arrived since the block before it, up to what fits
+the mainnet genesis's maxBlockBodySize (65,536 bytes over the
+transaction's size), and the rest waits for the next block.  No new
+randomness: the arrivals are a fractional accumulator, and the slot gaps
+the seed's leader schedule gives are what spread the block sizes.
+`config.json` then also says what the chain came to hold
+(`tx_arrivals.txs_per_block`: min, mean, max, empty blocks).
 """
 from __future__ import annotations
 
@@ -190,6 +203,44 @@ def synth_mock_praos(args) -> dict:
     return {"blocks": forged, "last_slot": slot - 1}
 
 
+# mainnet shelley-genesis.json, protocolParams.maxBlockBodySize
+MAX_BLOCK_BODY_SIZE = 65536
+
+
+class _Mempool:
+    """Transactions waiting for a block under `--tx-arrivals-per-slot`:
+    `rates[i]` arrive in every slot of phase i (`phase_slots` slots
+    each; the last rate holds after the last phase), exactly (Fractions),
+    and a block takes the whole ones that wait, up to `cap`."""
+
+    def __init__(self, rates: str, phase_slots: int, cap: int):
+        self.rates = [Fraction(r) for r in rates.split(",")]
+        if phase_slots < 1 or min(self.rates) < 0:
+            raise SystemExit("db_synth: --tx-arrivals-per-slot takes "
+                             "rates >= 0, --tx-arrival-phase-slots >= 1")
+        self.phase_slots, self.cap = phase_slots, cap
+        self.waiting = Fraction(0)
+        self.next_slot = 0            # the first slot not yet arrived in
+        self.taken: list[int] = []    # transactions of every block so far
+
+    def take(self, slot: int) -> int:
+        """Arrivals up to and including `slot`; the block's count."""
+        for s in range(self.next_slot, slot + 1):
+            self.waiting += self.rates[min(s // self.phase_slots,
+                                           len(self.rates) - 1)]
+        self.next_slot = slot + 1
+        n = min(int(self.waiting), self.cap)
+        self.waiting -= n
+        self.taken.append(n)
+        return n
+
+    def summary(self) -> dict:
+        t = self.taken
+        return {"min": min(t), "mean": round(sum(t) / len(t), 3),
+                "max": max(t), "empty_blocks": t.count(0),
+                "cap": self.cap, "left_waiting": int(self.waiting)}
+
+
 def synth_shelley(args) -> dict:
     """Forge a TPraos/Shelley chain: the flagship replay workload.
 
@@ -205,6 +256,7 @@ def synth_shelley(args) -> dict:
         shelley_genesis_setup,
     )
     from ouroboros_tpu.storage.fs import IoFS
+    from ouroboros_tpu.utils import cbor
 
     f = Fraction(args.f)
     # KES periods must cover the whole chain: the genesis's own length
@@ -222,21 +274,26 @@ def synth_shelley(args) -> dict:
         args.pools, cfg, stake_per_pool=100_000,
         seed=args.seed.encode())
 
+    config = {
+        "protocol": "shelley",
+        "k": cfg.k, "f": str(f), "epoch_length": cfg.epoch_length,
+        "slots_per_kes_period": cfg.slots_per_kes_period,
+        "kes_depth": cfg.kes_depth,
+        "max_kes_evolutions": cfg.max_kes_evolutions,
+        "genesis_seed": "shelley-genesis",
+        "genesis": {p["addr"].hex(): 100_000 for p in pools},
+        "pools": [{"pool_id": p["keys"].pool_id.hex(),
+                   "vrf_vk": p["keys"].vrf_vk.hex(),
+                   "addr": p["addr"].hex()} for p in pools],
+        "chunk_size": args.chunk_size,
+    }
+
+    def write_config() -> None:
+        with open(os.path.join(args.out, "config.json"), "w") as fh:
+            json.dump(config, fh, indent=2)
+
     os.makedirs(args.out, exist_ok=True)
-    with open(os.path.join(args.out, "config.json"), "w") as fh:
-        json.dump({
-            "protocol": "shelley",
-            "k": cfg.k, "f": str(f), "epoch_length": cfg.epoch_length,
-            "slots_per_kes_period": cfg.slots_per_kes_period,
-            "kes_depth": cfg.kes_depth,
-            "max_kes_evolutions": cfg.max_kes_evolutions,
-            "genesis_seed": "shelley-genesis",
-            "genesis": {p["addr"].hex(): 100_000 for p in pools},
-            "pools": [{"pool_id": p["keys"].pool_id.hex(),
-                       "vrf_vk": p["keys"].vrf_vk.hex(),
-                       "addr": p["addr"].hex()} for p in pools],
-            "chunk_size": args.chunk_size,
-        }, fh, indent=2)
+    write_config()
 
     fs = IoFS(args.out)
     db = open_out_db(fs, args)
@@ -253,6 +310,16 @@ def synth_shelley(args) -> dict:
     fresh = args.witness_keys == "fresh"
     holder_sk = {i: p["keys"].addr_sk for i, p in enumerate(pools)}
     n_tx = 0
+    mempool = None
+    if args.tx_arrivals_per_slot is not None:
+        # every transaction made here has one input, one output and one
+        # witness, so one of them says what all of them weigh
+        tx_bytes = len(cbor.dumps(make_shelley_tx(
+            inputs=[(GEN, 0)], outputs=[(pools[0]["addr"], 100_000)],
+            certs=[], signing_keys=[holder_sk[0]]).encode()))
+        mempool = _Mempool(args.tx_arrivals_per_slot,
+                           args.tx_arrival_phase_slots,
+                           MAX_BLOCK_BODY_SIZE // tx_bytes)
 
     prev = None
     slot = 0
@@ -274,8 +341,11 @@ def synth_shelley(args) -> dict:
             continue
         p = pools[leader_ix]
         body = []
-        for t in range(args.txs_per_block):
-            owner = (forged * args.txs_per_block + t) % len(pools)
+        n_body = (args.txs_per_block if mempool is None
+                  else mempool.take(slot))
+        for t in range(n_body):
+            owner = ((forged * n_body + t) if mempool is None
+                     else n_tx) % len(pools)
             if not spendable[owner]:
                 continue
             txid, ix, amount = spendable[owner].pop(0)
@@ -311,6 +381,12 @@ def synth_shelley(args) -> dict:
                   file=sys.stderr)
     if hasattr(db, "close"):
         db.close()              # flush the reference-format tail chunk
+    if mempool is not None:
+        config["tx_arrivals"] = {
+            "per_slot": args.tx_arrivals_per_slot,
+            "phase_slots": args.tx_arrival_phase_slots,
+            "tx_bytes": tx_bytes, "txs_per_block": mempool.summary()}
+        write_config()
     return {"blocks": forged, "last_slot": slot - 1}
 
 
@@ -511,8 +587,22 @@ def main() -> None:
                          "genesis gives it (mainnet: 129600); left out, "
                          "it is derived from the chain's length so that "
                          "the chain walks through the key's periods")
+    ap.add_argument("--tx-arrivals-per-slot", default=None,
+                    metavar="R0,R1,...",
+                    help="shelley: transactions that arrive in a slot, "
+                         "by phase (the last rate holds for good); a "
+                         "block takes what has arrived since the block "
+                         "before it, up to the body limit")
+    ap.add_argument("--tx-arrival-phase-slots", type=int, default=None,
+                    help="shelley: slots a phase of "
+                         "--tx-arrivals-per-slot lasts")
     ap.add_argument("--seed", default="db-synth")
     args = ap.parse_args()
+    if (args.tx_arrivals_per_slot is None) != (
+            args.tx_arrival_phase_slots is None) or (
+            args.tx_arrivals_per_slot and args.protocol != "shelley"):
+        ap.error("--tx-arrivals-per-slot and --tx-arrival-phase-slots "
+                 "go together, on a shelley chain")
 
     t0 = time.time()
     if args.protocol == "shelley":
