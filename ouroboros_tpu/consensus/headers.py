@@ -21,8 +21,9 @@ from ..observe import spans as _spans
 from ..utils import cbor
 
 
-# blocks whose cached header bytes, header spans and tx body bytes all
-# came from the offsets of the one walk (ProtocolBlock.from_bytes)
+# blocks whose cached header bytes and header spans, and every
+# transaction's id, all came from the offsets of the one walk
+# (ProtocolBlock.from_bytes)
 _ONE_WALK = _metrics.counter("replay.decode.one_walk_blocks")
 
 
@@ -56,16 +57,17 @@ class ProtocolHeader:
         """Serialisation with the named fields removed — what gets signed.
 
         When the header was decoded from stored bytes (ProtocolBlock.
-        from_bytes), the result is assembled from raw-byte spans instead
-        of re-encoding — re-encoding was ~40% of the replay host pass."""
-        sp = self._cache.get("spans")
+        from_bytes), the result is assembled from spans of the header's
+        own stored bytes instead of re-encoding — re-encoding was ~40%
+        of the replay host pass."""
+        c = self._cache
+        sp = c.get("spans")
         if sp is not None:
-            raw, helems, fpairs = sp
-            keep = [s for k, s in fpairs if k not in drop]
-            return (cbor._head(4, 6)
-                    + raw[helems[0][0]:helems[4][1]]
-                    + cbor._head(4, len(keep))
-                    + b"".join(raw[a:b] for a, b in keep))
+            own = c["bytes"]
+            start, end, fields = sp
+            keep = [own[a:b] for k, a, b in fields if k not in drop]
+            return (cbor._head(4, 6) + own[start:end]
+                    + cbor._head(4, len(keep)) + b"".join(keep))
         return cbor.dumps(self.encode(drop))
 
     @property
@@ -131,31 +133,41 @@ class ProtocolBlock:
     @classmethod
     def from_bytes(cls, raw: bytes, tx_decode=None,
                    tx_body_elems: int | None = None) -> "ProtocolBlock":
-        """Decode AND retain raw-byte slices so the hot sequential pass
-        (header hash, KES signing bytes, tx ids) never re-encodes.  The
+        """Decode AND keep what the hot sequential pass (header hash,
+        KES signing bytes, tx ids) would otherwise re-encode for.  The
         bytes are walked once: the parse keeps the offsets of the list
         elements down to a transaction's, and the slices are cut there.
+        What is kept is the header's OWN bytes with the offsets of its
+        elements inside them, and a 32-byte id a transaction: never the
+        block's bytes, so a built block pickles small (a decode worker's
+        reply, storage/decode_pool.py).
 
         tx_body_elems: when set, each tx item is a list whose first
         tx_body_elems elements form the tx BODY (ShelleyTx: 6 body
         fields + witnesses) — the body encoding is assembled from the
-        offsets and stashed in the tx's _cache for txid.
+        offsets and hashed here, and the transaction is handed its id
+        (`with_txid`), so `txid` never encodes or hashes later.
 
         An item not shaped so (a header that is not a 6-list, a tx of
-        fewer elements) keeps an empty cache and is re-encoded when
-        asked; `replay.decode.one_walk_blocks` counts the blocks where
-        no item was.
+        fewer elements, a transaction class without `with_txid`) stays
+        as `decode` built it and is re-encoded when asked;
+        `replay.decode.one_walk_blocks` counts the blocks where no item
+        was.
 
         Its three stages each run in a `disk` span a block (decode.parse
         around the one walk, decode.build around `decode`, decode.slices
-        around the cutting of the cached slices), children of the
-        prefetcher's `stream.decode` in a streamed replay."""
+        around the cutting of the header's slices and the hashing of the
+        ids), children of the prefetcher's `stream.decode` in a streamed
+        replay."""
         with _spans.span("decode.parse", cat="disk"):
             obj, spans = cbor.loads_spans(raw, depth=2)
         with _spans.span("decode.build", cat="disk"):
             block = cls.decode(obj, tx_decode=tx_decode)
         with _spans.span("decode.slices", cat="disk"):
-            if _cache_slices(block, raw, spans, tx_body_elems):
+            body, whole = _cache_slices(block, raw, spans, tx_body_elems)
+            if body is not None:
+                block = cls(block.header, body)
+            if whole:
                 _ONE_WALK.inc()
         return block
 
@@ -180,38 +192,50 @@ class BlockDecoder:
 
 
 def _cache_slices(block: ProtocolBlock, raw: bytes, spans,
-                  tx_body_elems: int | None) -> bool:
-    """Fill the header's and the transactions' caches from the offsets
-    `cbor.loads_spans(raw, depth=2)` kept of `[header, [tx, ...]]`; True
-    when every item was shaped as expected, so every cache is filled."""
+                  tx_body_elems: int | None):
+    """Fill the header's cache, and hash the transactions' ids, from the
+    offsets `cbor.loads_spans(raw, depth=2)` kept of `[header, [tx,
+    ...]]`.  Returns `(body, whole)`: the block's transactions each
+    carrying its id (None where none took one), and whether every item
+    was shaped as expected, so nothing is left to re-encode.
+
+    The header keeps `bytes`, its own stored bytes, and `spans` =
+    `(start, end, ((name, a, b), ...))`, offsets INTO those bytes: of
+    its first five elements together and of each protocol field."""
     if spans is None:
-        return False
+        return None, False
     bounds, subs = spans
     whole = True
     hsp = subs[0]
     if hsp is not None and len(hsp[1]) == 6 and hsp[1][5] is not None:
+        off = bounds[0]
         hb, fb = hsp[0], hsp[1][5][0]
         cache = block.header._cache
-        cache["bytes"] = raw[bounds[0]:bounds[1]]
+        cache["bytes"] = raw[off:bounds[1]]
         cache["spans"] = (
-            raw, list(zip(hb, hb[1:])),
-            list(zip((k for k, _v in block.header.fields),
-                     zip(fb, fb[1:]))))
+            hb[0] - off, hb[5] - off,
+            tuple((k, a - off, b - off) for (k, _v), a, b
+                  in zip(block.header.fields, fb, fb[1:])))
     else:
         whole = False
-    if tx_body_elems is not None and block.body:
-        if subs[1] is None:
-            return False
-        head = cbor._head(4, tx_body_elems)
-        for tx, tsp in zip(block.body, subs[1][1]):
-            cache = getattr(tx, "_cache", None)
-            if (cache is None or tsp is None
-                    or len(tsp[0]) <= tx_body_elems):
-                whole = False
-                continue
+    if tx_body_elems is None or not block.body:
+        return None, whole
+    if subs[1] is None:
+        return None, False
+    head = cbor._head(4, tx_body_elems)
+    blake2b = hashlib.blake2b
+    body, took = [], False
+    for tx, tsp in zip(block.body, subs[1][1]):
+        take = getattr(tx, "with_txid", None)
+        if take is None or tsp is None or len(tsp[0]) <= tx_body_elems:
+            whole = False
+        else:
             tb = tsp[0]
-            cache["body_bytes"] = head + raw[tb[0]:tb[tx_body_elems]]
-    return whole
+            tx = take(blake2b(head + raw[tb[0]:tb[tx_body_elems]],
+                              digest_size=32).digest())
+            took = True
+        body.append(tx)
+    return (tuple(body) if took else None), whole
 
 
 def body_hash_of(body: Sequence) -> bytes:

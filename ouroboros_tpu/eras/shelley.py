@@ -38,7 +38,8 @@ unit tests (tests/test_shelley_depth.py TestFullNonceRule).
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field, replace
+from collections import _tuplegetter     # the C getter namedtuple fields use
+from dataclasses import dataclass, replace
 from fractions import Fraction
 from functools import lru_cache
 from typing import Any, Optional, Sequence
@@ -491,8 +492,11 @@ CERT_DELEG = "deleg"
 CERT_RETIRE = "retire"
 
 
-@dataclass(frozen=True)
-class ShelleyTx:
+_tuple_new = tuple.__new__
+
+
+@dataclass(frozen=True, init=False, eq=False, match_args=False)
+class ShelleyTx(tuple):
     """Tx = inputs + outputs + certificates, Ed25519-witnessed over txid.
 
     One tx type serves the whole Shelley family, feature-gated per era
@@ -504,17 +508,47 @@ class ShelleyTx:
     - withdrawals: ((pool_id, amount), ...) — claim a reward balance into
       the tx's spendable value (must match the balance exactly, as in the
       reference's WDRL rule; witnessed by the pool's cold key)
-    """
-    inputs: tuple                      # TxIn-like (txid, ix) pairs
-    outputs: tuple                     # (addr, amount, assets) triples
-    certs: tuple = ()
-    witnesses: tuple = ()              # (vk, sig) pairs
-    validity: tuple = ()
-    mint: tuple = ()
-    withdrawals: tuple = ()            # ((pool_id, amount), ...)
 
-    _cache: dict = field(default_factory=dict, repr=False, hash=False,
-                         compare=False)
+    One flat row: a tuple of the seven fields and, last, the id, with
+    the fields read by name.  A replay holds one of these a transaction
+    of every decoded block, and a decode worker's reply (storage/
+    decode_pool.py) carries them all, so an instance is a single
+    object: no `__dict__`, no cache dict, and it unpickles by
+    `tuple.__new__` alone, with no call into Python.  To its callers it
+    is the frozen dataclass it was: keywords and defaults,
+    `dataclasses.replace`, `fields`, the repr; equality and hashing are
+    over the seven fields, never the id.
+
+    The id is Blake2b-256 of the body's encoding.  A transaction decoded
+    from stored bytes is handed it (`with_txid`: hashed from the exact
+    bytes on disk, where the block was decoded); one made without bytes
+    (the forge, the mempool, `replace`) hashes its own re-encoding on
+    first use and keeps it in the one-slot list it carries instead."""
+    __slots__ = ()
+    inputs: tuple = _tuplegetter(0, "TxIn-like (txid, ix) pairs")
+    outputs: tuple = _tuplegetter(1, "(addr, amount, assets) triples")
+    certs: tuple = _tuplegetter(2, "certificates")
+    witnesses: tuple = _tuplegetter(3, "(vk, sig) pairs")
+    validity: tuple = _tuplegetter(4, "() or (invalid_before, invalid_after)")
+    mint: tuple = _tuplegetter(5, "((asset_id, qty), ...)")
+    withdrawals: tuple = _tuplegetter(6, "((pool_id, amount), ...)")
+
+    def __new__(cls, inputs, outputs, certs=(), witnesses=(), validity=(),
+                mint=(), withdrawals=()):
+        return _tuple_new(cls, (inputs, outputs, certs, witnesses, validity,
+                                mint, withdrawals, [None]))
+
+    def __reduce__(self):
+        return _tuple_new, (ShelleyTx, tuple(self))
+
+    def __eq__(self, other):
+        return other.__class__ is ShelleyTx and self[:7] == other[:7]
+
+    def __ne__(self, other):           # tuple's own would look at the id
+        return not self == other
+
+    def __hash__(self):
+        return hash(self[:7])
 
     def body_encode(self):
         return [[list(i) for i in self.inputs],
@@ -527,14 +561,23 @@ class ShelleyTx:
 
     @property
     def txid(self) -> bytes:
-        c = self._cache
-        if "id" not in c:
-            # span-assembled body bytes from ProtocolBlock.from_bytes,
-            # when present — skips re-encoding the body
-            bb = c.pop("body_bytes", None)
-            c["id"] = _b2b(bb if bb is not None
-                           else cbor.dumps(self.body_encode()))
-        return c["id"]
+        t = self[7]
+        if t.__class__ is bytes:
+            return t
+        if t[0] is None:
+            t[0] = _b2b(cbor.dumps(self.body_encode()))
+        return t[0]
+
+    @property
+    def txid_hashed(self) -> bool:
+        """The id came with the transaction (`with_txid`); nothing is
+        left to encode or hash."""
+        return self[7].__class__ is bytes
+
+    def with_txid(self, txid: bytes) -> "ShelleyTx":
+        """This transaction carrying `txid`, the hash of the body bytes
+        it was decoded from (`ProtocolBlock.from_bytes`)."""
+        return _tuple_new(ShelleyTx, self[:7] + (txid,))
 
     def encode(self):
         return self.body_encode() + [[[vk, sig] for vk, sig in self.witnesses]]
@@ -575,7 +618,7 @@ def make_shelley_tx(inputs: Sequence, outputs: Sequence, certs: Sequence,
                    withdrawals=tuple(sorted(tuple(w) for w in withdrawals)))
     wits = tuple((ed25519_ref.public_key(sk), ed25519_ref.sign(sk, tx.txid))
                  for sk in signing_keys)
-    return replace(tx, witnesses=wits)
+    return replace(tx, witnesses=wits).with_txid(tx.txid)
 
 
 @dataclass(frozen=True)

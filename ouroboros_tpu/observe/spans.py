@@ -56,14 +56,18 @@ span named first; cat in brackets):
     stream.decode   decode.parse, decode.build, decode.slices [disk],
                     one each a block (consensus/headers.py): the one
                     walk of the bytes that keeps the list offsets, the
-                    block built from the parsed object, the cached raw
-                    slices cut at those offsets.  Where a decode worker
+                    block built from the parsed object, the header's
+                    slices cut and the transactions' ids hashed at
+                    those offsets.  Where a decode worker
                     process did them (storage/decode_pool.py) they are
                     its readings of the same clock, adopted (`adopt`)
                     with `thread` the worker's name, and lie before the
                     `stream.decode` they hang under: that span is then
                     the prefetch thread's wait for the chunk's reply and
-                    decode.unpack [disk], the reply turned into blocks
+                    decode.unpack [disk], the reply read from its pipe
+                    and turned into blocks (one where it was waiting
+                    whole; a look that found it not there yet is one
+                    more)
     window.host_seq seq.header, seq.body [host-seq], the header rules
                     and the ledger pass of one block, interleaved as
                     `_seq_block_step` runs them (consensus/batch.py)
@@ -113,7 +117,8 @@ clock, and on adopted rows), and the rest of its length (`off_cpu`,
 clipped to 0 and to the length) goes in whole microseconds to the
 counter `span.off_cpu_us.<name>` (gated, `stable=False`, bound once a
 name).  In a stage that makes no
-blocking call (`window.host_seq`, the unpickling of `decode.unpack`)
+blocking call (`window.host_seq`; `decode.unpack`, whose one read of a
+waiting reply does not block but gives the lock up)
 time off the CPU is the wait for the interpreter lock plus whatever the
 kernel took the core away for; in `window.submit`, `stream.read` and
 `stream.snapshot` it also holds the copies to the device and the file

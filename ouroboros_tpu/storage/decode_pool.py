@@ -33,9 +33,25 @@ Frames, parent to worker:
 clock this process's recorder reads too; None unless `timed`.  `counts`
 holds what the worker's registry counters rose by while it decoded the
 chunk (`replay.decode.one_walk_blocks`).
+
+What a reply costs the thread that takes it in is what it holds and how
+many system calls read it, because each call gives the interpreter lock
+up and has to get it back from a producer that wants it (ISSUE 39's
+step 0, PERF.md section 6: a reply read in 16 calls beside a spinning
+thread took 19.7 ms a block, in one call 1.7).  So a frame is written
+in one `write` and a reply that is waiting is read, head and body, by
+ONE `readv` into the worker's buffer (`_Worker.poll_reply`; the read end
+does not block, and `select` is only for a reply that is not there yet);
+and both of a worker's pipes are grown to the largest size the kernel
+grants (`grow_pipe`: 1 MiB on the chip's host against the default 64
+KiB), so a whole reply fits and the worker that wrote it is free for
+its next chunk.  What the blocks in it hold is the decoder's business
+(`ProtocolBlock.from_bytes`: the header's own bytes, a transaction as
+one flat row with its id).
 """
 from __future__ import annotations
 
+import fcntl
 import os
 import pickle
 import select
@@ -51,10 +67,13 @@ from ..observe import spans as _spans
 #: most workers a process starts.  Step 0 of ISSUE 32 (the chip's host,
 #: 13 cores, 512 full blocks; PERF.md section 6): 2 / 4 / 6 / 8 / 10
 #: workers deliver the chain in 2.29 / 1.26 / 0.98 / 0.81 / 0.73 s
-#: against 2.6-3.7 s in-thread, and the collecting thread's own CPU is
-#: 0.40-0.52 s throughout (0.8-1.0 ms a block: the unpickling).  At 8
-#: the workers and that thread are about level; two more buy 0.08 s a
-#: replay and leave the 13-core host's runtime threads no core
+#: against 2.6-3.7 s in-thread, and the collecting thread's own CPU was
+#: 0.40-0.52 s throughout (0.8-1.0 ms a block: the unpickling of the
+#: replies as they were then).  At 8 the workers and that thread were
+#: about level; two more bought 0.08 s a replay and leave the 13-core
+#: host's runtime threads no core.  Since ISSUE 39 a full block unpickles
+#: in ~0.45 ms (its step 0, same host), so the workers' ~0.7-0.8 ms a
+#: block (5.5 ms over 8) is the floor of a full-body chain's decode
 WORKER_CAP = 8
 #: the replay's own threads (prefetcher, producer, caller) keep a core each
 REPLAY_THREADS = 3
@@ -66,11 +85,20 @@ WORKER_NAME = "ouro-decode-worker"
 _PKG_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
+#: the pipe size asked for where /proc/sys/fs/pipe-max-size cannot be
+#: read: Linux's default limit for an unprivileged process
+PIPE_BYTES = 1 << 20
+
 # blocks that came back from a worker; whole microseconds the prefetch
 # thread waited for a reply that was not there yet (scheduling: unstable)
 _WORKER_BLOCKS = _metrics.counter("replay.decode.worker_blocks")
 _WORKER_WAIT_US = _metrics.counter("replay.decode.worker_wait_us",
                                    stable=False)
+# the replies of decoded chunks, added to once a reply: the bytes of
+# their pickles, and the read calls that took them in (a call that found
+# nothing there counts; how many depends on what had arrived: unstable)
+_REPLY_BYTES = _metrics.counter("replay.decode.reply_bytes")
+_REPLY_READS = _metrics.counter("replay.decode.reply_reads", stable=False)
 
 
 class DecodeWorkerDied(RuntimeError):
@@ -92,13 +120,45 @@ def worker_count() -> int:
                       len(os.sched_getaffinity(0)) - REPLAY_THREADS))
 
 
+# -- the pipes -----------------------------------------------------------------
+def _pipe_max() -> int:
+    try:
+        with open("/proc/sys/fs/pipe-max-size") as f:
+            return int(f.read())
+    except (OSError, ValueError):
+        return PIPE_BYTES
+
+
+def grow_pipe(fd: int) -> int:
+    """Ask the kernel for the largest pipe it grants on `fd`: the limit
+    it states (`_pipe_max`), halved until it accepts.  Returns the
+    pipe's size after that, 0 where it cannot be told.  A refusal (no
+    such call on this platform, EPERM, a pipe over the user's quota)
+    leaves the pipe as it was: a smaller pipe costs reads, nothing else."""
+    set_size = getattr(fcntl, "F_SETPIPE_SZ", None)
+    get_size = getattr(fcntl, "F_GETPIPE_SZ", None)
+    if set_size is None or get_size is None:
+        return 0
+    try:
+        have = fcntl.fcntl(fd, get_size)
+        want = _pipe_max()
+        while want > have:
+            try:
+                return fcntl.fcntl(fd, set_size, want)
+            except OSError:
+                want //= 2
+        return have
+    except OSError:
+        return 0
+
+
 # -- framing (both ends) -------------------------------------------------------
 def write_frame(fd: int, obj: Any) -> None:
+    """Head and pickle in one `write` where the pipe has the room."""
     data = pickle.dumps(obj, protocol=pickle.HIGHEST_PROTOCOL)
-    for part in (len(data).to_bytes(8, "little"), data):
-        view = memoryview(part)
-        while view:
-            view = view[os.write(fd, view):]
+    rest = memoryview(len(data).to_bytes(8, "little") + data)
+    while rest:
+        rest = rest[os.write(fd, rest):]
 
 
 def read_exact(fd: int, n: int) -> Optional[bytearray]:
@@ -138,6 +198,16 @@ class _Worker:
             env=env)
         self._in = self.proc.stdin.fileno()
         self._out = self.proc.stdout.fileno()
+        grow_pipe(self._in)
+        #: bytes the reply pipe holds (0: not told), the buffer's first size
+        self.pipe_bytes = grow_pipe(self._out)
+        os.set_blocking(self._out, False)
+        # the reply being read: its buffer (8-byte head, then the
+        # pickle), the bytes of it in hand, and the read calls made
+        # since the chunk went out
+        self._buf = bytearray(8 + max(self.pipe_bytes, 1 << 16))
+        self._got = 0
+        self.reads = 0
         self.closed = False
 
     def send(self, kind: str, payload: Any) -> None:
@@ -157,11 +227,44 @@ class _Worker:
                 return False
         return True
 
-    def read_reply(self) -> bytearray:
-        data = read_frame(self._out)
-        if data is None:
-            raise self._died()
-        return data
+    def poll_reply(self) -> Optional[memoryview]:
+        """Read what has arrived of the reply, without blocking: its
+        pickle (a view of this worker's buffer, good until the next
+        call) once it is whole, None while more is to come.  A reply
+        that was waiting whole costs one `readv`.  A worker answers one
+        frame at a time, so whatever the pipe holds is this reply's."""
+        buf, got = self._buf, self._got
+        need = 8 + int.from_bytes(buf[:8], "little") if got >= 8 else None
+        try:
+            while need is None or got < need:
+                if need is not None and need > len(buf):
+                    buf.extend(bytes(need - len(buf)))
+                self.reads += 1
+                with memoryview(buf) as view:
+                    k = os.readv(self._out, [view[got:need]])
+                if not k:
+                    raise self._died()
+                got += k
+                if need is None and got >= 8:
+                    need = 8 + int.from_bytes(buf[:8], "little")
+        except BlockingIOError:
+            self._got = got
+            return None
+        self._got = 0
+        if got > need:
+            self.close()
+            raise DecodeWorkerDied(
+                f"decode worker {self.name} sent {got - need} bytes more "
+                f"than its reply's head announced")
+        return memoryview(buf)[8:need]
+
+    def read_reply(self) -> memoryview:
+        """The whole reply's pickle, waited for (see `poll_reply`)."""
+        while True:
+            data = self.poll_reply()
+            if data is not None:
+                return data
+            self.wait_reply(_never)
 
     def _died(self) -> DecodeWorkerDied:
         self.close()
@@ -216,6 +319,7 @@ class Lease:
     def dispatch(self, raws: list) -> None:
         w = self._idle.pop()
         self._flight.append((w, len(raws)))
+        w.reads = 0
         w.send("decode", (_spans.RECORDER.enabled, raws))
 
     def collect(self, stopped: Callable[[], bool],
@@ -224,15 +328,29 @@ class Lease:
         came true while waiting.  A decode error of the worker's is
         raised here as the worker raised it.  With `into` (the caller's
         open `stream.decode` span) the worker's stage spans become its
-        children and the unpickling is timed as `decode.unpack`."""
+        children; reading the reply and unpickling it is timed as
+        `decode.unpack` (one span where the reply was waiting whole,
+        one more for every look that found it not all there yet)."""
         w, n = self._flight[0]
-        t0 = _spans.monotonic_now()
-        ready = w.wait_reply(stopped)
-        _WORKER_WAIT_US.inc(int((_spans.monotonic_now() - t0) * 1e6))
-        if not ready:
-            return None
-        with _spans.span("decode.unpack", cat="disk", cpu=True):
-            status, body = pickle.loads(w.read_reply())
+        reply = None
+        while reply is None:
+            # read first: a reply that is waiting costs this one call,
+            # and `select` is for one that is not there yet
+            with _spans.span("decode.unpack", cat="disk", cpu=True):
+                data = w.poll_reply()
+                if data is not None:
+                    _REPLY_BYTES.inc(len(data))
+                    reply = pickle.loads(data)
+                    data.release()
+            if reply is None:
+                t0 = _spans.monotonic_now()
+                ready = w.wait_reply(stopped)
+                _WORKER_WAIT_US.inc(
+                    int((_spans.monotonic_now() - t0) * 1e6))
+                if not ready:
+                    return None
+        _REPLY_READS.inc(w.reads)
+        status, body = reply
         self._flight.popleft()
         self._idle.append(w)
         if status != "ok":

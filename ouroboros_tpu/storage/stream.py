@@ -94,6 +94,12 @@ _CHUNKS = _metrics.counter("replay.stream.chunks_read")
 _BLOCKS = _metrics.counter("replay.stream.blocks_decoded")
 _BYTES = _metrics.counter("replay.stream.bytes_read")
 _ERAS = _metrics.counter("replay.stream.era_crossings")
+# transactions of the blocks the prefetcher took in, wherever they were
+# decoded, and those of them that came back from a decode worker with
+# their id already hashed there (`txid_hashed`: ProtocolBlock.from_bytes
+# handed it over, and nothing is left for the host pass to encode)
+_TXS = _metrics.counter("replay.decode.txs")
+_SHIPPED_TXIDS = _metrics.counter("replay.decode.shipped_txids")
 _SNAPS = _metrics.counter("replay.stream.snapshots_written")
 _STALLS = _metrics.counter("replay.stream.prefetch_stalls", stable=False)
 # whole microseconds the prefetch thread spent in those stalls: blocked at
@@ -288,7 +294,8 @@ class BlockPrefetcher:
     lambda, a stateful decoder), a DB without the chunk API, and a
     second replay while one holds the pool, decode on this thread.
     Both run `decode_pool.decode_blocks` with the same decoder, so the
-    blocks, their cached slices and a decode error's type are the same.
+    blocks, what they keep of their bytes and a decode error's type are
+    the same.
     Blocks in flight at the workers count as read ahead: no chunk goes
     out while decoded-but-unconsumed blocks plus those in flight would
     pass `depth * window` (one chunk always may, so the stream moves).
@@ -358,9 +365,11 @@ class BlockPrefetcher:
             self._thread.join()
 
     # -- the reading thread --------------------------------------------------
-    def _account(self, blocks: list) -> list:
-        """A chunk's decoded blocks, wherever they were decoded: era
-        crossings, counts, the caller's hook."""
+    def _account(self, blocks: list, shipped: bool = False) -> list:
+        """A chunk's decoded blocks, wherever they were decoded
+        (`shipped`: in a decode worker): era crossings, counts, the
+        caller's hook."""
+        txs = hashed = 0
         for b in blocks:
             hdr = getattr(b, "header", b)
             era = hdr.get(ERA_FIELD) if hasattr(hdr, "get") else None
@@ -369,6 +378,13 @@ class BlockPrefetcher:
                     self.era_crossings += 1
                     _ERAS.inc()
                 self._last_era = era
+            body = getattr(b, "body", ())
+            txs += len(body)
+            if shipped:    # a class that does not say carries none
+                hashed += sum([getattr(tx, "txid_hashed", False)
+                               for tx in body])
+        _TXS.inc(txs)
+        _SHIPPED_TXIDS.inc(hashed)
         self.blocks_decoded += len(blocks)
         _BLOCKS.inc(len(blocks))
         if self.on_decoded is not None:
@@ -408,7 +424,8 @@ class BlockPrefetcher:
         consumer closed the stream meanwhile."""
         with self._disk("stream.decode") as sp:
             blocks = lease.collect(lambda: self._stop, into=sp)
-            return None if blocks is None else self._account(blocks)
+            return None if blocks is None \
+                else self._account(blocks, shipped=True)
 
     def _read_chunks(self) -> Iterator[list]:
         """(entry, raw) pairs a chunk, from the resume cursor on."""

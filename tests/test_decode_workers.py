@@ -136,8 +136,9 @@ def test_blocks_from_workers_equal_blocks_decoded_in_thread(loaded):
     assert worker_blocks() - w0 == BLOCKS        # the closure shipped none
     assert len(shipped) == BLOCKS and shipped == local
     for a, b in zip(shipped, local):
-        # the cached slices crossed intact: the header's bytes and
-        # spans, every transaction's body bytes
+        # what was cut at the walk's offsets crossed intact: the
+        # header's own bytes and the spans inside them, and every
+        # transaction's id, hashed where the block was decoded
         assert a.header._cache.keys() == b.header._cache.keys() \
             >= {"bytes", "spans"}
         assert a.header._cache["bytes"] == b.header._cache["bytes"]
@@ -147,7 +148,7 @@ def test_blocks_from_workers_equal_blocks_decoded_in_thread(loaded):
             == b.header.bytes_dropping(KES_FIELD)
         assert len(a.body) == TXS
         for ta, tb in zip(a.body, b.body):
-            assert ta._cache["body_bytes"] == tb._cache["body_bytes"]
+            assert ta.txid_hashed and tb.txid_hashed
             assert ta.txid == tb.txid
     # and they are what the stored bytes say, read with no cache at all
     for blk, (_entry, raw) in zip(shipped, db.stream()):
@@ -493,3 +494,307 @@ def test_no_worker_outlives_its_parent(chain_dir, ending):
         if child.poll() is None:
             child.kill()
             child.wait()
+
+
+# -- what a reply holds (ISSUE 39) -----------------------------------------------
+
+def _through_a_worker(decode, raws) -> list:
+    """`raws` decoded as one chunk by a worker of the process's pool."""
+    lease = POOL.lease(decode)
+    assert lease is not None
+    try:
+        lease.dispatch(list(raws))
+        return lease.collect(lambda: False)
+    finally:
+        lease.release()
+
+
+def _blake2b(data: bytes) -> bytes:
+    import hashlib
+    return hashlib.blake2b(data, digest_size=32).digest()
+
+
+def _shelley_raw(n_txs: int, header_edit=None) -> bytes:
+    from test_block_decode import block_bytes, header_of, shelley_tx
+    from ouroboros_tpu.consensus.headers import ProtocolBlock
+    from ouroboros_tpu.utils import cbor
+    body = tuple(shelley_tx(i, rich=i % 7 == 3) for i in range(n_txs))
+    if header_edit is None:
+        return block_bytes(body)
+    enc = ProtocolBlock(header_of(body), body).encode()
+    header_edit(enc)
+    return cbor.dumps(enc)
+
+
+def _byron_raw() -> bytes:
+    from ouroboros_tpu.consensus.headers import ProtocolBlock, make_header
+    from ouroboros_tpu.eras.byron import make_byron_tx
+    from ouroboros_tpu.eras.cardano import BYRON, ERA_FIELD
+    body = tuple(make_byron_tx([(bytes([i]) * 32, i)],
+                               [(bytes([9 - i]) * 32, 100 + i)], [],
+                               [bytes([i + 1]) * 32]) for i in range(3))
+    header = make_header(None, 5, body, issuer=1).with_fields(
+        **{ERA_FIELD: BYRON, "byron_sig": bytes(64)})
+    return ProtocolBlock(header, body).bytes
+
+
+def _decoder_and_raw(kind: str):
+    from ouroboros_tpu.consensus.headers import BlockDecoder
+    from ouroboros_tpu.eras.cardano import cardano_block_from_bytes
+    from ouroboros_tpu.eras.shelley import ShelleyTx
+    if kind == "byron":
+        return cardano_block_from_bytes, _byron_raw()
+    return BlockDecoder(ShelleyTx.decode, 6), _shelley_raw(int(kind))
+
+
+@limit(120)
+@pytest.mark.parametrize("kind", ["0", "1", "88", "352", "byron"])
+def test_a_block_from_a_worker_is_the_block_decoded_here(kind):
+    from ouroboros_tpu.utils import cbor
+    decode, raw = _decoder_and_raw(kind)
+    w0 = worker_blocks()
+    (shipped,) = _through_a_worker(decode, [raw])
+    assert worker_blocks() - w0 == 1
+    local = decode(raw)
+    assert shipped == local and shipped.body == local.body
+    assert shipped.header.hash == local.header.hash \
+        == _blake2b(local.header.bytes)
+    assert shipped.header.bytes == local.header.bytes
+    assert raw[1:1 + len(local.header.bytes)] == local.header.bytes
+    for drop in ((KES_FIELD,), ("kes_sig",), ()):
+        assert shipped.header.bytes_dropping(*drop) \
+            == local.header.bytes_dropping(*drop) \
+            == cbor.dumps(local.header.encode(drop))
+    assert [t.txid for t in shipped.body] == [t.txid for t in local.body] \
+        == [_blake2b(cbor.dumps(t.body_encode())) for t in local.body]
+    assert shipped.bytes == local.bytes == raw
+    if kind != "byron":
+        assert len(shipped.body) == int(kind)
+        # hashed where the block was decoded; nothing is left to encode
+        assert all(t.txid_hashed for t in shipped.body)
+        assert all(t.txid_hashed for t in local.body)
+    # the header keeps its own bytes and offsets into them, no more
+    assert max(len(v) for v in shipped.header._cache.values()
+               if isinstance(v, bytes)) == len(local.header.bytes)
+
+
+def _seven_element_header(enc):
+    enc[0].append(0)
+
+
+@limit(120)
+@pytest.mark.parametrize("case", ["header-not-a-6-list",
+                                  "tx-of-too-few-elements"])
+def test_a_malformed_item_from_a_worker_falls_back_to_re_encoding(case):
+    """The item not shaped as expected comes back as `decode` built it
+    and is re-encoded when asked; the block is not counted as one walk's,
+    in the worker's registry or (through the reply's counts) in this
+    one."""
+    from ouroboros_tpu.consensus.headers import BlockDecoder
+    from ouroboros_tpu.eras.shelley import ShelleyTx
+    from ouroboros_tpu.utils import cbor
+    one_walk = observe.REGISTRY.get("replay.decode.one_walk_blocks")
+    if case == "header-not-a-6-list":
+        decode = BlockDecoder(ShelleyTx.decode, 6)
+        raw = _shelley_raw(3, _seven_element_header)
+    else:       # a body of 8 elements is asked of 7-element transactions
+        decode = BlockDecoder(ShelleyTx.decode, 8)
+        raw = _shelley_raw(3)
+    before = one_walk.value
+    (blk,) = _through_a_worker(decode, [raw])
+    assert one_walk.value == before
+    good = BlockDecoder(ShelleyTx.decode, 6)
+    (ok,) = _through_a_worker(good, [_shelley_raw(3)])
+    assert one_walk.value == before + 1
+    assert blk.body == ok.body
+    if case == "header-not-a-6-list":
+        assert not blk.header._cache
+        assert all(t.txid_hashed for t in blk.body)
+    else:
+        assert set(blk.header._cache) == {"bytes", "spans"}
+        assert not any(t.txid_hashed for t in blk.body)
+    assert blk.header.bytes_dropping("kes_sig") \
+        == cbor.dumps(blk.header.encode(("kes_sig",)))
+    assert [t.txid for t in blk.body] == [t.txid for t in ok.body] \
+        == [_blake2b(cbor.dumps(t.body_encode())) for t in blk.body]
+
+
+@pytest.mark.parametrize("n_txs", [88, 352])
+def test_a_reply_holds_no_block_bytes_and_loads_with_no_python_call(n_txs):
+    """What a worker writes for a chunk: under 1.5x the chunk's bytes on
+    disk (the decoded fields, the header's own bytes, a 32-byte id a
+    transaction; 2.7x while the header's spans held the block's bytes
+    and every transaction its body's), and `pickle.loads` of it runs no
+    Python code of the package, whatever the number of transactions."""
+    decode, raw = _decoder_and_raw(str(n_txs))
+    raws = [raw] * 5
+    blocks = decode_pool.decode_blocks(decode, raws)
+    reply = pickle.dumps(("ok", (blocks, None, {})),
+                         protocol=pickle.HIGHEST_PROTOCOL)
+    assert len(reply) < 1.5 * sum(map(len, raws))
+    tx_list = raw[1 + len(blocks[0].header.bytes):]
+    assert tx_list[:256] not in reply and tx_list[-256:] not in reply
+    calls = []
+
+    def profile(frame, event, _arg):
+        if event == "call":
+            calls.append(frame.f_code.co_filename)
+    sys.setprofile(profile)
+    try:
+        status, (back, _rows, _counts) = pickle.loads(reply)
+    finally:
+        sys.setprofile(None)
+    assert [c for c in calls if "ouroboros_tpu" in c] == []
+    assert len(calls) <= 2             # none a transaction, none a block
+    assert status == "ok" and back == blocks
+    assert [t.txid for b in back for t in b.body] \
+        == [t.txid for b in blocks for t in b.body]
+    # one object a transaction: no instance dict, no cache dict
+    tx = back[0].body[0]
+    assert not hasattr(tx, "__dict__") and not hasattr(tx, "_cache")
+
+
+def test_shelley_tx_made_and_replaced_hashes_and_compares_as_before():
+    import dataclasses
+    from ouroboros_tpu.eras.shelley import ShelleyTx, make_shelley_tx
+    from ouroboros_tpu.utils import cbor
+    sk = bytes(range(32))
+    tx = make_shelley_tx([(b"\x01" * 32, 0)], [(b"\x02" * 32, 7)], [], [sk])
+    want = _blake2b(cbor.dumps(tx.body_encode()))
+    assert tx.txid == want and len(tx.witnesses) == 1
+    bare = ShelleyTx(tx.inputs, tx.outputs)
+    assert not bare.txid_hashed and bare.txid == want
+    assert bare.txid is bare.txid                  # hashed once, kept
+    # equality and hashing: the seven fields, never the id
+    same = ShelleyTx(tx.inputs, tx.outputs, witnesses=tx.witnesses)
+    assert same == tx and hash(same) == hash(tx) and not (same != tx)
+    assert same.with_txid(want) == tx and {tx: 1}[same.with_txid(want)] == 1
+    assert bare != tx and tx != tx.encode() and tx != tuple(tx)
+    assert ShelleyTx.decode(tx.encode()) == tx
+    assert ShelleyTx.decode(cbor.loads(cbor.dumps(tx.encode()))).txid == want
+    # replace: a witness flipped keeps the id's value, a body changed
+    # has its own id, and neither touches the original
+    flipped = dataclasses.replace(tx, witnesses=((b"k" * 32, b"s" * 64),))
+    assert flipped != tx and flipped.txid == want
+    assert flipped.inputs == tx.inputs and flipped.outputs == tx.outputs
+    moved = dataclasses.replace(tx, outputs=((b"\x03" * 32, 7, ()),))
+    assert moved.txid == _blake2b(cbor.dumps(moved.body_encode())) != want
+    assert tx.txid == want
+    assert [f.name for f in dataclasses.fields(tx)] == [
+        "inputs", "outputs", "certs", "witnesses", "validity", "mint",
+        "withdrawals"]
+    with pytest.raises(dataclasses.FrozenInstanceError):
+        tx.inputs = ()
+    assert "witnesses=" in repr(tx) and repr(tx).startswith("ShelleyTx(")
+    for t in (tx, bare, flipped):                  # with and without an id
+        back = pickle.loads(pickle.dumps(t))
+        assert back == t and back.txid == t.txid \
+            and back.txid_hashed == t.txid_hashed
+
+
+# -- the pipes (ISSUE 39) -----------------------------------------------------------
+
+def _pipe_size(fd: int) -> int:
+    import fcntl
+    return fcntl.fcntl(fd, fcntl.F_GETPIPE_SZ)
+
+
+@limit(120)
+def test_reply_pipes_are_as_large_as_the_kernel_grants(loaded):
+    db, decode = loaded
+    stream(db, decode)                             # the pool is up
+    workers = POOL._ensure()
+    assert workers
+    for w in workers:
+        assert w.pipe_bytes == _pipe_size(w._out) >= 1 << 16
+        assert _pipe_size(w._in) >= 1 << 16
+        assert not os.get_blocking(w._out)
+    # what is asked for is the kernel's stated limit; where it grants
+    # that (a user over the pipe quota is held under it), that is it
+    asked = decode_pool._pipe_max()
+    r, w_ = os.pipe()
+    try:
+        got = decode_pool.grow_pipe(r)
+        assert got == _pipe_size(r)
+        assert got == asked or 1 << 16 <= got < asked
+    finally:
+        os.close(r)
+        os.close(w_)
+    if got == asked:
+        assert max(w.pipe_bytes for w in workers) == asked
+
+
+@limit(120)
+@pytest.mark.parametrize("refusal", ["EPERM", "no-such-call"])
+def test_a_refused_pipe_size_leaves_a_working_pool(monkeypatch, refusal):
+    """The pipes stay as `subprocess` made them, and a reply larger than
+    its pipe comes in pieces: the worker blocks in its write until the
+    first piece is read, and the reads go on where they stopped."""
+    import fcntl
+    if refusal == "EPERM":
+        real = fcntl.fcntl
+
+        def fcntl_refusing(fd, cmd, *arg):
+            if cmd == fcntl.F_SETPIPE_SZ:
+                raise PermissionError(1, "Operation not permitted")
+            return real(fd, cmd, *arg)
+        monkeypatch.setattr(decode_pool.fcntl, "fcntl", fcntl_refusing)
+    else:
+        monkeypatch.delattr(decode_pool.fcntl, "F_SETPIPE_SZ")
+    r, w_ = os.pipe()
+    try:
+        default = _pipe_size(r)
+        assert decode_pool.grow_pipe(r) == (default if refusal == "EPERM"
+                                            else 0)
+        assert _pipe_size(r) == default
+    finally:
+        os.close(r)
+        os.close(w_)
+    decode, raw = _decoder_and_raw("88")
+    pool = decode_pool.DecodePool()
+    try:
+        lease = pool.lease(decode)
+        assert lease is not None
+        reads = observe.REGISTRY.get("replay.decode.reply_reads")
+        size = observe.REGISTRY.get("replay.decode.reply_bytes")
+        r0, s0 = reads.value, size.value
+        lease.dispatch([raw] * 8)
+        blocks = lease.collect(lambda: False)
+        lease.release()
+        assert blocks == [decode(raw)] * 8
+        assert size.value - s0 > 2 * default       # larger than its pipe
+        assert reads.value - r0 >= 3               # ... so in pieces
+        assert all(_pipe_size(w._out) == default for w in pool._workers)
+    finally:
+        pool.close()
+
+
+@limit(60)
+@pytest.mark.parametrize("grown", [False, True])
+def test_a_frame_larger_than_its_pipe_is_read_whole(grown):
+    """`write_frame` against `read_frame` / `read_exact` (a worker's end
+    of both pipes, and the step-0 experiments): the writer blocks until
+    the reader has made room, as often as it takes."""
+    r, w = os.pipe()
+    try:
+        size = decode_pool.grow_pipe(r) if grown else _pipe_size(r)
+        payload = os.urandom(3 * size + 12345)
+        writer = threading.Thread(target=decode_pool.write_frame,
+                                  args=(w, payload))
+        writer.start()
+        head = decode_pool.read_exact(r, 8)
+        n = int.from_bytes(head, "little")
+        assert n > 3 * size
+        assert pickle.loads(decode_pool.read_exact(r, n)) == payload
+        writer.join()
+        decode_pool.write_frame(w, "small")
+        assert pickle.loads(decode_pool.read_frame(r)) == "small"
+        os.write(w, (100).to_bytes(8, "little") + b"cut short")
+        os.close(w)
+        w = None
+        assert decode_pool.read_frame(r) is None   # a frame cut short
+        assert decode_pool.read_exact(r, 8) is None        # end of file
+    finally:
+        for fd in (r, w):
+            if fd is not None:
+                os.close(fd)
