@@ -24,7 +24,7 @@ from typing import Any, Callable, Optional, Sequence
 from ..crypto.backend import CryptoBackend, default_backend
 from ..observe import spans as _spans
 from .header_validation import (
-    HeaderError, HeaderState, validate_envelope, revalidate_header,
+    HeaderError, HeaderState, reapply_ticked_header, validate_envelope,
 )
 from .ledger import (
     ExtLedgerRules, ExtLedgerState, LedgerError, OutsideForecastRange,
@@ -75,7 +75,7 @@ def _seq_header_pass(protocol: ConsensusProtocol, headers: Sequence[Any],
                 st.chain_dep_state, view, h.slot)
             protocol.sequential_checks(ticked, h, view)
             reqs = protocol.extract_proofs(ticked, h, view)
-            st = revalidate_header(protocol, view, h, st)
+            st = reapply_ticked_header(protocol, view, h, ticked)
         except OutsideForecastRange as e:
             # not a validation failure: the caller must wait for the chain
             # to advance (ChainSync forecast-horizon waiting)
@@ -168,7 +168,8 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
         with _spans.span("body.reapply", cat="host-seq"):
             ledger_state = ledger.reapply_block(ticked_ledger, b)
     with _spans.span("seq.header", cat="host-seq"):
-        header_state = revalidate_header(protocol, view, header, st.header)
+        header_state = reapply_ticked_header(protocol, view, header,
+                                             ticked_dep)
     return reqs, ExtLedgerState(ledger_state, header_state)
 
 

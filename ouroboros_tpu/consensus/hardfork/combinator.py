@@ -22,11 +22,16 @@ from __future__ import annotations
 from dataclasses import dataclass, replace
 from typing import Any, Callable, Optional, Sequence
 
+from ...observe import metrics as _metrics
+from ...observe import spans as _spans
 from ..ledger import ExtLedgerRules, LedgerError, LedgerRules
 from ..protocol import ConsensusProtocol, ProtocolError
 from .history import EraParams, PastHorizon, Summary
 
 ERA_FIELD = "hfc_era"
+
+# windows of a replay's host pass that held blocks of more than one era
+_MIXED_WINDOWS = _metrics.counter("hfc.mixed_windows")
 
 
 @dataclass(frozen=True)
@@ -116,11 +121,59 @@ def era_of_slot(eras: Sequence[Era], state: HardForkState,
         return len(s.eras) - 1       # open final era extends
 
 
+class HostPassTally:
+    """One window of a replay's host pass told apart by era: the blocks
+    the sequential step took and the seconds it took them, kept in local
+    lists a block and added to the registry once, at the window's end:
+    `hfc.era_blocks.<era>`, `hfc.era_host_us.<era>` (whole microseconds)
+    and, where more than one era had a block, `hfc.mixed_windows`."""
+
+    __slots__ = ("_counters", "_blocks", "_secs")
+
+    def __init__(self, counters: list):
+        self._counters = counters          # (blocks, host_us) an era
+        self._blocks = [0] * len(counters)
+        self._secs = [0.0] * len(counters)
+
+    def block(self, state_after: "HardForkState", secs: float) -> None:
+        """One block stepped: `state_after` is the ledger state it left,
+        whose era is the block's."""
+        self._blocks[state_after.era] += 1
+        self._secs[state_after.era] += secs
+
+    def close(self) -> None:
+        for (blocks, host_us), n, secs in zip(self._counters, self._blocks,
+                                              self._secs):
+            if n:
+                blocks.inc(n)
+                host_us.inc(int(secs * 1e6))
+        if sum(map(bool, self._blocks)) > 1:
+            _MIXED_WINDOWS.inc()
+
+
 class HardForkLedger(LedgerRules):
     """LedgerRules over HardForkState (Combinator/Ledger.hs)."""
 
     def __init__(self, eras: Sequence[Era]):
         self.eras = list(eras)
+        # eras of one name (the intra-Shelley hops keep theirs apart by
+        # name) share nothing: a counter pair an era
+        self._era_counters = [
+            (_metrics.counter(f"hfc.era_blocks.{e.name}"),
+             _metrics.counter(f"hfc.era_host_us.{e.name}", stable=False))
+            for e in self.eras]
+        # the last crossing made: (state before, era reached, state
+        # after).  One block's step crosses twice from the same state
+        # (the header's forecast view, then the ledger's tick), and
+        # headers validated ahead of the ledger forecast across the
+        # boundary one after another; the states are immutable, so the
+        # crossing of one state object is made once
+        self._crossed: tuple = (None, 0, None)
+
+    def host_pass_tally(self) -> HostPassTally:
+        """A fresh tally for one window of a replay's host pass (the
+        replay driver asks for one a window, consensus/pipeline.py)."""
+        return HostPassTally(self._era_counters)
 
     def initial_state(self) -> HardForkState:
         return HardForkState(0, self.eras[0].ledger.initial_state(), ())
@@ -133,15 +186,24 @@ class HardForkLedger(LedgerRules):
 
     def _cross(self, state: HardForkState, target_era: int,
                summary: Summary) -> HardForkState:
-        """Tick across era boundaries, translating state (CanHardFork)."""
+        """Tick across era boundaries, translating state (CanHardFork).
+        Each translation runs in an `hfc.translate` span."""
+        if state.era >= target_era:
+            return state
+        before, reached, after = self._crossed
+        if before is state and reached == target_era:
+            return after
+        start = state
         while state.era < target_era:
             era = self.eras[state.era]
             boundary = summary.eras[state.era].end
             # tick the old era's ledger up to its boundary, then translate
             inner = era.ledger.tick(state.inner, boundary.slot)
-            nxt = era.translate_ledger(inner)
+            with _spans.span("hfc.translate", cat="host-seq"):
+                nxt = era.translate_ledger(inner)
             state = HardForkState(state.era + 1, nxt,
                                   state.transitions + (boundary.epoch,))
+        self._crossed = (start, target_era, state)
         return state
 
     def tick(self, state: HardForkState, slot: int) -> HardForkState:
@@ -252,8 +314,9 @@ class HardForkProtocol(ConsensusProtocol):
             boundary = ledger_view.summary.eras[state.era].end
             inner = era.protocol.tick_chain_dep_state(
                 state.inner, ledger_view.inner, boundary.slot)
-            state = HardForkState(state.era + 1,
-                                  era.translate_chain_dep(inner),
+            with _spans.span("hfc.translate", cat="host-seq"):
+                nxt = era.translate_chain_dep(inner)
+            state = HardForkState(state.era + 1, nxt,
                                   state.transitions + (boundary.epoch,))
         inner = self.eras[state.era].protocol.tick_chain_dep_state(
             state.inner, ledger_view.inner, slot)

@@ -122,6 +122,15 @@ def revalidate_header(protocol: ConsensusProtocol, ledger_view: Any,
     validate_envelope(header, header_state, protocol)
     ticked = protocol.tick_chain_dep_state(
         header_state.chain_dep_state, ledger_view, header.slot)
+    return reapply_ticked_header(protocol, ledger_view, header, ticked)
+
+
+def reapply_ticked_header(protocol: ConsensusProtocol, ledger_view: Any,
+                          header: Any, ticked: Any) -> HeaderState:
+    """`revalidate_header`'s last step, for a caller that has checked the
+    envelope and ticked the chain-dep state to the header's slot itself
+    (the sequential passes of consensus/batch.py): the state is ticked,
+    and where the tick crosses an era translated, once a header."""
     new_dep = protocol.reupdate_chain_dep_state(ticked, header, ledger_view)
     return HeaderState(ann_tip_of(header), new_dep)
 

@@ -132,7 +132,9 @@ class ProtocolBlock:
 
     @classmethod
     def from_bytes(cls, raw: bytes, tx_decode=None,
-                   tx_body_elems: int | None = None) -> "ProtocolBlock":
+                   tx_body_elems: int | None = None,
+                   era_field: str | None = None,
+                   era_txs: tuple = ()) -> "ProtocolBlock":
         """Decode AND keep what the hot sequential pass (header hash,
         KES signing bytes, tx ids) would otherwise re-encode for.  The
         bytes are walked once: the parse keeps the offsets of the list
@@ -148,6 +150,14 @@ class ProtocolBlock:
         offsets and hashed here, and the transaction is handed its id
         (`with_txid`), so `txid` never encodes or hashes later.
 
+        era_txs: the blocks of an era-composed DB hold another
+        transaction type an era.  The header is decoded first and its
+        `era_field` (an era index; the first era where it has none) picks
+        that block's `(tx_decode, tx_body_elems)` out of `era_txs`, whose
+        last pair serves every later era.  The same one walk, the same
+        slices and ids: a Byron block and a Shelley block of one DB go
+        through this one decode.
+
         An item not shaped so (a header that is not a 6-list, a tx of
         fewer elements, a transaction class without `with_txid`) stays
         as `decode` built it and is re-encoded when asked;
@@ -162,7 +172,14 @@ class ProtocolBlock:
         with _spans.span("decode.parse", cat="disk"):
             obj, spans = cbor.loads_spans(raw, depth=2)
         with _spans.span("decode.build", cat="disk"):
-            block = cls.decode(obj, tx_decode=tx_decode)
+            header = ProtocolHeader.decode(obj[0])
+            if era_txs:
+                era = header.get(era_field, 0)
+                tx_decode, tx_body_elems = era_txs[
+                    era if era.__class__ is int and 0 <= era < len(era_txs)
+                    else -1]
+            block = cls(header, tuple(tx_decode(t) for t in obj[1])
+                        if tx_decode else tuple(obj[1]))
         with _spans.span("decode.slices", cat="disk"):
             body, whole = _cache_slices(block, raw, spans, tx_body_elems)
             if body is not None:
@@ -178,17 +195,23 @@ class ProtocolBlock:
 
 @dataclass(frozen=True)
 class BlockDecoder:
-    """`ProtocolBlock.from_bytes` with its two arguments bound: a DB's
+    """`ProtocolBlock.from_bytes` with its arguments bound: a DB's
     decoder as an importable, picklable callable (a closure over them
     could not be sent to the streamed replay's decode worker processes,
     storage/decode_pool.py).  `tx_decode` has to pickle too: a
-    module-level function or a classmethod (`ShelleyTx.decode`)."""
+    module-level function or a classmethod (`ShelleyTx.decode`).  A DB
+    of one era binds the first two; an era-composed DB binds the header
+    field that holds a block's era and a `(tx_decode, tx_body_elems)`
+    an era (`eras/cardano.py CARDANO_DECODER`)."""
     tx_decode: Any = None
     tx_body_elems: Optional[int] = None
+    era_field: Optional[str] = None
+    era_txs: tuple = ()
 
     def __call__(self, raw: bytes) -> ProtocolBlock:
-        return ProtocolBlock.from_bytes(raw, tx_decode=self.tx_decode,
-                                        tx_body_elems=self.tx_body_elems)
+        return ProtocolBlock.from_bytes(raw, self.tx_decode,
+                                        self.tx_body_elems,
+                                        self.era_field, self.era_txs)
 
 
 def _cache_slices(block: ProtocolBlock, raw: bytes, spans,

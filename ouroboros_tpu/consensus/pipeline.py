@@ -287,14 +287,25 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
     begin = getattr(backend, "begin_replay", None)
     if begin is not None:
         begin()                 # what it counts window to window restarts
+    # a backend that shapes its programs by what is coming is told, a
+    # submit, what the windows in sight hold (JaxBackend.expect_lanes)
+    expect = getattr(backend, "expect_lanes", None)
+    # rules that tell their eras apart keep the host pass's blocks and
+    # seconds by era, a window at a time (HardForkLedger.host_pass_tally)
+    new_tally = getattr(ledger, "host_pass_tally", None)
     # producer start; None once the first submit is made
     t_first: Optional[float] = _spans.monotonic_now()
 
     def next_window():
+        """The next window as (headers, blocks, the VRF proofs whose
+        betas its host pass will read), None at the chain's end."""
         t = _spans.monotonic_now()
         w = list(itertools.islice(block_iter, window))
         _WAIT_BLOCKS_US.inc(_us_since(t))
-        return w or None
+        if not w:
+            return None
+        headers = [getattr(b, "header", b) for b in w]
+        return headers, w, protocol.vrf_proofs_of(headers)
 
     try:
         # bounded look-ahead: ahead[0] = current window, ahead[1:] = the
@@ -304,13 +315,14 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             w = next_window()
             if w is None:
                 break
-            ahead.append(([getattr(b, "header", b) for b in w], w))
+            ahead.append(w)
         if ahead:
             # windows 0 and 1 ride a plain prefetch; window w's device
             # call then carries window w+2's betas
             with _spans.span("pipeline.beta_prefetch", cat="device"):
                 protocol.prefetch_window(
-                    [h for hs, _w in list(ahead)[:2] for h in hs], backend)
+                    [h for hs, _w, _ps in list(ahead)[:2] for h in hs],
+                    backend)
 
         st = ext_state
         k = -1                          # index of the window in the replay
@@ -326,11 +338,11 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                 if shared.stop:
                     return
             k += 1
-            headers_w, blk_window = ahead.popleft()
+            headers_w, blk_window, _proofs = ahead.popleft()
             nxt = next_window()
             if nxt is not None:
-                ahead.append(([getattr(b, "header", b) for b in nxt],
-                              nxt))
+                ahead.append(nxt)
+            tally = new_tally() if new_tally is not None else None
             reqs: list = []
             owner: list[int] = []
             seq_error: Optional[Exception] = None
@@ -341,6 +353,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
             with _spans.span("window.host_seq", cat="host-seq", cpu=True,
                              window=k):
                 for i, b in enumerate(blk_window):
+                    t_block = _spans.monotonic_now()
                     try:
                         rs, st = _seq_block_step(protocol, ledger, st, b)
                     except OutsideForecastRange as e:
@@ -356,15 +369,26 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                     reqs.extend(rs)
                     owner.extend([i] * len(rs))
                     n_seq_w += 1
+                    if tally is not None:
+                        tally.block(st.ledger,
+                                    _spans.monotonic_now() - t_block)
+            if tally is not None:
+                tally.close()
             if progress is not None:
                 progress.host_end()
             # carry betas for the window TWO ahead (ahead[1] after the
-            # pop): the consumer installs them at drain time, which the
-            # permit above orders before that window's sequential pass
-            next_proofs = (protocol.vrf_proofs_of(ahead[1][0])
+            # pop), whatever era this window is of: the consumer
+            # installs them at drain time, which the permit above orders
+            # before that window's sequential pass
+            next_proofs = (ahead[1][2]
                            if len(ahead) > 1 and seq_error is None else ())
             next_proofs = [p for p in next_proofs
                            if p not in GLOBAL_BETA_CACHE]
+            if expect is not None:
+                # in sight: windows k+1..k+3; this submit carries k+2's
+                # betas and the next one k+3's
+                sight = [len(ps) for _hs, _w, ps in ahead]
+                expect(max(sight, default=0), max(sight[1:], default=0))
             if t_first is not None:
                 _FIRST_SUBMIT_US.inc(_us_since(t_first))
                 t_first = None

@@ -80,6 +80,10 @@ _ED_WIDTH_CHANGES = _metrics.counter("jax_backend.ed_width_changes")
 _BETA_WINDOWS = _metrics.counter("jax_backend.beta_windows")
 _BETA_ROWS = _metrics.counter("jax_backend.beta_rows_carried")
 _KES_EMPTY = _metrics.counter("jax_backend.kes_empty_windows")
+# windows that had none of the three (a Byron window that carries no
+# beta for a Shelley window ahead): tile calls only, no composite, no
+# fold program
+_COMPOSITE_FREE = _metrics.counter("jax_backend.composite_free_windows")
 
 # device-side verdict-fold sentinel: "no failing request".  int32 max so
 # jnp.min over any real request index beats it; request lists are bounded
@@ -220,6 +224,9 @@ class JaxBackend(CryptoBackend):
         self._windows_padded = 0
         # tiles one device walked for the previous window of this replay
         self._prev_ed_tiles = None
+        # padded VRF and beta lanes of the widest window the replay
+        # driver has in sight (`expect_lanes`)
+        self._in_sight = (0, 0)
 
     # -- subclass seams (ShardedJaxBackend overrides them) -------------------
     def _pad(self, n: int) -> int:
@@ -269,8 +276,28 @@ class JaxBackend(CryptoBackend):
     def begin_replay(self) -> None:
         """A new sequence of windows starts (the replay driver's
         producer says so): its first window has no previous window to
-        differ from (`jax_backend.ed_width_changes`)."""
+        differ from (`jax_backend.ed_width_changes`), and nothing is in
+        sight yet."""
         self._prev_ed_tiles = None
+        self._in_sight = (0, 0)
+
+    def expect_lanes(self, vrf: int, betas: int) -> None:
+        """The replay driver's word, before a submit, on the windows it
+        has decoded and not yet submitted: the most VRF proofs one of
+        them holds, and the most beta proofs this submit or the next
+        will carry for them.  A composite built for this window is made
+        wide enough for those too (`_occasional_widths`), so the windows
+        of a chain ride ONE composite in whichever order its eras meet
+        the backend."""
+        self._in_sight = (self._pad(vrf) if vrf else 0,
+                          self._pad(betas) if betas else 0)
+
+    def _kes_lanes_to_come(self) -> int:
+        """KES hash-job lanes a composite gets that is built BEFORE the
+        first window with a KES signature: the narrowest part there is
+        (a pool's path is walked once, `depth` jobs; a window that
+        brings more than fit builds its own)."""
+        return self._pad(1)
 
     # -- lane occupancy ------------------------------------------------------
     def _note_padding(self, used: int, padded: int) -> None:
@@ -726,27 +753,47 @@ class JaxBackend(CryptoBackend):
 
     def _occasional_widths(self, nv: int, nb: int, nk: int) -> tuple:
         """(nv, nb, nk) for a window whose VRF, beta and KES parts need
-        that many lanes: those of the narrowest composite this backend
-        has ALREADY built that holds all three, else their own.
+        that many lanes: (0, 0, 0), no composite at all, for a window
+        that has none of the three; else those of the narrowest
+        composite this backend has ALREADY built that holds all three;
+        else its own, widened to what the driver has in sight.
 
         A sync meets these parts at several widths: betas ride in every
         window but a chain's last two, KES hash jobs only in the windows
         that first meet a pool's hash path (and whether window 1 does is
-        a race with window 0's drain), and a chain's last window, or one
-        cut short at an invalid header, has fewer VRF lanes than the
-        rest.  Every (nv, nb, nk) is its own program, a minute or more
+        a race with window 0's drain), a chain's last window, or one cut
+        short at an invalid header, has fewer VRF lanes than the rest,
+        and a Byron window has none: it needs a composite only for the
+        betas it carries for a Shelley window two ahead, and the window
+        that holds the hard fork has VRF lanes for its Shelley blocks
+        alone.  Every (nv, nb, nk) is its own program, a minute or more
         to trace, lower and build or load, where the empty lanes of a
-        wider part cost the device microseconds (PERF.md section 6, PR
-        33).  So a window rides a built program that covers it rather
-        than building its own, and a chain's first window, the widest in
-        all three, fixes the program for the rest.  A window with no VRF
-        lane at all (a Byron window) keeps a program of its own."""
+        wider part cost the device microseconds (PERF.md section 6, PRs
+        33 and 40).  So a window rides a built program that covers it
+        rather than building its own, whichever parts it has itself.
+        On a Shelley chain the first window, the widest in all three,
+        fixes the program for the rest.  Where the first window to need
+        one is narrower than those behind it (a Byron window carrying
+        the first Shelley betas), the program is built for what the
+        driver says is coming (`expect_lanes`): the VRF and beta lanes
+        in sight, and, those VRF lanes being of headers this window has
+        none of, a KES part for their hash paths
+        (`_kes_lanes_to_come`).  So a chain that starts in Byron builds
+        the composite a Shelley chain of the same window builds, once,
+        in set-up."""
+        if not (nv or nb or nk):
+            return 0, 0, 0
         best = None
         for v, b, k, _pallas in self._composites:
-            if v >= nv and bool(v) == bool(nv) and b >= nb and k >= nk \
+            if v >= nv and b >= nb and k >= nk \
                     and (best is None or v + b + k < sum(best)):
                 best = (v, b, k)
-        return best or (nv, nb, nk)
+        if best is not None:
+            return best
+        sv, sb = self._in_sight
+        if sv > nv:
+            nk = max(nk, self._kes_lanes_to_come())
+        return max(nv, sv), max(nb, sb), nk
 
     def submit_window(self, reqs, next_beta_proofs=(), fold: bool = False):
         """Dispatch one replay window's whole device workload — the mixed
@@ -762,10 +809,12 @@ class JaxBackend(CryptoBackend):
         packed output (on-device SHA-512 challenge fold for VRF —
         sha512_jax) and that index to the FIRST failing request index;
         finish_window returns a WindowVerdict scalar pair instead of the
-        boolean vector, after ONE transfer.  The composite is SHARED
-        between both modes (same program, same autotuned choice, same
-        compile); without fold the tiles run the bucket program of their
-        width and finish_window fetches their verdicts.
+        boolean vector, after ONE transfer.  A window without a
+        composite needs no second program: the tile calls' index IS its
+        verdict, and finish_window reads that scalar.  The composite is
+        SHARED between both modes (same program, same autotuned choice,
+        same compile); without fold the tiles run the bucket program of
+        their width and finish_window fetches their verdicts.
 
         `window.submit` holds one span a stage: submit.split,
         submit.pack_ed (key tables and the tiles' copy to the device
@@ -851,8 +900,10 @@ class JaxBackend(CryptoBackend):
             if nv or nb or nk:
                 state["packed"] = self._window_composite(
                     nv, nb, nk, allp)(vrf_args, beta_args, kes_args)
-            if fold and (tiles or state["packed"] is not None):
-                self._attach_fold(state, vrf_own)
+                if fold:
+                    self._attach_fold(state, vrf_own)
+            else:
+                _COMPOSITE_FREE.inc()
         return state
 
     def _note_ed_tiles(self, real: int, walked: int) -> None:
@@ -1088,6 +1139,12 @@ class JaxBackend(CryptoBackend):
         betas: dict = {}
         bad = state["host_first_bad"]
         if state["packed"] is None:
+            # no composite, so no fold program: the tile calls' running
+            # first-bad index is the device's whole verdict
+            ed_bad = state.get("ed_bad")
+            if ed_bad is not None:
+                with _spans.span("window.drain", cat="device"):
+                    bad = min(bad, int(np.asarray(ed_bad)))
             return WindowVerdict(
                 n, None if bad >= FOLD_SENT else bad), betas
         with _spans.span("window.drain", cat="device"):

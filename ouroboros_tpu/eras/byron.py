@@ -17,7 +17,8 @@ the batchable proofs (PBFT.hs:226-302; SURVEY.md §2 batching gap).
 from __future__ import annotations
 
 import hashlib
-from dataclasses import dataclass, field, replace
+from collections import _tuplegetter     # the C getter namedtuple fields use
+from dataclasses import dataclass, replace
 from typing import Optional, Sequence
 
 from ..chain.block import Point, point_of
@@ -26,7 +27,13 @@ from ..consensus.ledger import LedgerError, LedgerRules
 from ..consensus.protocol import ConsensusProtocol, ProtocolError
 from ..crypto import ed25519_ref
 from ..crypto.backend import Ed25519Req
+from ..observe import metrics as _metrics
 from ..utils import cbor
+from .txrow import TxRow, tuple_new
+
+# transactions the ledger walk applied, added to once a block from a
+# local integer (as `ledger.shelley.txs` is)
+_TXS = _metrics.counter("ledger.byron.txs")
 
 SIG_FIELD = "byron_sig"
 DELEGATE_FIELD = "byron_delegate_vk"
@@ -119,7 +126,7 @@ class ByronPBft(ConsensusProtocol):
         if header.get(SIG_FIELD) is None:
             raise ProtocolError("Byron/PBFT: header missing signature")
         signers = (ticked + (header.issuer,))[-self.window:]
-        count = sum(1 for s in signers if s == header.issuer)
+        count = signers.count(header.issuer)
         if count > max(1, self._limit()):
             raise ProtocolError(
                 f"Byron/PBFT: signer {header.issuer} signed {count} of "
@@ -183,28 +190,26 @@ CERT_DLG = "dlg"
 CERT_UPDATE = "upd"
 
 
-@dataclass(frozen=True)
-class ByronTx:
-    """UTxO tx + optional delegation certs, Ed25519-witnessed over txid."""
-    inputs: tuple                      # (txid, ix)
-    outputs: tuple                     # (addr, amount)
-    certs: tuple = ()
-    witnesses: tuple = ()              # (vk, sig)
+@dataclass(frozen=True, init=False, eq=False, match_args=False)
+class ByronTx(TxRow):
+    """UTxO tx + optional delegation certs, Ed25519-witnessed over txid.
 
-    _cache: dict = field(default_factory=dict, repr=False, hash=False,
-                         compare=False)
+    One flat row, as `ShelleyTx` is (`TxRow` says why): a tuple of the
+    four fields and, last, the id, with the fields read by name, so a
+    Byron transaction crosses from a decode worker carrying its id."""
+    __slots__ = ()
+    inputs: tuple = _tuplegetter(0, "(txid, ix) pairs")
+    outputs: tuple = _tuplegetter(1, "(addr, amount) pairs")
+    certs: tuple = _tuplegetter(2, "certificates")
+    witnesses: tuple = _tuplegetter(3, "(vk, sig) pairs")
+
+    def __new__(cls, inputs, outputs, certs=(), witnesses=()):
+        return tuple_new(cls, (inputs, outputs, certs, witnesses, [None]))
 
     def body_encode(self):
         return [[list(i) for i in self.inputs],
                 [list(o) for o in self.outputs],
                 [list(c) for c in self.certs]]
-
-    @property
-    def txid(self) -> bytes:
-        c = self._cache
-        if "id" not in c:
-            c["id"] = _b2b(cbor.dumps(self.body_encode()))
-        return c["id"]
 
     def encode(self):
         return self.body_encode() + [[[vk, sig] for vk, sig in self.witnesses]]
@@ -221,6 +226,11 @@ class ByronTx:
             tuple((bytes(vk), bytes(sig)) for vk, sig in obj[3]))
 
 
+#: elements of an encoded transaction that form its BODY, whose encoding
+#: the id hashes (`body_encode`; the witnesses follow)
+BYRON_TX_BODY_ELEMS = 3
+
+
 def make_byron_tx(inputs: Sequence, outputs: Sequence, certs: Sequence,
                   signing_keys: Sequence[bytes]) -> ByronTx:
     tx = ByronTx(tuple(tuple(i) for i in inputs),
@@ -228,7 +238,7 @@ def make_byron_tx(inputs: Sequence, outputs: Sequence, certs: Sequence,
                  tuple(tuple(c) for c in certs))
     wits = tuple((ed25519_ref.public_key(sk), ed25519_ref.sign(sk, tx.txid))
                  for sk in signing_keys)
-    return replace(tx, witnesses=wits)
+    return replace(tx, witnesses=wits).with_txid(tx.txid)
 
 
 @dataclass(frozen=True)
@@ -328,6 +338,7 @@ class ByronLedger(LedgerRules):
                 del utxo[(txid, ix)]
             for ix, (addr, amount) in enumerate(tx.outputs):
                 utxo[(tx.txid, ix)] = (addr, amount)
+        _TXS.inc(len(block.body))
         return replace(state, utxo=_freeze_utxo(utxo),
                        delegates=tuple(delegates), tip=point_of(block),
                        update_epoch=update_epoch)

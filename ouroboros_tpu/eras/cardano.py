@@ -19,20 +19,20 @@ Summary (eras/byron.py CERT_UPDATE).
 """
 from __future__ import annotations
 
+from fractions import Fraction
 from typing import Optional
 
 from ..consensus.hardfork import Era, EraParams, hard_fork_rules
 from ..consensus.hardfork.combinator import ERA_FIELD
-from ..consensus.headers import ProtocolBlock, ProtocolHeader
+from ..consensus.headers import BlockDecoder, ProtocolBlock, ProtocolHeader
 from ..crypto import ed25519_ref
-from ..utils import cbor
 from .byron import (
-    ByronLedger, ByronLedgerState, ByronPBft, ByronTx,
+    BYRON_TX_BODY_ELEMS, ByronLedger, ByronLedgerState, ByronPBft, ByronTx,
     byron_genesis_setup, byron_transition_epoch,
 )
 from .shelley import (
-    ShelleyLedger, ShelleyLedgerState, ShelleyTx, TPraos, TPraosConfig,
-    TPraosState, shelley_genesis_setup,
+    SHELLEY_TX_BODY_ELEMS, ShelleyLedger, ShelleyLedgerState, ShelleyTx,
+    TPraos, TPraosConfig, TPraosState, shelley_genesis_setup,
 )
 
 BYRON, SHELLEY, ALLEGRA, MARY = 0, 1, 2, 3
@@ -130,23 +130,44 @@ def cardano_setup(n_nodes: int, epoch_length: int = 20,
                   seed: bytes = b"cardano-net",
                   funds_per_key: int = 1000,
                   allegra_epoch: Optional[int] = None,
-                  mary_epoch: Optional[int] = None):
-    """Keys + eras for an n-node network that can cross the fork.
+                  mary_epoch: Optional[int] = None,
+                  byron_keys: Optional[int] = None,
+                  byron_epoch_length: Optional[int] = None,
+                  byron_k: Optional[int] = None,
+                  byron_threshold: float = 0.9,
+                  byron_window: int = 10,
+                  byron_slot_length: float = 1.0,
+                  shelley_slot_length: float = 0.5):
+    """Keys + eras for an n-node network that can cross the fork
+    (`protocolInfoCardano`: each era's parameters from its own genesis).
 
-    Every node holds both a Byron genesis/delegate key pair and a Shelley
-    pool (cold/VRF/KES) whose staking address is the SAME address funded in
-    the Byron genesis — so the Byron UTxO that crosses the boundary backs
-    the Shelley stake distribution (the genesis-staking bootstrap).
+    The Shelley era is `shelley_config` (left out: a small one of
+    `epoch_length`-slot epochs).  The Byron era has `byron_keys` genesis
+    keys, one delegate each (left out: `n_nodes`), epochs of
+    `byron_epoch_length` slots (left out: the Shelley era's) and a PBFT
+    signature threshold of `byron_threshold` over the last `byron_window`
+    blocks; its `k` is `byron_k` (left out: the Shelley era's).  The two
+    slot lengths are seconds (mainnet: 20 and 1).
 
-    Returns (eras, rules, nodes) where nodes[i] carries byron/shelley
-    credentials for forging."""
+    Every one of the `n_nodes` holds a Shelley pool (cold/VRF/KES) whose
+    staking address is funded in the BYRON genesis — so the Byron UTxO
+    that crosses the boundary backs the Shelley stake distribution (the
+    genesis-staking bootstrap).
+
+    Returns (eras, rules, nodes): nodes[i] carries the Byron credentials
+    of genesis key i and the Shelley credentials of pool i, whichever of
+    the two exist for that i."""
     if shelley_config is None:
         shelley_config = TPraosConfig(
             k=8, epoch_length=epoch_length, slots_per_kes_period=50,
             kes_depth=5, max_kes_evolutions=30)
     b_protocol, _b_ledger, b_nodes = byron_genesis_setup(
-        n_nodes, epoch_length=epoch_length, threshold=0.9, window=10,
-        k=shelley_config.k, funds_per_key=funds_per_key, seed=seed)
+        byron_keys if byron_keys is not None else n_nodes,
+        epoch_length=(byron_epoch_length if byron_epoch_length is not None
+                      else shelley_config.epoch_length),
+        threshold=byron_threshold, window=byron_window,
+        k=byron_k if byron_k is not None else shelley_config.k,
+        funds_per_key=funds_per_key, seed=seed)
     s_protocol, s_ledger_tmp, s_pools = shelley_genesis_setup(
         n_nodes, shelley_config, stake_per_pool=funds_per_key,
         seed=seed + b":shelley")
@@ -162,11 +183,35 @@ def cardano_setup(n_nodes: int, epoch_length: int = 20,
         initial_pools=dict(s_ledger_tmp.initial_pools),
         initial_delegs=dict(s_ledger_tmp.initial_delegs))
     eras = cardano_eras(b_protocol, b_ledger, s_protocol, s_ledger,
+                        byron_slot_length=byron_slot_length,
+                        shelley_slot_length=shelley_slot_length,
                         allegra_epoch=allegra_epoch, mary_epoch=mary_epoch)
-    nodes = []
-    for i in range(n_nodes):
-        nodes.append({**b_nodes[i], **s_pools[i], "index": i})
+    nodes = [{**(b_nodes[i] if i < len(b_nodes) else {}),
+              **(s_pools[i] if i < len(s_pools) else {}), "index": i}
+             for i in range(max(len(b_nodes), len(s_pools)))]
     return eras, hard_fork_rules(eras), nodes
+
+
+def cardano_rules(config: dict):
+    """`cardano_setup` from the record a forged chain's `config.json`
+    holds (`tools/db_synth.py synth_cardano` writes it and
+    `tools/db_analyser.load_db` reads it, so both build the same two
+    eras): `nodes`, `seed`, the hop epochs, and a `byron` and a `shelley`
+    block of that era's own parameters."""
+    b, s = config["byron"], config["shelley"]
+    return cardano_setup(
+        config["nodes"], seed=config["seed"].encode(),
+        shelley_config=TPraosConfig(
+            k=s["k"], f=Fraction(s["f"]), epoch_length=s["epoch_length"],
+            slots_per_kes_period=s["slots_per_kes_period"],
+            kes_depth=s["kes_depth"],
+            max_kes_evolutions=s["max_kes_evolutions"]),
+        allegra_epoch=config.get("allegra_epoch"),
+        mary_epoch=config.get("mary_epoch"),
+        byron_keys=b["genesis_keys"], byron_epoch_length=b["epoch_length"],
+        byron_k=b["k"], byron_threshold=b["threshold"],
+        byron_window=b["window"], byron_slot_length=b["slot_length"],
+        shelley_slot_length=s["slot_length"])
 
 
 def cardano_block_decode(obj) -> ProtocolBlock:
@@ -180,9 +225,14 @@ def cardano_block_decode(obj) -> ProtocolBlock:
     return ProtocolBlock(header, body)
 
 
-def cardano_block_from_bytes(raw: bytes) -> ProtocolBlock:
-    """`cardano_block_decode` of a stored block's bytes: the decoder of
-    a Cardano-composed DB (`tools/db_analyser.load_db`), a module-level
-    function so that a streamed replay can ship it to its decode worker
-    processes (storage/decode_pool.py)."""
-    return cardano_block_decode(cbor.loads(raw))
+#: the decoder of a Cardano-composed DB (`tools/db_analyser.load_db`):
+#: the one walk of a block's bytes every DB's decoder makes
+#: (`ProtocolBlock.from_bytes`), with the era tag of the header picking
+#: the body's transaction type, so header slices and transaction ids come
+#: from the walk's offsets in both eras.  A module-level object, so a
+#: streamed replay ships it to its decode worker processes
+#: (storage/decode_pool.py)
+CARDANO_DECODER = BlockDecoder(
+    era_field=ERA_FIELD,
+    era_txs=((ByronTx.decode, BYRON_TX_BODY_ELEMS),
+             (ShelleyTx.decode, SHELLEY_TX_BODY_ELEMS)))

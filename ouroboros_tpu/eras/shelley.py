@@ -54,6 +54,7 @@ from ..crypto.backend import (
 )
 from ..observe import metrics as _metrics
 from ..utils import cbor
+from .txrow import TxRow, tuple_new
 
 # header protocol-evidence fields (sign-the-header-minus-KES-sig convention)
 ETA_VRF_FIELD = "tp_eta_vrf"
@@ -492,11 +493,8 @@ CERT_DELEG = "deleg"
 CERT_RETIRE = "retire"
 
 
-_tuple_new = tuple.__new__
-
-
 @dataclass(frozen=True, init=False, eq=False, match_args=False)
-class ShelleyTx(tuple):
+class ShelleyTx(TxRow):
     """Tx = inputs + outputs + certificates, Ed25519-witnessed over txid.
 
     One tx type serves the whole Shelley family, feature-gated per era
@@ -509,21 +507,11 @@ class ShelleyTx(tuple):
       the tx's spendable value (must match the balance exactly, as in the
       reference's WDRL rule; witnessed by the pool's cold key)
 
-    One flat row: a tuple of the seven fields and, last, the id, with
-    the fields read by name.  A replay holds one of these a transaction
-    of every decoded block, and a decode worker's reply (storage/
-    decode_pool.py) carries them all, so an instance is a single
-    object: no `__dict__`, no cache dict, and it unpickles by
-    `tuple.__new__` alone, with no call into Python.  To its callers it
-    is the frozen dataclass it was: keywords and defaults,
-    `dataclasses.replace`, `fields`, the repr; equality and hashing are
-    over the seven fields, never the id.
-
-    The id is Blake2b-256 of the body's encoding.  A transaction decoded
-    from stored bytes is handed it (`with_txid`: hashed from the exact
-    bytes on disk, where the block was decoded); one made without bytes
-    (the forge, the mempool, `replace`) hashes its own re-encoding on
-    first use and keeps it in the one-slot list it carries instead."""
+    One flat row (`TxRow`, which says why and how the id rides along):
+    a tuple of the seven fields and, last, the id, with the fields read
+    by name.  To its callers it is the frozen dataclass it was: keywords
+    and defaults, `dataclasses.replace`, `fields`, the repr; equality
+    and hashing are over the seven fields, never the id."""
     __slots__ = ()
     inputs: tuple = _tuplegetter(0, "TxIn-like (txid, ix) pairs")
     outputs: tuple = _tuplegetter(1, "(addr, amount, assets) triples")
@@ -535,20 +523,8 @@ class ShelleyTx(tuple):
 
     def __new__(cls, inputs, outputs, certs=(), witnesses=(), validity=(),
                 mint=(), withdrawals=()):
-        return _tuple_new(cls, (inputs, outputs, certs, witnesses, validity,
-                                mint, withdrawals, [None]))
-
-    def __reduce__(self):
-        return _tuple_new, (ShelleyTx, tuple(self))
-
-    def __eq__(self, other):
-        return other.__class__ is ShelleyTx and self[:7] == other[:7]
-
-    def __ne__(self, other):           # tuple's own would look at the id
-        return not self == other
-
-    def __hash__(self):
-        return hash(self[:7])
+        return tuple_new(cls, (inputs, outputs, certs, witnesses, validity,
+                               mint, withdrawals, [None]))
 
     def body_encode(self):
         return [[list(i) for i in self.inputs],
@@ -558,26 +534,6 @@ class ShelleyTx(tuple):
                 list(self.validity),
                 [list(mv) for mv in self.mint],
                 [list(w) for w in self.withdrawals]]
-
-    @property
-    def txid(self) -> bytes:
-        t = self[7]
-        if t.__class__ is bytes:
-            return t
-        if t[0] is None:
-            t[0] = _b2b(cbor.dumps(self.body_encode()))
-        return t[0]
-
-    @property
-    def txid_hashed(self) -> bool:
-        """The id came with the transaction (`with_txid`); nothing is
-        left to encode or hash."""
-        return self[7].__class__ is bytes
-
-    def with_txid(self, txid: bytes) -> "ShelleyTx":
-        """This transaction carrying `txid`, the hash of the body bytes
-        it was decoded from (`ProtocolBlock.from_bytes`)."""
-        return _tuple_new(ShelleyTx, self[:7] + (txid,))
 
     def encode(self):
         return self.body_encode() + [[[vk, sig] for vk, sig in self.witnesses]]
@@ -597,6 +553,11 @@ class ShelleyTx(tuple):
             tuple(int(v) for v in obj[3]),
             tuple((bytes(a), int(q)) for a, q in obj[4]),
             tuple((bytes(p), int(q)) for p, q in obj[5]))
+
+
+#: elements of an encoded transaction that form its BODY, whose encoding
+#: the id hashes (`body_encode`; the witnesses follow)
+SHELLEY_TX_BODY_ELEMS = 6
 
 
 def _norm_output(o) -> tuple:

@@ -218,6 +218,9 @@ class ShardedJaxBackend(JaxBackend):
         super()._note_padding(used, padded)
         _SHARD_LANES_PADDED.inc(padded // self.n_shards)
 
+    def _kes_lanes_to_come(self) -> int:
+        return 0                # KES hash paths are walked on the host
+
     def _split_mixed_device(self, reqs):
         """Mesh windows reduce KES hash paths on host — through the
         cross-window outcome cache (one Merkle walk per (pool, period)

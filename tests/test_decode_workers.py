@@ -540,10 +540,10 @@ def _byron_raw() -> bytes:
 
 def _decoder_and_raw(kind: str):
     from ouroboros_tpu.consensus.headers import BlockDecoder
-    from ouroboros_tpu.eras.cardano import cardano_block_from_bytes
+    from ouroboros_tpu.eras.cardano import CARDANO_DECODER
     from ouroboros_tpu.eras.shelley import ShelleyTx
     if kind == "byron":
-        return cardano_block_from_bytes, _byron_raw()
+        return CARDANO_DECODER, _byron_raw()
     return BlockDecoder(ShelleyTx.decode, 6), _shelley_raw(int(kind))
 
 
@@ -568,14 +568,67 @@ def test_a_block_from_a_worker_is_the_block_decoded_here(kind):
     assert [t.txid for t in shipped.body] == [t.txid for t in local.body] \
         == [_blake2b(cbor.dumps(t.body_encode())) for t in local.body]
     assert shipped.bytes == local.bytes == raw
-    if kind != "byron":
-        assert len(shipped.body) == int(kind)
-        # hashed where the block was decoded; nothing is left to encode
-        assert all(t.txid_hashed for t in shipped.body)
-        assert all(t.txid_hashed for t in local.body)
+    assert len(shipped.body) == (3 if kind == "byron" else int(kind))
+    # hashed where the block was decoded, in either era; nothing is left
+    # to encode
+    assert all(t.txid_hashed for t in shipped.body)
+    assert all(t.txid_hashed for t in local.body)
     # the header keeps its own bytes and offsets into them, no more
     assert max(len(v) for v in shipped.header._cache.values()
                if isinstance(v, bytes)) == len(local.header.bytes)
+
+
+@pytest.fixture(scope="module")
+def hfc_loaded(tmp_path_factory):
+    """An HFC DB forged on each era's own parameters: 7 Byron blocks of 2
+    transactions ending a 30-slot Byron epoch, then 5 Shelley blocks of
+    3."""
+    d = str(tmp_path_factory.mktemp("hfcdb"))
+    r = subprocess.run(
+        [sys.executable, os.path.join(REPO, "tools", "db_synth.py"),
+         "--out", d, "--protocol", "cardano", "--blocks", "12",
+         "--byron-blocks", "7", "--byron-epoch-length", "30",
+         "--byron-keys", "7", "--byron-txs-per-block", "2",
+         "--txs-per-block", "3", "--pools", "2", "--f", "1/2",
+         "--epoch-length", "5000", "--kes-depth", "4", "--k", "20",
+         "--chunk-size", "4"],
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-2000:]
+    db, _rules, decode, _cfg = db_analyser.load_db(d)
+    return db, decode
+
+
+@limit(120)
+@pytest.mark.parametrize("era,at,n_txs", [("byron", 3, 2),
+                                          ("shelley", 9, 3)])
+def test_both_eras_of_an_hfc_db_cross_from_a_worker_with_their_ids(
+        hfc_loaded, era, at, n_txs):
+    """One decode for every DB: a Byron block and a Shelley block of one
+    Cardano-composed DB come back from a worker as the in-thread decode
+    builds them, from one walk of their bytes, every transaction carrying
+    its id."""
+    from ouroboros_tpu.eras.byron import ByronTx
+    from ouroboros_tpu.eras.cardano import ERA_FIELD
+    from ouroboros_tpu.eras.shelley import ShelleyTx
+    from ouroboros_tpu.utils import cbor
+    db, decode = hfc_loaded
+    raws = [raw for _entry, raw in db.stream()]
+    one_walk = observe.REGISTRY.get("replay.decode.one_walk_blocks")
+    w0, o0 = worker_blocks(), one_walk.value
+    shipped = stream(db, decode)
+    assert worker_blocks() - w0 == len(raws) == 12
+    assert one_walk.value - o0 == 12          # Byron blocks count too
+    a, b = shipped[at], decode(raws[at])
+    assert a.header.get(ERA_FIELD) == ("byron", "shelley").index(era)
+    assert a == b and a.body == b.body and a.hash == b.hash
+    assert a.header.bytes == b.header.bytes and a.bytes == b.bytes == raws[at]
+    assert len(a.body) == n_txs
+    for ta, tb in zip(a.body, b.body):
+        assert type(ta) is type(tb) is (ByronTx if era == "byron"
+                                        else ShelleyTx)
+        assert ta.txid_hashed and tb.txid_hashed
+        assert ta.txid == tb.txid == _blake2b(cbor.dumps(ta.body_encode()))
+        assert pickle.loads(pickle.dumps(ta)).txid_hashed
 
 
 def _seven_element_header(enc):

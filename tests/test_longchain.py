@@ -109,7 +109,7 @@ def longchain(tmp_path_factory):
         check=True, capture_output=True)
     db, rules, decode, cfg = dba.load_db(chain)
     ctx = (db, rules, decode, cfg, chain)
-    was_on = observe.metrics.REGISTRY.enabled
+    was_recording = observe.spans.RECORDER.enabled
     observe.enable()
     try:
         cpu = dba.make_backend("cpp")
@@ -134,8 +134,8 @@ def longchain(tmp_path_factory):
             t.name == "ouro-replay-producer" and t.is_alive()
             for t in threading.enumerate())
     finally:
-        if not was_on:
-            observe.disable()
+        if not was_recording:
+            observe.spans.RECORDER.disable()
     return rec
 
 

@@ -283,7 +283,7 @@ def mixedfill(tmp_path_factory):
     _forge(equal, BLOCKS, "38", "--txs-per-block", "1")
     ctx = _load(mixed)
     db, rules, decode = ctx[:3]
-    was_on = observe.metrics.REGISTRY.enabled
+    was_recording = observe.spans.RECORDER.enabled
     observe.enable()
     try:
         cpu = dba.make_backend("cpp")
@@ -328,8 +328,8 @@ def mixedfill(tmp_path_factory):
                       "reference": _stop(rules, broken, cpu)}
         rec["programs_at_the_end"] = _programs(dev)
     finally:
-        if not was_on:
-            observe.disable()
+        if not was_recording:
+            observe.spans.RECORDER.disable()
     return rec
 
 
@@ -468,7 +468,7 @@ def test_a_replay_cut_at_an_invalid_header_builds_no_program(mixedfill):
     ((16, 256, 0), (16, 256, 0)),     # wider than any built: its own
     ((32, 0, 0), (64, 0, 0)),         # the narrowest that holds it
     ((0, 0, 0), (0, 0, 0)),           # nothing for the composite
-    ((0, 16, 0), (0, 16, 0))])        # no VRF lane: a program of its own
+    ((0, 16, 0), (16, 16, 16))])      # no VRF lane of its own: it rides too
 def test_a_window_rides_the_narrowest_built_composite_that_holds_it(
         need, rides):
     jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
@@ -555,5 +555,4 @@ def test_folded_first_bad_index_is_the_references(lanes, width, planted):
 
 
 def test_six_widths_ran_one_tile_program_in_its_two_forms(lanes):
-    assert lanes["programs"] == ([(False, False), (False, True)], [],
-                                 [(0, 0, 0)])
+    assert lanes["programs"] == ([(False, False), (False, True)], [], [])

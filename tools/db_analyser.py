@@ -52,6 +52,8 @@ def load_db(db_dir: str):
     with open(os.path.join(db_dir, "config.json")) as fh:
         cfg = json.load(fh)
 
+    db = _open_immutable(IoFS(db_dir), cfg)
+
     if cfg["protocol"] == "mock-praos":
         from ouroboros_tpu.consensus.protocols.praos import (
             Praos, PraosConfig, PraosNode,
@@ -70,32 +72,19 @@ def load_db(db_dir: str):
         tx_body_elems = None
     elif cfg["protocol"] == "cardano":
         from ouroboros_tpu.eras.cardano import (
-            cardano_block_from_bytes, cardano_setup,
+            CARDANO_DECODER, cardano_rules,
         )
-        shelley_config = None
-        if "slots_per_kes_period" in cfg:
-            # db_synth sized the KES period to the chain length
-            # (long-chain DBs); mirror cardano_setup's defaults with
-            # only that knob overridden
-            from ouroboros_tpu.eras.shelley import TPraosConfig
-            shelley_config = TPraosConfig(
-                k=8, epoch_length=cfg["epoch_length"],
-                slots_per_kes_period=cfg["slots_per_kes_period"],
-                kes_depth=5, max_kes_evolutions=30)
-        _eras, rules, _nodes = cardano_setup(
-            cfg["nodes"], epoch_length=cfg["epoch_length"],
-            shelley_config=shelley_config,
-            seed=cfg["seed"].encode(),
-            allegra_epoch=cfg.get("allegra_epoch"),
-            mary_epoch=cfg.get("mary_epoch"))
-        fs = IoFS(db_dir)
-        db = _open_immutable(fs, cfg)
-        return db, rules, cardano_block_from_bytes, cfg
+        # both eras from the record db_synth left, each on its own
+        # parameters; the decoder is the one every DB has, with the
+        # header's era tag picking the transaction type
+        _eras, rules, _nodes = cardano_rules(cfg)
+        return db, rules, CARDANO_DECODER, cfg
     elif cfg["protocol"] == "shelley":
         from fractions import Fraction
 
         from ouroboros_tpu.eras.shelley import (
-            ShelleyLedger, ShelleyTx, TPraos, TPraosConfig,
+            SHELLEY_TX_BODY_ELEMS, ShelleyLedger, ShelleyTx, TPraos,
+            TPraosConfig,
         )
         tcfg = TPraosConfig(
             k=cfg["k"], f=Fraction(cfg["f"]),
@@ -112,13 +101,11 @@ def load_db(db_dir: str):
             {bytes.fromhex(a): amt for a, amt in cfg["genesis"].items()},
             tcfg, pools, delegs)
         tx_decode = ShelleyTx.decode
-        tx_body_elems = 6          # ShelleyTx: 6 body fields + witnesses
+        tx_body_elems = SHELLEY_TX_BODY_ELEMS
     else:
         raise SystemExit(f"unknown protocol {cfg['protocol']!r}")
 
     rules = ExtLedgerRules(protocol, ledger)
-    fs = IoFS(db_dir)
-    db = _open_immutable(fs, cfg)
 
     # span-retaining decode: header bytes / KES message / tx ids come
     # from raw slices instead of re-encoding (the replay host pass).

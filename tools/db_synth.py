@@ -241,6 +241,57 @@ class _Mempool:
                 "cap": self.cap, "left_waiting": int(self.waiting)}
 
 
+class _SpendChains:
+    """The bodies `synth_shelley` and `synth_cardano` forge: every owner
+    holds one open output, and a transaction spends one owner's output
+    and pays its whole amount on, signed by whoever holds it: the
+    owner's payment key, for good (`fresh` false) or until the first
+    spend (`fresh`: each transaction pays to a key derived from (seed,
+    running index), which signs that chain of spends' next transaction
+    and nothing else).  The first outputs are the genesis pseudo-tx's,
+    which both ledgers index in sorted(address) order."""
+
+    def __init__(self, owners: list, genesis_txid: bytes, amount: int,
+                 seed: bytes, fresh: bool = False):
+        self.addrs = [addr for addr, _sk in owners]
+        order = sorted(self.addrs)
+        self.spendable = {i: [(genesis_txid, order.index(addr), amount)]
+                          for i, addr in enumerate(self.addrs)}
+        self.holder_sk = {i: sk for i, (_addr, sk) in enumerate(owners)}
+        self.seed, self.fresh = seed, fresh
+        self.n_tx = 0
+
+    def body(self, n_body: int, make_tx, first=None) -> list:
+        """`n_body` transactions made by `make_tx(inputs, outputs, certs,
+        signing_keys)`; transaction t is owner `(first + t) % owners`'s
+        (`first` left out: the running transaction index)."""
+        from ouroboros_tpu.crypto import ed25519_ref
+        if first is None:
+            first = self.n_tx
+        body = []
+        for t in range(n_body):
+            owner = (first + t) % len(self.addrs)
+            if not self.spendable[owner]:
+                continue
+            txid, ix, amount = self.spendable[owner].pop(0)
+            if self.fresh:
+                # pay to a key no earlier block has seen; it signs this
+                # chain of spends' next transaction and nothing else
+                next_sk = hashlib.blake2b(
+                    b"fresh-witness:%s:%d" % (self.seed, self.n_tx),
+                    digest_size=32).digest()
+                pay_to = ed25519_ref.public_key(next_sk)
+            else:
+                next_sk, pay_to = self.holder_sk[owner], self.addrs[owner]
+            tx = make_tx(inputs=[(txid, ix)], outputs=[(pay_to, amount)],
+                         certs=[], signing_keys=[self.holder_sk[owner]])
+            self.holder_sk[owner] = next_sk
+            self.n_tx += 1
+            self.spendable[owner].append((tx.txid, 0, amount))
+            body.append(tx)
+        return body
+
+
 def synth_shelley(args) -> dict:
     """Forge a TPraos/Shelley chain: the flagship replay workload.
 
@@ -300,23 +351,18 @@ def synth_shelley(args) -> dict:
 
     ext = ExtLedgerRules(protocol, ledger)
     state = ext.initial_state()
-    # spendable (txid, ix, amount) per pool owner, from the genesis pseudo-tx
+    spends = _SpendChains(
+        [(p["addr"], p["keys"].addr_sk) for p in pools],
+        ledger.GENESIS_TXID, 100_000, args.seed.encode(),
+        fresh=args.witness_keys == "fresh")
     GEN = ledger.GENESIS_TXID
-    gen_order = sorted(p["addr"] for p in pools)
-    spendable = {i: [(GEN, gen_order.index(p["addr"]), 100_000)]
-                 for i, p in enumerate(pools)}
-    # who may spend a chain of spends' one open output: the owner's
-    # payment key, for good (`pool`) or until the first spend (`fresh`)
-    fresh = args.witness_keys == "fresh"
-    holder_sk = {i: p["keys"].addr_sk for i, p in enumerate(pools)}
-    n_tx = 0
     mempool = None
     if args.tx_arrivals_per_slot is not None:
         # every transaction made here has one input, one output and one
         # witness, so one of them says what all of them weigh
         tx_bytes = len(cbor.dumps(make_shelley_tx(
             inputs=[(GEN, 0)], outputs=[(pools[0]["addr"], 100_000)],
-            certs=[], signing_keys=[holder_sk[0]]).encode()))
+            certs=[], signing_keys=[pools[0]["keys"].addr_sk]).encode()))
         mempool = _Mempool(args.tx_arrivals_per_slot,
                            args.tx_arrival_phase_slots,
                            MAX_BLOCK_BODY_SIZE // tx_bytes)
@@ -340,31 +386,11 @@ def synth_shelley(args) -> dict:
             slot += 1
             continue
         p = pools[leader_ix]
-        body = []
-        n_body = (args.txs_per_block if mempool is None
-                  else mempool.take(slot))
-        for t in range(n_body):
-            owner = ((forged * n_body + t) if mempool is None
-                     else n_tx) % len(pools)
-            if not spendable[owner]:
-                continue
-            txid, ix, amount = spendable[owner].pop(0)
-            if fresh:
-                # pay to a key no earlier block has seen; it signs this
-                # chain of spends' next transaction and nothing else
-                next_sk = hashlib.blake2b(
-                    b"fresh-witness:%s:%d" % (args.seed.encode(), n_tx),
-                    digest_size=32).digest()
-                pay_to = ed25519_ref.public_key(next_sk)
-            else:
-                next_sk, pay_to = holder_sk[owner], pools[owner]["addr"]
-            tx = make_shelley_tx(
-                inputs=[(txid, ix)], outputs=[(pay_to, amount)],
-                certs=[], signing_keys=[holder_sk[owner]])
-            holder_sk[owner] = next_sk
-            n_tx += 1
-            spendable[owner].append((tx.txid, 0, amount))
-            body.append(tx)
+        if mempool is None:
+            body = spends.body(args.txs_per_block, make_shelley_tx,
+                               first=forged * args.txs_per_block)
+        else:
+            body = spends.body(mempool.take(slot), make_shelley_tx)
         hdr = make_header(prev, slot, body, issuer=0)
         signed = forge_tpraos_fields(protocol, p["hot_key"],
                                      p["can_be_leader"], lead, hdr)
@@ -391,19 +417,36 @@ def synth_shelley(args) -> dict:
 
 
 def synth_cardano(args) -> dict:
-    """Forge a chain crossing the full era ladder (BASELINE config #5
-    shape, now Byron->Shelley->Allegra->Mary per Cardano/Block.hs:161-186):
-    PBFT blocks + EBBs, a Byron update proposal naming the Shelley fork
-    epoch, TPraos blocks, then configured-epoch hops into Allegra (a
-    validity-interval tx exercises the timelock gate) and Mary (a minting
-    tx exercises multi-asset) — all through the combinator."""
+    """Forge a chain that crosses the hard fork, through the combinator.
+
+    Without `--byron-blocks`: the full era ladder (BASELINE config #5
+    shape, Byron->Shelley->Allegra->Mary per Cardano/Block.hs:161-186) on
+    ONE small set of parameters for both eras: PBFT blocks + EBBs from
+    slot 0, a Byron update proposal naming the Shelley fork epoch, TPraos
+    blocks, then configured-epoch hops into Allegra (a validity-interval
+    tx exercises the timelock gate) and Mary (a minting tx exercises
+    multi-asset); empty bodies but for those.
+
+    With `--byron-blocks N`: each era on its own genesis's parameters
+    (mainnet: Byron epochs of 21,600 slots, k 2160, PBFT threshold 0.22
+    over the last k blocks, seven genesis keys signing in turn; Shelley
+    as `synth_shelley` takes it), and the shape of a sync that crosses
+    the fork: N Byron blocks in the N consecutive slots that END Byron
+    epoch 0, the fork at that epoch boundary (the first block's update
+    proposal names epoch 1), then Shelley blocks up to `--blocks`.  The
+    epoch's earlier slots carry no block, so the chain holds no EBB (an
+    epoch's EBB lies before its first block).  Bodies in both eras are
+    the chains of spends `synth_shelley` forges (`_SpendChains`),
+    `--byron-txs-per-block` and `--txs-per-block` a block, the Byron
+    era's open outputs crossing the fork in the translated UTxO."""
     from ouroboros_tpu.consensus.hardfork.combinator import ERA_FIELD
     from ouroboros_tpu.consensus.headers import ProtocolBlock, make_header
+    from ouroboros_tpu.crypto import kes as kes_mod
     from ouroboros_tpu.eras.byron import (
         CERT_UPDATE, byron_sign_header, make_byron_tx, make_ebb,
     )
     from ouroboros_tpu.eras.cardano import (
-        ALLEGRA, BYRON, MARY, SHELLEY, cardano_setup,
+        ALLEGRA, BYRON, MARY, cardano_rules,
     )
     from ouroboros_tpu.eras.shelley import (
         forge_tpraos_fields, make_shelley_tx, pool_id_of,
@@ -411,51 +454,83 @@ def synth_cardano(args) -> dict:
     from ouroboros_tpu.storage.fs import IoFS
 
     epoch_length = args.epoch_length
-    total_epochs = max(8, args.blocks // epoch_length)
-    # Byron spans >= 2 epochs so the chain contains an EBB with a same-slot
-    # Byron successor (the EBB layout the storage layer must handle)
-    fork_epoch = max(2, total_epochs // 4)
-    if getattr(args, "eras", "ladder") == "byron-shelley":
-        # the two-era chain of the streaming-replay scenario (ISSUE 15):
-        # Byron EBBs -> ONE translation -> a long Shelley tail, no
-        # intra-Shelley hops — the minimal shape that still crosses the
-        # hard fork mid-stream
-        allegra_epoch = mary_epoch = None
+    byron_blocks = getattr(args, "byron_blocks", None)
+    per_era = byron_blocks is not None
+    allegra_epoch = mary_epoch = None
+    if per_era:
+        byron_epoch_length = args.byron_epoch_length or epoch_length
+        if not 0 < byron_blocks <= min(byron_epoch_length, args.blocks):
+            raise SystemExit("db_synth: --byron-blocks has to fit the "
+                             "Byron epoch and the chain")
+        fork_epoch = 1
+        first_slot = byron_epoch_length - byron_blocks
+        byron_txs, shelley_txs = args.byron_txs_per_block, args.txs_per_block
+        shelley = {
+            "k": args.k, "f": args.f, "epoch_length": epoch_length,
+            "slots_per_kes_period": args.slots_per_kes_period or max(
+                1, int(args.blocks * 2 / Fraction(args.f))
+                // kes_mod.total_periods(args.kes_depth) + 1),
+            "kes_depth": args.kes_depth,
+            "max_kes_evolutions": kes_mod.total_periods(args.kes_depth) - 2,
+            "slot_length": args.slot_length}
+        byron = {
+            "genesis_keys": args.byron_keys or args.pools,
+            "epoch_length": byron_epoch_length, "k": args.k,
+            "threshold": args.pbft_threshold,
+            "window": args.pbft_window or args.k,
+            "slot_length": args.byron_slot_length}
     else:
-        allegra_epoch = fork_epoch + max(1, total_epochs // 4)
-        mary_epoch = allegra_epoch + max(1, total_epochs // 4)
-    # KES periods must cover the whole chain (synth_shelley discipline):
-    # cardano_setup's default 50 slots/period exhausts the depth-5 key's
-    # 30 usable evolutions after ~1500 slots, capping chains well below
-    # the >=10k-block streaming scenario.  Sized here and recorded in
-    # config.json so db_analyser rebuilds the identical setup.
-    from ouroboros_tpu.eras.shelley import TPraosConfig
-    slots_per_kes_period = max(50, (args.blocks * 2) // 30 + 1)
-    shelley_config = TPraosConfig(
-        k=8, epoch_length=epoch_length,
-        slots_per_kes_period=slots_per_kes_period,
-        kes_depth=5, max_kes_evolutions=30)
-    eras, rules, nodes = cardano_setup(
-        args.pools, epoch_length=epoch_length,
-        shelley_config=shelley_config, seed=args.seed.encode(),
-        allegra_epoch=allegra_epoch, mary_epoch=mary_epoch)
+        total_epochs = max(8, args.blocks // epoch_length)
+        # Byron spans >= 2 epochs so the chain contains an EBB with a
+        # same-slot Byron successor (the EBB layout the storage layer
+        # must handle)
+        fork_epoch = max(2, total_epochs // 4)
+        first_slot = 0
+        byron_txs = shelley_txs = 0
+        if getattr(args, "eras", "ladder") != "byron-shelley":
+            # (`byron-shelley`: the two-era chain of the streaming-replay
+            # scenario, ISSUE 15: Byron EBBs -> ONE translation -> a long
+            # Shelley tail, no intra-Shelley hops)
+            allegra_epoch = fork_epoch + max(1, total_epochs // 4)
+            mary_epoch = allegra_epoch + max(1, total_epochs // 4)
+        # what every earlier version forged: one epoch length, k = 8 and
+        # a depth-5 KES key for both eras.  KES periods must cover the
+        # whole chain (synth_shelley discipline): 50 slots a period
+        # exhausts the key's 30 usable evolutions after ~1500 slots
+        shelley = {
+            "k": 8, "f": "1/2", "epoch_length": epoch_length,
+            "slots_per_kes_period": max(50, (args.blocks * 2) // 30 + 1),
+            "kes_depth": 5, "max_kes_evolutions": 30, "slot_length": 0.5}
+        byron = {"genesis_keys": args.pools, "epoch_length": epoch_length,
+                 "k": 8, "threshold": 0.9, "window": 10, "slot_length": 1.0}
+    # everything `db_analyser.load_db` needs to build the same two eras
+    config = {
+        "protocol": "cardano", "nodes": args.pools, "seed": args.seed,
+        "fork_epoch": fork_epoch, "allegra_epoch": allegra_epoch,
+        "mary_epoch": mary_epoch, "chunk_size": args.chunk_size,
+        "byron": byron, "shelley": shelley,
+    }
+    eras, rules, nodes = cardano_rules(config)
+    if per_era:
+        config["chain"] = {"byron_blocks": byron_blocks,
+                           "first_slot": first_slot,
+                           "byron_txs_per_block": byron_txs,
+                           "txs_per_block": shelley_txs}
 
     os.makedirs(args.out, exist_ok=True)
     with open(os.path.join(args.out, "config.json"), "w") as fh:
-        json.dump({
-            "protocol": "cardano", "nodes": args.pools,
-            "epoch_length": epoch_length, "seed": args.seed,
-            "fork_epoch": fork_epoch, "allegra_epoch": allegra_epoch,
-            "mary_epoch": mary_epoch, "chunk_size": args.chunk_size,
-            "slots_per_kes_period": slots_per_kes_period,
-        }, fh, indent=2)
+        json.dump(config, fh, indent=2)
     fs = IoFS(args.out)
     db = open_out_db(fs, args)
 
     byron_era, shelley_era = eras[0], eras[1]
+    pools = [n for n in nodes if "can_be_leader" in n]
+    spends = _SpendChains([(p["addr"], p["keys"].addr_sk) for p in pools],
+                          byron_era.ledger.GENESIS_TXID, 1000,
+                          args.seed.encode())
     state = rules.initial_state()
     prev = None
-    slot = 0
+    slot = first_slot
     forged = 0
     update_sent = False
     # one feature tx per new era (none when the ladder stops at Shelley)
@@ -473,8 +548,9 @@ def synth_cardano(args) -> dict:
         ticked_dep = rules.protocol.tick_chain_dep_state(
             state.header.chain_dep_state, view, slot)
         if ticked_dep.era == BYRON:
-            if slot % epoch_length == 0 and slot > 0:
-                ebb = make_ebb(prev, slot // epoch_length, epoch_length)
+            if slot % byron["epoch_length"] == 0 and slot > 0:
+                ebb = make_ebb(prev, slot // byron["epoch_length"],
+                               byron["epoch_length"])
                 ebb = ebb.with_fields(**{ERA_FIELD: BYRON})
                 blk = ProtocolBlock(ebb, ())
                 state = rules.tick_then_reapply(state, blk)
@@ -491,6 +567,7 @@ def synth_cardano(args) -> dict:
                             b"")],
                     signing_keys=[node["genesis_sk"]]))
                 update_sent = True
+            body += spends.body(max(0, byron_txs - len(body)), make_byron_tx)
             hdr = make_header(prev, slot, body, issuer=leader_ix)
             hdr = hdr.with_fields(**{ERA_FIELD: BYRON})
             hdr = byron_sign_header(node["delegate_sk"], hdr)
@@ -498,7 +575,7 @@ def synth_cardano(args) -> dict:
         else:
             era_ix = ticked_dep.era
             lead = node = None
-            for node in nodes:
+            for node in pools:
                 lead = shelley_era.protocol.check_is_leader(
                     node["can_be_leader"], slot, ticked_dep.inner,
                     view.inner)
@@ -532,6 +609,7 @@ def synth_cardano(args) -> dict:
                             mint=[(aid, 5)])
                     body.append(tx)
                     feature_todo.discard(era_ix)
+            body += spends.body(shelley_txs, make_shelley_tx)
             hdr = make_header(prev, slot, body, issuer=0)
             hdr = hdr.with_fields(**{ERA_FIELD: era_ix})
             hdr = forge_tpraos_fields(shelley_era.protocol, node["hot_key"],
@@ -596,6 +674,38 @@ def main() -> None:
     ap.add_argument("--tx-arrival-phase-slots", type=int, default=None,
                     help="shelley: slots a phase of "
                          "--tx-arrivals-per-slot lasts")
+    ap.add_argument("--k", type=int, default=2160,
+                    help="cardano with --byron-blocks: the security "
+                         "parameter of both eras (mainnet: 2160)")
+    ap.add_argument("--slot-length", type=float, default=1.0,
+                    help="cardano with --byron-blocks: seconds a "
+                         "Shelley slot lasts")
+    ap.add_argument("--byron-blocks", type=int, default=None,
+                    help="cardano: forge each era on its own "
+                         "parameters, this many Byron blocks in the "
+                         "last slots of Byron epoch 0 and the fork at "
+                         "that epoch's end (left out: the small era "
+                         "ladder from slot 0)")
+    ap.add_argument("--byron-epoch-length", type=int, default=None,
+                    help="with --byron-blocks: slots of a Byron epoch "
+                         "(mainnet: 21600; left out: --epoch-length)")
+    ap.add_argument("--byron-txs-per-block", type=int, default=2,
+                    help="with --byron-blocks: transactions a Byron "
+                         "block holds (--txs-per-block a Shelley one)")
+    ap.add_argument("--byron-keys", type=int, default=None,
+                    help="with --byron-blocks: genesis keys, one "
+                         "delegate each, signing in turn (mainnet: 7; "
+                         "left out: --pools)")
+    ap.add_argument("--byron-slot-length", type=float, default=20.0,
+                    help="with --byron-blocks: seconds a Byron slot "
+                         "lasts")
+    ap.add_argument("--pbft-threshold", type=float, default=0.22,
+                    help="with --byron-blocks: the share of the last "
+                         "--pbft-window blocks one genesis key may sign "
+                         "(mainnet's PBftSignatureThreshold)")
+    ap.add_argument("--pbft-window", type=int, default=None,
+                    help="with --byron-blocks: blocks the threshold "
+                         "looks back over (left out: --k)")
     ap.add_argument("--seed", default="db-synth")
     args = ap.parse_args()
     if (args.tx_arrivals_per_slot is None) != (
