@@ -18,10 +18,13 @@ is pointer-heavy, and the FLOP-heavy group math on device.
 """
 from __future__ import annotations
 
+from bisect import bisect_right
 from dataclasses import dataclass
 from typing import Any, Callable, Optional, Sequence
 
-from ..crypto.backend import CryptoBackend, default_backend
+from ..crypto.backend import (
+    CryptoBackend, default_backend, lane_count, request_at,
+)
 from ..observe import spans as _spans
 from .header_validation import (
     HeaderError, HeaderState, reapply_ticked_header, validate_envelope,
@@ -141,6 +144,11 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
     extraction + optimistic reapply.  Shared by the synchronous and the
     pipelined drivers.  Raises on any sequential failure.
 
+    Returns (the block's ITEMS, the state after it): the header's
+    request objects, then what the ledger handed for the body, a columns
+    item that counts for a request a witness (`lane_count`); a driver
+    joins them to its window's stream and keeps the count.
+
     The header rules run in `seq.header` spans and the ledger pass in
     `seq.body` spans.  The statements keep their order, since which
     error a bad block raises first depends on it, so each name opens
@@ -173,6 +181,20 @@ def _seq_block_step(protocol: ConsensusProtocol, ledger, st: ExtLedgerState,
     return reqs, ExtLedgerState(ledger_state, header_state)
 
 
+def first_false(ok) -> Optional[int]:
+    """The index of the first verdict that does not hold, None if all
+    do."""
+    return next((j for j, good in enumerate(ok) if not good), None)
+
+
+def block_of(ends: Sequence[int], j: int) -> int:
+    """The block request `j` belongs to, `ends[b]` being the requests up
+    to and including block b's (the run-length map of a window: requests
+    lie block after block, so the first bad request is of the first bad
+    block)."""
+    return bisect_right(ends, j)
+
+
 def validate_blocks_batched(
         ext_rules: ExtLedgerRules,
         blocks: Sequence[Any],
@@ -187,13 +209,13 @@ def validate_blocks_batched(
     protocol.prefetch_window([getattr(b, "header", b) for b in blocks],
                              backend)
     states: list[ExtLedgerState] = []
-    proofs: list = []
-    owner: list[int] = []
+    proofs: list = []               # the window's stream of items
+    ends: list[int] = []            # requests up to each block's last
     seq_error: Optional[Exception] = None
     n_seq = 0
 
     st = ext_state
-    for i, b in enumerate(blocks):
+    for b in blocks:
         try:
             reqs, st = _seq_block_step(protocol, ledger, st, b)
         except OutsideForecastRange as e:
@@ -207,21 +229,18 @@ def validate_blocks_batched(
                          else LedgerError(str(e)))
             break
         proofs.extend(reqs)
-        owner.extend([i] * len(reqs))
+        ends.append(lane_count(reqs) + (ends[-1] if ends else 0))
         states.append(st)
         n_seq += 1
 
     ok = _verify_mixed(backend, proofs) if proofs else []
     first_bad = n_seq
-    bad_proof = None
-    for j, good in enumerate(ok):
-        if not good and owner[j] < first_bad:
-            first_bad, bad_proof = owner[j], j
-
+    bad_proof = first_false(ok)
     if bad_proof is not None:
+        first_bad = block_of(ends, bad_proof)
         err: Optional[Exception] = LedgerError(
-            f"proof {type(proofs[bad_proof]).__name__} failed for block "
-            f"index {first_bad} (slot {blocks[first_bad].slot})")
+            f"proof {type(request_at(proofs, bad_proof)).__name__} failed "
+            f"for block index {first_bad} (slot {blocks[first_bad].slot})")
     else:
         err = seq_error
     return BatchValidationResult(states[:first_bad], first_bad, err)

@@ -60,13 +60,22 @@ class LedgerRules:
 
     def extract_proofs(self, ticked: Any, block: Any) -> list:
         """Independent crypto obligations of the block body (the reference's
-        BBODY Ed25519 witness multi-verify — Shelley/Ledger/Ledger.hs:279).
+        BBODY Ed25519 witness multi-verify — Shelley/Ledger/Ledger.hs:279),
+        as a list of ITEMS (crypto/backend.py): request objects, or ONE
+        `Ed25519Cols` for the body, the witnesses' keys, messages (the
+        transactions' ids) and signatures as three parallel columns in
+        transaction-then-witness order, which counts for a request a
+        witness and makes no object a witness.  The sequential pass joins
+        the items after the block's header requests, so request indices
+        keep their order and a verdict still names a request
+        (`lane_count`, `request_at`, `iter_requests`).
         Default: none (mock ledgers check structurally)."""
         return []
 
     def tx_proofs(self, state: Any, tx: Any) -> Optional[list]:
         """Independent crypto obligations of ONE tx — the mempool
-        admission unit (extract_proofs at tx granularity).  The adaptive
+        admission unit (extract_proofs at tx granularity, items as
+        there).  The adaptive
         batching service pre-verifies these coalesced with other
         threads' traffic, then apply_tx runs with the verdicts honored
         (Mempool.try_add_txs_async).  None = unknown: witness crypto

@@ -80,7 +80,9 @@ from collections import deque
 from typing import Any, Optional
 
 from ..chain.block import Point
-from ..crypto.backend import GLOBAL_BETA_CACHE, WindowVerdict
+from ..crypto.backend import (
+    GLOBAL_BETA_CACHE, WindowVerdict, lane_count, request_at,
+)
 from ..observe import flight as _flight
 from ..observe import metrics as _metrics
 from ..observe import spans as _spans
@@ -264,8 +266,9 @@ class _Shared:
 
     def __init__(self):
         self.cond = threading.Condition()
-        # (start, sub, reqs, owner, n_seq, t_submit, state_after, point,
-        #  window index)
+        # (start, sub, reqs, ends, n_seq, t_submit, state_after, point,
+        #  window index): reqs the window's stream of items, ends the
+        #  requests up to each block's last (batch.block_of)
         self.pending: deque = deque()
         self.progress: Optional[ProgressTracker] = None
         self.submitted = 0
@@ -344,7 +347,8 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                 ahead.append(nxt)
             tally = new_tally() if new_tally is not None else None
             reqs: list = []
-            owner: list[int] = []
+            ends: list[int] = []
+            n_reqs = 0
             seq_error: Optional[Exception] = None
             n_seq_w = 0
             progress = shared.progress
@@ -352,7 +356,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                 progress.host_begin()
             with _spans.span("window.host_seq", cat="host-seq", cpu=True,
                              window=k):
-                for i, b in enumerate(blk_window):
+                for b in blk_window:
                     t_block = _spans.monotonic_now()
                     try:
                         rs, st = _seq_block_step(protocol, ledger, st, b)
@@ -367,7 +371,8 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                                      else LedgerError(str(e)))
                         break
                     reqs.extend(rs)
-                    owner.extend([i] * len(rs))
+                    n_reqs += lane_count(rs)
+                    ends.append(n_reqs)
                     n_seq_w += 1
                     if tally is not None:
                         tally.block(st.ledger,
@@ -413,7 +418,7 @@ def _produce(shared: _Shared, ext_rules, block_iter, ext_state, backend,
                   else None)
             with shared.cond:
                 shared.pending.append(
-                    (shared.seq_done, sub, reqs, owner, n_seq_w,
+                    (shared.seq_done, sub, reqs, ends, n_seq_w,
                      _spans.monotonic_now(), st, pt, k))
                 shared.submitted += 1
                 shared.seq_done += n_seq_w
@@ -434,7 +439,7 @@ def _drain(backend, entry) -> tuple:
     """Finish one window's device call; install its carried betas.
     Returns (error, n_valid): error None when every proof held, else
     n_valid is the global index of the first bad block."""
-    start, sub, reqs, owner, n_seq_w, t_submit, _st, _pt, k = entry
+    start, sub, reqs, ends, n_seq_w, t_submit, _st, _pt, k = entry
     # named distinctly from jax_backend's inner "window.drain" span,
     # so a reader that pairs submits with drains by name sees one
     # interval a drain.  This outer span exists for EVERY async backend
@@ -444,22 +449,15 @@ def _drain(backend, entry) -> tuple:
     _SUBMIT_DRAIN.observe(_spans.monotonic_now() - t_submit)
     if betas:
         GLOBAL_BETA_CACHE.store_many(betas.keys(), betas.values())
-    if isinstance(ok, WindowVerdict):
-        # device-folded form: the first failing request index directly
-        # (owner maps are non-decreasing, so the first bad request is
-        # also the first bad block)
-        bad, first_bad = ok.first_bad, n_seq_w
-        if bad is not None:
-            first_bad = owner[bad]
-    else:
-        first_bad, bad = n_seq_w, None
-        for j, good in enumerate(ok):
-            if not good and owner[j] < first_bad:
-                first_bad, bad = owner[j], j
+    # the first failing request index, device-folded or read off the
+    # vector: requests lie block after block, so it is of the first bad
+    # block
+    bad = ok.first_bad if isinstance(ok, WindowVerdict) else first_false(ok)
     if bad is not None:
+        first_bad = block_of(ends, bad)
         return LedgerError(
-            f"proof {type(reqs[bad]).__name__} failed for block "
-            f"{start + first_bad}"), start + first_bad
+            f"proof {type(request_at(reqs, bad)).__name__} failed for "
+            f"block {start + first_bad}"), start + first_bad
     return None, start + n_seq_w
 
 
@@ -590,4 +588,4 @@ def _run_producer(*args) -> None:
 
 # placed at the bottom to avoid a circular import at module load
 # (batch.py imports replay_threaded; we only need its seq step)
-from .batch import _seq_block_step  # noqa: E402
+from .batch import _seq_block_step, block_of, first_false  # noqa: E402

@@ -89,7 +89,10 @@ class ConsensusProtocol:
                        ledger_view: Any) -> list:
         """Independent proof obligations of this header given ticked state.
 
-        Returns Ed25519Req/VrfReq/KesReq items (crypto/backend.py).  MUST be
+        Returns a list of ITEMS of the request stream (crypto/backend.py):
+        Ed25519Req/VrfReq/KesReq objects, one request each (a ledger's
+        `extract_proofs` may also hand an `Ed25519Cols`, which counts
+        for a request a lane; a header's handful stay objects).  MUST be
         state-independent once `ticked` is known, so a window of headers can
         be verified as one device batch.
         """
@@ -149,7 +152,7 @@ class NullProtocol(ConsensusProtocol):
 
 
 def _verify_mixed(backend: CryptoBackend, reqs: Sequence) -> list[bool]:
-    """Dispatch a mixed list of proof requests through the backend's fused
+    """Dispatch a mixed stream of proof items through the backend's fused
     mixed-batch path (KES hash-paths reduced to Ed25519 leaves on host, one
     Ed25519 batch + one VRF batch), preserving order."""
     return backend.verify_mixed(reqs)

@@ -41,6 +41,122 @@ class Ed25519Req:
     sig: bytes       # 64B
 
 
+class Ed25519Cols:
+    """A run of Ed25519 requests as three parallel columns: `vks[j]`,
+    `msgs[j]` and `sigs[j]` are lane j's key, message and signature, and
+    the lane stands for `Ed25519Req(vks[j], msgs[j], sigs[j])`.
+
+    The form a block body's witnesses cross from the ledger pass to the
+    packers in: a ledger's `extract_proofs` makes ONE a block, of the
+    bytes the decoded transactions already hold, whatever their length,
+    and no object a witness.  In a request stream it is one ITEM that
+    counts for `len` requests, in the block's order, where a request
+    object counts for one (`lane_count`, `request_at`); a splitter joins
+    its columns to the Ed25519 group whole, and the group a splitter
+    returns is one of these too.  Iterated or indexed it gives the
+    requests it stands for, so a reader that knows only request objects
+    is served."""
+
+    __slots__ = ("vks", "msgs", "sigs")
+
+    def __init__(self, vks, msgs, sigs):
+        self.vks = vks
+        self.msgs = msgs
+        self.sigs = sigs
+
+    @classmethod
+    def of_witnesses(cls, txs) -> "Ed25519Cols":
+        """Every `(vk, sig)` of each transaction's `witnesses` over that
+        transaction's `txid`, in transaction-then-witness order: a block
+        body's lanes.  One walk, each row touched once; a transaction
+        with one witness (most of a chain) takes no inner loop."""
+        vks: list = []
+        msgs: list = []
+        sigs: list = []
+        for tx in txs:
+            wits = tx.witnesses
+            if len(wits) == 1:
+                vk, sig = wits[0]
+                vks.append(vk)
+                msgs.append(tx.txid)
+                sigs.append(sig)
+            elif wits:
+                txid = tx.txid
+                for vk, sig in wits:
+                    vks.append(vk)
+                    msgs.append(txid)
+                    sigs.append(sig)
+        return cls(vks, msgs, sigs)
+
+    def append(self, vk: bytes, msg: bytes, sig: bytes) -> None:
+        """One more lane (a splitter building its group)."""
+        self.vks.append(vk)
+        self.msgs.append(msg)
+        self.sigs.append(sig)
+
+    def extend(self, cols: "Ed25519Cols") -> None:
+        """Another run's lanes after this one's, column by column."""
+        self.vks.extend(cols.vks)
+        self.msgs.extend(cols.msgs)
+        self.sigs.extend(cols.sigs)
+
+    def __len__(self) -> int:
+        return len(self.vks)
+
+    def __getitem__(self, j: int) -> Ed25519Req:
+        return Ed25519Req(self.vks[j], self.msgs[j], self.sigs[j])
+
+    def __iter__(self):
+        return map(Ed25519Req, self.vks, self.msgs, self.sigs)
+
+    def __eq__(self, other) -> bool:
+        """Equal to any sequence that holds the same requests."""
+        try:
+            return len(self) == len(other) and all(
+                a == b for a, b in zip(self, other))
+        except TypeError:
+            return NotImplemented
+
+    __hash__ = None
+
+
+def ed25519_columns(reqs) -> tuple:
+    """(vks, msgs, sigs) of an Ed25519 group: the columns themselves of
+    an `Ed25519Cols` (not to be changed), three new lists of a sequence
+    of `Ed25519Req`."""
+    if isinstance(reqs, Ed25519Cols):
+        return reqs.vks, reqs.msgs, reqs.sigs
+    return ([r.vk for r in reqs], [r.msg for r in reqs],
+            [r.sig for r in reqs])
+
+
+def lane_count(items) -> int:
+    """The requests a stream of items stands for: `len` of a columns
+    item, one of a request object."""
+    return sum(len(it) if isinstance(it, Ed25519Cols) else 1
+               for it in items)
+
+
+def iter_requests(items):
+    """The request objects a stream of items stands for, in order (a
+    columns item's are made here)."""
+    for it in items:
+        if isinstance(it, Ed25519Cols):
+            yield from it
+        else:
+            yield it
+
+
+def request_at(items, j: int):
+    """Request `j` of a stream of items, as a request object."""
+    for it in items:
+        n = len(it) if isinstance(it, Ed25519Cols) else 1
+        if j < n:
+            return it[j] if isinstance(it, Ed25519Cols) else it
+        j -= n
+    raise IndexError("request index out of range")
+
+
 @dataclass(frozen=True)
 class VrfReq:
     vk: bytes        # 32B
@@ -65,10 +181,10 @@ class WindowVerdict:
     Instead of a per-proof boolean vector crossing the host<->device
     link, the fused window program folds ok-flags on device and returns
     only the FIRST failing request's index (None = every proof held).
-    `first_bad` indexes the submitted request list, so a replay driver
-    maps it through its owner table exactly like `min(owner[j] for bad
-    j)` over the old vector — owner maps are non-decreasing, making the
-    first bad request also the first bad block."""
+    `first_bad` indexes the requests the submitted items stand for (a
+    columns item counts for `len` of them), so a replay driver looks it
+    up in its per-block lane counts: requests lie block after block,
+    making the first bad request also the first bad block."""
     n: int
     first_bad: Optional[int] = None
 
@@ -115,14 +231,27 @@ class CryptoBackend:
         """Shared dispatch skeleton of the host split variants: group
         Ed25519/VRF requests, reduce each KES request through
         `kes_leaf(req) -> (leaf_vk, leaf_sig) | None` (None = the hash
-        path is invalid / known-bad, request stays False)."""
-        ed_reqs: list = []
+        path is invalid / known-bad, request stays False).
+
+        `reqs` is a stream of items: a columns item joins the Ed25519
+        group's three columns whole and answers for the run of request
+        indices it stands for; a request object goes into the same
+        columns, one lane."""
+        ed_reqs = Ed25519Cols([], [], [])
         ed_owner: list[int] = []
         vrf_reqs: list = []
         vrf_owner: list[int] = []
-        for i, r in enumerate(reqs):
+        n = 0                  # requests the items walked so far stand for
+        for r in reqs:
+            i = n              # the item's first request index
+            if isinstance(r, Ed25519Cols):
+                n += len(r)
+                ed_reqs.extend(r)
+                ed_owner.extend(range(i, n))
+                continue
+            n += 1
             if isinstance(r, Ed25519Req):
-                ed_reqs.append(r)
+                ed_reqs.append(r.vk, r.msg, r.sig)
                 ed_owner.append(i)
             elif isinstance(r, VrfReq):
                 vrf_reqs.append(r)
@@ -132,20 +261,22 @@ class CryptoBackend:
                 if leaf is None:
                     continue          # stays False
                 leaf_vk, leaf_sig = leaf
-                ed_reqs.append(Ed25519Req(leaf_vk, r.msg, leaf_sig))
+                ed_reqs.append(leaf_vk, r.msg, leaf_sig)
                 ed_owner.append(i)
             else:
                 raise TypeError(f"unknown proof request type {type(r)}")
-        return ed_reqs, ed_owner, vrf_reqs, vrf_owner, len(reqs)
+        return ed_reqs, ed_owner, vrf_reqs, vrf_owner, n
 
     def split_mixed(self, reqs: Sequence):
-        """Host-side split of a mixed request list: KES requests are reduced
-        to their Ed25519 leaf checks (hash-path verification happens here)
-        and merged into the Ed25519 group, so a mixed window costs ONE
-        Ed25519 batch + ONE VRF batch instead of three calls.
+        """Host-side split of a mixed stream of items: KES requests are
+        reduced to their Ed25519 leaf checks (hash-path verification
+        happens here) and merged into the Ed25519 group, so a mixed window
+        costs ONE Ed25519 batch + ONE VRF batch instead of three calls.
 
-        Returns (ed_reqs, ed_owner, vrf_reqs, vrf_owner, n) where owner maps
-        each grouped request back to its index in `reqs`."""
+        Returns (ed_reqs, ed_owner, vrf_reqs, vrf_owner, n): the Ed25519
+        group as one `Ed25519Cols`, owner mapping each grouped lane back
+        to the index of the request it answers for, n the requests the
+        items stand for."""
         def kes_leaf(r):
             try:
                 sig = kes_mod.KesSig.from_bytes(r.depth, r.sig_bytes)
@@ -188,7 +319,8 @@ class CryptoBackend:
         return self._split_mixed_loop(reqs, kes_leaf)
 
     def verify_mixed(self, reqs: Sequence) -> list[bool]:
-        """Verify a mixed Ed25519/VRF/KES request list, preserving order."""
+        """Verify a mixed stream of Ed25519/VRF/KES items; one verdict a
+        request the items stand for, in order."""
         ed_reqs, ed_owner, vrf_reqs, vrf_owner, n = self.split_mixed(reqs)
         out = [False] * n
         for i, ok in zip(ed_owner, self.verify_ed25519_batch(ed_reqs)):
@@ -288,7 +420,7 @@ class CpuRefBackend(CryptoBackend):
     name = "cpu-ref"
 
     def verify_ed25519_batch(self, reqs):
-        return [ed25519_ref.verify(r.vk, r.msg, r.sig) for r in reqs]
+        return list(map(ed25519_ref.verify, *ed25519_columns(reqs)))
 
     def verify_vrf_batch(self, reqs):
         return [vrf_ref.verify(r.vk, r.alpha, r.proof) for r in reqs]
@@ -312,9 +444,9 @@ class OpensslBackend(CpuRefBackend):
         except ImportError:     # absent OR broken binding: degrade
             return super().verify_ed25519_batch(reqs)
         out = []
-        for r in reqs:
+        for vk, msg, sig in zip(*ed25519_columns(reqs)):
             try:
-                Ed25519PublicKey.from_public_bytes(r.vk).verify(r.sig, r.msg)
+                Ed25519PublicKey.from_public_bytes(vk).verify(sig, msg)
                 out.append(True)
             except (InvalidSignature, ValueError):
                 out.append(False)

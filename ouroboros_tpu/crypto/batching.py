@@ -57,7 +57,7 @@ from ..observe import metrics as _metrics
 from ..simharness.stm import TVar, retry
 from . import autotune as _autotune
 from .backend import (
-    CpuRefBackend, CryptoBackend, Ed25519Req, KesReq, VrfReq,
+    CpuRefBackend, CryptoBackend, Ed25519Req, KesReq, VrfReq, iter_requests,
 )
 
 __all__ = [
@@ -446,10 +446,11 @@ class VerifyService:
 
     async def verify_many(self, reqs: Sequence,
                           deadline: Optional[float] = None) -> list:
-        """Submit a request list and await all verdicts, order-
-        preserving (the batched-call analog; the whole list coalesces
-        with every other caller's traffic)."""
-        futs = [await self.submit(r, deadline) for r in reqs]
+        """Submit a stream of items and await all verdicts, one a
+        request the items stand for, order-preserving (the batched-call
+        analog; the whole list coalesces with every other caller's
+        traffic)."""
+        futs = [await self.submit(r, deadline) for r in iter_requests(reqs)]
         return [await f.wait() for f in futs]
 
     # -- flusher -------------------------------------------------------------
@@ -661,10 +662,11 @@ class PrecheckedBackend(CryptoBackend):
 
 async def verdict_map(service: VerifyService, reqs: Sequence,
                       deadline: Optional[float] = None) -> dict:
-    """{request: verdict} for a request list, verified through the
+    """{request: verdict} for a stream of items (what a ledger's
+    `tx_proofs` hands), verified through the
     service (dedup'd — a repeated request is submitted once).  Feed the
     result to PrecheckedBackend for the sync validation path."""
-    uniq = list(dict.fromkeys(reqs))
+    uniq = list(dict.fromkeys(iter_requests(reqs)))
     oks = await service.verify_many(uniq, deadline)
     return dict(zip(uniq, oks))
 

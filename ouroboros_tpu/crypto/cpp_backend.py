@@ -19,7 +19,9 @@ from typing import Optional, Sequence
 import numpy as np
 
 from . import kes as kes_mod
-from .backend import CryptoBackend, Ed25519Req, KesReq, VrfReq
+from .backend import (
+    CryptoBackend, Ed25519Req, KesReq, VrfReq, ed25519_columns,
+)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                            "native")
@@ -183,16 +185,17 @@ class CppBackend(CryptoBackend):
         if not reqs:
             return []
         n = len(reqs)
-        vks = b"".join(r.vk if len(r.vk) == 32 else b"\x00" * 32
-                       for r in reqs)
-        msgs = b"".join(r.msg for r in reqs)
-        lens = (ctypes.c_size_t * n)(*[len(r.msg) for r in reqs])
-        sigs = b"".join(r.sig if len(r.sig) == 64 else b"\x00" * 64
-                        for r in reqs)
+        vk_col, msg_col, sig_col = ed25519_columns(reqs)
+        vks = b"".join(vk if len(vk) == 32 else b"\x00" * 32
+                       for vk in vk_col)
+        msgs = b"".join(msg_col)
+        lens = (ctypes.c_size_t * n)(*map(len, msg_col))
+        sigs = b"".join(sig if len(sig) == 64 else b"\x00" * 64
+                        for sig in sig_col)
         out = (ctypes.c_uint8 * n)()
         self.lib.ouro_ed25519_verify_batch(n, vks, msgs, lens, sigs, out)
-        return [bool(out[i]) and len(reqs[i].vk) == 32
-                and len(reqs[i].sig) == 64 for i in range(n)]
+        return [bool(o) and len(vk) == 32 and len(sig) == 64
+                for o, vk, sig in zip(out, vk_col, sig_col)]
 
     def verify_vrf_batch(self, reqs: Sequence[VrfReq]) -> list[bool]:
         if not reqs:

@@ -51,7 +51,10 @@ from . import blake2b_jax as B2
 from . import ed25519_jax as EJ
 from . import edwards as ed
 from . import kes as kes_mod
-from .backend import CryptoBackend, Ed25519Req, KesReq, VrfReq
+from .backend import (
+    CryptoBackend, Ed25519Cols, Ed25519Req, KesReq, VrfReq, ed25519_columns,
+    lane_count,
+)
 from .precompute import GLOBAL_PRECOMPUTE_CACHE
 
 # observational (gated) counters: window/dispatch volume on the hot path
@@ -73,6 +76,10 @@ _ED_TILES = _metrics.counter("jax_backend.ed_tiles")
 # replay (`begin_replay` forgets the previous one)
 _ED_LANES_REAL = _metrics.counter("jax_backend.ed_lanes_real")
 _ED_LANES_WALKED = _metrics.counter("jax_backend.ed_lanes_walked")
+# of a window's real Ed25519 lanes, those that reached the packer inside
+# a columns item (`Ed25519Cols`: no request object was made for them);
+# 0 = the ledger handed objects
+_ED_ROW_LANES = _metrics.counter("jax_backend.ed_row_lanes")
 _ED_WIDTH_CHANGES = _metrics.counter("jax_backend.ed_width_changes")
 # what a window's two occasional parts held (real work, not padding):
 # windows whose composite carried betas for the window two ahead and the
@@ -396,12 +403,11 @@ class JaxBackend(CryptoBackend):
         `verify_full_split_words_core`, parse_ok); keys the cache could
         not decompress are masked out of parse_ok (the kernels trust the
         cached affine x and skip the A square root)."""
-        pad = m - len(reqs)
-        vks = [r.vk for r in reqs] + [b"\x00" * 32] * pad
+        vks, msgs, sigs = ed25519_columns(reqs)
+        pad = m - len(vks)
+        vks = [*vks, *[b"\x00" * 32] * pad]
         arrays, parse_ok = EJ.prepare_words_batch(
-            vks,
-            [r.msg for r in reqs] + [b""] * pad,
-            [r.sig for r in reqs] + [b"\x00" * 64] * pad)
+            vks, [*msgs, *[b""] * pad], [*sigs, *[b"\x00" * 64] * pad])
         Aw, _signA, Rw, signR, sw, kw = arrays
         xa, xw, yw, known = EJ.GLOBAL_A128_CACHE.assemble(vks)
         return ((Aw, xa, xw, yw, Rw, signR.reshape(1, -1), sw, kw),
@@ -553,22 +559,37 @@ class JaxBackend(CryptoBackend):
         windows schedule zero Blake2b jobs).  Identical paths within one
         cold window collapse to one job slice too.
 
+        `reqs` is a stream of items: a columns item (`Ed25519Cols`, a
+        block body's witnesses) joins the Ed25519 lanes' three columns
+        whole, at its place in the order, and answers for the run of
+        request indices it stands for; a request object gives one lane.
+
         Returns (ed_reqs, ed_owner, vrf_reqs, vrf_owner, kes_msgs,
-        kes_expects, kes_checks, n); kes_checks lists the pending cache
+        kes_expects, kes_checks, n): the Ed25519 lanes as one
+        `Ed25519Cols` and the request index each answers for; n the
+        requests the items stand for; kes_checks lists the pending cache
         stores as (key, job_start, n_jobs, owners, leaf_vk) —
         finish_window folds the per-job verdicts into one outcome per
         path and records it."""
         cache = GLOBAL_PRECOMPUTE_CACHE
-        ed_reqs: list = []
+        ed_reqs = Ed25519Cols([], [], [])
         ed_owner: list[int] = []
         vrf_reqs: list = []
         vrf_owner: list[int] = []
         kes_msgs: list[bytes] = []
         kes_expects: list[bytes] = []
         pending: dict = {}     # key -> [start, n_jobs, owners, leaf_vk]
-        for i, r in enumerate(reqs):
+        n = 0                  # requests the items walked so far stand for
+        for r in reqs:
+            i = n              # the item's first request index
+            if isinstance(r, Ed25519Cols):
+                n += len(r)
+                ed_reqs.extend(r)
+                ed_owner.extend(range(i, n))
+                continue
+            n += 1
             if isinstance(r, Ed25519Req):
-                ed_reqs.append(r)
+                ed_reqs.append(r.vk, r.msg, r.sig)
                 ed_owner.append(i)
             elif isinstance(r, VrfReq):
                 vrf_reqs.append(r)
@@ -597,8 +618,7 @@ class JaxBackend(CryptoBackend):
                         kes_msgs.append(msg)
                         kes_expects.append(expect)
                     pending[key] = [start, len(jobs), [i], leaf_vk]
-                ed_reqs.append(Ed25519Req(leaf_vk, r.msg,
-                                          r.sig_bytes[:64]))
+                ed_reqs.append(leaf_vk, r.msg, r.sig_bytes[:64])
                 ed_owner.append(i)
             else:
                 raise TypeError(f"unknown proof request type {type(r)}")
@@ -606,7 +626,7 @@ class JaxBackend(CryptoBackend):
                       for key, (start, nj, owners, leaf_vk)
                       in pending.items()]
         return (ed_reqs, ed_owner, vrf_reqs, vrf_owner,
-                kes_msgs, kes_expects, kes_checks, len(reqs))
+                kes_msgs, kes_expects, kes_checks, n)
 
     def _prep_kes_hash(self, kes_msgs, kes_expects, m: int):
         msgs = np.frombuffer(b"".join(kes_msgs), dtype=np.uint8)
@@ -816,6 +836,11 @@ class JaxBackend(CryptoBackend):
         same compile); without fold the tiles run the bucket program of
         their width and finish_window fetches their verdicts.
 
+        `reqs` is the window's stream of items as the sequential pass
+        made it: request objects (the headers') and one columns item a
+        block body (`Ed25519Cols`, which counts for `len` requests);
+        verdicts are indices into the requests the items stand for.
+
         `window.submit` holds one span a stage: submit.split,
         submit.pack_ed (key tables and the tiles' copy to the device
         included), submit.pack_vrf (beta words included),
@@ -834,6 +859,8 @@ class JaxBackend(CryptoBackend):
             (ed_reqs, ed_owner, vrf_reqs, vrf_owner,
              kes_msgs, kes_expects, kes_checks, n) = \
                 self._split_mixed_device(reqs)
+            row_lanes = lane_count(
+                r for r in reqs if isinstance(r, Ed25519Cols))
             beta_proofs = list(dict.fromkeys(next_beta_proofs))
             ne = self._pad_ed_window(len(ed_reqs))
             nv, nb, nk = self._occasional_widths(*(
@@ -888,6 +915,7 @@ class JaxBackend(CryptoBackend):
                     vrf_args, beta_args, kes_args)
             if tiles:
                 self._note_ed_tiles(len(ed_reqs), ne)
+                _ED_ROW_LANES.inc(row_lanes)
                 run = self._ed_tile_program(allp, fold)
                 with _spans.span("submit.ed_tiles", cat="dispatch"):
                     if fold:

@@ -26,7 +26,7 @@ from ..consensus.headers import body_hash_of, make_header
 from ..consensus.ledger import LedgerError, LedgerRules
 from ..consensus.protocol import ConsensusProtocol, ProtocolError
 from ..crypto import ed25519_ref
-from ..crypto.backend import Ed25519Req
+from ..crypto.backend import Ed25519Cols, Ed25519Req
 from ..observe import metrics as _metrics
 from ..utils import cbor
 from .txrow import TxRow, tuple_new
@@ -369,17 +369,19 @@ class ByronLedger(LedgerRules):
             self.check_tx_witnesses(ticked, tx)
 
     def extract_proofs(self, ticked: ByronLedgerState, block) -> list:
-        return [Ed25519Req(vk=vk, msg=tx.txid, sig=sig)
-                for tx in block.body for vk, sig in tx.witnesses]
+        """ONE columns item for the body's witnesses, nothing for an
+        empty body (`LedgerRules.extract_proofs`)."""
+        cols = Ed25519Cols.of_witnesses(block.body)
+        return [cols] if cols else []
 
     def apply_block(self, ticked: ByronLedgerState, block,
                     backend=None) -> ByronLedgerState:
         from ..crypto.backend import default_backend
         backend = backend or default_backend()
         self.sequential_checks(ticked, block)
-        reqs = self.extract_proofs(ticked, block)
-        if reqs:
-            if not all(backend.verify_ed25519_batch(reqs)):
+        cols = Ed25519Cols.of_witnesses(block.body)
+        if cols:
+            if not all(backend.verify_ed25519_batch(cols)):
                 raise LedgerError(
                     f"invalid tx witness in block at slot {block.slot}")
         return self._apply_txs(ticked, block)
@@ -395,7 +397,7 @@ class ByronLedger(LedgerRules):
         self.check_tx_witnesses(state, tx)
         from ..crypto.backend import default_backend
         ok = (backend or default_backend()).verify_ed25519_batch(
-            self.extract_proofs(state, blk))
+            Ed25519Cols.of_witnesses(blk.body))
         if not all(ok):
             raise LedgerError(f"tx {tx.txid.hex()[:12]}: bad witness")
         return replace(self._apply_txs(state, blk), tip=state.tip)

@@ -50,7 +50,7 @@ from ..consensus.protocol import ConsensusProtocol, ProtocolError
 from ..consensus.protocols.praos import HotKey
 from ..crypto import ed25519_ref, kes as kes_mod, vrf_ref
 from ..crypto.backend import (
-    Ed25519Req, GLOBAL_BETA_CACHE, KesReq, VrfReq,
+    Ed25519Cols, Ed25519Req, GLOBAL_BETA_CACHE, KesReq, VrfReq,
 )
 from ..observe import metrics as _metrics
 from ..utils import cbor
@@ -1170,18 +1170,20 @@ class ShelleyLedger(LedgerRules):
 
     def extract_proofs(self, ticked: ShelleyLedgerState, block) -> list:
         """The BBODY Ed25519 witness multi-verify, batched
-        (Shelley/Ledger/Ledger.hs:279-284)."""
-        return [Ed25519Req(vk=vk, msg=tx.txid, sig=sig)
-                for tx in block.body for vk, sig in tx.witnesses]
+        (Shelley/Ledger/Ledger.hs:279-284): ONE columns item for the
+        body, every witness's own bytes over its transaction's id (hashed
+        where the block was decoded); nothing for an empty body."""
+        cols = Ed25519Cols.of_witnesses(block.body)
+        return [cols] if cols else []
 
     def apply_block(self, ticked: ShelleyLedgerState, block,
                     backend=None) -> ShelleyLedgerState:
         from ..crypto.backend import default_backend
         backend = backend or default_backend()
         self.sequential_checks(ticked, block)
-        reqs = self.extract_proofs(ticked, block)
-        if reqs:
-            ok = backend.verify_ed25519_batch(reqs)
+        cols = Ed25519Cols.of_witnesses(block.body)
+        if cols:
+            ok = backend.verify_ed25519_batch(cols)
             if not all(ok):
                 raise LedgerError(
                     f"invalid tx witness in block at slot {block.slot}")
@@ -1200,14 +1202,15 @@ class ShelleyLedger(LedgerRules):
         self.check_tx_witnesses(state, tx)
         from ..crypto.backend import default_backend
         ok = (backend or default_backend()).verify_ed25519_batch(
-            self.extract_proofs(state, blk))
+            Ed25519Cols.of_witnesses(blk.body))
         if not all(ok):
             raise LedgerError(f"tx {tx.txid.hex()[:12]}: bad witness")
         return replace(self._apply_txs(state, blk), tip=state.tip)
 
     def tx_proofs(self, state: ShelleyLedgerState, tx: ShelleyTx) -> list:
         """One tx's witness obligations (the batching-service admission
-        seam): same requests apply_tx verifies inline."""
+        seam), as `extract_proofs` hands a body's: the items stand for
+        the same requests apply_tx verifies inline."""
         return self.extract_proofs(state, _OneTxBlock(tx, state.tip))
 
 

@@ -77,10 +77,18 @@ span named first; cat in brackets):
                     `sequential_checks`, `extract_proofs`,
                     `reapply_block`), whatever the era.  None a
                     transaction: a span costs about what a light
-                    transaction's share of the pass does
+                    transaction's share of the pass does.
+                    body.extract ends with the block's ITEMS in hand:
+                    the header's request objects and ONE columns item
+                    for the body's witnesses (crypto/backend.py
+                    `Ed25519Cols`), no object a witness
     window.submit   submit.split, submit.pack_ed, submit.pack_vrf,
                     submit.pack_kes, submit.dispatch, submit.fold
-                    [dispatch], one each a window (crypto/jax_backend.py)
+                    [dispatch], one each a window (crypto/jax_backend.py).
+                    What crosses into it is the window's stream of
+                    items: submit.split walks the items (a handful a
+                    block) and joins each columns item to the Ed25519
+                    lanes whole, submit.pack_ed reads the columns
     submit.pack_ed  pack_ed.challenge [dispatch], the Ed25519 challenge
                     scalars of the window's lanes (crypto/ed25519_jax.py
                     `challenge_rows`); a root in `verify_ed25519_batch`

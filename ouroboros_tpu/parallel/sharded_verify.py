@@ -114,6 +114,7 @@ def build_sharded_gamma8(mesh: Mesh):
 
 
 from ..crypto.backend import CryptoBackend  # noqa: F401  (re-export)
+from ..crypto.backend import ed25519_columns
 from ..crypto import jax_backend as jb
 from ..crypto.jax_backend import JaxBackend
 
@@ -235,8 +236,7 @@ class ShardedJaxBackend(JaxBackend):
         if not reqs:
             return []
         return sharded_batch_verify(
-            [r.vk for r in reqs], [r.msg for r in reqs],
-            [r.sig for r in reqs], self.mesh, pad_to=self._pad(len(reqs)))
+            *ed25519_columns(reqs), self.mesh, pad_to=self._pad(len(reqs)))
 
     def _vrf_runner(self):
         fn = build_sharded_vrf(self.mesh)
