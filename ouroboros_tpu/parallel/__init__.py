@@ -4,13 +4,10 @@ The reference's parallelism is peers/threads/STM (SURVEY.md §2 "Parallelism
 strategies"); its crypto hot path is strictly sequential.  Here the device
 dimension is first-class: a window of independent proofs (the "sequence" of
 headers being validated) is sharded over a jax.sharding.Mesh axis and each
-chip runs the same branch-free ladder on its shard, with psum aggregation
-over ICI.  No NCCL/MPI analog: collectives are XLA's.
+chip runs the same branch-free ladder on its shard; the shards meet only in
+the fold's `pmin` over ICI.  No NCCL/MPI analog: collectives are XLA's.
 """
 from .mesh import log_compile_time, make_mesh
-from .sharded_verify import (
-    ShardedJaxBackend, build_sharded_verifier, sharded_batch_verify,
-)
+from .sharded_verify import ShardedJaxBackend
 
-__all__ = ["ShardedJaxBackend", "log_compile_time", "make_mesh",
-           "build_sharded_verifier", "sharded_batch_verify"]
+__all__ = ["ShardedJaxBackend", "log_compile_time", "make_mesh"]

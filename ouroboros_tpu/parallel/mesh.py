@@ -53,7 +53,7 @@ def make_mesh(n_devices: Optional[int] = None,
     window of independent headers/tx-witnesses being validated (the
     sequence-parallel analog for a blockchain's 'sequence').  A 1-D mesh
     suffices because the ladder kernel has no cross-example communication;
-    psum aggregation is the only collective.
+    the fold's `pmin` is the only collective.
     """
     devs = jax.devices()
     if n_devices is not None:

@@ -1,12 +1,10 @@
-"""Sharded batched verification: shard_map over the window axis + psum.
+"""The window's programs over a device mesh: shard_map over the window axis.
 
-Each device runs the Strauss ladder (crypto.ed25519_jax.verify_core) on its
-shard of the proof window; a psum over the mesh axis aggregates the count of
-fast-path-zero diffs (a device-side statistic; the exact accept decision
-stays on host, crypto.ed25519_jax.finalize).  This is the multi-chip
-"training step" of the framework: validation throughput scales linearly in
-mesh size because the ladder needs no cross-example communication — the
-collective rides ICI only for the final scalar.
+Each device runs the one-chip packed-words cores (the Ed25519 tile body,
+the VRF and beta parts of the composite) on its shard of the window's
+lanes.  The proofs are independent, so the ladders need no cross-device
+communication and throughput scales with the mesh; the one collective
+is the fold's `pmin`, in which the shards' first-bad indexes meet.
 """
 from __future__ import annotations
 
@@ -18,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..crypto import ed25519_jax as EJ
 from ..observe import metrics as _metrics
 from ..observe import spans as _spans
 from .mesh import WINDOW_AXIS
@@ -28,78 +25,6 @@ from .mesh import WINDOW_AXIS
 # device_put, and the padded lanes ONE shard carries (handles pre-bound)
 _SHARD_PUT_BYTES = _metrics.counter("jax_backend.shard_put_bytes")
 _SHARD_LANES_PADDED = _metrics.counter("jax_backend.shard_lanes_padded")
-
-
-@functools.lru_cache(maxsize=8)
-def build_sharded_verifier(mesh: Mesh):
-    """Returns a jitted fn over sharded inputs:
-    (yA, signA, yR, signR, s_bits, k_bits) -> (ok (N,), total_ok scalar).
-
-    Inputs as in crypto.ed25519_jax.verify_full_kernel, batch axis sharded
-    over the mesh's window axis; batch size must divide by mesh size.  The
-    per-shard ladder needs no communication; the psum totals the accepted
-    count over ICI.
-    """
-    axis = mesh.axis_names[0]
-    spec2 = P(None, axis)
-    spec1 = P(axis)
-
-    def step(yA, signA, yR, signR, sb, kb):
-        ok = EJ.verify_full_core(yA, signA, yR, signR, sb, kb)
-        total = jax.lax.psum(jnp.sum(ok), axis)
-        return ok, total
-
-    mapped = jax.shard_map(
-        step, mesh=mesh,
-        in_specs=(spec2, spec1, spec2, spec1, spec2, spec2),
-        out_specs=(spec1, P()))
-    return jax.jit(mapped)
-
-
-def sharded_batch_verify(vks, msgs, sigs, mesh: Mesh,
-                         pad_to: int | None = None) -> list[bool]:
-    """End-to-end sharded verify (host prep -> mesh kernel -> host accept)."""
-    n = len(vks)
-    if n == 0:
-        return []
-    d = mesh.devices.size
-    m = pad_to if pad_to and pad_to >= n else n
-    m = ((m + d - 1) // d) * d
-    vks = list(vks) + [b"\x00" * 32] * (m - n)
-    msgs = list(msgs) + [b""] * (m - n)
-    sigs = list(sigs) + [b"\x00" * 64] * (m - n)
-    arrays, parse_ok = EJ.prepare_bytes_batch(vks, msgs, sigs)
-    fn = build_sharded_verifier(mesh)
-    axis = mesh.axis_names[0]
-    shard2 = NamedSharding(mesh, P(None, axis))
-    shard1 = NamedSharding(mesh, P(axis))
-    specs = [shard2, shard1, shard2, shard1, shard2, shard2]
-    dev_arrays = [jax.device_put(a, s) for a, s in zip(arrays, specs)]
-    ok, _total = fn(*dev_arrays)
-    ok = np.asarray(ok)
-    return [bool(o) and bool(p) for o, p in zip(ok[:n], parse_ok[:n])]
-
-
-# ---------------------------------------------------------------------------
-# Sharded VRF + the mesh-wide CryptoBackend
-# ---------------------------------------------------------------------------
-
-@functools.lru_cache(maxsize=8)
-def build_sharded_vrf(mesh: Mesh):
-    """shard_map of crypto.vrf_jax.vrf_verify_core over the window axis:
-    each device decompresses, maps Elligator2, and runs the split-scalar
-    128-iteration ladders on its shard of the VRF batch — no cross-device
-    communication (the proofs are independent), so throughput scales
-    linearly over ICI."""
-    from ..crypto import vrf_jax
-    axis = mesh.axis_names[0]
-    spec2 = P(None, axis)
-    spec1 = P(axis)
-    mapped = jax.shard_map(
-        vrf_jax.vrf_verify_core, mesh=mesh,
-        in_specs=(spec2, spec1, spec2, spec1, spec2, spec2, spec2, spec2),
-        out_specs=P(axis, None))
-    return jax.jit(mapped)
 
 
 @functools.lru_cache(maxsize=8)
@@ -114,7 +39,6 @@ def build_sharded_gamma8(mesh: Mesh):
 
 
 from ..crypto.backend import CryptoBackend  # noqa: F401  (re-export)
-from ..crypto.backend import ed25519_columns
 from ..crypto import jax_backend as jb
 from ..crypto.jax_backend import JaxBackend
 
@@ -146,9 +70,9 @@ class ShardedJaxBackend(JaxBackend):
     only property.  KES hash paths still reduce on host here (via the
     cached split), so the composite stays Ed25519+VRF+betas.
 
-    The legacy bit-rows mesh API (sharded_batch_verify / verify_*_batch
-    overrides below) is kept for the standalone-batch surface and its
-    tests.  The served replay touches ONE piece of it: `vrf_betas_batch`,
+    The standalone batch forms (`verify_ed25519_batch`,
+    `verify_vrf_batch`) go through the window path too; no replay
+    reaches them.  One bit-rows form is left: `vrf_betas_batch`,
     the bit-rows `gamma8_kernel` under shard_map, is what the producer's
     beta prefetch (VrfBetaCache.prefetch) calls for the first TWO
     windows' betas, all there are on a two-window chain; window w+2's
@@ -233,41 +157,11 @@ class ShardedJaxBackend(JaxBackend):
         return ed_reqs, ed_owner, vrf_reqs, vrf_owner, [], [], [], n
 
     def verify_ed25519_batch(self, reqs):
-        if not reqs:
-            return []
-        return sharded_batch_verify(
-            *ed25519_columns(reqs), self.mesh, pad_to=self._pad(len(reqs)))
+        """A standalone batch is a window of one kind: JaxBackend's own
+        batch forms hand `_dev` inputs with no lane axis to shard."""
+        return self.verify_mixed(reqs)
 
-    def _vrf_runner(self):
-        fn = build_sharded_vrf(self.mesh)
-        axis = self.mesh.axis_names[0]
-        s2 = NamedSharding(self.mesh, P(None, axis))
-        s1 = NamedSharding(self.mesh, P(axis))
-        specs = (s2, s1, s2, s1, s2, s2, s2, s2)
-
-        def run(*args):
-            return fn(*(jax.device_put(np.asarray(a), s)
-                        for a, s in zip(args, specs)))
-        return run
-
-    def verify_vrf_batch(self, reqs):
-        # the mesh runners shard the limb/bit-rows kernel form, so prep
-        # goes through vrf_jax._prepare directly (vrf_jax._submit moved
-        # to the packed-words single-chip form in r5)
-        if not reqs:
-            return []
-        from ..crypto import vrf_jax
-        n = len(reqs)
-        m = self._pad(n)
-        vks = [r.vk for r in reqs] + [b"\x00" * 32] * (m - n)
-        alphas = [r.alpha for r in reqs] + [b""] * (m - n)
-        proofs = [r.proof for r in reqs] + [b"\x00" * 80] * (m - n)
-        args, parse_ok, gamma_ok, s_ok, pf_arr = vrf_jax._prepare(
-            vks, alphas, proofs)
-        handle = self._vrf_runner()(*args)
-        oks, _betas = vrf_jax._finish(handle, parse_ok, gamma_ok, s_ok,
-                                      pf_arr, n)
-        return oks
+    verify_vrf_batch = verify_ed25519_batch
 
     def vrf_betas_batch(self, proofs):
         if not proofs:

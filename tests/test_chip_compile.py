@@ -38,6 +38,9 @@ from ouroboros_tpu.crypto import jax_backend as JB
 from ouroboros_tpu.crypto import pallas_kernels as PK
 from ouroboros_tpu.crypto import vrf_jax as VJ
 
+# minutes of compile off the chip: conftest.py starts this file first
+pytestmark = pytest.mark.device
+
 # one 1024-block window of chip_smoke.py's chain (SYNTH: 2 txs/block,
 # depth-10 KES): OCert + KES leaf + 2 witnesses per block, 2 VRF proofs per block,
 # the next-next window's betas, the cold KES hash-path jobs.  Since the

@@ -30,6 +30,10 @@ import pytest
 
 jax = pytest.importorskip("jax")
 
+# the mesh's and the one-chip backend's window programs: minutes of
+# XLA:CPU compile, so conftest.py starts this file first
+pytestmark = pytest.mark.device
+
 from ouroboros_tpu.consensus.headers import ProtocolBlock      # noqa: E402
 from ouroboros_tpu.crypto.backend import (                     # noqa: E402
     GLOBAL_BETA_CACHE, Ed25519Req,
@@ -550,3 +554,20 @@ def test_no_span_when_recording_is_off(chain, mesh_backend, lines):
     spans_mod.RECORDER.drain()
     _validate(chain, mesh_backend)
     assert spans_mod.RECORDER.drain() == []
+
+
+# -- no replay reaches the standalone batch forms (ISSUE 43) -------------------
+
+def test_no_replay_reaches_the_standalone_forms(chain, lines, mesh_backend,
+                                                monkeypatch):
+    """What lets the standalone forms change (tests/test_mesh_batch.py
+    holds them to the reference) without a replay, and so a cell of the
+    benchmark, feeling it."""
+    def reached(reqs):
+        raise AssertionError("a replay called a standalone batch form")
+
+    monkeypatch.setattr(mesh_backend, "verify_ed25519_batch", reached)
+    monkeypatch.setattr(mesh_backend, "verify_vrf_batch", reached)
+    line = _validate(chain, mesh_backend)
+    assert line["blocks"] == BLOCKS
+    assert line["state_hash"] == lines["cpp"]["state_hash"]
