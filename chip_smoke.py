@@ -50,34 +50,19 @@ import time
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
 
-# Budget (ISSUE 22 step 4; the bills are in PERF.md and ROADMAP A9/C3).
-# The contract gives this script 1200 s, compilation included, and a
-# cold process compiles everything.  The bill grows with the number of
-# distinct window SHAPES, not with blocks.  Measured on the chip
-# machine (my chip run A, PR 22, default path, 2 windows): autotuner
-# 749 s (every part in both forms), one Pallas composite 375-411 s, the
-# per-key fill 38 s — 1652 s in all.  So, in the issue's order:
-#  (a) fewer whole windows: 2, not 4.  A 4-window chain needs TWO
-#      composite shapes (windows that carry the next-next window's
-#      betas, and the last two that do not), a 2-window chain one.
-#      That alone still cannot fit (749 + 375 + 38 s before any replay);
-#  (b) so the backend is built with the constructor options that exist,
-#      JaxBackend(use_pallas=True, autotune=False): only the Pallas form
-#      compiles (the form the tuner chose for ed/vrf/beta in run A).
+# Budget (ISSUE 22 step 4): the contract gives this script 1200 s,
+# compilation included, and a cold process compiles everything.  The
+# bill grows with the number of distinct window SHAPES, not with blocks,
+# so the chain is two whole windows: one composite, one fold, the tile
+# program (ROADMAP C10 has what is left to decide here).
 WINDOW = 1024
 BLOCKS = 2 * WINDOW
-BUDGET_NOTE = (
-    "2 windows of 1024, not >=4096 blocks, and the autotuner off "
-    "(Pallas form pinned): the default path's cold start measured "
-    "1652 s on the chip machine (tuner 749 s + one Pallas composite "
-    "375-411 s per window shape + fill 38 s) against a 1200 s limit; "
-    "4 windows need a second composite shape")
 SEED = "chip-smoke-22"
 SYNTH = ("--protocol", "shelley", "--pools", "2", "--f", "4/5",
          "--txs-per-block", "2", "--epoch-length", "600",
          "--kes-depth", "10")
 # rehearsal 1 runs at the shapes tests/test_served_replay.py compiles
-# (depth-4 KES, empty bodies, min_bucket 16, XLA form): a new composite
+# (depth-4 KES, empty bodies, min_bucket 16): a new composite
 # shape costs minutes of XLA:CPU compile
 REHEARSE_SYNTH = ("--protocol", "shelley", "--pools", "2", "--f", "4/5",
                   "--txs-per-block", "0", "--epoch-length", "500",
@@ -210,17 +195,13 @@ def main() -> int:
     from ouroboros_tpu import observe
     from ouroboros_tpu.compile_cache import ENV_VAR, cache_dir
     from ouroboros_tpu.consensus.batch import replay_blocks_pipelined
-    from ouroboros_tpu.crypto import pallas_kernels as PK
     from ouroboros_tpu.crypto.jax_backend import JaxBackend
     from tools import db_analyser as dba
     observe.enable()
-    require(not on_tpu or PK._interpret() is False,
-            "Pallas on a TPU must be Mosaic, not the interpreter")
     emit(phase="start", device=device, rehearse=args.rehearse,
          blocks=args.blocks, window=args.window, windows=n_windows,
          mesh=args.mesh, cache_dir=cache_dir(),
          cache_dir_from_env=bool(os.environ.get(ENV_VAR)),
-         pallas_interpret=PK._interpret(), pallas_mul=PK._mul_form(),
          jax=jax.__version__, host_cpus=os.cpu_count())
 
     # removed at the end, or by its finalizer if a phase fails
@@ -260,15 +241,11 @@ def main() -> int:
         jb = (ShardedJaxBackend(mesh, min_bucket=16) if args.rehearse
               else ShardedJaxBackend(mesh))
     elif args.rehearse:
-        jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
+        jb = JaxBackend(min_bucket=16)
     else:
-        # NOT dba.make_backend("jax"): see the budget note at the top
-        jb = JaxBackend(use_pallas=True, autotune=False)
+        jb = dba.make_backend("jax")        # what `--backend jax` builds
     emit(phase="backend", name=jb.name, platform=jb.platform,
-         device_kind=jb.device_kind, device_count=jb.device_count,
-         form="pallas" if jb.use_pallas else "xla",
-         autotuner="on" if jb.autotune else "off",
-         budget=None if args.rehearse or args.mesh else BUDGET_NOTE)
+         device_kind=jb.device_kind, device_count=jb.device_count)
     require(jb.platform == device["platform"],
             "the backend took another platform than JAX reports")
 
@@ -290,9 +267,7 @@ def main() -> int:
          line={k: dev[k] for k in ("backend_name", "platform",
                                    "device_kind", "device_count")},
          compile_spans=compile_spans(observe),
-         xla=compile_report(ev0),
-         kernel_choices={"@".join(map(str, k)): "pallas" if v else "xla"
-                         for k, v in jb.kernel_choices.items()})
+         xla=compile_report(ev0))
     require(dev["state_hash"] == ref["state_hash"],
             "device state hash differs from the reference")
     require(dev["blocks"] == args.blocks

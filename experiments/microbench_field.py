@@ -1,6 +1,6 @@
 #!/usr/bin/env python
 """Microbenchmark the crypto hot path on the real chip: device-only kernel
-times vs host-prep times, plus per-field-op costs inside a pallas kernel.
+times vs host-prep times, plus per-field-op costs inside a jitted chain.
 
 Run on the TPU machine:  python experiments/microbench_field.py [--ops]
 """
@@ -42,7 +42,6 @@ def bench_e2e():
 
     from ouroboros_tpu.crypto import ed25519_jax as EJ
     from ouroboros_tpu.crypto import ed25519_ref, kes, vrf_jax, vrf_ref
-    from ouroboros_tpu.crypto import pallas_kernels as PK
     from ouroboros_tpu.crypto.backend import KesReq
 
     n = 4096
@@ -84,33 +83,34 @@ def bench_e2e():
     vvks = [vvk] * nv
     print("fixtures: vrf ready", flush=True)
 
-    med, lo, hi = timed(lambda: vrf_jax._prepare(vvks, alphas, proofs))
-    report(f"vrf _prepare n={nv}", med, lo, hi)
-
-    args, parse_ok, gamma_ok, s_ok, pf_arr = vrf_jax._prepare(
-        vvks, alphas, proofs)
+    med, lo, hi = timed(
+        lambda: vrf_jax._prepare_words(vvks, alphas, proofs))
+    report(f"vrf _prepare_words n={nv}", med, lo, hi)
 
     def run_vrf():
-        return np.asarray(PK.vrf_verify_pallas(*args))
+        return np.asarray(vrf_jax._submit(vvks, alphas, proofs, nv)[0])
     med, lo, hi = timed(run_vrf)
-    report(f"vrf pallas device n={nv}", med, lo, hi, per=f"{nv/med:.0f}/s")
+    report(f"vrf prep + device n={nv}", med, lo, hi, per=f"{nv/med:.0f}/s")
 
-    rows = np.asarray(PK.vrf_verify_pallas(*args))
+    handle, parse_ok, gamma_ok, s_ok, pf_arr = vrf_jax._submit(
+        vvks, alphas, proofs, nv)
+    rows = np.asarray(handle)
     med, lo, hi = timed(lambda: vrf_jax._finish(rows, parse_ok, gamma_ok,
                                                 s_ok, pf_arr, nv))
     report(f"vrf _finish n={nv}", med, lo, hi)
 
     # betas
-    med, lo, hi = timed(lambda: vrf_jax._prepare_betas(proofs))
-    report(f"beta _prepare n={nv}", med, lo, hi)
-    (yG, signG), decode_ok = vrf_jax._prepare_betas(proofs)
+    med, lo, hi = timed(lambda: vrf_jax._prepare_betas_words(proofs))
+    report(f"beta _prepare_betas_words n={nv}", med, lo, hi)
+    (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(proofs)
+    Gw, signG = jnp.asarray(Gw), jnp.asarray(signG)
 
     def run_beta():
-        return np.asarray(PK.gamma8_pallas(yG, signG))
+        return np.asarray(vrf_jax.gamma8_words_kernel(Gw, signG))
     med, lo, hi = timed(run_beta)
-    report(f"beta pallas device n={nv}", med, lo, hi, per=f"{nv/med:.0f}/s")
+    report(f"beta device n={nv}", med, lo, hi, per=f"{nv/med:.0f}/s")
 
-    rows_b = np.asarray(PK.gamma8_pallas(yG, signG))
+    rows_b = run_beta()
     med, lo, hi = timed(lambda: vrf_jax._finish_betas(rows_b, decode_ok, nv))
     report(f"beta _finish n={nv}", med, lo, hi)
 
@@ -126,69 +126,47 @@ def bench_e2e():
 
 
 def bench_ops():
-    """Per-op costs inside a pallas kernel: chains of K ops, difference two
-    K values to cancel fixed overhead."""
+    """Per-op costs inside a jitted chain of K ops, difference two K
+    values to cancel fixed overhead."""
     import jax
     import jax.numpy as jnp
     from jax import lax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
 
     from ouroboros_tpu.crypto import ed25519_jax as EJ
     from ouroboros_tpu.crypto import field_jax as F
 
-    TILE = 512
-    GRID = 8
-    N = TILE * GRID
+    N = 4096
     rng = np.random.default_rng(0)
     a_np = rng.integers(0, 8191, size=(F.NLIMBS, N), dtype=np.int32)
     b_np = rng.integers(0, 8191, size=(F.NLIMBS, N), dtype=np.int32)
 
     def make_chain(op_name, k):
-        def kernel(a_ref, b_ref, o_ref):
-            a = a_ref[:]
-            b = b_ref[:]
-
+        def chain(a, b):
             def body(i, a):
                 if op_name == "mul":
                     return F.mul(a, b)
                 if op_name == "sqr":
-                    return F.mul(a, a)
+                    return F.sqr(a)
                 if op_name == "add":
                     return F.add(a, b)
                 if op_name == "carry":
                     return F.carry_round(a)
                 raise ValueError(op_name)
-            o_ref[:] = lax.fori_loop(0, k, body, a)
-
-        lane = lambda i: (0, i)
-        spec = pl.BlockSpec((F.NLIMBS, TILE), lane, memory_space=pltpu.VMEM)
-        with F.mul_impl("columns"):
-            f = pl.pallas_call(
-                kernel, grid=(GRID,), in_specs=[spec, spec], out_specs=spec,
-                out_shape=jax.ShapeDtypeStruct((F.NLIMBS, N), jnp.int32))
-        return jax.jit(f)
+            return lax.fori_loop(0, k, body, a)
+        return jax.jit(chain)
 
     def make_pt_chain(kind, k):
         """Chain of point ops: kind in dbl | addc (add with fixed point)."""
-        def kernel(x_ref, y_ref, z_ref, t_ref, o_ref):
-            P = (x_ref[:], y_ref[:], z_ref[:], t_ref[:])
-            Q = P
+        def chain(x, y, z, t):
+            P = (x, y, z, t)
 
             def body(i, Q):
                 if kind == "dbl":
                     return EJ.pt_double(Q)
-                return EJ.pt_add(Q, P, TILE)
-            Q = lax.fori_loop(0, k, body, Q)
-            o_ref[:] = Q[0] + Q[1] + Q[2] + Q[3]
-
-        lane = lambda i: (0, i)
-        spec = pl.BlockSpec((F.NLIMBS, TILE), lane, memory_space=pltpu.VMEM)
-        with F.mul_impl("columns"):
-            f = pl.pallas_call(
-                kernel, grid=(GRID,), in_specs=[spec] * 4, out_specs=spec,
-                out_shape=jax.ShapeDtypeStruct((F.NLIMBS, N), jnp.int32))
-        return jax.jit(f)
+                return EJ.pt_add(Q, P, N)
+            Q = lax.fori_loop(0, k, body, P)
+            return Q[0] + Q[1] + Q[2] + Q[3]
+        return jax.jit(chain)
 
     a = jnp.asarray(a_np)
     b = jnp.asarray(b_np)

@@ -1,9 +1,9 @@
-"""Where compiled programs and measured choices are kept: one rule.
+"""Where compiled programs and measured tables are kept: one rule.
 
 The ladder kernels take minutes to compile per window shape, so every
 device path keeps JAX's persistent compilation cache on, and the
-autotune choice file and the break-even table sit in the same directory
-(a choice is only worth keeping beside the programs it chose between).
+verify service's break-even table (crypto/batching.py) sits in the
+same directory: it was measured against those programs.
 
 The rule, in `cache_dir()` and nowhere else:
 

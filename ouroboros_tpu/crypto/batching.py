@@ -12,8 +12,8 @@ budget.  This module is the dynamic-batching tier between the two:
 - **futures-based submit**: many concurrent protocol threads
   ``await service.submit(req)`` / ``await fut.wait()``; the service owns
   the only dispatch loop.
-- **deadline-aware coalescing**: a batch flushes when the autotuned
-  bucket fills (``max_batch`` — a shape the backend already compiles, so
+- **deadline-aware coalescing**: a batch flushes when its bucket
+  fills (``max_batch`` — a shape the backend already compiles, so
   the hot path never triggers a new composite compile) or when the
   oldest request's deadline minus the *measured* flush latency (EWMA)
   minus a safety margin arrives — whichever is earlier.  Under the sim
@@ -26,7 +26,7 @@ budget.  This module is the dynamic-batching tier between the two:
   device cannot beat the CPU reference path (fixed dispatch cost
   dominates); such flushes run on the CPU backend.  The break-even table
   is calibrated ONCE per (primitive, device-kind) and persisted beside
-  the autotuner's choice file, so every later process starts routed.
+  the compile cache, so every later process starts routed.
 
 The service runs entirely on the runtime clock through the simharness
 facade: identical code executes deterministically under ``sim.run``
@@ -55,7 +55,6 @@ from .. import simharness as sim
 from ..compile_cache import cache_dir
 from ..observe import metrics as _metrics
 from ..simharness.stm import TVar, retry
-from . import autotune as _autotune
 from .backend import (
     CpuRefBackend, CryptoBackend, Ed25519Req, KesReq, VrfReq, iter_requests,
 )
@@ -65,6 +64,18 @@ __all__ = [
     "ServiceConfig", "ServiceStopped", "VerifyFuture", "VerifyService",
     "calibrate_break_even", "validate_headers_coalesced",
 ]
+
+# The revision of the device kernels a break-even table was measured
+# against: part of the table's file name and checked on load, so a table
+# from other kernels is measured again.  Bump it when a kernel changes
+# enough to move a break-even batch size.
+KERNEL_REV = "r8-fold-1"
+
+
+def _slug(s: str) -> str:
+    """A device kind as part of a file name."""
+    return "".join(c if c.isalnum() or c in "-._" else "-" for c in s)
+
 
 # -- metrics (handles pre-bound, OBS002) ------------------------------------
 _QUEUE_DEPTH = _metrics.gauge("service.queue_depth", stable=False)
@@ -116,9 +127,9 @@ class BreakEvenTable:
     dispatch beats ``n`` sequential CPU-reference verifies; flushes
     below it take the CPU fallback.  Entries carry the raw measurements
     (``cpu_secs_per_req``, ``device_secs_batch`` at ``bucket``) so the
-    decision is auditable.  Persisted as JSON beside the autotuner's
-    choice file, keyed by (KERNEL_REV, device kind) exactly like the
-    kernel choices — a new kernel revision re-calibrates."""
+    decision is auditable.  Persisted as JSON beside the compile cache
+    (`compile_cache.cache_dir`), keyed by (KERNEL_REV, device kind) — a
+    new kernel revision re-calibrates."""
 
     def __init__(self, entries: Optional[dict] = None,
                  device_kind: str = "uncalibrated"):
@@ -134,19 +145,19 @@ class BreakEvenTable:
         ent = self.entries.get(prim)
         return int(ent["n_star"]) if ent else 1
 
-    # -- persistence (beside the autotune choice file) ----------------------
+    # -- persistence (beside the compile cache) -----------------------------
     @staticmethod
     def path_for(device_kind: str) -> str:
         return os.path.join(
             cache_dir(),
-            f"ouro-breakeven-{_autotune.KERNEL_REV}-"
-            f"{_autotune._slug(device_kind)}.json")
+            f"ouro-breakeven-{KERNEL_REV}-"
+            f"{_slug(device_kind)}.json")
 
     def save(self, path: Optional[str] = None) -> str:
         path = path or self.path_for(self.device_kind)
         tmp = path + ".tmp"
         with open(tmp, "w") as f:
-            json.dump({"kernel_rev": _autotune.KERNEL_REV,
+            json.dump({"kernel_rev": KERNEL_REV,
                        "device_kind": self.device_kind,
                        "entries": {k: self.entries[k]
                                    for k in sorted(self.entries)}},
@@ -164,7 +175,7 @@ class BreakEvenTable:
         try:
             with open(path) as f:
                 data = json.load(f)
-            if data.get("kernel_rev") != _autotune.KERNEL_REV:
+            if data.get("kernel_rev") != KERNEL_REV:
                 return None
             return cls(data.get("entries") or {},
                        data.get("device_kind", device_kind))
@@ -179,8 +190,8 @@ class BreakEvenTable:
 
 
 def _min_of_k(fn: Callable[[], Any], k: int = 3) -> float:
-    """Min-of-k wall timing (the autotuner's estimator: on a noisy chip
-    only the min resists slow-tail outliers)."""
+    """Min-of-k wall timing (on a noisy chip only the min resists
+    slow-tail outliers)."""
     best = None
     for _ in range(k):
         t0 = time.perf_counter()
@@ -298,8 +309,8 @@ class ServiceConfig:
 
     max_batch       — flush when this many requests are pending.  Set it
                       to a bucket shape the backend already compiles
-                      (the autotuner pins per-bucket choices; the
-                      service never introduces a new composite shape).
+                      (the service never introduces a new composite
+                      shape).
     max_queue       — admission bound; past it submit blocks (back-
                       pressure) and try_submit returns None.
     default_deadline— seconds from submit to verdict-due when the caller

@@ -92,19 +92,17 @@ def _g(v, a, b, c, d, mx, my):
 _SIGMA_ARR = np.array(_ROUNDS, dtype=np.int32)   # (12, 16)
 
 
-def compress_block64(m_words, unroll: bool = False):
+def compress_block64(m_words):
     """One final-block BLAKE2b-256 compression over 64-byte messages.
 
     m_words: (16, N) uint32 — message words 0..7 as (lo, hi) interleaved
     rows (row 2i = lo of 64-bit word i); words 8..15 are implicit zero.
     Returns (8, N) uint32 — the 32-byte digest as interleaved (lo, hi).
 
-    unroll=False runs the 12 rounds as a lax.fori_loop with the per-round
-    message permutation done by one jnp.take over a (16, 2, N) word stack
-    — a fully-unrolled trace made XLA:CPU compilation pathological
-    (>10 min on one core) for identical runtime.  unroll=True emits the
-    static 12-round trace: required inside Mosaic kernels, where a
-    dynamic take of a value has no lowering (pallas_kernels).
+    The 12 rounds run as a lax.fori_loop with the per-round message
+    permutation done by one jnp.take over a (16, 2, N) word stack — a
+    fully-unrolled trace made XLA:CPU compilation pathological (>10 min
+    on one core) for identical runtime.
     """
     ref = m_words[0]
     zero = ref * 0
@@ -123,26 +121,20 @@ def compress_block64(m_words, unroll: bool = False):
         _g(v, 2, 7, 8, 13, m[12], m[13])
         _g(v, 3, 4, 9, 14, m[14], m[15])
 
-    if unroll:
-        m = [(m_words[2 * i], m_words[2 * i + 1]) for i in range(8)]
-        m = m + [(zero, zero)] * 8
-        for s in _ROUNDS:
-            run_round(v, [m[j] for j in s])
-    else:
-        m_stack = jnp.stack(
-            [jnp.stack([m_words[2 * i], m_words[2 * i + 1]])
-             for i in range(8)]
-            + [jnp.stack([zero, zero])] * 8)           # (16, 2, N)
-        sigma = jnp.asarray(_SIGMA_ARR)
+    m_stack = jnp.stack(
+        [jnp.stack([m_words[2 * i], m_words[2 * i + 1]])
+         for i in range(8)]
+        + [jnp.stack([zero, zero])] * 8)           # (16, 2, N)
+    sigma = jnp.asarray(_SIGMA_ARR)
 
-        def round_body(r, carry):
-            vv = [list(w) for w in carry]
-            msel = jnp.take(m_stack, jnp.take(sigma, r, axis=0), axis=0)
-            run_round(vv, [(msel[i, 0], msel[i, 1]) for i in range(16)])
-            return tuple(tuple(w) for w in vv)
+    def round_body(r, carry):
+        vv = [list(w) for w in carry]
+        msel = jnp.take(m_stack, jnp.take(sigma, r, axis=0), axis=0)
+        run_round(vv, [(msel[i, 0], msel[i, 1]) for i in range(16)])
+        return tuple(tuple(w) for w in vv)
 
-        v = list(jax.lax.fori_loop(0, 12, round_body,
-                                   tuple(tuple(w) for w in v)))
+    v = list(jax.lax.fori_loop(0, 12, round_body,
+                               tuple(tuple(w) for w in v)))
     out = []
     for i in range(4):
         lo, hi = _xor64(_xor64(h[i], v[i]), v[i + 8])

@@ -163,8 +163,7 @@ def joint_table_16(Bs, As, n):
 
 
 def _onehot_entry(table, idx, k):
-    """Sum-of-onehot select of a k-entry stacked table (XLA path; see
-    pallas_kernels._select16 for the where-chain form Mosaic prefers)."""
+    """Sum-of-onehot select of a k-entry stacked table."""
     sel = (idx[None, :] == jnp.arange(k, dtype=jnp.int32)[:, None])
     sel = sel.astype(jnp.int32)[:, None, :]                       # (k,1,N)
     return tuple(jnp.sum(table[c] * sel, axis=0) for c in range(4))
@@ -287,36 +286,6 @@ def verify_split_idx_core(negA, negA128, Rx, Ry, idx_rows):
     Q = lax.fori_loop(0, 128, body, ident)
     X, Y, Z, _ = Q
     return F.sub(F.mul(Rx, Z), X), F.sub(F.mul(Ry, Z), Y)
-
-
-def verify_split_core(negA, negA128, Rx, Ry, s_bits, k_bits):
-    """Bit-rows form of the split ladder (s_bits/k_bits as (256, N)
-    MSB-first rows, same layout verify_core takes)."""
-    idx = (s_bits[128:] + 2 * s_bits[:128]
-           + 4 * k_bits[128:] + 8 * k_bits[:128])
-    return verify_split_idx_core(negA, negA128, Rx, Ry, idx)
-
-
-def verify_full_split_core(yA, signA, xA128, yA128, yR, signR,
-                           s_bits, k_bits):
-    """Whole split-ladder verification on device (the XLA form of the
-    pallas kernel in pallas_kernels._ed25519_split_kernel): decompress A
-    and R, negate A and the host-supplied affine A128, ladder, compare.
-    Returns (N,) int32 0/1."""
-    xA, okA = device_decompress(yA, signA)
-    xR, okR = device_decompress(yR, signR)
-    one = F.one_like(yA)
-    nax = F.sub(yA * 0, xA)
-    negA = (nax, yA, one, F.mul(nax, yA))
-    nax128 = F.sub(yA * 0, xA128)
-    negA128 = (nax128, yA128, one, F.mul(nax128, yA128))
-    d1, d2 = verify_split_core(negA, negA128, xR, yR, s_bits, k_bits)
-    ok = jnp.logical_and(jnp.logical_and(okA, okR),
-                         jnp.logical_and(F.is_zero(d1), F.is_zero(d2)))
-    return ok.astype(jnp.int32)
-
-
-verify_full_split_kernel = jax.jit(verify_full_split_core)
 
 
 def verify_full_split_words_core(Aw, xAw, A128xw, A128yw, Rw, signR,
@@ -515,13 +484,6 @@ def verify_full_core(yA, signA, yR, signR, s_bits, k_bits):
 
 
 verify_full_kernel = jax.jit(verify_full_core)
-
-
-def verify_kernel_full_submit(arrays):
-    """Submit a prepared batch without blocking (async dispatch): returns the
-    device array handle; np.asarray(handle) later blocks and fetches.  Lets
-    callers pipeline host prep of the next batch under device execution."""
-    return verify_full_kernel(*[jnp.asarray(a) for a in arrays])
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,6 @@
 """GF(2^255-19) arithmetic on batched int32 limb vectors — the TPU field core.
 
-Design (TPU-first, see /opt/skills/guides/pallas_guide.md and SURVEY.md §7):
+Design (TPU-first, see SURVEY.md §7):
 - A field element batch is an int32 array of shape (NLIMBS, N): limbs on the
   sublane axis, batch on the 128-wide lane axis, so every op is elementwise
   over the batch with full lane utilisation.
@@ -67,10 +67,8 @@ _TWO_P_LIMBS = np.array(int_to_limbs(2 * P), dtype=np.int32)[:, None]
 
 
 def _col(limbs, n: int) -> jnp.ndarray:
-    """(NLIMBS, n) int32 limb constant built from Python-int scalars —
-    pallas-safe (Mosaic kernels may not capture array constants, and
-    1-wide lane dims upset its tiling; scalars broadcast to full width
-    are fine, and XLA constant-folds the concat on the regular path)."""
+    """(NLIMBS, n) int32 limb constant built from Python-int scalars
+    broadcast to full width (XLA constant-folds the concat)."""
     return jnp.concatenate(
         [jnp.full((1, n), int(v), jnp.int32) for v in limbs], axis=0)
 
@@ -114,8 +112,8 @@ def sub(a, b):
 
 
 def _row_update(v, i, row):
-    """v with row i replaced — concatenation, not scatter (scatter has no
-    Mosaic lowering, and XLA fuses the concat just as well)."""
+    """v with row i replaced — concatenation, not scatter (XLA fuses the
+    concat)."""
     parts = []
     if i > 0:
         parts.append(v[:i])
@@ -125,10 +123,11 @@ def _row_update(v, i, row):
     return jnp.concatenate(parts, axis=0)
 
 
-def _mul_shifted(a, b):
-    """Shifted-accumulate form: prod = Σ_j shift_j(a·b_j) with zero-pad
-    concatenations — ~70 primitives per product, the small-trace default
-    (the XLA op-by-op path fuses it; Mosaic compiles it quickly)."""
+def mul(a, b):
+    """Schoolbook product with fold; output carried to input bounds.
+    Shifted-accumulate form: prod = Σ_j shift_j(a·b_j) with zero-pad
+    concatenations — ~70 primitives per product, a small trace that XLA
+    fuses."""
     n = a.shape[1]
     acc = None
     for j in range(NLIMBS):
@@ -150,91 +149,9 @@ def _mul_shifted(a, b):
     return carry3(low)
 
 
-def _mul_columns(a, b):
-    """Column form: prod[k] = Σ_{i+j=k} a_i·b_j, one row sum per column —
-    exactly the needed multiply-adds, no padded zero work.  ~780 primitives
-    per product (slow to Mosaic-compile) but ~3.5x faster at runtime inside
-    the fused pallas ladders, where every op stays in VMEM."""
-    cols = []
-    for k in range(NPROD):
-        terms = [a[i] * b[k - i]
-                 for i in range(max(0, k - NLIMBS + 1), min(NLIMBS, k + 1))]
-        s = terms[0]
-        for t in terms[1:]:
-            s = s + t
-        cols.append(s)
-    low = cols[:NLIMBS]
-    for k in range(NLIMBS, NPROD):
-        hi = cols[k]
-        low[k - NLIMBS] = low[k - NLIMBS] + (hi & MASK) * FOLD
-        low[k - NLIMBS + 1] = low[k - NLIMBS + 1] + (hi >> RADIX) * FOLD
-    return carry3(jnp.stack(low))
-
-
-def _sqr_columns(a):
-    """Squaring, column form: exploits symmetry — cross terms a_i·a_j
-    (i < j) are computed once and doubled, so ~half the multiplies of
-    _mul_columns.  Bound: inputs ≤ 10015 ⇒ worst column (k = 19) sums
-    10 doubled products = 2·10·10015² < 2^31; every other column is
-    smaller, so int32 accumulation stays exact."""
-    cols = []
-    for k in range(NPROD):
-        lo = max(0, k - NLIMBS + 1)
-        hi = min(NLIMBS - 1, k)
-        cross = None
-        for i in range(lo, (k + 1) // 2):
-            t = a[i] * a[k - i]
-            cross = t if cross is None else cross + t
-        s = None
-        if cross is not None:
-            s = cross + cross
-        if k % 2 == 0 and lo <= k // 2 <= hi:
-            c = a[k // 2] * a[k // 2]
-            s = c if s is None else s + c
-        cols.append(s)
-    low = cols[:NLIMBS]
-    for k in range(NLIMBS, NPROD):
-        hi = cols[k]
-        low[k - NLIMBS] = low[k - NLIMBS] + (hi & MASK) * FOLD
-        low[k - NLIMBS + 1] = low[k - NLIMBS + 1] + (hi >> RADIX) * FOLD
-    return carry3(jnp.stack(low))
-
-
-_mul_active = "shifted"
-
-
-class mul_impl:
-    """``with mul_impl("columns"):`` — select the multiplication form for
-    everything traced inside the block (pallas kernel bodies pick the
-    runtime-fast column form; everyone else keeps the small trace)."""
-
-    def __init__(self, name: str):
-        self._name = name
-
-    def __enter__(self):
-        global _mul_active
-        self._prev, _mul_active = _mul_active, self._name
-        return self
-
-    def __exit__(self, *exc):
-        global _mul_active
-        _mul_active = self._prev
-        return False
-
-
-def mul(a, b):
-    """Schoolbook product with fold; output carried to input bounds."""
-    if _mul_active == "columns":
-        return _mul_columns(a, b)
-    return _mul_shifted(a, b)
-
-
 def sqr(a):
-    """Squaring; the column form halves the multiply count vs mul(a, a)
-    (the shifted form has no cheaper squaring shape, so it just defers)."""
-    if _mul_active == "columns":
-        return _sqr_columns(a)
-    return _mul_shifted(a, a)
+    """Squaring (the shifted form has no cheaper squaring shape)."""
+    return mul(a, a)
 
 
 # 40*p as a 20-limb vector with an oversized top limb (40p needs 261 bits);
@@ -362,5 +279,5 @@ def const_batch(x: int, n: int):
 def one_like(x):
     """Limb vector of 1 with x's shape AND varying-axis type (derived from
     x, so it stays a legal lax.fori_loop carry under shard_map — a pure
-    constant would not; also scatter-free for pallas)."""
+    constant would not)."""
     return x * 0 + const_batch(1, x.shape[1])

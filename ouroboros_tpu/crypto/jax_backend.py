@@ -9,7 +9,7 @@ instead of per-item host hashing.
 
 ALL device inputs travel as packed uint32 words, 32x smaller than the
 (256, N) int32 bit rows of earlier rounds.  Unpacking is a tiny
-on-device XLA prologue fused ahead of the Mosaic kernels.  The link is
+on-device prologue fused ahead of the ladders.  The link is
 not the bottleneck it was on the device this form was designed for (a
 whole 4096-lane Ed25519 dispatch + compute + drain took 14 ms on a TPU
 v5 lite — smoke reading, PR 22, ROADMAP A2); the packed form is kept
@@ -24,16 +24,11 @@ accelerator), so a replay whose windows hold 1,500 lanes and 90,000
 builds what a replay of equal windows builds, and the device walks only
 the tiles that hold a real lane.
 
-Kernel selection is MEASURED, not assumed: on a TPU the fused pallas
-(Mosaic) kernels and the op-by-op XLA kernels are timed head-to-head
-the first time each batch shape appears on this machine (persistent,
-fenced, min-of-k — crypto/autotune.py), and the winner stays pinned per
-(kernel, bucket, device kind) — a hardcoded choice was repeatedly wrong
-(VERDICT r3 "weak" #3), and an UNFENCED re-measure mid-run was the prime
-suspect for a recorded VRF regression.  Whether either form wins beyond
-the spread on the present chip is ROADMAP C3's question; the tuner's
-price is that a new window shape compiles BOTH forms of every part
-(749 s on the chip machine, PR 22 — ROADMAP A9).
+Every device program has ONE form, the jitted XLA cores of
+ed25519_jax, vrf_jax and blake2b_jax, on every platform: the form every
+cell of the benchmark times.  (A second, Pallas form of each ladder and
+a tuner that chose between the two went in PR 44; the last tree that
+holds them is that PR's parent.)
 
 Repeated verification keys cost nothing past their first window: the
 cross-window precomputation cache (crypto/precompute.py) memoises the
@@ -46,7 +41,6 @@ import numpy as np
 
 from ..observe import metrics as _metrics
 from ..observe import spans as _spans
-from . import autotune as autotune_mod
 from . import blake2b_jax as B2
 from . import ed25519_jax as EJ
 from . import edwards as ed
@@ -123,9 +117,8 @@ def _bucket(n: int, lo: int = 128) -> int:
     return m
 
 
-# Width of one Ed25519 tile on an accelerator, in lanes (a multiple of
-# pallas_kernels.TILE = 512, so the Pallas grid divides it).  What a lane
-# costs the XLA ladder is not flat in the width of the program: 3.5 us at
+# Width of one Ed25519 tile on an accelerator, in lanes.  What a lane
+# costs the ladder is not flat in the width of the program: 3.5 us at
 # 2,048 and 4,096 lanes, 3.9 at 8,192, 5.8 at 16,384, 7.8 at 32,768, 19.0
 # at 65,536 and 29.1 at 131,072 (a TPU v5e; PERF.md section 6, PR 30, has
 # the sweep and the device operations that grow).  So a window's lanes go
@@ -182,47 +175,33 @@ class JaxBackend(CryptoBackend):
     # producer/consumer replay driver asks — consensus/pipeline.py)
     supports_window_fold = True
 
-    def __init__(self, min_bucket: int = 128, use_pallas: bool | None = None,
-                 autotune: bool | None = None):
+    def __init__(self, min_bucket: int = 128, use_pallas: bool | None = False,
+                 autotune: bool | None = False):
+        # `use_pallas` and `autotune` select nothing: the kernels have
+        # one form since PR 44.  The names stay until the benchmark's
+        # configurations stop passing them (ROADMAP C19).
+        for name, value in (("use_pallas", use_pallas),
+                            ("autotune", autotune)):
+            if value not in (None, False):
+                raise ValueError(
+                    f"JaxBackend({name}={value!r}): the Pallas kernels "
+                    f"and the tuner went in PR 44; the argument takes "
+                    f"only False or None and selects nothing")
         import jax
         self._devices = jax.devices()
         self.platform = self._devices[0].platform
         self.device_kind = self._devices[0].device_kind
         self.device_count = len(self._devices)
         self.name = f"jax-{self.platform}"
-        on_tpu = self.platform == "tpu"
-        if autotune is None:
-            # measure pallas-vs-XLA per shape on a real chip UNLESS the
-            # caller pinned the path explicitly; off-TPU pallas interpret
-            # mode just re-runs the same jnp ops with extra overhead, so
-            # XLA is always right there and measuring would waste compiles
-            autotune = on_tpu and use_pallas is None
-        if use_pallas is None:
-            use_pallas = on_tpu
-        self.use_pallas = use_pallas      # static fallback when not tuning
-        self.autotune = autotune
-        if use_pallas or autotune:
-            from . import pallas_kernels as PK
-            self._pk = PK
-            min_bucket = max(min_bucket, PK.TILE)
         self.min_bucket = min_bucket
         self.ed_tile = ed_tile_width(self.platform, self.min_bucket)
-        self._composites: dict = {}   # (nv, nb, nk, pallas) -> program
+        self._composites: dict = {}   # (nv, nb, nk) -> program
         self._folds: dict = {}        # (nv, nb, nk) -> fold program
-        self._ed_tile_programs: dict = {}  # (pallas, fold) -> tile program
-        self._pk_vrf_folds: dict = {} # m -> jitted pallas verify+fold
+        self._ed_tile_programs: dict = {}  # fold -> tile program
         # donate the window inputs to the composite so a warm-path window
         # reuses the previous window's device buffers instead of
         # reallocating (XLA:CPU ignores donation with a warning -> gate)
         self._donate = self.platform in ("tpu", "gpu")
-        # persistent fenced tuner shared process-wide per device kind —
-        # only consulted when this instance is itself autotuning, so an
-        # explicitly pinned use_pallas/autotune setting is never
-        # overridden by a stale measurement file (crypto/autotune.py)
-        self._tuner = (autotune_mod.tuner_for(self.device_kind)
-                       if autotune else None)
-        # static-path choices recorded for kernel_choices() reporting
-        self._static_choice: dict = {}
         # per-instance lane occupancy accumulators (padding_stats());
         # written only on the submit path, which has a single writer
         # thread in the pipelined replay (the producer)
@@ -368,34 +347,6 @@ class JaxBackend(CryptoBackend):
                 self.submit_window(reqs, next_beta_proofs, fold=fold))
         return _time.perf_counter() - t0, ok
 
-    # -- measured kernel selection ------------------------------------------
-    @property
-    def kernel_choices(self) -> dict:
-        """Stable {shape key tuple: use_pallas} of every pinned choice
-        this backend can run with (chip_smoke.py prints it)."""
-        if self._tuner is not None:
-            return self._tuner.choices_snapshot()
-        return {k: self._static_choice[k]
-                for k in sorted(self._static_choice)}
-
-    def _pick(self, key, run_pallas, run_xla):
-        """Return (use_pallas, cached_result) for this shape key.
-
-        Pinned choices (persisted by an earlier process, or measured
-        earlier in this one) return instantly.  First sighting of a
-        shape under autotune measures both paths through the fenced
-        min-of-k tuner and pins the winner — loudly failing if a timed
-        region froze the tuner first.  cached_result is the winner's
-        last measured output (simple batch callers reuse it to skip one
-        dispatch); None whenever no measurement ran."""
-        if not self.autotune:
-            self._static_choice[key] = self.use_pallas
-            return self.use_pallas, None
-        use = self._tuner.get(key)
-        if use is not None:
-            return use, None
-        return self._tuner.measure(key, run_pallas, run_xla)
-
     # -- host prep ----------------------------------------------------------
     def _pack_ed(self, reqs, m: int):
         """Packed-words prep + A128 assembly for an Ed25519 batch padded
@@ -419,26 +370,14 @@ class JaxBackend(CryptoBackend):
         arrays, parse_ok = self._pack_ed(reqs, m)
         return tuple(self._dev(a) for a in arrays), parse_ok
 
-    def _ed_dispatch(self, args, m: int, use_pallas: bool):
-        """Async-dispatch one prepared Ed25519 batch; (m,) int32 handle."""
-        if use_pallas:
-            return self._pk._ed25519_split_jit(*args, m).reshape(-1)
-        Aw, xa, xw, yw, Rw, signR2, sw, kw = args
-        return EJ.verify_full_split_words_kernel(
-            Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
-
     def verify_ed25519_batch(self, reqs):
         if not reqs:
             return []
         n = len(reqs)
-        m = self._pad(n)
-        args, parse_ok = self._prep_ed(reqs, m)
-        use, ok = self._pick(
-            ("ed", m),
-            lambda: np.asarray(self._ed_dispatch(args, m, True)),
-            lambda: np.asarray(self._ed_dispatch(args, m, False)))
-        if ok is None:
-            ok = np.asarray(self._ed_dispatch(args, m, use))
+        args, parse_ok = self._prep_ed(reqs, self._pad(n))
+        Aw, xa, xw, yw, Rw, signR2, sw, kw = args
+        ok = np.asarray(EJ.verify_full_split_words_kernel(
+            Aw, xa, xw, yw, Rw, signR2[0], sw, kw))
         return [bool(o) and bool(p)
                 for o, p in zip(ok[:n], parse_ok[:n])]
 
@@ -457,72 +396,26 @@ class JaxBackend(CryptoBackend):
                self._dev(rw), self._dev(cw), self._dev(sw))
         return dev, (parse_ok & known, gamma_ok, s_ok, pf_arr)
 
-    def _vrf_dispatch(self, dev, m: int, use_pallas: bool):
-        from . import vrf_jax
-        if use_pallas:
-            return self._pk._vrf_verify_jit(*dev, m)
-        Yw, xa, Gw, signG2, rw, cw, sw = dev
-        return vrf_jax.vrf_verify_words_kernel(Yw, xa, Gw,
-                                               signG2[0], rw, cw, sw)
-
-    def _vrf_fold_dispatch(self, dev, gamma_b, c_b, valid, m: int,
-                           use_pallas: bool):
-        """Verify + on-device challenge fold: (m,) uint8 verdicts.  The
-        (m, 130) point rows never leave the device — 1 B/proof crosses
-        the link instead of 130 B."""
-        from . import vrf_jax
-        if use_pallas:
-            fn = self._pk_vrf_folds.get(m)
-            if fn is None:
-                import jax
-                import jax.numpy as jnp
-                PK = self._pk
-
-                def call(Yw, xa, Gw, signG2, rw, cw, sw, gb, cb, va,
-                         _m=m):
-                    rows = PK._vrf_verify_call(Yw, xa, Gw, signG2, rw,
-                                               cw, sw, _m)
-                    ok = vrf_jax.challenge_ok_device(rows, gb, cb)
-                    return (ok & (va != 0)).astype(jnp.uint8)
-                fn = self._pk_vrf_folds[m] = jax.jit(call)
-            return fn(*dev, gamma_b, c_b, valid)
-        Yw, xa, Gw, signG2, rw, cw, sw = dev
-        return vrf_jax.vrf_verify_fold_words_kernel(
-            Yw, xa, Gw, signG2[0], rw, cw, sw, gamma_b, c_b, valid)
-
     def verify_vrf_batch(self, reqs):
+        """Verify + on-device challenge fold: the (m, 130) point rows
+        never leave the device — 1 B/proof crosses the link instead of
+        130 B."""
         if not reqs:
             return []
+        from . import vrf_jax
         n = len(reqs)
-        m = self._pad(n)
-        dev, (parse_ok, gamma_ok, s_ok, pf_arr) = self._prep_vrf(reqs, m)
-        gamma_b = self._dev(np.ascontiguousarray(pf_arr[:, :32]))
-        c_b = self._dev(np.ascontiguousarray(pf_arr[:, 32:48]))
-        valid = self._dev(parse_ok.astype(np.uint8))
-        # own key: this measures the verify+challenge-fold program pair,
-        # a different program than the ("vrf", m) rows form the window
-        # composite fuses — sharing the key would pin a choice measured
-        # on the wrong program for whichever path ran second
-        use, ok = self._pick(
-            ("vrff", m),
-            lambda: np.asarray(self._vrf_fold_dispatch(
-                dev, gamma_b, c_b, valid, m, True)),
-            lambda: np.asarray(self._vrf_fold_dispatch(
-                dev, gamma_b, c_b, valid, m, False)))
-        if ok is None:
-            ok = np.asarray(self._vrf_fold_dispatch(dev, gamma_b, c_b,
-                                                    valid, m, use))
+        dev, (parse_ok, _gamma_ok, _s_ok, pf_arr) = self._prep_vrf(
+            reqs, self._pad(n))
+        Yw, xa, Gw, signG2, rw, cw, sw = dev
+        ok = np.asarray(vrf_jax.vrf_verify_fold_words_kernel(
+            Yw, xa, Gw, signG2[0], rw, cw, sw,
+            self._dev(np.ascontiguousarray(pf_arr[:, :32])),
+            self._dev(np.ascontiguousarray(pf_arr[:, 32:48])),
+            self._dev(parse_ok.astype(np.uint8))))
         return [bool(o) for o in ok[:n]]
 
     # largest single gamma8 dispatch: bounds the set of compiled shapes
-    # (a fresh pallas shape costs minutes through the AOT helper)
     BETA_CHUNK = 2048
-
-    def _beta_dispatch(self, Gw, signG2, m: int, use_pallas: bool):
-        from . import vrf_jax
-        if use_pallas:
-            return self._pk._gamma8_jit(Gw, signG2, m)
-        return vrf_jax.gamma8_words_kernel(Gw, signG2[0])
 
     def vrf_betas_batch(self, proofs):
         from . import vrf_jax
@@ -538,14 +431,8 @@ class JaxBackend(CryptoBackend):
         m = self._pad(n)
         padded = list(proofs) + [b"\x00" * 80] * (m - n)
         (Gw, signG), decode_ok = vrf_jax._prepare_betas_words(padded)
-        Gwd = self._dev(Gw)
-        signG2 = self._dev(signG.reshape(1, -1))
-        use, rows = self._pick(
-            ("beta", m),
-            lambda: np.asarray(self._beta_dispatch(Gwd, signG2, m, True)),
-            lambda: np.asarray(self._beta_dispatch(Gwd, signG2, m, False)))
-        if rows is None:
-            rows = np.asarray(self._beta_dispatch(Gwd, signG2, m, use))
+        rows = vrf_jax.gamma8_words_kernel(
+            self._dev(Gw), self._dev(signG.reshape(1, -1))[0])
         return vrf_jax._finish_betas(np.asarray(rows), decode_ok, n)
 
     # -- mixed windows -------------------------------------------------------
@@ -637,21 +524,15 @@ class JaxBackend(CryptoBackend):
         ew = _pad_words(B2.digest_words(exps), m)
         return self._dev(mw), self._dev(ew)
 
-    def _kes_dispatch(self, mw, ew, m: int, use_pallas: bool):
-        if use_pallas:
-            return self._pk._kes_hash_jit(mw, ew, m).reshape(-1)
-        return B2.check_block64_jit(mw, ew)
-
-    def _ed_tile_program(self, pallas: bool, fold: bool):
+    def _ed_tile_program(self, fold: bool):
         """THE Ed25519 program of the window path: verify one tile of
         `ed_tile` lanes.  A window of T tiles is T asynchronous calls of
         it, so the tile COUNT is no program's shape: a replay builds
         this once whatever widths its windows have, and the device walks
-        only the tiles it is handed.  The XLA form is
+        only the tiles it is handed.  It is
         `verify_full_split_words_core` at the width the power-of-two
-        buckets top out at; the Pallas form its own grid over the same
-        tile.  (What T launches cost the producer: span submit.ed_tiles;
-        PERF.md section 6, PR 38.)
+        buckets top out at.  (What T launches cost the producer: span
+        submit.ed_tiles; PERF.md section 6, PR 38.)
 
         fold=True: `(first_bad, own, Aw, xa, xw, yw, Rw, signR2, sw, kw)
         -> first_bad`, the tile's verdicts folded into the running
@@ -661,28 +542,23 @@ class JaxBackend(CryptoBackend):
         before returned.  fold=False: `(Aw, ..., kw) -> (tile,) uint8`
         verdicts.  The inputs are donated: fresh every window, never
         read again."""
-        fn = self._ed_tile_programs.get((pallas, fold))
+        fn = self._ed_tile_programs.get(fold)
         if fn is not None:
             return fn
         import jax
-        return self._keep_ed_tile_program(pallas, fold, jax.jit(
-            self._ed_tile_body(pallas, fold),
+        return self._keep_ed_tile_program(fold, jax.jit(
+            self._ed_tile_body(fold),
             donate_argnums=self._ed_tile_donated(fold)))
 
-    def _ed_tile_body(self, pallas: bool, fold: bool, across=None):
+    def _ed_tile_body(self, fold: bool, across=None):
         """The tile program before `jit`: what ONE device does with its
         `ed_tile` lanes.  `across` names the mesh axis the shards'
         first-bad indexes meet over (the mesh backend's, under
         shard_map)."""
         import jax
         import jax.numpy as jnp
-        tile = self.ed_tile
-        PK = getattr(self, "_pk", None)
 
         def verify(Aw, xa, xw, yw, Rw, signR2, sw, kw):
-            if pallas:
-                return PK._ed25519_split_call(
-                    Aw, xa, xw, yw, Rw, signR2, sw, kw, tile).reshape(-1)
             return EJ.verify_full_split_words_core(
                 Aw, xa, xw, yw, Rw, signR2[0], sw, kw)
 
@@ -702,13 +578,13 @@ class JaxBackend(CryptoBackend):
         read again."""
         return tuple(range(10 if fold else 8)) if self._donate else ()
 
-    def _keep_ed_tile_program(self, pallas: bool, fold: bool, fn):
+    def _keep_ed_tile_program(self, fold: bool, fn):
         fn = _compile_span_on_first_call(
             fn, f"window.ed_tile({self.ed_tile},fold={int(fold)})")
-        self._ed_tile_programs[(pallas, fold)] = fn
+        self._ed_tile_programs[fold] = fn
         return fn
 
-    def _window_composite(self, nv: int, nb: int, nk: int, pallas: bool):
+    def _window_composite(self, nv: int, nb: int, nk: int):
         """One jitted device program for the parts of a window whose
         widths the protocol fixes: VRF verify + next-window gamma8 betas
         + KES hash checks, results concatenated into the packed flat
@@ -716,14 +592,8 @@ class JaxBackend(CryptoBackend):
         (The Ed25519 lanes, whose count follows the bodies, are not in
         it: `_ed_tile_program`.  Every composite SHAPE is its own
         compile of a minute or more, so the three widths are few by
-        construction: `_occasional_widths`.)
-
-        The program is HOMOGENEOUS (all ladder parts pallas or all XLA):
-        mixing an op-by-op XLA ladder into a pallas composite made XLA's
-        compile of the combined program pathological (>1h at replay
-        shapes, vs minutes for either pure form), and only the chosen
-        form is ever compiled."""
-        key = (nv, nb, nk, pallas)
+        construction: `_occasional_widths`.)"""
+        key = (nv, nb, nk)
         fn = self._composites.get(key)
         if fn is not None:
             return fn
@@ -731,30 +601,20 @@ class JaxBackend(CryptoBackend):
         import jax.numpy as jnp
 
         from . import vrf_jax
-        PK = getattr(self, "_pk", None)
 
         def call(vrf_args, beta_args, kes_args):
             parts = []
             if vrf_args is not None:
-                if pallas:
-                    rows = PK._vrf_verify_call(*vrf_args, nv)
-                else:
-                    Yw, xa, Gw, sG2, rw, cw, sw = vrf_args
-                    rows = vrf_jax.vrf_verify_words_core(
-                        Yw, xa, Gw, sG2[0], rw, cw, sw)
+                Yw, xa, Gw, sG2, rw, cw, sw = vrf_args
+                rows = vrf_jax.vrf_verify_words_core(
+                    Yw, xa, Gw, sG2[0], rw, cw, sw)
                 parts.append(rows.reshape(-1))
             if beta_args is not None:
-                if pallas:
-                    rows = PK._gamma8_call(*beta_args, nb)
-                else:
-                    bGw, bsG2 = beta_args
-                    rows = vrf_jax.gamma8_words_core(bGw, bsG2[0])
+                bGw, bsG2 = beta_args
+                rows = vrf_jax.gamma8_words_core(bGw, bsG2[0])
                 parts.append(rows.reshape(-1))
             if kes_args is not None:
-                if pallas:
-                    ok = PK._kes_hash_call(*kes_args, nk)
-                else:
-                    ok = B2.check_block64(*kes_args)
+                ok = B2.check_block64(*kes_args)
                 parts.append(ok.reshape(-1).astype(jnp.uint8))
             return parts[0] if len(parts) == 1 else jnp.concatenate(parts)
 
@@ -804,7 +664,7 @@ class JaxBackend(CryptoBackend):
         if not (nv or nb or nk):
             return 0, 0, 0
         best = None
-        for v, b, k, _pallas in self._composites:
+        for v, b, k in self._composites:
             if v >= nv and b >= nb and k >= nk \
                     and (best is None or v + b + k < sum(best)):
                 best = (v, b, k)
@@ -832,8 +692,8 @@ class JaxBackend(CryptoBackend):
         boolean vector, after ONE transfer.  A window without a
         composite needs no second program: the tile calls' index IS its
         verdict, and finish_window reads that scalar.  The composite is
-        SHARED between both modes (same program, same autotuned choice,
-        same compile); without fold the tiles run the bucket program of
+        SHARED between both modes (same program, same compile); without
+        fold the tiles run the bucket program of
         their width and finish_window fetches their verdicts.
 
         `reqs` is the window's stream of items as the sequential pass
@@ -846,8 +706,8 @@ class JaxBackend(CryptoBackend):
         included), submit.pack_vrf (beta words included),
         submit.pack_kes, folding submit.fold (the lanes' owner rows,
         which the tile calls read, and their copy to the device), and
-        submit.dispatch (the choice, submit.ed_tiles = the T tile calls,
-        the composite call and, folding, the fold program's)."""
+        submit.dispatch (submit.ed_tiles = the T tile calls, the
+        composite call and, folding, the fold program's)."""
         with _spans.span("window.submit", cat="dispatch", cpu=True):
             return self._submit_window(reqs, next_beta_proofs, fold)
 
@@ -909,14 +769,10 @@ class JaxBackend(CryptoBackend):
                 ed_own, vrf_own = self._fold_owners(state)
                 own_tiles = self._dev_tiles((ed_own.reshape(1, -1),), ne)
         with _spans.span("submit.dispatch", cat="dispatch"):
-            if tiles or nv or nb or nk:
-                allp = self._window_choice(
-                    nv, nb, nk, tiles[0] if tiles else None,
-                    vrf_args, beta_args, kes_args)
             if tiles:
                 self._note_ed_tiles(len(ed_reqs), ne)
                 _ED_ROW_LANES.inc(row_lanes)
-                run = self._ed_tile_program(allp, fold)
+                run = self._ed_tile_program(fold)
                 with _spans.span("submit.ed_tiles", cat="dispatch"):
                     if fold:
                         bad = self._dev_scalar(FOLD_SENT)
@@ -927,7 +783,7 @@ class JaxBackend(CryptoBackend):
                         state["ed_ok"] = [run(*tile) for tile in tiles]
             if nv or nb or nk:
                 state["packed"] = self._window_composite(
-                    nv, nb, nk, allp)(vrf_args, beta_args, kes_args)
+                    nv, nb, nk)(vrf_args, beta_args, kes_args)
                 if fold:
                     self._attach_fold(state, vrf_own)
             else:
@@ -1038,67 +894,6 @@ class JaxBackend(CryptoBackend):
             fn, f"window.fold({nv},{nb},{nk})")
         self._folds[key] = fn
         return fn
-
-    def _window_choice(self, nv, nb, nk, ed_tile_args, vrf_args,
-                       beta_args, kes_args) -> bool:
-        """Homogeneous pallas-vs-XLA choice for one window shape (the
-        tile program's form with the composite's).
-
-        A pinned ("win", ...) choice (persisted by an earlier run, or
-        voted earlier in this one) returns with ZERO extra dispatches —
-        the warm path never re-measures, so once a benchmark's warmup
-        phase has seen every window shape, its timed reps cannot retune.
-        First sighting under autotune measures each present component
-        through the fenced tuner (keys shared with the simple-batch
-        paths; the Ed25519 part on the window's first tile), votes, and
-        pins the vote persistently."""
-        win_key = ("win", nv, nb, nk)
-        if not self.autotune:
-            self._static_choice[win_key] = self.use_pallas
-            return self.use_pallas
-        allp = self._tuner.get(win_key)
-        if allp is not None:
-            return allp
-        use_ed = use_vrf = use_beta = use_kes = False
-        if ed_tile_args is not None:
-            te = self.ed_tile * self.n_shards
-            use_ed, _ = self._pick(
-                ("ed", te),
-                lambda: np.asarray(self._ed_dispatch(ed_tile_args, te,
-                                                     True)),
-                lambda: np.asarray(self._ed_dispatch(ed_tile_args, te,
-                                                     False)))
-        if vrf_args is not None:
-            use_vrf, _ = self._pick(
-                ("vrf", nv),
-                lambda: np.asarray(self._vrf_dispatch(vrf_args, nv,
-                                                      True)),
-                lambda: np.asarray(self._vrf_dispatch(vrf_args, nv,
-                                                      False)))
-        if beta_args is not None:
-            use_beta, _ = self._pick(
-                ("beta", nb),
-                lambda: np.asarray(self._beta_dispatch(*beta_args, nb,
-                                                       True)),
-                lambda: np.asarray(self._beta_dispatch(*beta_args, nb,
-                                                       False)))
-        if kes_args is not None:
-            use_kes, _ = self._pick(
-                ("kesh", nk),
-                lambda: np.asarray(self._kes_dispatch(*kes_args, nk,
-                                                      True)),
-                lambda: np.asarray(self._kes_dispatch(*kes_args, nk,
-                                                      False)))
-        # all-pallas unless every present LADDER component measured XLA
-        # faster (see _window_composite on why no mixing); the kes hash
-        # kernel is too small to swing the vote
-        pallas_votes = [v for v, present in
-                        ((use_ed, ed_tile_args is not None),
-                         (use_vrf, vrf_args is not None),
-                         (use_beta, beta_args is not None)) if present]
-        allp = any(pallas_votes) if pallas_votes else use_kes
-        self._tuner.put_derived(win_key, allp)
-        return allp
 
     def finish_window(self, state):
         """Block on a submit_window dispatch; returns (ok list aligned

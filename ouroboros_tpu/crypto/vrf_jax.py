@@ -88,12 +88,6 @@ def _triple_ladder_idx(P1, P1p, P2, idx_rows):
     return Q[0], Q[1], Q[2]
 
 
-def _triple_ladder_128(P1, P1p, P2, lo_bits, hi_bits, c_bits):
-    """Bit-rows compatibility wrapper around _triple_ladder_idx."""
-    return _triple_ladder_idx(P1, P1p, P2,
-                              lo_bits + 2 * hi_bits + 4 * c_bits)
-
-
 def _select(mask, a, b):
     return jnp.where(mask[None, :], a, b)
 
@@ -179,61 +173,6 @@ def compress_device(x_aff, y_aff):
     w = (1 << jnp.arange(8, dtype=jnp.int32))[None, :, None]
     byts = jnp.sum(bits.reshape(32, 8, -1) * w, axis=1)   # (32, N)
     return byts.at[31].add(sign << 7)
-
-
-def vrf_verify_idx_core(yY, signY, yG, signG, r, idx_rows):
-    """Full device half of batched VRF verification.
-
-    idx_rows: (128, N) int32 joint digits lo + 2·hi + 4·c (MSB-first).
-    Returns an (N, 130) uint8 array per item:
-      [0:32]   compressed H        [32:64]  compressed U
-      [64:96]  compressed V        [96:128] compressed [8]Gamma
-      [128]    okY  [129]  okG
-    """
-    n = yY.shape[1]
-    one = F.one_like(yY)
-    xY, okY = EJ.device_decompress(yY, signY)
-    xG, okG = EJ.device_decompress(yG, signG)
-    H = _double3(elligator2_fraction(r))             # cofactor clearing
-    G8 = _double3((xG, yG, one, F.mul(xG, yG)))      # for beta
-    # ladder halves, split-scalar form (s = hi*2^128 + lo, c < 2^128):
-    #   U = [lo]B + [hi]B' + [c](-Y)     with B' = [2^128]B (constant)
-    #   V = [lo]H + [hi]H' + [c](-Gamma) with H' = [2^128]H (128 doubles)
-    nYx = F.sub(yY * 0, xY)
-    nGx = F.sub(yG * 0, xG)
-    B = (F.const_batch(_GX, n), F.const_batch(_GY, n), one,
-         F.const_batch(_GX * _GY % ed.P, n))
-    Bp = (F.const_batch(_G2X, n), F.const_batch(_G2Y, n), one,
-          F.const_batch(_G2X * _G2Y % ed.P, n))
-    Hp = _double_n(H, 128)
-    negY = (nYx, yY, one, F.mul(nYx, yY))
-    negG = (nGx, yG, one, F.mul(nGx, yG))
-    P1 = tuple(jnp.concatenate([B[c], H[c]], axis=1) for c in range(4))
-    P1p = tuple(jnp.concatenate([Bp[c], Hp[c]], axis=1) for c in range(4))
-    P2 = tuple(jnp.concatenate([negY[c], negG[c]], axis=1)
-               for c in range(4))
-    idx2 = jnp.concatenate([idx_rows, idx_rows], axis=1)
-    UV = _triple_ladder_idx(P1, P1p, P2, idx2)
-    # one inversion chain for every Z: [H | U | V | G8]
-    Zall = jnp.concatenate([H[2], UV[2], G8[2]], axis=1)      # (NLIMBS, 4n)
-    Zi = EJ.pow_inv(Zall)
-    Xall = jnp.concatenate([H[0], UV[0], G8[0]], axis=1)
-    Yall = jnp.concatenate([H[1], UV[1], G8[1]], axis=1)
-    comp = compress_device(F.mul(Xall, Zi), F.mul(Yall, Zi))  # (32, 4n)
-    rows = jnp.concatenate([comp[:, :n], comp[:, n:2 * n],
-                            comp[:, 2 * n:3 * n], comp[:, 3 * n:],
-                            okY.astype(jnp.int32)[None, :],
-                            okG.astype(jnp.int32)[None, :]], axis=0)
-    return rows.T.astype(jnp.uint8)                  # (n, 130)
-
-
-def vrf_verify_core(yY, signY, yG, signG, r, c_bits, s_lo_bits, s_hi_bits):
-    """Bit-rows compatibility form (parallel/sharded_verify wraps this)."""
-    return vrf_verify_idx_core(yY, signY, yG, signG, r,
-                               s_lo_bits + 2 * s_hi_bits + 4 * c_bits)
-
-
-vrf_verify_kernel = jax.jit(vrf_verify_core)
 
 
 def vrf_verify_idx_xy_core(yY, xY, yG, signG, r, idx_rows):
@@ -386,56 +325,6 @@ def _prepare_betas_words(proofs):
 # Host orchestration
 # ---------------------------------------------------------------------------
 
-def _bits128_from_le(rows: np.ndarray) -> np.ndarray:
-    """(N, 16) little-endian scalar bytes -> (128, N) MSB-first int32
-    bits (one 128-bit ladder half)."""
-    bits = np.flip(np.unpackbits(rows, axis=1, bitorder="little"), axis=1)
-    return np.ascontiguousarray(bits.T).astype(np.int32)
-
-
-def _r_limbs(vks, alphas) -> np.ndarray:
-    """Elligator2 inputs: r = SHA512(suite || 0x01 || vk || alpha)[:32] with
-    the top bit masked (vrf_ref._hash_to_curve:25-27)."""
-    rows = bytearray()
-    for vk, alpha in zip(vks, alphas):
-        rows += hashlib.sha512(SUITE + b"\x01" + vk + alpha).digest()[:32]
-    arr = np.frombuffer(bytes(rows), dtype=np.uint8).reshape(len(vks), 32)
-    arr = arr.copy()
-    arr[:, 31] &= 0x7F
-    limbs, _sign, _ok = EJ._decode_compressed(arr)
-    return limbs
-
-
-def _default_runner(Yw, xYw, Gw, signG, rw, cw, sw):
-    return vrf_verify_words_kernel(
-        jnp.asarray(Yw), jnp.asarray(xYw), jnp.asarray(Gw),
-        jnp.asarray(signG), jnp.asarray(rw), jnp.asarray(cw),
-        jnp.asarray(sw))
-
-
-def _prepare(vks, alphas, proofs):
-    """Host-side parse of one padded batch into kernel inputs.
-
-    Returns (kernel_args, parse_ok, gamma_ok, s_ok, pf_arr); kernel_args
-    is the 8-tuple the verify kernels take (limbs + sign vectors + bit
-    rows), so callers can dispatch it themselves (e.g. fused into one
-    per-window device program)."""
-    vk_arr, vk_ok = EJ._bytes_rows(vks, 32)
-    pf_arr, pf_ok = EJ._bytes_rows(proofs, PROOF_LEN)
-    yY, signY, okYc = EJ._decode_compressed(vk_arr)
-    yG, signG, okGc = EJ._decode_compressed(pf_arr[:, :32])
-    s_rows = np.ascontiguousarray(pf_arr[:, 48:80])
-    s_ok = EJ._scalar_lt_L(s_rows)
-    gamma_ok = pf_ok & okGc
-    parse_ok = vk_ok & okYc & gamma_ok & s_ok
-    args = (yY, signY.astype(np.int32), yG, signG.astype(np.int32),
-            _r_limbs(vks, alphas),
-            _bits128_from_le(np.ascontiguousarray(pf_arr[:, 32:48])),  # c
-            _bits128_from_le(np.ascontiguousarray(s_rows[:, :16])),    # lo
-            _bits128_from_le(np.ascontiguousarray(s_rows[:, 16:])))    # hi
-    return args, parse_ok, gamma_ok, s_ok, pf_arr
-
-
 def _r_rows(vks, alphas) -> np.ndarray:
     """Elligator2 input byte rows: r = SHA512(suite || 0x01 || vk ||
     alpha)[:32] with the top bit masked (vrf_ref._hash_to_curve:25-27)."""
@@ -449,11 +338,11 @@ def _r_rows(vks, alphas) -> np.ndarray:
 
 
 def _prepare_words(vks, alphas, proofs):
-    """Packed-words host prep (the transfer-thin analog of _prepare).
+    """Packed-words host prep.
 
     Returns (kernel_args, parse_ok, gamma_ok, s_ok, pf_arr) with
     kernel_args = (Yw, signY, Gw, signG, rw, cw, sw) — uint32 word rows
-    for vrf_verify_words_kernel / the pallas packed kernel."""
+    for vrf_verify_words_kernel."""
     vk_arr, vk_ok = EJ._bytes_rows(vks, 32)
     pf_arr, pf_ok = EJ._bytes_rows(proofs, PROOF_LEN)
     signY = (vk_arr[:, 31] >> 7).astype(np.int32)
@@ -478,11 +367,9 @@ def _prepare_words(vks, alphas, proofs):
     return args, parse_ok, gamma_ok, s_ok, pf_arr
 
 
-def _submit(vks, alphas, proofs, m, runner=None):
+def _submit(vks, alphas, proofs, m):
     """Parse + dispatch one padded batch; returns (device handle, masks,
-    proof rows).  Does not block — callers may pipeline.  `runner` swaps
-    the kernel invocation (packed-words signature: Yw, xYw, Gw, signG,
-    rw, cw, sw — e.g. pallas_kernels.vrf_verify_pallas).  Y's affine x
+    proof rows).  Does not block — callers may pipeline.  Y's affine x
     is resolved through the global point cache; unknown/bad keys fold
     into parse_ok."""
     from .precompute import GLOBAL_PRECOMPUTE_CACHE
@@ -490,7 +377,8 @@ def _submit(vks, alphas, proofs, m, runner=None):
                                                             proofs)
     Yw, _signY, Gw, signG, rw, cw, sw = args
     xa, _x128, _y128, known = GLOBAL_PRECOMPUTE_CACHE.assemble(list(vks))
-    handle = (runner or _default_runner)(Yw, xa, Gw, signG, rw, cw, sw)
+    handle = vrf_verify_words_kernel(
+        *(jnp.asarray(a) for a in (Yw, xa, Gw, signG, rw, cw, sw)))
     return handle, parse_ok & known, gamma_ok, s_ok, pf_arr
 
 
@@ -546,17 +434,6 @@ def _prepare_betas(proofs):
     return (yG, signG.astype(np.int32)), pf_ok & okGc & s_ok
 
 
-def _submit_betas(proofs, m, runner=None):
-    """Parse + dispatch a gamma8 batch; returns (handle, decode_ok).
-    `runner` takes the packed-words pair (Gw, signG)."""
-    (Gw, signG), decode_ok = _prepare_betas_words(proofs)
-    if runner is None:
-        handle = gamma8_words_kernel(jnp.asarray(Gw), jnp.asarray(signG))
-    else:
-        handle = runner(Gw, signG)
-    return handle, decode_ok
-
-
 def _finish_betas(rows: np.ndarray, decode_ok, n: int) -> list:
     ok = rows[:, 32].astype(bool) & decode_ok
     return [hashlib.sha512(SUITE + b"\x03" + rows[j, :32].tobytes()).digest()
@@ -572,5 +449,6 @@ def batch_betas(proofs, pad_to: int | None = None) -> list:
         return []
     m = pad_to if pad_to and pad_to >= n else n
     proofs = list(proofs) + [b"\x00" * PROOF_LEN] * (m - n)
-    handle, decode_ok = _submit_betas(proofs, m)
+    (Gw, signG), decode_ok = _prepare_betas_words(proofs)
+    handle = gamma8_words_kernel(jnp.asarray(Gw), jnp.asarray(signG))
     return _finish_betas(np.asarray(handle), decode_ok, n)

@@ -3,9 +3,9 @@
 Three parts, one seam (ISSUE 7):
 
 - `metrics`: a process-wide registry of named counters/gauges/histograms
-  with deterministic sorted snapshots.  The precompute cache stats, the
-  autotuner's decision/frozen-write counters, subscription reconnects,
-  watchdog firings and mux teardowns all live here.
+  with deterministic sorted snapshots.  The precompute cache stats,
+  subscription reconnects, watchdog firings and mux teardowns all live
+  here.
 - `spans`: hierarchical timing spans with explicit block_until_ready
   fencing, splitting every replay window into host-seq / dispatch /
   device / compile / sync phases.  Monotonic-clock only, sim-time aware
@@ -33,8 +33,8 @@ read clocks; the benchmark and tests enable them around regions they
 study).
 Both layers are near-free when off — `spans.span()` returns a shared
 null context manager, a gated metric write is a single flag read — and
-`enable()/disable()` flip them together.  The migrated precompute/
-autotune counters are `always=True`: they are load-bearing program
+`enable()/disable()` flip them together.  The precompute counters are
+`always=True`: they are load-bearing program
 state (tests assert on them) that the registry exports, not
 observation that the flag may drop.
 """

@@ -3,9 +3,9 @@ deterministic snapshots.
 
 The reference threads a contravariant `Tracer m a` through every
 constructor but ships no metrics layer; our reproduction had outgrown
-its ad-hoc equivalents (private counters in crypto/precompute.py and
-crypto/autotune.py, one-off breakdowns printed by scripts).  This
-module is the one seam they all migrate into.
+its ad-hoc equivalents (private counters in crypto/precompute.py,
+one-off breakdowns printed by scripts).  This module is the one seam
+they all migrate into.
 
 Design constraints, in order:
 
@@ -22,9 +22,9 @@ Design constraints, in order:
    are created with `stable=False` and excluded from `snapshot()`
    (they still appear in the Prometheus exposition, which is allowed to
    vary run to run).
-3. **Functional counters stay functional.**  The migrated precompute /
-   autotune counters are *load-bearing* — tests gate on them (warm
-   windows do zero fills; frozen tuners reject writes).  Those are created with `always=True`: they count whether or
+3. **Functional counters stay functional.**  The precompute counters
+   are *load-bearing* — tests gate on them (warm windows do zero
+   fills).  Those are created with `always=True`: they count whether or
    not observation is enabled, and their writes are not charged to
    `data_writes` (they are program state that happens to be exported,
    not observation).
@@ -287,7 +287,7 @@ class MetricsRegistry:
         self.data_writes = 0
 
 
-# the process-wide registry: crypto caches, the autotuner, network
+# the process-wide registry: crypto caches, network
 # counters and the span layer all bind into this one
 REGISTRY = MetricsRegistry()
 

@@ -24,9 +24,8 @@ tests, the IO runtime's monotonic offset in production).  No wall-clock
 steps, and sim tests must see exact virtual durations.
 
 Fencing: a span created with `fence=True` drains the async dispatch
-queue (`jax.block_until_ready` on a dummy transfer — the same fence the
-autotuner uses) at BOTH edges, so the measured interval covers
-exactly the work dispatched inside it and inherits nothing in flight.
+queue (`jax.block_until_ready` on a dummy transfer) at BOTH edges, so
+the measured interval covers exactly the work dispatched inside it and inherits nothing in flight.
 The fence is skipped when jax was never imported — host-only flows must
 not pull in the device stack just by timing themselves.
 
@@ -174,12 +173,14 @@ def monotonic_now() -> float:
 
 
 def device_fence() -> None:
-    """Drain the async dispatch queue.  No-op unless jax is already
-    imported (a fenced span in a host-only process must not load it)."""
+    """Drain the async dispatch queue, so a timed interval never
+    inherits the previous dispatch's in-flight device work.  No-op
+    unless jax is already imported (a fenced span in a host-only process
+    must not load it)."""
     if "jax" not in sys.modules:
         return
-    from ..crypto.autotune import _fence
-    _fence()
+    import jax
+    jax.block_until_ready(jax.device_put(0.0))
 
 
 class Span:

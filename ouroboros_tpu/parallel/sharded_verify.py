@@ -82,8 +82,7 @@ class ShardedJaxBackend(JaxBackend):
     7, "Also open")."""
 
     def __init__(self, mesh: Mesh, min_bucket: int = 128):
-        super().__init__(min_bucket=min_bucket, use_pallas=False,
-                         autotune=False)
+        super().__init__(min_bucket=min_bucket)
         self.mesh = mesh
         self.name = f"jax-mesh-{mesh.devices.size}"
         # report the devices of the MESH, not whatever jax.devices()
@@ -183,26 +182,26 @@ class ShardedJaxBackend(JaxBackend):
     # submit_window / finish_window / verify_mixed / the fold=True path
     # are inherited from JaxBackend; only the composite is mesh-built.
 
-    def _ed_tile_program(self, pallas: bool, fold: bool):
+    def _ed_tile_program(self, fold: bool):
         """The one-chip tile program's body under shard_map: each shard
         verifies its `ed_tile` lanes of the tile; folding, the shards'
         first-bad indexes meet in one `pmin` and the running index stays
         replicated."""
-        fn = self._ed_tile_programs.get((False, fold))
+        fn = self._ed_tile_programs.get(fold)
         if fn is not None:
             return fn
         mesh = self.mesh
         axis = mesh.axis_names[0]
         s2 = P(None, axis)
-        body = self._ed_tile_body(False, fold, across=axis)
+        body = self._ed_tile_body(fold, across=axis)
         mapped = jax.shard_map(
             body, mesh=mesh, in_specs=(P(),) + (s2,) * 9,
             out_specs=P()) if fold else jax.shard_map(
             body, mesh=mesh, in_specs=(s2,) * 8, out_specs=P(axis))
-        return self._keep_ed_tile_program(False, fold, jax.jit(
+        return self._keep_ed_tile_program(fold, jax.jit(
             mapped, donate_argnums=self._ed_tile_donated(fold)))
 
-    def _window_composite(self, nv: int, nb: int, nk: int, pallas: bool):
+    def _window_composite(self, nv: int, nb: int, nk: int):
         """One jitted mesh program per (VRF, beta) shape: shard_map of
         the SAME packed-words component cores the single-device
         composite fuses, each shard running the identical per-shard
@@ -214,7 +213,7 @@ class ShardedJaxBackend(JaxBackend):
         the compile-budget fix: XLA compiles one shard-sized program +
         the SPMD partitioning, not an N-lane super-program."""
         assert nk == 0, "mesh windows reduce KES on host"
-        key = (nv, nb, 0, False)
+        key = (nv, nb, 0)
         fn = self._composites.get(key)
         if fn is not None:
             return fn

@@ -3,26 +3,24 @@ on-chip-measurement guide): the programs of ONE 1024-block replay window
 of chip_smoke.py's chain, at their real widths, compiled for a described TPU
 v5e by the compiler installed here.
 
-What interpret mode cannot show, this does: a block not aligned to the
-(8, 128) int32 tiling, a kernel over the fast-memory limit, a program
-the partitioner refuses.  Nothing runs, so it says nothing about results
-or times on the chip — `chip_smoke.py` does that.
+What a CPU run cannot show, this does: a program that does not fit the
+device's memory, or one the partitioner refuses.  Nothing runs, so it
+says nothing about results or times on the chip — `chip_smoke.py` does
+that.
 
-The Pallas kernels are compiled as the chip runs them: `interpret=False`
-and the "columns" multiply.  `pallas_kernels._interpret()` reads
-`jax.devices()`, which is the CPU here, so the module fixture steers it;
-the program has no option for this.  The persistent compilation cache is
-off around these tests: a compile for a described device is written to
-it but cannot be read back without a chip.
+The persistent compilation cache is off around these tests: a compile
+for a described device is written to it but cannot be read back without
+a chip.
 
 The topology is described inside a module-scoped fixture, never at
 import: only one process may load libtpu, and under xdist every worker
 imports every test file.  Keep these tests in this ONE file.
 
-Tier-1 keeps the cases that together take about three minutes here (KES
-hash, gamma8, the Ed25519 split ladder, the fold, the per-key fill).
-The VRF kernel (~250 s alone), the XLA forms, the whole composites are
-`slow`; their seconds are in CHANGES.md (PR 22).
+Tier-1 keeps the programs every window of every cell calls (the
+Ed25519 tile program in both fold forms, the fold, the per-key fill)
+and the cores of the Ed25519 ladder, gamma8 and the KES hash.  The VRF
+core and the whole composites are `slow`; their seconds are in
+CHANGES.md (PR 22).
 """
 import numpy as np
 import pytest
@@ -35,7 +33,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, \
 from ouroboros_tpu.crypto import blake2b_jax as B2
 from ouroboros_tpu.crypto import ed25519_jax as EJ
 from ouroboros_tpu.crypto import jax_backend as JB
-from ouroboros_tpu.crypto import pallas_kernels as PK
 from ouroboros_tpu.crypto import vrf_jax as VJ
 
 # minutes of compile off the chip: conftest.py starts this file first
@@ -66,13 +63,10 @@ def topo():
     except Exception as e:
         mp.undo()
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    # compile what the chip runs, not the CPU interpreter's form
-    mp.setattr(PK, "_interpret", lambda: False)
     # lower the jitted program itself, not the span wrapper around it
     mp.setattr(JB, "_compile_span_on_first_call", lambda fn, name: fn)
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
-    assert PK._mul_form() == "columns"
     yield t
     jax.config.update("jax_enable_compilation_cache", True)
     compilation_cache.reset_cache()
@@ -112,36 +106,6 @@ def _kes_args(n, s):
     return (S((16, n), U32), S((8, n), U32))
 
 
-# Every jitted callable below is a FRESH lambda: jit caches traces by
-# function identity, and a trace with interpret=False baked in must not
-# be found again by a CPU test in the same worker.
-
-PALLAS = {
-    "kes_hash": (lambda *a: PK._kes_hash_call(*a, NK),
-                 lambda s: _kes_args(NK, s)),
-    "kes_hash_all_new": (lambda *a: PK._kes_hash_call(*a, NK_ALL_NEW),
-                         lambda s: _kes_args(NK_ALL_NEW, s)),
-    "gamma8": (lambda *a: PK._gamma8_call(*a, NB),
-               lambda s: _beta_args(NB, s)),
-    "ed25519_split": (lambda *a: PK._ed25519_split_call(*a, NE),
-                      lambda s: _ed_args(NE, s)),
-    "vrf_verify": (lambda *a: PK._vrf_verify_call(*a, NV),
-                   lambda s: _vrf_args(NV, s)),
-}
-
-
-@pytest.mark.parametrize("kernel", [
-    "kes_hash", "kes_hash_all_new", "gamma8", "ed25519_split",
-    pytest.param("vrf_verify", marks=pytest.mark.slow)])
-def test_pallas_kernel_compiles_for_v5e(kernel, one_chip):
-    """The Mosaic form of each window part, at the window's lane count:
-    accepted by the chip's compiler, and really a Mosaic kernel."""
-    fn, make_args = PALLAS[kernel]
-    compiled = jax.jit(lambda *a: fn(*a)).lower(
-        *make_args(one_chip)).compile()
-    assert "tpu_custom_call" in compiled.as_text()
-
-
 XLA = {
     "ed25519_split": (
         lambda Aw, xa, xw, yw, Rw, sR, sw, kw:
@@ -156,31 +120,33 @@ XLA = {
                lambda s: _beta_args(NB, s)),
     "kes_hash": (lambda mw, ew: B2.check_block64(mw, ew),
                  lambda s: _kes_args(NK, s)),
+    "kes_hash_all_new": (lambda mw, ew: B2.check_block64(mw, ew),
+                         lambda s: _kes_args(NK_ALL_NEW, s)),
 }
 
 
 @pytest.mark.parametrize("kernel", [
-    pytest.param("ed25519_split", marks=pytest.mark.slow),
+    "ed25519_split",
     pytest.param("vrf_verify", marks=pytest.mark.slow),
-    pytest.param("gamma8", marks=pytest.mark.slow),
-    "kes_hash"])
+    "gamma8",
+    "kes_hash",
+    "kes_hash_all_new"])
 def test_xla_form_compiles_for_v5e(kernel, one_chip):
-    """The op-by-op XLA form of each part: what the autotuner also
-    compiles on the chip the first time it sees a window shape."""
+    """The core of each window part, at the window's lane count: the
+    one form there is (no custom call in it)."""
     fn, make_args = XLA[kernel]
     compiled = jax.jit(lambda *a: fn(*a)).lower(
         *make_args(one_chip)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
 
 
-def _backend(pallas: bool) -> JB.JaxBackend:
-    be = JB.JaxBackend(use_pallas=pallas, autotune=False)
+def _backend() -> JB.JaxBackend:
+    be = JB.JaxBackend()
     be._donate = True       # as on the chip (off on the CPU it sees here)
     be.ed_tile = JB.ED_TILE   # likewise: the CPU's tile is `min_bucket`
     return be
 
 
-@pytest.mark.slow
 @pytest.mark.parametrize("fold", [True, False])
 def test_ed_tile_program_compiles_for_v5e(fold, one_chip):
     """THE Ed25519 program of the window path, at the chip's tile: a
@@ -188,7 +154,7 @@ def test_ed_tile_program_compiles_for_v5e(fold, one_chip):
     so its temporaries are a tile's: the flat program of 131,072 lanes
     asks the compiler for 4 GB."""
     S = _spec(one_chip)
-    run = _backend(False)._ed_tile_program(False, fold)
+    run = _backend()._ed_tile_program(fold)
     carry = (S((), I32), S((1, JB.ED_TILE), I32)) if fold else ()
     compiled = run.lower(*carry, *_ed_args(JB.ED_TILE, one_chip)).compile()
     assert "tpu_custom_call" not in compiled.as_text()
@@ -202,7 +168,7 @@ def test_fold_program_compiles_for_v5e(nb, nk, one_chip):
     first-bad index, for both shapes a replay meets:
     windows that carry betas and the last two that do not."""
     S = _spec(one_chip)
-    fold = _backend(False)._fold_program(NV, nb, nk)
+    fold = _backend()._fold_program(NV, nb, nk)
     fold.lower(
         S((130 * NV + 33 * nb + nk,), U8), S((), I32),
         S((NV,), I32), S((NV, 32), U8), S((NV, 16), U8)).compile()
@@ -224,18 +190,14 @@ def test_key_fill_compiles_for_v5e(width, one_chip):
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("pallas", [True, False])
-def test_window_composite_compiles_for_v5e(pallas, one_chip):
-    """One whole fused window composite, homogeneous form: what ONE new
-    window shape costs a cold start (jax_backend._window_composite
-    records that a MIXED composite took over an hour; the homogeneous
-    ones take minutes)."""
-    comp = _backend(pallas)._window_composite(NV, NB, NK, pallas)
+def test_window_composite_compiles_for_v5e(one_chip):
+    """One whole fused window composite: what ONE new window shape
+    costs a cold start."""
+    comp = _backend()._window_composite(NV, NB, NK)
     compiled = comp.lower(
         _vrf_args(NV, one_chip), _beta_args(NB, one_chip),
         _kes_args(NK, one_chip)).compile()
-    assert compiled.as_text().count("tpu_custom_call") == \
-        (3 if pallas else 0)
+    assert "tpu_custom_call" not in compiled.as_text()
 
 
 @pytest.mark.slow
@@ -249,14 +211,14 @@ def test_sharded_composite_compiles_for_four_v5e(topo):
     lanes = NamedSharding(mesh, P(None, WINDOW_AXIS))
     sb = ShardedJaxBackend(mesh)
     assert sb._donate and sb.device_count == 4 and sb.platform == "tpu"
-    comp = sb._window_composite(NV, NB, 0, False)
+    comp = sb._window_composite(NV, NB, 0)
     compiled = comp.lower(
         _vrf_args(NV, lanes), _beta_args(NB, lanes), None).compile()
     per_dev = compiled.memory_analysis()
     assert per_dev.argument_size_in_bytes < 2 ** 30
     assert sb.ed_tile == JB.ED_TILE
     S = _spec(lanes)
-    tile = sb._ed_tile_program(False, True).lower(
+    tile = sb._ed_tile_program(True).lower(
         _spec(NamedSharding(mesh, P()))((), I32),
         S((1, 4 * JB.ED_TILE), I32),
         *_ed_args(4 * JB.ED_TILE, lanes)).compile()

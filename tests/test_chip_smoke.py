@@ -17,7 +17,6 @@ import pytest
 import jax
 
 from ouroboros_tpu import compile_cache
-from ouroboros_tpu.crypto import autotune as autotune_mod
 from ouroboros_tpu.crypto import backend as backend_mod
 from ouroboros_tpu.crypto import jax_backend as JB
 from ouroboros_tpu.crypto.batching import BreakEvenTable
@@ -37,8 +36,8 @@ def _run(*argv, env=None, timeout=300):
 
 def test_cache_dir_follows_the_env_var_and_sets_nothing(monkeypatch,
                                                         tmp_path):
-    """Variable set: that directory, for the choice file and the
-    break-even table too; JAX's own setting is left to JAX and the
+    """Variable set: that directory, for the break-even table too;
+    JAX's own setting is left to JAX and the
     environment is not written."""
     d = str(tmp_path / "placed" / "cache")
     monkeypatch.setenv(compile_cache.ENV_VAR, d)
@@ -46,12 +45,10 @@ def test_cache_dir_follows_the_env_var_and_sets_nothing(monkeypatch,
     assert compile_cache.cache_dir() == d and os.path.isdir(d)
     assert jax.config.jax_compilation_cache_dir == before
     assert os.environ[compile_cache.ENV_VAR] == d
-    monkeypatch.setattr(autotune_mod, "_TUNERS", {})
-    tuner = autotune_mod.tuner_for("test kind/22")
-    assert os.path.dirname(tuner.path) == d
-    tuner._store_choice(("ed", 512), True)
-    assert os.listdir(d) == [os.path.basename(tuner.path)]
-    assert os.path.dirname(BreakEvenTable.path_for("test kind/22")) == d
+    table = BreakEvenTable({}, "test kind/22")
+    assert os.path.dirname(table.path_for("test kind/22")) == d
+    saved = table.save()
+    assert os.listdir(d) == [os.path.basename(saved)]
 
 
 def test_cache_dir_defaults_to_one_fixed_path_in_the_checkout(monkeypatch):
@@ -64,8 +61,6 @@ def test_cache_dir_defaults_to_one_fixed_path_in_the_checkout(monkeypatch):
     assert compile_cache.cache_dir() == d           # same answer again
     assert jax.config.jax_compilation_cache_dir == d
     assert compile_cache.ENV_VAR not in os.environ
-    monkeypatch.setattr(autotune_mod, "_TUNERS", {})
-    assert os.path.dirname(autotune_mod.tuner_for("k").path) == d
     assert os.path.dirname(BreakEvenTable.path_for("k")) == d
     with open(os.path.join(REPO, ".gitignore")) as f:
         assert ".jax_cache/" in f.read().split()
@@ -73,7 +68,7 @@ def test_cache_dir_defaults_to_one_fixed_path_in_the_checkout(monkeypatch):
 
 def test_nothing_in_the_tree_assigns_the_cache_variable():
     """Code never writes JAX_COMPILATION_CACHE_DIR, and no compile-cache
-    or autotune path is built from the temp dir."""
+    path is built from the temp dir."""
     offenders = []
     for root, dirs, files in os.walk(REPO):
         dirs[:] = [d for d in dirs
@@ -93,22 +88,6 @@ def test_nothing_in_the_tree_assigns_the_cache_variable():
                     if "jax-ouro-cache" in code:
                         offenders.append(f"{path}:{n}")
     assert not offenders, offenders
-
-
-def test_autotuner_tolerates_no_file_and_nothing_else(tmp_path):
-    """A choice file that cannot be written or read raises: silently
-    carrying on would re-measure (and re-compile both forms of) every
-    shape in every process."""
-    missing = autotune_mod.Autotuner(str(tmp_path / "none.json"), "dev")
-    assert missing.get(("ed", 512)) is None         # no file yet: fine
-    unwritable = autotune_mod.Autotuner(
-        str(tmp_path / "no-such-dir" / "tune.json"), "dev")
-    with pytest.raises(OSError):
-        unwritable._store_choice(("ed", 512), True)
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    with pytest.raises(ValueError):
-        autotune_mod.Autotuner(str(bad), "dev")
 
 
 # ---------------------------------------------------------------------------
@@ -133,10 +112,10 @@ def test_default_backend_raises_when_the_accelerator_backend_fails(
     """JAX reports an accelerator and JaxBackend() fails: the error
     propagates instead of a quiet OpenSSL/pure-Python replay."""
     def boom(*a, **kw):
-        raise RuntimeError("mosaic refused the kernel")
+        raise RuntimeError("the compiler refused the program")
     monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu()])
     monkeypatch.setattr(JB, "JaxBackend", boom)
-    with pytest.raises(RuntimeError, match="mosaic refused"):
+    with pytest.raises(RuntimeError, match="compiler refused"):
         backend_mod.default_backend()
 
 

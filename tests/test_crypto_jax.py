@@ -158,39 +158,6 @@ def test_jax_backend_vrf_and_kes():
         == [True] * 5 + [False]
 
 
-@pytest.mark.slow
-def test_vrf_batch_autotunes_under_its_own_key(monkeypatch):
-    """ISSUE 11 satellite (the r04->r05 VRF primitive regression):
-    verify_vrf_batch measures/pins under its OWN ("vrff", m) autotune
-    key — the fold-form verify+challenge program pair — never the
-    ("vrf", m) rows-form key the window composite pins.  r05 shared the
-    key, inheriting a choice measured on the wrong program for
-    whichever path ran second (fixed in r06; this pins the fix).
-    slow (ISSUE 15 budget rebalance): the shape-provider it used to
-    piggyback on (test_jax_backend_vrf_and_kes) moved to the slow lane
-    in ISSUE 14, leaving this test paying its own ~45s fold-program
-    trace in tier-1; the vrf fold path itself stays tier-1-gated by
-    test_served_replay.py::test_vrf_batch_fold_form_equals_reference
-    and ::test_fold_verdict_names_the_first_bad_request."""
-    from ouroboros_tpu.crypto import vrf_ref
-    from ouroboros_tpu.crypto.backend import VrfReq
-    from ouroboros_tpu.crypto.jax_backend import JaxBackend
-    jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
-    keys = []
-    orig = JaxBackend._pick
-
-    def spy(self, key, run_pallas, run_xla):
-        keys.append(key)
-        return orig(self, key, run_pallas, run_xla)
-    monkeypatch.setattr(JaxBackend, "_pick", spy)
-    vsk = hashlib.sha256(b"vrff-key").digest()
-    vvk = vrf_ref.public_key(vsk)
-    reqs = [VrfReq(vvk, b"a%d" % i, vrf_ref.prove(vsk, b"a%d" % i))
-            for i in range(8)]
-    assert jb.verify_vrf_batch(reqs) == [True] * 8
-    assert keys == [("vrff", 16)]
-
-
 # slow: ~35s tracing this test's own vrf batch shape; beta correctness
 # is tier-1-gated through test_served_replay.py::
 # test_device_replay_state_hash_equals_reference (betas feed the nonce

@@ -189,7 +189,7 @@ def test_no_program_is_built_for_a_tile_count(runs):
     a window of Ed25519 lanes alone has no composite and no fold
     program: the tile calls' running index is its verdict."""
     tile_programs, composites, folds = runs.tiled_programs
-    assert tile_programs == [(False, False), (False, True)]
+    assert tile_programs == [False, True]
     assert composites == [] and folds == []
     assert runs.all_windows["jax_backend.composite_builds"] == 0
 

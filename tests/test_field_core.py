@@ -1,5 +1,5 @@
 """Fast-partition coverage of the GF(2^255-19) limb core (field_jax) —
-both multiplication forms, canonicalisation and helpers, checked against
+multiplication, canonicalisation and helpers, checked against
 Python big-int arithmetic.  Tiny batches of plain jnp ops: milliseconds
 on CPU, so the DEFAULT gate always exercises the arithmetic the ladder
 kernels are built from (the full ladders live in the device partition)."""
@@ -28,22 +28,18 @@ A = _vals(N)
 B = list(reversed(_vals(N)))
 
 
-class TestMulForms:
-    @pytest.mark.parametrize("form", ["shifted", "columns"])
-    def test_mul_matches_bigint(self, form):
-        with F.mul_impl(form):
-            got = F.unpack(np.asarray(F.mul(jnp.asarray(F.pack(A)),
-                                            jnp.asarray(F.pack(B)))))
+class TestMulForms:  # one form since PR 44; the name keeps the ids
+    def test_mul_matches_bigint(self):
+        got = F.unpack(np.asarray(F.mul(jnp.asarray(F.pack(A)),
+                                        jnp.asarray(F.pack(B)))))
         assert got == [a * b % P for a, b in zip(A, B)]
 
-    @pytest.mark.parametrize("form", ["shifted", "columns"])
-    def test_mul_chain_stays_in_bounds(self, form):
+    def test_mul_chain_stays_in_bounds(self):
         """Repeated products keep limbs inside the carry3 invariant."""
-        with F.mul_impl(form):
-            x = jnp.asarray(F.pack(A))
-            for _ in range(5):
-                x = F.mul(x, x)
-            arr = np.asarray(x)
+        x = jnp.asarray(F.pack(A))
+        for _ in range(5):
+            x = F.mul(x, x)
+        arr = np.asarray(x)
         assert int(arr.max()) < (1 << 14), int(arr.max())
         want = A
         for _ in range(5):

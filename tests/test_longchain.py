@@ -165,7 +165,7 @@ def test_one_composite_serves_all_eight_windows(longchain):
     (nv, nb, nk), = longchain["shapes"]
     assert nb >= 2 * WINDOW and nk >= 2 * 6 and nv
     # and the one folding tile program, and one fold
-    assert longchain["tile_programs"] == [(False, True)]
+    assert longchain["tile_programs"] == [True]
     assert longchain["folds"] == [(nv, nb, nk)]
 
 

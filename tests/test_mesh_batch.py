@@ -117,7 +117,7 @@ def test_sharded_submit_window_pipelines(piped, reference_backend):
     assert set(piped["betas"]) == set(piped["next_proofs"])
     for p, b in piped["betas"].items():
         assert b == vrf_ref.proof_to_hash(p)
-    assert piped["composites"] == [(16, 16, 0, False)]
+    assert piped["composites"] == [(16, 16, 0)]
 
 
 def test_sharded_backend_mixed_window_parity(piped, mesh_backend,

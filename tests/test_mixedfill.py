@@ -372,7 +372,7 @@ def test_programs_are_those_of_a_chain_of_equal_windows(mixedfill):
     assert equal["compile_spans"] == 3
     assert equal["moved"]["jax_backend.ed_width_changes"] == 0
     tile_programs, composites, folds = mixedfill["equal_chain_programs"]
-    assert tile_programs == [(False, True)]
+    assert tile_programs == [True]
     assert len(composites) == len(folds) == 1
     assert mixedfill["programs"] == mixedfill["equal_chain_programs"]
 
@@ -472,8 +472,8 @@ def test_a_replay_cut_at_an_invalid_header_builds_no_program(mixedfill):
 def test_a_window_rides_the_narrowest_built_composite_that_holds_it(
         need, rides):
     jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
-    jb._composites = {(16, 16, 16, False): None, (64, 0, 0, False): None,
-                      (128, 128, 128, False): None}
+    jb._composites = {(16, 16, 16): None, (64, 0, 0): None,
+                      (128, 128, 128): None}
     assert jb._occasional_widths(*need) == rides
 
 
@@ -555,4 +555,4 @@ def test_folded_first_bad_index_is_the_references(lanes, width, planted):
 
 
 def test_six_widths_ran_one_tile_program_in_its_two_forms(lanes):
-    assert lanes["programs"] == ([(False, False), (False, True)], [], [])
+    assert lanes["programs"] == ([False, True], [], [])

@@ -1,8 +1,7 @@
-"""Cross-window precomputation cache (crypto/precompute.py) + persistent
-fenced autotuner (crypto/autotune.py).
+"""Cross-window precomputation cache (crypto/precompute.py).
 
 Host-only partition: LRU/eviction semantics (with a stubbed device
-fill), the KES hash-path outcome namespace, tuner persistence/freezing.
+fill), the KES hash-path outcome namespace.
 Device partition: cold-vs-warm parity for every primitive through the
 real XLA kernels (a cache-warm window does ZERO per-key fill dispatches
 and gives identical verdicts/betas).
@@ -13,9 +12,6 @@ import numpy as np
 import pytest
 
 from ouroboros_tpu.crypto import ed25519_ref, kes, vrf_ref
-from ouroboros_tpu.crypto.autotune import (
-    Autotuner, FrozenAutotunerError,
-)
 from ouroboros_tpu.crypto.backend import (
     CpuRefBackend, Ed25519Req, KesReq, VrfReq,
 )
@@ -217,56 +213,6 @@ def test_split_mixed_cached_warm_path_skips_host_hashing():
     assert c.kes_len() == 2 and c.misses == misses
     # the oracle agrees with the leaf reduction
     assert ed25519_ref.verify(eds[0].vk, b"m1", eds[0].sig)
-
-
-# ---------------------------------------------------------------------------
-# host partition: autotuner
-# ---------------------------------------------------------------------------
-
-def test_autotuner_persistence_round_trip(tmp_path):
-    path = str(tmp_path / "tune.json")
-    t = Autotuner(path, "test-dev")
-    t._store_choice(("ed", 4096), True, (1.0, 2.0))
-    t._store_choice(("win", 16, 16, 0, 32), False)
-    t2 = Autotuner(path, "test-dev")
-    assert t2.get(("ed", 4096)) is True
-    assert t2.get(("win", 16, 16, 0, 32)) is False
-    assert t2.get(("vrf", 2048)) is None
-    # stable ordering: two runs report byte-identical kernel_choices
-    assert list(t2.choices_snapshot()) == sorted(t2.choices_snapshot())
-    t2.invalidate()
-    assert Autotuner(path, "test-dev").get(("ed", 4096)) is None
-
-
-def test_autotuner_freeze_blocks_stores(tmp_path):
-    t = Autotuner(str(tmp_path / "tune.json"), "test-dev")
-    t._store_choice(("ed", 128), True)
-    t.freeze()
-    assert t.get(("ed", 128)) is True      # reads stay fine
-    with pytest.raises(FrozenAutotunerError):
-        t._store_choice(("vrf", 128), False)
-    with pytest.raises(FrozenAutotunerError):
-        t.measure(("vrf", 128), lambda: None, lambda: None)
-    # an unchanged derived vote is a no-op, not a violation
-    t.put_derived(("ed", 128), True)
-    with pytest.raises(FrozenAutotunerError):
-        t.put_derived(("ed", 128), False)
-    assert t.writes_while_frozen == 3
-    t.thaw()
-    t._store_choice(("vrf", 128), False)
-    assert t.get(("vrf", 128)) is False
-
-
-def test_backend_pick_uses_pinned_choice_without_dispatch(tmp_path):
-    jax = pytest.importorskip("jax")  # noqa: F841
-    from ouroboros_tpu.crypto.jax_backend import JaxBackend
-    jb = JaxBackend(use_pallas=False, autotune=False)
-    # static path records choices for reporting, runners never called
-    def boom():
-        raise AssertionError("runner dispatched for a pinned choice")
-    use, out = jb._pick(("ed", 128), boom, boom)
-    assert use is False and out is None
-    assert jb.kernel_choices == {("ed", 128): False}
 
 
 # ---------------------------------------------------------------------------

@@ -90,7 +90,7 @@ class HostProgramsBackend(JaxBackend):
     """JaxBackend with its three device programs (Ed25519 tile, window
     composite, fold) computed on the host by the OpenSSL reference.
     `submit_window`, `_submit_window`, the split, the three packers, the
-    tiles' copy, the choice, `_fold_owners` and `_attach_fold` are the
+    tiles' copy, `_fold_owners` and `_attach_fold` are the
     real ones; so is `finish_window`."""
 
     def __init__(self):
@@ -103,10 +103,10 @@ class HostProgramsBackend(JaxBackend):
         self._asked = (list(reqs), list(dict.fromkeys(next_beta_proofs)))
         return super().submit_window(reqs, next_beta_proofs, fold)
 
-    def _ed_tile_program(self, pallas, fold):
+    def _ed_tile_program(self, fold):
         return lambda bad, _own, *_lanes: bad
 
-    def _window_composite(self, nv, nb, nk, pallas):
+    def _window_composite(self, nv, nb, nk):
         return lambda *_args: self._asked
 
     def _fold_program(self, nv, nb, nk):

@@ -249,7 +249,7 @@ def served(tmp_path_factory):
     started, finished, alive = _producers()
     out.stream_threads = (prefetcher_threads_alive() + alive
                           + (started - finished))
-    out.kernel_choices = jb.kernel_choices
+    out.composites = sorted(jb._composites)
     return out
 
 
@@ -277,9 +277,8 @@ def test_every_step_ran_the_one_composite_shape(served):
     """The replay, both mixed batches, the unobserved batch and the
     streamed replay: 16 VRF lanes, no betas, 32 KES jobs (the 16
     Ed25519 lanes are one call of the tile program and no part of the
-    key).  A second `win` key is minutes of XLA:CPU compile."""
-    assert [k for k in served.kernel_choices if k[0] == "win"] \
-        == [("win", 16, 0, 32)]
+    key).  A second composite is minutes of XLA:CPU compile."""
+    assert served.composites == [(16, 0, 32)]
 
 
 def test_producer_ran_and_is_gone(served):

@@ -363,8 +363,7 @@ def _folding(run):
 def _stand_in_backend(parts) -> JaxBackend:
     jb = JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
     honest = _honest_lanes(parts)
-    jb._ed_tile_programs.update({(False, False): honest,
-                                 (False, True): _folding(honest)})
+    jb._ed_tile_programs.update({False: honest, True: _folding(honest)})
     return jb
 
 

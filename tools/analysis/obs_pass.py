@@ -54,8 +54,8 @@ payload does work must therefore sit under a `tracer.active` guard:
 
 Cheap payloads (names, constants, attribute reads, plain tuples of
 those) pass OBS001: a tuple build of locals is two bytecode ops, the
-guard would cost as much as it saves.  Cold-path sites (an autotune
-measurement that runs once per shape per process) are tolerated via
+guard would cost as much as it saves.  Cold-path sites (a measurement
+that runs once per shape per process) are tolerated via
 justified baseline entries, the same contract as every other pass.
 """
 from __future__ import annotations
