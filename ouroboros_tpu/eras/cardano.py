@@ -31,8 +31,9 @@ from .byron import (
     byron_genesis_setup, byron_transition_epoch,
 )
 from .shelley import (
-    SHELLEY_TX_BODY_ELEMS, ShelleyLedger, ShelleyLedgerState, ShelleyTx,
-    TPraos, TPraosConfig, TPraosState, shelley_genesis_setup,
+    SHELLEY_TX_BODY_ELEMS, PersistentMap, ShelleyLedger, ShelleyLedgerState,
+    ShelleyTx, TPraos, TPraosConfig, TPraosState, UtxoMap,
+    shelley_genesis_setup,
 )
 
 BYRON, SHELLEY, ALLEGRA, MARY = 0, 1, 2, 3
@@ -54,10 +55,9 @@ def translate_ledger_byron_to_shelley(shelley_ledger: ShelleyLedger):
     cfg = shelley_ledger.config
 
     def translate(b: ByronLedgerState) -> ShelleyLedgerState:
-        from .shelley import UtxoMap
         utxo = UtxoMap.from_items((t, i, a, m, ()) for t, i, a, m in b.utxo)
-        delegs = tuple(sorted(shelley_ledger.initial_delegs.items()))
-        pools = tuple(sorted(shelley_ledger.initial_pools.items()))
+        delegs = PersistentMap.from_dict(shelley_ledger.initial_delegs)
+        pools = PersistentMap.from_dict(shelley_ledger.initial_pools)
         snap = ShelleyLedger._stake_distr(utxo, delegs, pools)
         # the combinator ticked the Byron ledger to the boundary slot (the
         # first slot of the Shelley era)

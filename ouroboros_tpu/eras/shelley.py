@@ -42,6 +42,7 @@ from collections import _tuplegetter     # the C getter namedtuple fields use
 from dataclasses import dataclass, replace
 from fractions import Fraction
 from functools import lru_cache
+from time import perf_counter_ns
 from typing import Any, Optional, Sequence
 
 from ..chain.block import Point, point_of
@@ -589,8 +590,8 @@ class ShelleyLedgerState:
     pool-retirement queue — the NEWEPOCH state surface of
     Shelley/Ledger/Ledger.hs:238-284's `applyBlock` rules."""
     utxo: Any                # UtxoMap: (txid, ix) -> (addr, amount, assets)
-    delegs: tuple                      # sorted ((addr, pool_id), ...)
-    pools: tuple                       # sorted ((pool_id, vrf_vk), ...)
+    delegs: Any                        # PersistentMap: addr -> pool_id
+    pools: Any                         # PersistentMap: pool_id -> vrf_vk
     epoch: int
     snap_mark: tuple                   # ((pool_id, stake, vrf_vk), ...)
     snap_set: tuple                    # snapshot used for leader election
@@ -599,6 +600,8 @@ class ShelleyLedgerState:
     snap_go: tuple = ()                # snapshot rewards are computed from
     reserves: int = 0                  # undistributed coin (shrinks by rho)
     treasury: int = 0
+    # these three stay sorted tuples: one entry a pool, and nothing but an
+    # epoch boundary, a withdrawal or a retirement grows or changes them
     rewards: tuple = ()                # sorted ((pool_id, claimable), ...)
     retiring: tuple = ()               # sorted ((pool_id, epoch), ...)
     blocks_made: tuple = ()            # sorted ((pool_id, n)) this epoch
@@ -608,6 +611,11 @@ class ShelleyLedgerState:
             # decoders/tests build states from plain 5-tuple sequences
             object.__setattr__(self, "utxo",
                                UtxoMap.from_items(self.utxo))
+        # ... and the two maps from dicts or sequences of pairs
+        for name in ("delegs", "pools"):
+            if not isinstance(getattr(self, name), PersistentMap):
+                object.__setattr__(self, name, PersistentMap.from_dict(
+                    getattr(self, name)))
 
     def utxo_dict(self) -> dict:
         return self.utxo.to_dict()
@@ -636,14 +644,18 @@ class ShelleyLedgerState:
         return _b2b(enc)
 
 
-class UtxoMap:
-    """Persistent UTxO set: immutable view over a shared base dict plus an
+class PersistentMap:
+    """Persistent map: an immutable view over a shared base dict plus an
     overlay (adds + deletes), so extending the chain by one block is
-    O(inputs + outputs) instead of O(|UTxO|) — the tuple-freeze
-    representation made a mainnet-scale replay quadratic.
+    O(what the block changed) instead of O(|map|).  The UTxO set
+    (`UtxoMap`, below), the delegation map and the pool registry are all
+    this one type: on sorted tuples each of them made a replay quadratic
+    in its size as soon as a chain grew it (the UTxO at mainnet scale;
+    the delegation map on a chain whose holders delegate, 20 ms a block
+    at 54,000 entries).
 
     The unit of change is the BLOCK: `thaw()` hands the ledger walk one
-    private copy of the overlay, the walk spends from and adds to it for
+    private copy of the overlay, the walk deletes from and adds to it for
     every transaction of the block, and `freeze()` makes the block's one
     new map from it.  There the overlay is flattened into a fresh base
     once it has passed ~|base|/4 entries, keeping lookup chains one level
@@ -651,13 +663,16 @@ class UtxoMap:
     ExtLedgerState) stay valid: a base is never mutated in place, and an
     overlay is not touched again once a map holds it.
 
-    The contract is `get` / `in` / `len`, `to_dict()` and iteration.  How
-    the entries are split over `_base` / `_adds` / `_dels` at a given
-    block is not: it depends on where the flatten rule was applied.
+    The contract is `get` / `in` / `len`, `to_dict()`, equality and
+    iteration.  How the entries are split over `_base` / `_adds` /
+    `_dels` at a given block is not: it depends on where the flatten rule
+    was applied.  No value is None.
 
-    Iteration yields sorted (txid, ix, addr, amount, assets) 5-tuples —
-    the exact order of the old sorted-tuple representation, so
-    state_hash()es are unchanged."""
+    Iteration yields (key, value) pairs sorted by key, the order of the
+    sorted-tuple representation it replaces, so `dict(m)` and a
+    state_hash() that encodes the pairs in turn are what they were; it
+    sorts, so it is for a hash, a snapshot or an epoch boundary and not
+    for a block's walk."""
 
     __slots__ = ("_base", "_adds", "_dels")
 
@@ -667,13 +682,9 @@ class UtxoMap:
         self._dels = dels
 
     @classmethod
-    def from_dict(cls, d: dict) -> "UtxoMap":
+    def from_dict(cls, d) -> "PersistentMap":
+        """A map of a dict's entries, or of a sequence of pairs."""
         return cls(dict(d), {}, frozenset())
-
-    @classmethod
-    def from_items(cls, items) -> "UtxoMap":
-        return cls({(t, i): (a, m, assets)
-                    for t, i, a, m, assets in items}, {}, frozenset())
 
     def get(self, key, default=None):
         v = self._adds.get(key)
@@ -697,15 +708,20 @@ class UtxoMap:
             return True
         return key not in self._dels and key in self._base
 
-    def to_dict(self) -> dict:
-        d = {k: v for k, v in self._base.items() if k not in self._dels}
-        d.update(self._adds)
+    @staticmethod
+    def _merged(base: dict, adds: dict, dels) -> dict:
+        """A new dict of the live entries."""
+        if not dels:
+            return {**base, **adds}
+        d = {k: v for k, v in base.items() if k not in dels}
+        d.update(adds)
         return d
 
+    def to_dict(self) -> dict:
+        return self._merged(self._base, self._adds, self._dels)
+
     def __iter__(self):
-        return iter(sorted((t, i, a, m, assets)
-                           for (t, i), (a, m, assets)
-                           in self.to_dict().items()))
+        return iter(sorted(self.to_dict().items()))
 
     def __len__(self) -> int:
         # adds that shadow a live base entry are overwrites, not new keys
@@ -715,7 +731,7 @@ class UtxoMap:
                 - sum(1 for k in self._dels if k in self._base))
 
     def __eq__(self, other) -> bool:
-        if isinstance(other, UtxoMap):
+        if isinstance(other, PersistentMap):
             return self.to_dict() == other.to_dict()
         return NotImplemented
 
@@ -723,22 +739,57 @@ class UtxoMap:
 
     def thaw(self) -> tuple[dict, dict, set]:
         """(base, adds, dels) for one block's walk: the base as it is,
-        shared and read-only, and a private copy of the overlay.  An
-        outpoint is live if it is in `adds`, or in `base` and not in
-        `dels`.  To spend one: `adds.pop(k, None); dels.add(k)` — ALWAYS
-        record the delete: popping only the overlay entry would resurrect
-        a stale base entry if the same outpoint was deleted, re-created,
-        and spent again.  To add one: `adds[k] = v; dels.discard(k)`."""
+        shared and read-only, and a private copy of the overlay.  A key
+        is live if it is in `adds`, or in `base` and not in `dels`.  To
+        delete one: `adds.pop(k, None); dels.add(k)` — ALWAYS record the
+        delete: popping only the overlay entry would resurrect a stale
+        base entry if the same key was deleted, re-created, and deleted
+        again.  To set one: `adds[k] = v; dels.discard(k)`.  A walk that
+        touches a map many times a block does that inline (the UTxO's);
+        one that touches it a few times calls `thawed_holds` and
+        `thawed_set`."""
         return self._base, dict(self._adds), set(self._dels)
 
     @classmethod
-    def freeze(cls, base: dict, adds: dict, dels: set) -> "UtxoMap":
+    def freeze(cls, base: dict, adds: dict, dels: set) -> "PersistentMap":
         """The map a block's walk ends in — O(delta) amortized."""
         if len(adds) + len(dels) > max(64, len(base) // 4):
-            flat = {k: v for k, v in base.items() if k not in dels}
-            flat.update(adds)
-            return cls(flat, {}, frozenset())
+            return cls(cls._merged(base, adds, dels), {}, frozenset())
         return cls(base, adds, frozenset(dels))
+
+
+def thawed_holds(thawed: tuple, key) -> bool:
+    """Is `key` live in a `PersistentMap.thaw()`?"""
+    base, adds, dels = thawed
+    return key in adds or (key not in dels and key in base)
+
+
+def thawed_set(thawed: tuple, key, value) -> None:
+    """`key` set to `value` in a `PersistentMap.thaw()`."""
+    _base, adds, dels = thawed
+    adds[key] = value
+    dels.discard(key)
+
+
+class UtxoMap(PersistentMap):
+    """The UTxO set: (txid, ix) -> (addr, amount, assets), read and
+    written a block at a time as every `PersistentMap`.
+
+    Iteration yields sorted (txid, ix, addr, amount, assets) 5-tuples —
+    the exact order of the old sorted-tuple representation, so
+    state_hash()es are unchanged."""
+
+    __slots__ = ()
+
+    @classmethod
+    def from_items(cls, items) -> "UtxoMap":
+        return cls({(t, i): (a, m, assets)
+                    for t, i, a, m, assets in items}, {}, frozenset())
+
+    def __iter__(self):
+        return iter(sorted((t, i, a, m, assets)
+                           for (t, i), (a, m, assets)
+                           in self.to_dict().items()))
 
 
 def _freeze_utxo(utxo: dict) -> UtxoMap:
@@ -755,6 +806,19 @@ _MARY = SHELLEY_FAMILY.index("mary")
 # guard of the walk fell through; each added to once a block
 _TXS = _metrics.counter("ledger.shelley.txs")
 _LIGHT_TXS = _metrics.counter("ledger.shelley.light_txs")
+# what the guarded half of the walk met: transactions with a certificate,
+# certificates by kind, those that gave the delegation map a key it did
+# not hold, and whole microseconds from a block's first thaw of the
+# delegation map and the pool registry to their freeze (one clock pair a
+# block that carries a certificate); the witnesses a body's transactions
+# hold, counted where they become the block's Ed25519 lanes.  Each added
+# to once a block from local integers
+_CERT_TXS = _metrics.counter("ledger.shelley.cert_txs")
+_CERTS_DELEG = _metrics.counter("ledger.shelley.certs.deleg")
+_CERTS_POOL = _metrics.counter("ledger.shelley.certs.pool")
+_DELEG_NEW = _metrics.counter("ledger.shelley.deleg_new_entries")
+_CERT_US = _metrics.counter("ledger.shelley.cert_us", stable=False)
+_WITNESSES = _metrics.counter("ledger.shelley.witnesses")
 
 
 class ShelleyLedger(LedgerRules):
@@ -813,23 +877,24 @@ class ShelleyLedger(LedgerRules):
                 for ix, (addr, amount) in enumerate(
                     sorted(self.genesis.items()))}
         utxo_f = _freeze_utxo(utxo)
-        delegs = tuple(sorted(self.initial_delegs.items()))
-        pools = tuple(sorted(self.initial_pools.items()))
+        delegs = PersistentMap.from_dict(self.initial_delegs)
+        pools = PersistentMap.from_dict(self.initial_pools)
         snap = self._stake_distr(utxo_f, delegs, pools)
         return ShelleyLedgerState(utxo_f, delegs, pools, 0, snap, snap,
                                   -1, Point.genesis(), snap_go=snap,
                                   reserves=self.initial_reserves)
 
     @staticmethod
-    def _stake_distr(utxo: "UtxoMap", delegs: tuple, pools: tuple) -> tuple:
+    def _stake_distr(utxo: "UtxoMap", delegs: PersistentMap,
+                     pools: PersistentMap) -> tuple:
         """Aggregate UTxO lovelace per pool through the delegation map
         (native assets carry no stake)."""
         by_addr: dict = {}
         for addr, amount, _assets in utxo.to_dict().values():
             by_addr[addr] = by_addr.get(addr, 0) + amount
-        registered = dict(pools)
+        registered = pools.to_dict()
         by_pool: dict = {}
-        for addr, pid in delegs:
+        for addr, pid in delegs.to_dict().items():
             if pid in registered:
                 by_pool[pid] = by_pool.get(pid, 0) + by_addr.get(addr, 0)
         return tuple(sorted((pid, stake, registered[pid])
@@ -884,9 +949,14 @@ class ShelleyLedger(LedgerRules):
             #    leave the registry; their delegations lapse; accrued
             #    rewards stay claimable
             due = {p for p, e in state.retiring if e <= nxt}
-            pools = tuple((p, v) for p, v in state.pools if p not in due)
-            delegs = (tuple((a, p) for a, p in state.delegs
-                            if p not in due) if due else state.delegs)
+            pools, delegs = state.pools, state.delegs
+            if due:
+                pools = PersistentMap.from_dict(
+                    {p: v for p, v in pools.to_dict().items()
+                     if p not in due})
+                delegs = PersistentMap.from_dict(
+                    {a: p for a, p in delegs.to_dict().items()
+                     if p not in due})
             state = replace(
                 state, epoch=nxt, snap_go=state.snap_set,
                 snap_set=state.snap_mark, snap_mark=live,
@@ -965,9 +1035,11 @@ class ShelleyLedger(LedgerRules):
         base, adds, dels = state.utxo.thaw()
         slot = block.slot
         gated = not self.supports_multiasset
-        delegs = pools = None          # copied lazily: certs are rare
-        rewards = retiring = None      # likewise
+        delegs = pools = None          # thawed lazily, at a block's first
+        #                                certificate: (base, adds, dels)
+        rewards = retiring = None      # copied lazily likewise
         n_light = 0
+        n_cert_txs = n_deleg = n_pool = n_new = t_thaw = 0
         for tx in block.body:
             light = not tx.validity    # until a guard below finds work
             if gated or not light:
@@ -1051,26 +1123,31 @@ class ShelleyLedger(LedgerRules):
                         f"(consumed+minted != produced)")
             if tx.certs:
                 light = False
+                n_cert_txs += 1
                 if pools is None:
-                    delegs = dict(state.delegs)
-                    pools = dict(state.pools)
+                    t_thaw = perf_counter_ns()
+                    delegs = state.delegs.thaw()
+                    pools = state.pools.thaw()
                 for kind, a, b in tx.certs:
                     if kind == CERT_POOL:
+                        n_pool += 1
                         pid = pool_id_of(a)
-                        pools[pid] = b
+                        thawed_set(pools, pid, b)
                         if retiring is None:
                             retiring = dict(state.retiring)
                         # re-registration cancels a pending retirement
                         retiring.pop(pid, None)
                     elif kind == CERT_DELEG:
-                        if b not in pools:
+                        if not thawed_holds(pools, b):
                             raise LedgerError(
                                 f"delegation to unregistered pool "
                                 f"{b.hex()[:12]}")
-                        delegs[a] = b
+                        n_deleg += 1
+                        n_new += not thawed_holds(delegs, a)
+                        thawed_set(delegs, a, b)
                     elif kind == CERT_RETIRE:
                         pid = pool_id_of(a)
-                        if pid not in pools:
+                        if not thawed_holds(pools, pid):
                             raise LedgerError(
                                 f"retirement of unregistered pool "
                                 f"{pid.hex()[:12]}")
@@ -1099,12 +1176,18 @@ class ShelleyLedger(LedgerRules):
             pid = pool_id_of(issuer_vk)
             made[pid] = made.get(pid, 0) + 1
             blocks_made = tuple(sorted(made.items()))
+        if pools is not None:
+            delegs = PersistentMap.freeze(*delegs)
+            pools = PersistentMap.freeze(*pools)
+            _CERT_US.inc((perf_counter_ns() - t_thaw) // 1000)
+            _CERT_TXS.inc(n_cert_txs)
+            _CERTS_DELEG.inc(n_deleg)
+            _CERTS_POOL.inc(n_pool)
+            _DELEG_NEW.inc(n_new)
         return _fast_replace(
             state, utxo=UtxoMap.freeze(base, adds, dels),
-            delegs=state.delegs if delegs is None
-            else tuple(sorted(delegs.items())),
-            pools=state.pools if pools is None
-            else tuple(sorted(pools.items())),
+            delegs=state.delegs if delegs is None else delegs,
+            pools=state.pools if pools is None else pools,
             rewards=state.rewards if rewards is None
             else tuple(sorted(rewards.items())),
             retiring=state.retiring if retiring is None
@@ -1173,15 +1256,22 @@ class ShelleyLedger(LedgerRules):
         (Shelley/Ledger/Ledger.hs:279-284): ONE columns item for the
         body, every witness's own bytes over its transaction's id (hashed
         where the block was decoded); nothing for an empty body."""
-        cols = Ed25519Cols.of_witnesses(block.body)
+        cols = self._witness_cols(block.body)
         return [cols] if cols else []
+
+    @staticmethod
+    def _witness_cols(body) -> Ed25519Cols:
+        """A body's witnesses as its Ed25519 lanes, counted."""
+        cols = Ed25519Cols.of_witnesses(body)
+        _WITNESSES.inc(len(cols))
+        return cols
 
     def apply_block(self, ticked: ShelleyLedgerState, block,
                     backend=None) -> ShelleyLedgerState:
         from ..crypto.backend import default_backend
         backend = backend or default_backend()
         self.sequential_checks(ticked, block)
-        cols = Ed25519Cols.of_witnesses(block.body)
+        cols = self._witness_cols(block.body)
         if cols:
             ok = backend.verify_ed25519_batch(cols)
             if not all(ok):
@@ -1202,7 +1292,7 @@ class ShelleyLedger(LedgerRules):
         self.check_tx_witnesses(state, tx)
         from ..crypto.backend import default_backend
         ok = (backend or default_backend()).verify_ed25519_batch(
-            Ed25519Cols.of_witnesses(blk.body))
+            self._witness_cols(blk.body))
         if not all(ok):
             raise LedgerError(f"tx {tx.txid.hex()[:12]}: bad witness")
         return replace(self._apply_txs(state, blk), tip=state.tip)

@@ -54,14 +54,15 @@ else:
 # driver's command (six workers on 8 cores, 733 s in all, PR 43).  The
 # workers take the first six at second 0: five files whose composites
 # no other file builds, the longest first, and a short one.  The last
-# three start as those end.  Two of them build nothing of their own
+# four start as those end.  Three of them build nothing of their own
 # and load from the persistent cache what the files before them
 # compiled, if they start behind them and not beside them:
 # `test_ed_tiles` the tile programs and the flat bucket of four other
 # files (567 s beside them, 462 s behind `test_fresh_keys`),
 # `test_longchain` `test_hardfork_sync`'s four programs (432 s beside
-# it, 187 s behind it).  `test_mesh_batch` is the shortest of those
-# that build their own.
+# it, 187 s behind it), and so does `test_delegrush` (PR 45: the same
+# composite; 181-212 s on a cache that held it).  `test_mesh_batch` is
+# the shortest of those that build their own.
 # tests/test_suite_order.py fails on a name that is no `device` file
 # under tests/.
 COMPILES_FOR_MINUTES = (
@@ -74,6 +75,7 @@ COMPILES_FOR_MINUTES = (
     ("test_ed_tiles.py", 462),
     ("test_mesh_batch.py", 364),
     ("test_longchain.py", 187),
+    ("test_delegrush.py", 212),
 )
 
 

@@ -16,9 +16,16 @@ what that must not change, pinned.
   snapshot written mid-chain restores to a state that replays to the
   same final hash.  Only `UtxoMap.to_dict()` and `state_hash()` are
   held, not the overlay's `_base` / `_adds` / `_dels` split.
+- The delegation map and the pool registry are the same persistent map
+  (ISSUE 45): `PersistentMap` against a dict over random streams of sets,
+  overwrites and deletes with a freeze every few steps, and the state
+  hash of a chain that carries certificates as the sorted-tuple form
+  made it.
 """
 import hashlib
 import os
+import pickle
+import random
 import subprocess
 import sys
 from dataclasses import replace
@@ -32,9 +39,9 @@ from ouroboros_tpu.consensus.ledger import ExtLedgerRules, LedgerError
 from ouroboros_tpu.crypto import ed25519_ref
 from ouroboros_tpu.crypto.backend import OpensslBackend
 from ouroboros_tpu.eras.shelley import (
-    CERT_DELEG, CERT_POOL, CERT_RETIRE, ShelleyLedger, TPraosConfig,
-    forge_tpraos_fields, make_shelley_tx, pool_id_of,
-    shelley_genesis_setup,
+    CERT_DELEG, CERT_POOL, CERT_RETIRE, PersistentMap, ShelleyLedger,
+    TPraosConfig, UtxoMap, forge_tpraos_fields, make_shelley_tx,
+    pool_id_of, shelley_genesis_setup, thawed_holds, thawed_set,
 )
 from ouroboros_tpu.storage.fs import MockFS
 from ouroboros_tpu.storage.ledgerdb import LedgerDB
@@ -435,14 +442,24 @@ GOLDEN = {
 }
 
 
-def _synth(root, keys: str, blocks: int, txs: int):
+# 40 blocks of 3 plain spends, 2 first delegations and 1 re-delegation, a
+# pool registered in blocks 15 and 31: the hash the ledger of the commit
+# before ISSUE 45 (delegation map and pool registry as sorted tuples)
+# replayed this chain to
+CERT_MIX = ("--deleg-txs-per-block", "2", "--redeleg-txs-per-block", "1",
+            "--redeleg-after-blocks", "8", "--pool-reg-every-blocks", "16")
+GOLDEN_CERT_MIX = \
+    "bb91185392f72d56a27daf1155e3cecfedc36722e6c5ad3c246a79060351b6f1"
+
+
+def _synth(root, keys: str, blocks: int, txs: int, *more: str):
     chain = os.path.join(str(root), f"{keys}-{blocks}-{txs}")
     subprocess.run(
         [sys.executable, os.path.join(REPO, "tools", "db_synth.py"),
          "--out", chain, "--protocol", "shelley", "--blocks", str(blocks),
          "--txs-per-block", str(txs), "--pools", "2", "--f", "1/20",
          "--epoch-length", "432000", "--kes-depth", "6",
-         "--witness-keys", keys, "--seed", "37"],
+         "--witness-keys", keys, "--seed", "37", *more],
         check=True, capture_output=True)
     db, rules, decode, _cfg = dba.load_db(chain)
     return rules, [decode(raw) for _entry, raw in db.stream()]
@@ -466,6 +483,91 @@ def test_db_synth_chain_ends_in_the_recorded_state(tmp_path_factory, keys,
     assert sum(len(b.body) for b in chain) == blocks * txs
     final = _walk(rules, chain)[-1]
     assert final.ledger.state_hash().hex() == GOLDEN[(keys, blocks, txs)]
+
+
+def test_a_chain_with_certificates_ends_in_the_tuple_forms_state(
+        tmp_path_factory):
+    rules, chain = _synth(tmp_path_factory.mktemp("walk"), "pool", 40, 6,
+                          *CERT_MIX)
+    final = _walk(rules, chain)[-1].ledger
+    assert final.state_hash().hex() == GOLDEN_CERT_MIX
+    assert (len(final.delegs), len(final.pools)) == (2 + 88 - 2, 2 + 2)
+    # the pairs a hash, a snapshot or an epoch boundary reads, in key order
+    assert list(final.delegs) == sorted(final.delegs.to_dict().items())
+
+
+# -- the one persistent map, against a dict ------------------------------------
+
+def _random_walk(seed: int, cls=PersistentMap):
+    """Blocks of sets, overwrites and deletes over a small key space,
+    one thaw and one freeze a block; every map made, beside the dict it
+    has to equal."""
+    rng = random.Random(seed)
+    keys = [b"%04d" % k for k in range((12, 90, 400, 1500)[seed % 4])]
+    model = {k: b"v0" for k in rng.sample(keys, len(keys) // 3)}
+    m = cls.from_dict(model)
+    made = [(m, dict(model))]
+    for block in range(60):
+        thawed = base, adds, dels = m.thaw()
+        for step in range(rng.randrange(0, 2 + len(keys) // 6)):
+            k = rng.choice(keys)
+            assert thawed_holds(thawed, k) == (k in model)
+            if rng.random() < 0.35 and k in model:
+                adds.pop(k, None)
+                dels.add(k)
+                del model[k]
+            else:
+                v = b"v%d.%d" % (block, step)
+                thawed_set(thawed, k, v)
+                model[k] = v
+        m = cls.freeze(base, adds, dels)
+        made.append((m, dict(model)))
+    return made
+
+
+@pytest.mark.parametrize("seed", range(8))
+def test_persistent_map_reads_as_the_dict_it_stands_for(seed):
+    made = _random_walk(seed)
+    if len(made[-1][1]) > 100:
+        assert len({id(m._base) for m, _d in made}) > 2, "never flattened"
+    # every map ever made, read after all the later ones were: none was
+    # touched by a later block's walk
+    for m, model in made:
+        assert m.to_dict() == model and len(m) == len(model)
+        assert list(m) == sorted(model.items())
+        assert dict(m) == model
+        get = m.getter()
+        for k in [*model, b"\xff" * 4]:
+            assert (k in m) == (k in model)
+            assert m.get(k) == get(k) == model.get(k)
+        assert m.get(b"\xff" * 4, 7) == 7
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_persistent_map_equality_and_pickling(seed):
+    made = _random_walk(seed)
+    for (m, model), (later, later_model) in zip(made, made[1:]):
+        assert m == PersistentMap.from_dict(model)
+        assert (m == later) == (model == later_model)
+        back = pickle.loads(pickle.dumps(m, pickle.HIGHEST_PROTOCOL))
+        assert type(back) is PersistentMap and back == m
+        assert list(back) == list(m)
+    with pytest.raises(TypeError):
+        hash(made[0][0])
+
+
+def test_the_utxo_map_is_the_same_map_with_rows_for_entries():
+    rows = [(b"t" * 32, ix, b"a" * 32, 10 + ix, ()) for ix in (2, 0, 1)]
+    utxo = UtxoMap.from_items(rows)
+    assert isinstance(utxo, PersistentMap)
+    assert list(utxo) == sorted(rows)
+    base, adds, dels = utxo.thaw()
+    adds.pop((b"t" * 32, 1), None)
+    dels.add((b"t" * 32, 1))
+    after = UtxoMap.freeze(base, adds, dels)
+    assert type(after) is UtxoMap and len(after) == 2 and len(utxo) == 3
+    assert list(after) == [r for r in sorted(rows) if r[1] != 1]
+    assert pickle.loads(pickle.dumps(after)) == after
 
 
 # -- earlier states stay what they were --------------------------------------

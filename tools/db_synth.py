@@ -18,6 +18,8 @@ Usage: python tools/db_synth.py --out DIR [--protocol shelley] [--blocks N]
        [--txs-per-block M] [--pools P] [--f NUM/DEN]
        [--witness-keys pool|fresh] [--slots-per-kes-period N]
        [--tx-arrivals-per-slot R0,R1,... --tx-arrival-phase-slots S]
+       [--deleg-txs-per-block D --redeleg-txs-per-block R
+        --redeleg-after-blocks B --pool-reg-every-blocks E]
 
 --witness-keys (shelley) says who signs the transactions.  `pool` (the
 default, the chain every earlier version forged, byte for byte): every
@@ -42,6 +44,26 @@ randomness: the arrivals are a fractional accumulator, and the slot gaps
 the seed's leader schedule gives are what spread the block sizes.
 `config.json` then also says what the chain came to hold
 (`tx_arrivals.txs_per_block`: min, mean, max, empty blocks).
+
+--deleg-txs-per-block D, --redeleg-txs-per-block R (shelley; with
+neither, every transaction is the plain spend and the chain is byte for
+byte what it was): of a block's `--txs-per-block` transactions, D are
+FIRST DELEGATIONS (one input, one output, a `deleg` certificate of a
+stake key derived from (seed, running index), which no earlier block has
+seen; witnessed by the spender's key and by that stake key) and R are
+RE-DELEGATIONS (the same shape, by a stake key that delegated at least
+`--redeleg-after-blocks` blocks earlier, to another pool; while no key
+is that old, first delegations stand in).  In every
+`--pool-reg-every-blocks`-th block one first delegation gives its place
+to a POOL REGISTRATION (a `pool` certificate of a new cold key and VRF
+key, witnessed by the spender's key and the cold key).  The rest are
+plain spends; the order inside a block and every delegation's target
+(uniform over the pools registered so far) are drawn from the seed.
+The change output goes where a plain spend's goes (a wallet's change
+returns to its payment key, and an address IS a key here), so a stake
+key holds no output and the delegated stake is 0: nothing reads it
+before the first epoch boundary.  `config.json` records the mix, the
+three transaction sizes and what was forged (`tx_mix`).
 """
 from __future__ import annotations
 
@@ -49,6 +71,7 @@ import argparse
 import hashlib
 import json
 import os
+import random
 import sys
 import time
 from fractions import Fraction
@@ -261,13 +284,16 @@ class _SpendChains:
         self.seed, self.fresh = seed, fresh
         self.n_tx = 0
 
-    def body(self, n_body: int, make_tx, first=None) -> list:
+    def body(self, n_body: int, make_tx, first=None, mix=None) -> list:
         """`n_body` transactions made by `make_tx(inputs, outputs, certs,
         signing_keys)`; transaction t is owner `(first + t) % owners`'s
-        (`first` left out: the running transaction index)."""
+        (`first` left out: the running transaction index).  With a
+        `_CertMix`, each carries what the mix deals this block: nothing,
+        or one certificate and its authorising key's witness."""
         from ouroboros_tpu.crypto import ed25519_ref
         if first is None:
             first = self.n_tx
+        dealt = mix.deal(n_body) if mix is not None else None
         body = []
         for t in range(n_body):
             owner = (first + t) % len(self.addrs)
@@ -283,13 +309,106 @@ class _SpendChains:
                 pay_to = ed25519_ref.public_key(next_sk)
             else:
                 next_sk, pay_to = self.holder_sk[owner], self.addrs[owner]
+            certs, cert_sks = dealt[t]() if dealt is not None else ([], [])
             tx = make_tx(inputs=[(txid, ix)], outputs=[(pay_to, amount)],
-                         certs=[], signing_keys=[self.holder_sk[owner]])
+                         certs=certs,
+                         signing_keys=[self.holder_sk[owner], *cert_sks])
             self.holder_sk[owner] = next_sk
             self.n_tx += 1
             self.spendable[owner].append((tx.txid, 0, amount))
             body.append(tx)
         return body
+
+
+class _CertMix:
+    """The certificates of a chain whose holders delegate: what each of a
+    block's transactions carries beside its spend (module docstring,
+    --deleg-txs-per-block).  `deal(n)` is called once a block and returns
+    one maker a transaction, in an order drawn from the seed; a maker
+    returns `(certs, extra signing keys)` and records what it made."""
+
+    def __init__(self, seed: bytes, pool_ids: list, delegs: int,
+                 redelegs: int, redeleg_after: int, pool_every: int):
+        self.seed = seed
+        self.rng = random.Random(hashlib.blake2b(
+            b"cert-mix:" + seed, digest_size=8).digest())
+        self.pool_ids = list(pool_ids)    # registered so far, in order
+        self.delegs, self.redelegs = delegs, redelegs
+        self.redeleg_after, self.pool_every = redeleg_after, pool_every
+        # every stake key that has delegated: [secret, index of its pool],
+        # in delegation order, and how many of them each block began with
+        self.staked: list = []
+        self.staked_before_block: list = []
+        self.made = {"plain": 0, "deleg": 0, "redeleg": 0, "pool": 0}
+
+    def _key(self, tag: bytes, n: int) -> bytes:
+        return hashlib.blake2b(b"%s:%s:%d" % (tag, self.seed, n),
+                               digest_size=32).digest()
+
+    def _plain(self):
+        self.made["plain"] += 1
+        return [], []
+
+    def _deleg_cert(self, entry: list):
+        """Stake key `entry` delegating to the pool it now names."""
+        from ouroboros_tpu.crypto import ed25519_ref
+        from ouroboros_tpu.eras.shelley import CERT_DELEG
+        sk, to = entry
+        return [(CERT_DELEG, ed25519_ref.public_key(sk),
+                 self.pool_ids[to])], [sk]
+
+    def _deleg(self):
+        entry = [self._key(b"stake-key", len(self.staked)),
+                 self.rng.randrange(len(self.pool_ids))]
+        self.staked.append(entry)
+        self.made["deleg"] += 1
+        return self._deleg_cert(entry)
+
+    def _redeleg(self, old_enough: int):
+        entry = self.staked[self.rng.randrange(old_enough)]
+        # another pool than the one it delegates to now
+        entry[1] = (entry[1] + 1 + self.rng.randrange(
+            len(self.pool_ids) - 1)) % len(self.pool_ids)
+        self.made["redeleg"] += 1
+        return self._deleg_cert(entry)
+
+    def _pool(self):
+        from ouroboros_tpu.crypto import ed25519_ref
+        from ouroboros_tpu.eras.shelley import CERT_POOL, pool_id_of
+        n = self.made["pool"]
+        cold_sk = self._key(b"pool-cold", n)
+        cold_vk = ed25519_ref.public_key(cold_sk)
+        vrf_vk = ed25519_ref.public_key(self._key(b"pool-vrf", n))
+        self.pool_ids.append(pool_id_of(cold_vk))
+        self.made["pool"] += 1
+        return [(CERT_POOL, cold_vk, vrf_vk)], [cold_sk]
+
+    def deal(self, n_body: int) -> list:
+        block_no = len(self.staked_before_block)
+        self.staked_before_block.append(len(self.staked))
+        # stake keys old enough to delegate again: those of the blocks
+        # up to `redeleg_after` before this one
+        at = block_no - self.redeleg_after
+        old_enough = self.staked_before_block[at + 1] if at >= 0 else 0
+        n_re = self.redelegs if old_enough and len(self.pool_ids) > 1 else 0
+        n_first = self.delegs + self.redelegs - n_re
+        n_pool = int(self.pool_every > 0 and n_first > 0
+                     and block_no % self.pool_every == self.pool_every - 1)
+        makers = ([self._pool] * n_pool + [self._deleg] * (n_first - n_pool)
+                  + [lambda: self._redeleg(old_enough)] * n_re
+                  + [self._plain] * (n_body - n_first - n_re))
+        self.rng.shuffle(makers)
+        return makers
+
+    def summary(self, tx_bytes: dict, txs_per_block: int) -> dict:
+        return {"txs_per_block": txs_per_block,
+                "deleg_txs_per_block": self.delegs,
+                "redeleg_txs_per_block": self.redelegs,
+                "redeleg_after_blocks": self.redeleg_after,
+                "pool_reg_every_blocks": self.pool_every,
+                "tx_bytes": tx_bytes, "made": dict(self.made),
+                "stake_keys": len(self.staked),
+                "pools_registered": len(self.pool_ids)}
 
 
 def synth_shelley(args) -> dict:
@@ -356,6 +475,34 @@ def synth_shelley(args) -> dict:
         ledger.GENESIS_TXID, 100_000, args.seed.encode(),
         fresh=args.witness_keys == "fresh")
     GEN = ledger.GENESIS_TXID
+    mix = None
+    if args.deleg_txs_per_block or args.redeleg_txs_per_block:
+        mix = _CertMix(args.seed.encode(),
+                       [p["keys"].pool_id for p in pools],
+                       args.deleg_txs_per_block, args.redeleg_txs_per_block,
+                       args.redeleg_after_blocks, args.pool_reg_every_blocks)
+        # three shapes, three sizes: a body's bytes are reckoned from what
+        # it holds of each, against the genesis's limit
+        sized = _CertMix(b"size", mix.pool_ids, 0, 0, 0, 0)
+        mix_bytes = {
+            kind: len(cbor.dumps(make_shelley_tx(
+                inputs=[(GEN, 0)], outputs=[(pools[0]["addr"], 100_000)],
+                certs=certs, signing_keys=[pools[0]["keys"].addr_sk,
+                                           *sks]).encode()))
+            for kind, (certs, sks) in (("plain", sized._plain()),
+                                       ("deleg", sized._deleg()),
+                                       ("pool", sized._pool()))}
+        n_cert = args.deleg_txs_per_block + args.redeleg_txs_per_block
+        n_pool = int(args.pool_reg_every_blocks > 0)    # at most one a block
+        body_bytes = ((args.txs_per_block - n_cert) * mix_bytes["plain"]
+                      + (n_cert - n_pool) * mix_bytes["deleg"]
+                      + n_pool * mix_bytes["pool"])
+        if n_cert > args.txs_per_block or body_bytes > MAX_BLOCK_BODY_SIZE:
+            raise SystemExit(
+                f"db_synth: {n_cert} certificate transactions of "
+                f"{args.txs_per_block} a block come to {body_bytes} bytes; "
+                f"a body holds {MAX_BLOCK_BODY_SIZE}")
+        mix_bytes["body_max"] = body_bytes
     mempool = None
     if args.tx_arrivals_per_slot is not None:
         # every transaction made here has one input, one output and one
@@ -388,7 +535,7 @@ def synth_shelley(args) -> dict:
         p = pools[leader_ix]
         if mempool is None:
             body = spends.body(args.txs_per_block, make_shelley_tx,
-                               first=forged * args.txs_per_block)
+                               first=forged * args.txs_per_block, mix=mix)
         else:
             body = spends.body(mempool.take(slot), make_shelley_tx)
         hdr = make_header(prev, slot, body, issuer=0)
@@ -412,6 +559,9 @@ def synth_shelley(args) -> dict:
             "per_slot": args.tx_arrivals_per_slot,
             "phase_slots": args.tx_arrival_phase_slots,
             "tx_bytes": tx_bytes, "txs_per_block": mempool.summary()}
+    if mix is not None:
+        config["tx_mix"] = mix.summary(mix_bytes, args.txs_per_block)
+    if mempool is not None or mix is not None:
         write_config()
     return {"blocks": forged, "last_slot": slot - 1}
 
@@ -674,6 +824,22 @@ def main() -> None:
     ap.add_argument("--tx-arrival-phase-slots", type=int, default=None,
                     help="shelley: slots a phase of "
                          "--tx-arrivals-per-slot lasts")
+    ap.add_argument("--deleg-txs-per-block", type=int, default=0,
+                    help="shelley: of --txs-per-block, the transactions "
+                         "that carry a first delegation of a never-seen "
+                         "stake key, and that key's witness")
+    ap.add_argument("--redeleg-txs-per-block", type=int, default=0,
+                    help="shelley: of --txs-per-block, the transactions "
+                         "in which a stake key that delegated "
+                         "--redeleg-after-blocks earlier delegates to "
+                         "another pool (first delegations until then)")
+    ap.add_argument("--redeleg-after-blocks", type=int, default=256,
+                    help="shelley: blocks a stake key waits before it "
+                         "may delegate again")
+    ap.add_argument("--pool-reg-every-blocks", type=int, default=0,
+                    help="shelley: every so many blocks one first "
+                         "delegation gives its place to the registration "
+                         "of a new pool (0: never)")
     ap.add_argument("--k", type=int, default=2160,
                     help="cardano with --byron-blocks: the security "
                          "parameter of both eras (mainnet: 2160)")
@@ -713,6 +879,14 @@ def main() -> None:
             args.tx_arrivals_per_slot and args.protocol != "shelley"):
         ap.error("--tx-arrivals-per-slot and --tx-arrival-phase-slots "
                  "go together, on a shelley chain")
+    if (args.deleg_txs_per_block or args.redeleg_txs_per_block) and (
+            args.protocol != "shelley" or args.tx_arrivals_per_slot
+            or min(args.deleg_txs_per_block, args.redeleg_txs_per_block,
+                   args.pool_reg_every_blocks) < 0
+            or args.redeleg_after_blocks < 1):
+        ap.error("--deleg-txs-per-block and --redeleg-txs-per-block are "
+                 "counts of a shelley chain's --txs-per-block (no "
+                 "--tx-arrivals-per-slot), --redeleg-after-blocks >= 1")
 
     t0 = time.time()
     if args.protocol == "shelley":
