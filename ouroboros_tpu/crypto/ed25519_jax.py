@@ -348,7 +348,7 @@ def a128_words_core(Aw, signA):
 
 
 # one program a WIDTH, whatever the count of new keys: the cache calls it
-# a tile at a time (precompute.PrecomputeCache._device_tables)
+# a tile at a time (precompute.PrecomputeCache._dispatch_tables)
 a128_words_kernel = jax.jit(a128_words_core)
 
 # filler for padding / undecodable keys: [2^128]B (any valid point works —

@@ -349,25 +349,46 @@ class JaxBackend(CryptoBackend):
 
     # -- host prep ----------------------------------------------------------
     def _pack_ed(self, reqs, m: int):
-        """Packed-words prep + A128 assembly for an Ed25519 batch padded
-        to m, on the host.  Returns (the eight (rows, m) lane arrays of
-        `verify_full_split_words_core`, parse_ok); keys the cache could
-        not decompress are masked out of parse_ok (the kernels trust the
-        cached affine x and skip the A square root)."""
+        """Packed-words prep for an Ed25519 batch padded to m, on the
+        host, with the per-key tables on their way: returns (the six
+        arrays of `prepare_words_batch`, parse_ok, the tables' handle)
+        for `_finish_ed`.
+
+        The order is the point.  The key column is in hand first, so
+        the tables come first: `begin_assemble` dispatches the fill of
+        the keys the cache has not seen and waits for nothing.  Then the
+        lanes are hashed and packed, which needs no table, with the
+        device at work on the fill beside it; the caller may put more
+        such work (the window's VRF and KES packers) before it asks for
+        the tables in `_finish_ed`.  Hashed first, as it was until PR
+        46, a window of new keys held the producer for the device's
+        whole 0.2 s with nothing else of the window on the chip.  A
+        batch whose keys all hit gets a handle with nothing in flight:
+        one path for every window."""
         vks, msgs, sigs = ed25519_columns(reqs)
         pad = m - len(vks)
         vks = [*vks, *[b"\x00" * 32] * pad]
+        tables = EJ.GLOBAL_A128_CACHE.begin_assemble(vks)
         arrays, parse_ok = EJ.prepare_words_batch(
             vks, [*msgs, *[b""] * pad], [*sigs, *[b"\x00" * 64] * pad])
-        Aw, _signA, Rw, signR, sw, kw = arrays
-        xa, xw, yw, known = EJ.GLOBAL_A128_CACHE.assemble(vks)
+        return arrays, parse_ok, tables
+
+    def _finish_ed(self, packed):
+        """`_pack_ed`'s batch with its tables: (the eight (rows, m) lane
+        arrays of `verify_full_split_words_core`, parse_ok).  Waits for
+        what is left of the fill and stores it; keys the cache could
+        not decompress are masked out of parse_ok (the kernels trust the
+        cached affine x and skip the A square root)."""
+        (Aw, _signA, Rw, signR, sw, kw), parse_ok, tables = packed
+        xa, xw, yw, known = EJ.GLOBAL_A128_CACHE.finish_assemble(tables)
         return ((Aw, xa, xw, yw, Rw, signR.reshape(1, -1), sw, kw),
                 parse_ok & known)
 
     def _prep_ed(self, reqs, m: int):
-        """`_pack_ed` with every array on the device: one program as
-        wide as the batch (the simple batch entry point)."""
-        arrays, parse_ok = self._pack_ed(reqs, m)
+        """`_pack_ed` and `_finish_ed` with every array on the device:
+        one program as wide as the batch (the simple batch entry
+        point)."""
+        arrays, parse_ok = self._finish_ed(self._pack_ed(reqs, m))
         return tuple(self._dev(a) for a in arrays), parse_ok
 
     def verify_ed25519_batch(self, reqs):
@@ -701,10 +722,12 @@ class JaxBackend(CryptoBackend):
         block body (`Ed25519Cols`, which counts for `len` requests);
         verdicts are indices into the requests the items stand for.
 
-        `window.submit` holds one span a stage: submit.split,
-        submit.pack_ed (key tables and the tiles' copy to the device
-        included), submit.pack_vrf (beta words included),
-        submit.pack_kes, folding submit.fold (the lanes' owner rows,
+        `window.submit` holds one span a stage, submit.pack_ed in two
+        pieces: submit.split, submit.pack_ed (the new keys' fill
+        dispatched, the lanes hashed and packed), submit.pack_vrf (beta
+        words included), submit.pack_kes, submit.pack_ed again (the
+        wait that is left for the key tables, their store, the tiles'
+        copy to the device), folding submit.fold (the lanes' owner rows,
         which the tile calls read, and their copy to the device), and
         submit.dispatch (submit.ed_tiles = the T tile calls, the
         composite call and, folding, the fold program's)."""
@@ -742,9 +765,7 @@ class JaxBackend(CryptoBackend):
         tiles: list = []
         with _spans.span("submit.pack_ed", cat="dispatch"):
             if ne:
-                ed_arrays, parse_ok = self._pack_ed(ed_reqs, ne)
-                state["ed"] = (None, parse_ok)
-                tiles = self._dev_tiles(ed_arrays, ne)
+                ed_packed = self._pack_ed(ed_reqs, ne)
         with _spans.span("submit.pack_vrf", cat="dispatch"):
             if nv:
                 vrf_args, masks = self._prep_vrf(vrf_reqs, nv)
@@ -760,6 +781,14 @@ class JaxBackend(CryptoBackend):
         with _spans.span("submit.pack_kes", cat="dispatch"):
             if nk:
                 kes_args = self._prep_kes_hash(kes_msgs, kes_expects, nk)
+        # the key tables as late as the window's host work allows: the
+        # VRF and KES packers need none, the tiles' copy and the owner
+        # rows do
+        with _spans.span("submit.pack_ed", cat="dispatch"):
+            if ne:
+                ed_arrays, parse_ok = self._finish_ed(ed_packed)
+                state["ed"] = (None, parse_ok)
+                tiles = self._dev_tiles(ed_arrays, ne)
         self._note_padding(
             len(ed_reqs) + len(vrf_reqs) + len(beta_proofs) + len(kes_msgs),
             ne + nv + nb + nk)

@@ -38,13 +38,37 @@ readable in a run's counters.  `hits` counts LANES served from the
 table (and KES lookups that hit); `misses` counts distinct keys that had
 to be filled (and KES lookups that missed).
 
+A batch's tables come in TWO PHASES (ISSUE 46).  `begin_assemble(vks)`
+looks the keys up, copies the hits out and DISPATCHES the fill of the
+distinct new keys, asynchronously; it returns a handle (`_Fill`) and
+waits for nothing.  `finish_assemble(handle)` waits for what is left of
+the device's work, copies the tables back, stores them and returns the
+batch's lanes.  Between the two the caller does the host work that
+needs no table: the window path (`JaxBackend._pack_ed`, `_finish_ed`)
+hashes and packs the window's lanes there, so the producer no longer
+sits out the device's 0.2 s a window of new keys.  `assemble(vks)` is
+the one right after the other.  A handle belongs to the thread that
+began it and is finished at most once; several threads may each hold
+one at a time (the window's producer, `VerifyService`'s submitters).
+Nothing is stored before `finish_assemble`: a handle dropped by an
+exception leaves the table as if its keys had never been seen (their
+fill was counted, and is made again when they are next met); a key two
+open handles both began is filled twice and stored once; `clear()`
+between the phases loses nothing (the hits were copied out at the
+begin, the new keys go into the cleared table).  In BOTH phases the
+device's part stays outside the stripe, which is held for the lookup
+and for the store only.  `early_fill_keys` counts the keys whose fill
+was begun ahead like that (of `filled_keys`, all of them), and
+`fill_wait_us` the whole microseconds a finish stood blocked on the
+device.
+
 Unlike the r5 A128Cache, undecodable keys are cached too (as negative
 entries): a bad key repeated across windows used to re-dispatch the fill
 kernel every window just to re-discover it cannot be decompressed.
 
 Import discipline: this module must import WITHOUT jax (backend.py and
 host-only tooling read the KES namespace); the device fill imports
-ed25519_jax lazily inside `_device_tables`.
+ed25519_jax lazily inside `_dispatch_tables`.
 
 Counters live in the observability registry (ISSUE 7): the process-wide
 cache registers its hit/miss/device_fill/eviction counters under the
@@ -58,6 +82,7 @@ carry private unregistered counters with the same API.
 from __future__ import annotations
 
 import threading
+import time
 from collections import OrderedDict
 from itertools import repeat
 
@@ -114,17 +139,42 @@ class _Stripe:
         self._lock.release()
 
 
+class _Fill:
+    """A batch's tables between `begin_assemble` and `finish_assemble`:
+    the output buffer with the hits already in it, the lanes that
+    missed (`miss`) and the key each carries (`missed`), the distinct
+    new keys in first-seen order (`keys`; empty = nothing in flight,
+    the finish does nothing) and what `_dispatch_tables` handed back
+    for them (`pending`).  Held by the thread that began it, finished
+    at most once; dropping it unfinished costs the cache nothing."""
+
+    __slots__ = ("out", "known", "miss", "missed", "keys", "pending")
+
+    def __init__(self, n: int):
+        self.out = np.empty((_TAB_ROWS, n), dtype=np.uint32)
+        self.known = np.empty(n, dtype=bool)
+        self.miss = self.missed = self.pending = None
+        self.keys: list = []
+
+
 class PrecomputeCache:
     """vk bytes -> per-key precomputation, LRU-bounded, with batched
     device fill and a separate KES hash-path outcome namespace.
 
     assemble() returns ((8, N) uint32 xA-words, x128-words, y128-words,
     known (N,) bool) for a batch of keys, computing every missing unique
-    key on the device in programs of two fixed widths (`_device_tables`).
-    `known` is False for keys that failed decompression (not on the
-    curve / bad length) — callers must mask those invalid, since the
-    verify kernels trust the cached x and skip the square-root check
-    entirely.
+    key on the device in programs of two fixed widths
+    (`_dispatch_tables`).  `known` is False for keys that failed
+    decompression (not on the curve / bad length) — callers must mask
+    those invalid, since the verify kernels trust the cached x and skip
+    the square-root check entirely.
+
+    assemble() is begin_assemble() then finish_assemble(): the first
+    dispatches the new keys' fill and returns a handle without waiting,
+    the second waits, stores and returns the lanes; a caller with host
+    work that needs no table does it in between (the module's text says
+    who may hold a handle and what a dropped one costs).  The device's
+    part of a fill stays outside the stripe in both.
 
     Eviction is exact LRU per namespace: every hit refreshes the entry,
     and inserts past `max_entries` drop the least-recently-used entry
@@ -134,7 +184,11 @@ class PrecomputeCache:
     # counter names in the registry namespace (ISSUE 7); the attribute
     # aliases below expose each as plain read/write ints
     _COUNTERS = ("hits", "misses", "device_fills", "filled_keys",
-                 "fill_lanes_padded", "evictions")
+                 "fill_lanes_padded", "evictions", "early_fill_keys")
+    # timing-shaped ones: how often two submitters collide, how long a
+    # finish stood blocked on the device.  Unlike the functional
+    # counters they are excluded from the deterministic snapshot
+    _TIMING_COUNTERS = ("lock_wait", "fill_wait_us")
 
     def __init__(self, max_entries: int = 200_000, register: bool = False):
         # point entries: vk -> slot of the word table.  A slot holds the
@@ -162,11 +216,8 @@ class PrecomputeCache:
               else (lambda n, **kw: _metrics.Counter(n, always=True, **kw)))
         self._counters = {name: mk(f"precompute.{name}")
                           for name in self._COUNTERS}
-        # lock contention is timing-shaped (how often two submitters
-        # collide), so unlike the functional counters it is excluded
-        # from the deterministic snapshot (stable=False)
-        self._counters["lock_wait"] = mk("precompute.lock_wait",
-                                         stable=False)
+        for name in self._TIMING_COUNTERS:
+            self._counters[name] = mk(f"precompute.{name}", stable=False)
         # per-namespace lock striping: point entries and KES hash-path
         # outcomes contend independently
         self._lock_c = _Stripe(self)
@@ -187,7 +238,9 @@ class PrecomputeCache:
     filled_keys = _alias("filled_keys")
     fill_lanes_padded = _alias("fill_lanes_padded")
     evictions = _alias("evictions")
+    early_fill_keys = _alias("early_fill_keys")
     lock_wait = _alias("lock_wait")
+    fill_wait_us = _alias("fill_wait_us")
     del _alias
 
     def __len__(self):
@@ -198,9 +251,20 @@ class PrecomputeCache:
 
     # -- point entries (Ed25519 A / VRF Y) ----------------------------------
     def assemble(self, vks):
+        return self.finish_assemble(self._begin(vks))
+
+    def begin_assemble(self, vks):
+        """First phase of `assemble`, for a caller with host work to do
+        before it needs the tables: hits copied out, the new keys' fill
+        dispatched, nothing waited for.  Returns the handle
+        `finish_assemble` takes."""
+        fill = self._begin(vks)
+        self.early_fill_keys += len(fill.keys)
+        return fill
+
+    def _begin(self, vks) -> _Fill:
         n = len(vks)
-        out = np.empty((_TAB_ROWS, n), dtype=np.uint32)
-        known = np.empty(n, dtype=bool)
+        fill = _Fill(n)
         # this batch's hits are copied out while the stripe is held: a
         # fill larger than max_entries may evict keys this very batch
         # hit, and the lanes must still carry them (results stay correct
@@ -210,47 +274,51 @@ class PrecomputeCache:
             hit = np.flatnonzero(slots >= 0)
             if hit.size:
                 at = slots[hit]
-                out[:, hit] = self._tab[:, at]
-                known[hit] = self._known[at]
+                fill.out[:, hit] = self._tab[:, at]
+                fill.known[hit] = self._known[at]
                 self._stamp[at] = self._stamps(hit.size)
         self.hits += int(hit.size)
         if hit.size < n:
-            miss = np.flatnonzero(slots < 0)
-            missed = [vks[j] for j in miss.tolist()]
-            # the distinct new keys in first-seen order, and each missed
-            # lane's place among them
-            place = dict.fromkeys(missed)
-            self.misses += len(place)
-            tab, ok = self._fill(list(place))
-            if len(place) < len(missed):      # a new key met twice
-                place = {vk: i for i, vk in enumerate(place)}
-                at = np.array(list(map(place.__getitem__, missed)))
+            fill.miss = np.flatnonzero(slots < 0)
+            fill.missed = [vks[j] for j in fill.miss.tolist()]
+            # the distinct new keys in first-seen order
+            fill.keys = list(dict.fromkeys(fill.missed))
+            self.misses += len(fill.keys)
+            self.device_fills += 1
+            self.filled_keys += len(fill.keys)
+            with _spans.span("precompute.fill", cat="device"):
+                fill.pending = self._dispatch_tables(fill.keys)
+        return fill
+
+    def finish_assemble(self, fill: _Fill):
+        """Second phase: the new keys' tables fetched (the wait that is
+        left of the device's work), stored, and written to the lanes
+        that missed.  Undecodable keys are stored as negative entries
+        so they never refill.  The lanes read this fill's own columns,
+        so LRU eviction during the store can never lose them."""
+        keys = fill.keys
+        if keys:
+            with _spans.span("precompute.fill", cat="device"):
+                tab, ok = self._fetch_tables(fill.pending)
+                with _spans.span("fill.store", cat="device"):
+                    self._store(keys, tab, ok)
+            if len(keys) < len(fill.missed):      # a new key met twice
+                place = {vk: i for i, vk in enumerate(keys)}
+                at = np.array(list(map(place.__getitem__, fill.missed)))
                 tab, ok = tab[:, at], ok[at]
-            out[:, miss] = tab
-            known[miss] = ok
-        return out[0:8], out[8:16], out[16:24], known
+            fill.out[:, fill.miss] = tab
+            fill.known[fill.miss] = ok
+            fill.keys, fill.pending = [], None    # finished: once only
+        out = fill.out
+        return out[0:8], out[8:16], out[16:24], fill.known
 
-    def _fill(self, keys):
-        """Tables for the distinct new `keys`, made on the device and
-        stored; returns ((24, k) uint32 columns, ok (k,) bool) so that
-        assemble reads this batch's entries directly (LRU eviction
-        during the store can never lose them).  Undecodable keys are
-        stored as negative entries so they never refill."""
-        self.device_fills += 1
-        self.filled_keys += len(keys)
-        with _spans.span("precompute.fill", cat="device"):
-            tab, ok = self._device_tables(keys)
-            with _spans.span("fill.store", cat="device"):
-                self._store(keys, tab, ok)
-        return tab, ok
-
-    def _device_tables(self, keys):
-        """The device's part of a fill, at fixed program widths: up to
-        FILL_NARROW keys in one narrow call, more as whole ED_TILE-lane
-        tiles of one program called a tile at a time (all dispatched,
-        then all fetched).  Spans: fill.pack (key bytes to words, on the
-        host), fill.dispatch, fill.fetch (the wait for the device and
-        the copy back)."""
+    def _dispatch_tables(self, keys):
+        """The device's part of a fill, first half, at fixed program
+        widths: up to FILL_NARROW keys in one narrow call, more as whole
+        ED_TILE-lane tiles of one program called a tile at a time, all
+        dispatched and none waited for.  Spans: fill.pack (key bytes to
+        words, on the host), fill.dispatch.  Returns what
+        `_fetch_tables` takes."""
         import jax.numpy as jnp
 
         from . import ed25519_jax as EJ
@@ -268,11 +336,26 @@ class PrecomputeCache:
             parts = [EJ.a128_words_kernel(jnp.asarray(Aw[:, o:o + width]),
                                           jnp.asarray(sign[o:o + width]))
                      for o in range(0, lanes, width)]
+        return parts, len_ok & y_ok[:k]
+
+    def _fetch_tables(self, pending):
+        """Second half: ((24, k) uint32 columns, ok (k,) bool) of a
+        dispatched fill.  Span fill.fetch: the wait for the device (the
+        calls run in order, so for the last one; `fill_wait_us`) and the
+        copy back."""
+        import jax
+
+        from . import ed25519_jax as EJ
+        parts, host_ok = pending
+        k = host_ok.size
         with _spans.span("fill.fetch", cat="device"):
-            tab = np.concatenate([np.asarray(t) for t, _ok in parts],
-                                 axis=1)[:, :k]
-            ok = np.concatenate([np.asarray(o) for _t, o in parts])[:k]
-        ok = ok & len_ok & y_ok[:k]
+            t0 = time.perf_counter()
+            jax.block_until_ready(parts[-1])
+            self.fill_wait_us += int((time.perf_counter() - t0) * 1e6)
+            parts = jax.device_get(parts)     # every copy asked for at once
+            tab = np.concatenate([t for t, _ok in parts], axis=1)[:, :k]
+            ok = np.concatenate([o for _t, o in parts])[:k]
+        ok = ok & host_ok
         # any valid point works for a key that does not decode: its
         # lanes are masked via `known`
         tab[:, ~ok] = EJ._FILLER_COL[:, None]
@@ -403,7 +486,9 @@ class PrecomputeCache:
                 "filled_keys": self.filled_keys,
                 "fill_lanes_padded": self.fill_lanes_padded,
                 "evictions": self.evictions,
-                "lock_wait": self.lock_wait}
+                "early_fill_keys": self.early_fill_keys,
+                "lock_wait": self.lock_wait,
+                "fill_wait_us": self.fill_wait_us}
 
 
 # one process-wide cache: every backend instance (single-chip, sharded)

@@ -83,7 +83,11 @@ span named first; cat in brackets):
                     `Ed25519Cols`), no object a witness
     window.submit   submit.split, submit.pack_ed, submit.pack_vrf,
                     submit.pack_kes, submit.dispatch, submit.fold
-                    [dispatch], one each a window (crypto/jax_backend.py).
+                    [dispatch], one each a window but submit.pack_ed,
+                    which is two (crypto/jax_backend.py: the lanes
+                    packed beside the new keys' fill, then, behind the
+                    VRF and KES packers, the key tables collected and
+                    the tiles copied to the device).
                     What crosses into it is the window's stream of
                     items: submit.split walks the items (a handful a
                     block) and joins each columns item to the Ed25519

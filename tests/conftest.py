@@ -58,7 +58,9 @@ else:
 # and load from the persistent cache what the files before them
 # compiled, if they start behind them and not beside them:
 # `test_ed_tiles` the tile programs and the flat bucket of four other
-# files (567 s beside them, 462 s behind `test_fresh_keys`),
+# files (567 s beside them, 462 s behind `test_fresh_keys`; since PR 46
+# `test_fresh_keys` builds both tile programs itself, 152 -> 390 s, and
+# `test_ed_tiles` behind it takes 342 s),
 # `test_longchain` `test_hardfork_sync`'s four programs (432 s beside
 # it, 187 s behind it), and so does `test_delegrush` (PR 45: the same
 # composite; 181-212 s on a cache that held it).  `test_mesh_batch` is
@@ -71,7 +73,7 @@ COMPILES_FOR_MINUTES = (
     ("test_served_replay.py", 534),
     ("test_hardfork_sync.py", 369),
     ("test_chip_compile.py", 345),
-    ("test_fresh_keys.py", 152),
+    ("test_fresh_keys.py", 390),
     ("test_ed_tiles.py", 462),
     ("test_mesh_batch.py", 364),
     ("test_longchain.py", 187),

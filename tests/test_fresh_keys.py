@@ -16,6 +16,12 @@ crypto/ed25519_ref.py's, also with an LRU bound smaller than the batch;
 the lanes handed to the fill programs are whole tiles and the programs
 are two whatever the count; and `db_synth`'s default chain is the
 parent's, byte for byte.
+
+Since ISSUE 46 a fill has two phases (`begin_assemble` dispatches it,
+`finish_assemble` collects it): every case of (a) and (b) runs both as
+`assemble` and as the two calls, and (c) holds the window path to its
+order: a window of new keys has its fill on the device before its
+lanes are hashed, and gives the verdicts it gave before.
 """
 import hashlib
 import os
@@ -102,51 +108,85 @@ def _lanes(n_keys: int) -> int:
     return -(-n_keys // width) * width
 
 
+# `assemble` in one go and as its two phases: the same tables, `known`
+# and counters; `early_fill_keys` says which it was
+HOW = ["assemble", "begin-finish"]
+
+
+def _assemble(cache, vks, how):
+    if how == "assemble":
+        return cache.assemble(vks)
+    return cache.finish_assemble(cache.begin_assemble(vks))
+
+
 # -- (a) the table entries against the integers ------------------------------
 
-@pytest.mark.parametrize("n", [1, T - 1, T, T + 1, 3 * T])
-def test_new_keys_get_the_integers_tables_at_every_count(n):
+@pytest.mark.parametrize("how", HOW)
+@pytest.mark.parametrize("n", [1, NARROW, NARROW + 1, T - 1, T, T + 1, 3 * T])
+def test_new_keys_get_the_integers_tables_at_every_count(n, how):
     cache = PrecomputeCache()
     vks = [_vk(1000 * n + i) for i in range(n)]
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert (cache.misses, cache.filled_keys, cache.device_fills) == (n, n, 1)
     assert cache.hits == 0 and len(cache) == n
     assert cache.fill_lanes_padded == _lanes(n)
+    assert cache.early_fill_keys == (0 if how == "assemble" else n)
     # the same keys again: every lane a hit, nothing filled
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert (cache.hits, cache.filled_keys, cache.device_fills) == (n, n, 1)
+    assert cache.early_fill_keys in (0, n) and cache.fill_wait_us >= 0
 
 
+@pytest.mark.parametrize("how", HOW)
 @pytest.mark.parametrize("odd", sorted(ODD_KEYS))
-def test_a_key_no_wallet_made_among_new_keys(odd):
+def test_a_key_no_wallet_made_among_new_keys(odd, how):
     cache = PrecomputeCache()
     vks = [_vk(1), ODD_KEYS[odd], _vk(2)]
-    out = cache.assemble(vks)
+    out = _assemble(cache, vks, how)
     _check_lanes(vks, out)
     assert bool(out[3][1]) == odd.startswith("order")
     # cached as it is, a negative entry too: no second fill
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert cache.device_fills == 1 and cache.hits == 3
 
 
-def test_a_new_key_twice_in_one_batch_is_filled_once():
+@pytest.mark.parametrize("how", HOW)
+def test_a_new_key_twice_in_one_batch_is_filled_once(how):
     cache = PrecomputeCache()
     vks = [_vk(i % 5) for i in range(T + 3)]      # 5 distinct, 19 lanes
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert (cache.misses, cache.filled_keys, len(cache)) == (5, 5, 5)
     assert cache.fill_lanes_padded == NARROW and cache.hits == 0
 
 
-def test_new_and_cached_keys_mixed_in_one_batch():
+@pytest.mark.parametrize("how", HOW)
+def test_new_and_cached_keys_mixed_in_one_batch(how):
     cache = PrecomputeCache()
     old = [_vk(100 + i) for i in range(6)]
     cache.assemble(old)
     new = [_vk(200 + i) for i in range(T + 1)] + [ODD_KEYS["off-curve"]]
     vks = [k for pair in zip(new, old * 3) for k in pair]   # interleaved
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert cache.hits == len(vks) // 2            # a hit is a lane
     assert cache.misses == 6 + len(new) == cache.filled_keys
     assert cache.fill_lanes_padded == NARROW + _lanes(len(new))
+    assert cache.early_fill_keys == (0 if how == "assemble" else len(new))
+
+
+def test_two_fills_in_flight_at_once_on_overlapping_keys():
+    """Both on the device before either is collected: each key stored
+    once, both batches' lanes right."""
+    cache = PrecomputeCache()
+    one = [_vk(900 + i) for i in range(NARROW + 2)]
+    two = one[NARROW:] + [_vk(950 + i) for i in range(3)] + one[:1]
+    first, second = cache.begin_assemble(one), cache.begin_assemble(two)
+    assert len(cache) == 0 and cache.device_fills == 2
+    _check_lanes(two, cache.finish_assemble(second))
+    _check_lanes(one, cache.finish_assemble(first))
+    assert len(cache) == len(set(one + two)) == NARROW + 5
+    assert cache.filled_keys == cache.early_fill_keys == NARROW + 2 + 6
+    _check_lanes(one + two, cache.assemble(one + two))
+    assert cache.device_fills == 2
 
 
 def test_the_fill_programs_are_two_whatever_the_count():
@@ -165,19 +205,21 @@ def test_the_fill_programs_are_two_whatever_the_count():
 
 # -- (b) an LRU bound smaller than one batch ---------------------------------
 
-def test_a_bound_smaller_than_the_batch_keeps_the_lanes_right():
+@pytest.mark.parametrize("how", HOW)
+def test_a_bound_smaller_than_the_batch_keeps_the_lanes_right(how):
     cache = PrecomputeCache(max_entries=4)
     cache.assemble([_vk(700), _vk(701)])
     vks = [_vk(700)] + [_vk(710 + i % 11) for i in range(T + 6)] + [_vk(701)]
-    _check_lanes(vks, cache.assemble(vks))         # hits evicted mid-batch
+    # hits evicted mid-batch: they were copied out at the begin
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert len(cache) == 4 and cache.evictions == 2 + 11 - 4
     # the last four of the fill stay, as if inserted one by one
     assert [_vk(710 + i) in cache for i in range(11)] == [False] * 7 + [True] * 4
-    _check_lanes(vks, cache.assemble(vks))
+    _check_lanes(vks, _assemble(cache, vks, how))
     assert len(cache) == 4
 
 
-def _requests():
+def _requests(flipped=(5,), off_curve=(9,), identity=(13,)):
     """Signatures by new keys, one tampered, one by a key that is no
     point, one by the identity, and a key that signs twice."""
     reqs = []
@@ -185,11 +227,11 @@ def _requests():
         sk, msg = _sk(800 + i % 18), b"tx-%02d" % i
         sig = ed25519_ref.sign(sk, msg)
         vk = ed25519_ref.public_key(sk)
-        if i == 5:
+        if i in flipped:
             sig = sig[:40] + bytes([sig[40] ^ 1]) + sig[41:]
-        if i == 9:
+        if i in off_curve:
             vk = ODD_KEYS["off-curve"]
-        if i == 13:
+        if i in identity:
             vk = ODD_KEYS["order-1"]
         reqs.append(Ed25519Req(vk, msg, sig))
     return reqs
@@ -208,6 +250,94 @@ def test_verdicts_on_new_keys_equal_the_reference(monkeypatch, bound):
         assert be.verify_ed25519_batch(reqs) == want     # every key new
         assert be.verify_ed25519_batch(reqs) == want     # cached, or evicted
         assert len(cache) <= bound
+    finally:
+        cache.clear()
+
+
+# -- (c) the window path: the fill before the hashing ---------------------------
+
+# the requests that fail, as the parent's `_submit_window` answered (and
+# as crypto/ed25519_ref.py does), by what the window holds
+WINDOWS = {
+    "flipped-witness-on-a-new-key": (
+        dict(flipped=(7,), off_curve=(), identity=()), [7]),
+    "undecodable-new-key": (
+        dict(flipped=(), off_curve=(3,), identity=()), [3]),
+}
+
+
+@pytest.fixture(scope="module")
+def window_backend():
+    """One backend for the window cases: its two tile programs (fold
+    and no fold) are traced and built once."""
+    return JB.JaxBackend(min_bucket=16, use_pallas=False, autotune=False)
+
+
+@pytest.mark.parametrize("fold", [False, True], ids=["no-fold", "fold"])
+@pytest.mark.parametrize("window", sorted(WINDOWS))
+def test_a_window_of_new_keys_fills_before_it_hashes(
+        monkeypatch, window_backend, window, fold):
+    """When the packer starts hashing the window's lanes
+    (`EJ.challenge_rows`), the new keys' fill is already dispatched and
+    counted and nothing is stored yet; the verdict is the parent's."""
+    cache = precompute.GLOBAL_PRECOMPUTE_CACHE
+    cache.clear()
+    kwargs, bad = WINDOWS[window]
+    reqs = _requests(**kwargs)
+    assert [i for i, r in enumerate(reqs)
+            if not ed25519_ref.verify(r.vk, r.msg, r.sig)] == bad
+    new = len({r.vk for r in reqs}) + 1          # and the pad lanes' key
+    before = (cache.device_fills, cache.early_fill_keys, cache.filled_keys)
+    seen = []
+    hashing = EJ.challenge_rows
+
+    def spy(*args):
+        seen.append((cache.device_fills - before[0],
+                     cache.early_fill_keys - before[1],
+                     cache.filled_keys - before[2], len(cache)))
+        return hashing(*args)
+
+    monkeypatch.setattr(EJ, "challenge_rows", spy)
+    be = window_backend
+    try:
+        verdict, _betas = be.finish_window(be.submit_window(reqs, fold=fold))
+        assert seen == [(1, new, new, 0)]
+        assert len(cache) == new
+        if fold:
+            assert verdict.first_bad == (bad[0] if bad else None)
+        else:
+            assert [i for i, ok in enumerate(verdict) if not ok] == bad
+        # the same window again: every lane a hit, a handle with nothing
+        # in flight, the same verdict
+        again, _betas = be.finish_window(be.submit_window(reqs, fold=fold))
+        assert again == verdict and seen[1:] == [(1, new, new, new)]
+    finally:
+        cache.clear()
+
+
+def test_a_malformed_witness_in_the_packer_leaves_the_cache_alone(
+        monkeypatch, window_backend):
+    """An exception between the window's begin and its finish (here in
+    the hashing): nothing of the window is stored, and the same window
+    later is filled and answered right."""
+    cache = precompute.GLOBAL_PRECOMPUTE_CACHE
+    cache.clear()
+    reqs = _requests()
+    want = [ed25519_ref.verify(r.vk, r.msg, r.sig) for r in reqs]
+    be = window_backend
+    filled = cache.filled_keys
+
+    def refuse(*args):
+        raise ValueError("malformed witness")
+
+    try:
+        with monkeypatch.context() as m:
+            m.setattr(EJ, "challenge_rows", refuse)
+            with pytest.raises(ValueError, match="malformed"):
+                be.submit_window(reqs)
+        assert len(cache) == 0 and cache.filled_keys > filled
+        assert be.verify_mixed(reqs) == want
+        assert len(cache) == len({r.vk for r in reqs}) + 1
     finally:
         cache.clear()
 

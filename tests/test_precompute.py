@@ -7,6 +7,7 @@ real XLA kernels (a cache-warm window does ZERO per-key fill dispatches
 and gives identical verdicts/betas).
 """
 import hashlib
+import os
 
 import numpy as np
 import pytest
@@ -16,6 +17,11 @@ from ouroboros_tpu.crypto.backend import (
     CpuRefBackend, Ed25519Req, KesReq, VrfReq,
 )
 from ouroboros_tpu.crypto.precompute import PrecomputeCache
+
+
+def _sha_words(vk):
+    return np.tile(np.frombuffer(hashlib.sha256(vk).digest(),
+                                 dtype=np.uint32), 3)
 
 
 def _stub_fill(cache, log=None):
@@ -28,24 +34,47 @@ def _stub_fill(cache, log=None):
         tab = np.empty((24, len(keys)), dtype=np.uint32)
         ok = np.ones(len(keys), dtype=bool)
         for j, vk in enumerate(keys):
-            tab[:, j] = np.tile(np.frombuffer(hashlib.sha256(vk).digest(),
-                                              dtype=np.uint32), 3)
+            tab[:, j] = _sha_words(vk)
             ok[j] = not vk.startswith(b"bad")
         return tab, ok
-    cache._device_tables = tables
+    # the two halves of the device's part: "dispatched" tables are
+    # already there, and the fetch hands them over
+    cache._dispatch_tables = tables
+    cache._fetch_tables = lambda pending: pending
     return cache
+
+
+# `assemble` in one go, and as its two phases with the caller's own work
+# in between (ISSUE 46): the same tables, `known` and LRU state
+HOW = ["assemble", "begin-finish"]
+
+
+def _assemble(cache, vks, how):
+    if how == "assemble":
+        return cache.assemble(vks)
+    return cache.finish_assemble(cache.begin_assemble(vks))
+
+
+def _check_stub_lanes(vks, out):
+    xa, xw, yw, known = out
+    tab = np.concatenate([xa, xw, yw])
+    for j, vk in enumerate(vks):
+        assert bool(known[j]) == (not vk.startswith(b"bad")), j
+        if known[j]:
+            assert (tab[:, j] == _sha_words(vk)).all(), j
 
 
 # ---------------------------------------------------------------------------
 # host partition: LRU semantics
 # ---------------------------------------------------------------------------
 
-def test_lru_eviction_drops_oldest_and_results_stay_correct():
+@pytest.mark.parametrize("how", HOW)
+def test_lru_eviction_drops_oldest_and_results_stay_correct(how):
     log = []
     c = _stub_fill(PrecomputeCache(max_entries=4), log)
     keys = [b"k%02d" % i + b"\x00" * 28 for i in range(6)]
     # fill past capacity: 6 inserts into a 4-entry cache
-    xa, _xs, _ys, known = c.assemble(keys)
+    xa, _xs, _ys, known = _assemble(c, keys, how)
     assert known.all()
     assert len(c) == 4 and c.evictions == 2
     # the OLDEST two were evicted, the newest four retained
@@ -56,31 +85,199 @@ def test_lru_eviction_drops_oldest_and_results_stay_correct():
         want = np.frombuffer(hashlib.sha256(k).digest(), dtype=np.uint32)
         assert (xa[:, j] == want).all()
     # re-assembling an evicted key refills exactly that key
-    c.assemble([keys[0]])
+    _assemble(c, [keys[0]], how)
     assert log[-1] == [keys[0]]
     assert keys[0] in c
+    assert c.early_fill_keys == (0 if how == "assemble" else 7)
 
 
-def test_lru_hit_refreshes_recency():
+@pytest.mark.parametrize("how", HOW)
+def test_lru_hit_refreshes_recency(how):
     c = _stub_fill(PrecomputeCache(max_entries=3))
     a, b, d, e = (b"a" * 32, b"b" * 32, b"d" * 32, b"e" * 32)
-    c.assemble([a, b, d])
-    c.assemble([a])              # refresh a: b is now the LRU entry
-    c.assemble([e])              # evicts b, not a
+    _assemble(c, [a, b, d], how)
+    _assemble(c, [a], how)       # refresh a: b is now the LRU entry
+    _assemble(c, [e], how)       # evicts b, not a
     assert a in c and d in c and e in c and b not in c
 
 
-def test_negative_entries_cached_without_refill():
+@pytest.mark.parametrize("how", HOW)
+def test_negative_entries_cached_without_refill(how):
     log = []
     c = _stub_fill(PrecomputeCache(max_entries=8), log)
     bad = b"bad" + b"\x00" * 29
-    _, _, _, known = c.assemble([bad, b"ok" + b"\x00" * 30])
+    _, _, _, known = _assemble(c, [bad, b"ok" + b"\x00" * 30], how)
     assert list(known) == [False, True]
     fills = c.device_fills
-    _, _, _, known2 = c.assemble([bad])
+    _, _, _, known2 = _assemble(c, [bad], how)
     assert not known2[0]
     assert c.device_fills == fills     # no refill for a known-bad key
     assert c.hits == 1
+
+
+# ---------------------------------------------------------------------------
+# host partition: a fill in two phases (ISSUE 46)
+# ---------------------------------------------------------------------------
+
+def _keys(*ids):
+    return [b"k%03d" % i + b"\x00" * 28 for i in ids]
+
+
+def _counts(c):
+    return (c.hits, c.misses, c.device_fills, c.filled_keys, c.evictions)
+
+
+@pytest.mark.parametrize("batch", [
+    _keys(1),                                   # one new key
+    _keys(*range(40)),                          # many
+    _keys(1, 2, 1, 3, 2, 1),                    # a new key met twice
+    _keys(100, 1, 101, 2, 100, 3),              # hits and misses mixed
+    _keys(1, 2) + [b"bad" + b"\x00" * 29] + _keys(3),   # one undecodable
+    _keys(100, 101),                            # every lane a hit
+], ids=["one", "many", "twice", "mixed", "undecodable", "all-hits"])
+@pytest.mark.parametrize("bound", [200_000, 3])
+def test_begin_then_finish_is_assemble(batch, bound):
+    """Lanes, `known`, counters and what stays cached are `assemble`'s,
+    also with a bound smaller than the batch: the hits copied at the
+    begin survive the store's evictions."""
+    caches = []
+    for how in HOW:
+        c = _stub_fill(PrecomputeCache(max_entries=bound))
+        c.assemble(_keys(100, 101))
+        out = _assemble(c, batch, how)
+        _check_stub_lanes(batch, out)
+        caches.append((c, out))
+    (one, out_one), (two, out_two) = caches
+    assert all((a == b).all() for a, b in zip(out_one, out_two))
+    assert _counts(one) == _counts(two)
+    assert sorted(one._slot) == sorted(two._slot) and len(two) <= bound
+    new = len(set(batch) - set(_keys(100, 101)))
+    assert (one.early_fill_keys, two.early_fill_keys) == (0, new)
+    assert two.stats()["early_fill_keys"] == new
+    assert two.stats()["fill_wait_us"] == two.fill_wait_us == 0  # stubbed
+
+
+def test_begin_dispatches_and_waits_for_nothing_and_stores_nothing():
+    dispatched, fetched = [], []
+    c = _stub_fill(PrecomputeCache(), dispatched)
+    c._fetch_tables = lambda pending: fetched.append(1) or pending
+    c.assemble(_keys(1))
+    fetched.clear()
+    fill = c.begin_assemble(_keys(1, 2, 3))
+    # the device's part is on its way, counted, and the table untouched
+    assert dispatched[-1] == _keys(2, 3) and not fetched
+    assert (c.device_fills, c.filled_keys, c.early_fill_keys) == (2, 3, 2)
+    assert len(c) == 1 and not c._lock_c._lock.locked()
+    # the stripe is free between the phases: another batch goes through
+    _check_stub_lanes(_keys(9), c.assemble(_keys(9)))
+    fetched.clear()
+    _check_stub_lanes(_keys(1, 2, 3), c.finish_assemble(fill))
+    assert fetched == [1] and len(c) == 4
+
+
+def test_a_handle_with_every_lane_a_hit_has_nothing_in_flight():
+    dispatched = []
+    c = _stub_fill(PrecomputeCache(), dispatched)
+    c.assemble(_keys(1, 2))
+    c._fetch_tables = None             # a finish that fetched would raise
+    fill = c.begin_assemble(_keys(2, 1, 2))
+    assert fill.keys == [] and fill.pending is None
+    assert len(dispatched) == 1 and c.early_fill_keys == 0
+    _check_stub_lanes(_keys(2, 1, 2), c.finish_assemble(fill))
+    assert (c.hits, c.device_fills) == (3, 1)
+
+
+def test_two_handles_open_at_once_on_overlapping_keys():
+    """Two submitters each between their phases: a key both began is
+    filled twice and stored once, and both get right lanes."""
+    dispatched = []
+    c = _stub_fill(PrecomputeCache(), dispatched)
+    first = c.begin_assemble(_keys(1, 2, 3, 4))
+    second = c.begin_assemble(_keys(3, 4, 5, 6, 3))
+    assert dispatched == [_keys(1, 2, 3, 4), _keys(3, 4, 5, 6)]
+    assert len(c) == 0
+    # finished in the other order
+    _check_stub_lanes(_keys(3, 4, 5, 6, 3), c.finish_assemble(second))
+    assert len(c) == 4
+    _check_stub_lanes(_keys(1, 2, 3, 4), c.finish_assemble(first))
+    assert len(c) == 6 and sorted(c._slot) == _keys(1, 2, 3, 4, 5, 6)
+    assert c._used == 6                      # one slot a key
+    assert (c.misses, c.filled_keys, c.device_fills) == (8, 8, 2)
+    assert c.early_fill_keys == 8 and c.evictions == 0
+    # every key a hit from here on
+    _check_stub_lanes(_keys(1, 3, 6), c.assemble(_keys(1, 3, 6)))
+    assert c.device_fills == 2
+
+
+def test_many_threads_each_between_their_phases():
+    """More submitters than cores, each holding a handle while the
+    others look up and store, at a switch interval short enough to cut
+    into every compound step: every batch answers right, the bound
+    holds, and no fill is lost from the counters."""
+    import sys
+    import threading
+    n_threads = 2 * (os.cpu_count() or 4) + 1
+    c = _stub_fill(PrecomputeCache(max_entries=16))
+    begun = threading.Barrier(n_threads)
+    failures = []
+
+    def submitter(seed):
+        try:
+            for r in range(25):
+                ks = _keys(*((seed + r + j) % 24 for j in range(6)))
+                fill = c.begin_assemble(ks)
+                if r == 0:
+                    begun.wait(timeout=30)   # every thread holds a handle
+                _check_stub_lanes(ks, c.finish_assemble(fill))
+        except Exception as e:              # noqa: BLE001
+            failures.append(repr(e))
+
+    threads = [threading.Thread(target=submitter, args=(7 * i,))
+               for i in range(n_threads)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in threads) and not failures
+    assert len(c) <= 16 and len(c) == len(c._slot)
+    assert not c._lock_c._lock.locked()
+
+
+def test_a_handle_dropped_between_the_phases_costs_the_cache_nothing():
+    """A malformed witness raising in the packer between begin and
+    finish: the table is as if the keys had never been seen."""
+    c = _stub_fill(PrecomputeCache())
+    c.assemble(_keys(100))
+    with pytest.raises(ValueError, match="malformed"):
+        fill = c.begin_assemble(_keys(100, 1, 2, 3))
+        assert fill.keys == _keys(1, 2, 3)
+        raise ValueError("malformed witness")
+    del fill
+    assert len(c) == 1 and _keys(1)[0] not in c
+    assert (c.filled_keys, c.device_fills, c.misses) == (4, 2, 4)
+    # the same keys later: filled (again) and right
+    _check_stub_lanes(_keys(100, 1, 2, 3), c.assemble(_keys(100, 1, 2, 3)))
+    assert len(c) == 4 and (c.filled_keys, c.device_fills) == (7, 3)
+    assert c.hits == 2
+
+
+def test_clear_between_the_phases_loses_nothing():
+    c = _stub_fill(PrecomputeCache())
+    c.assemble(_keys(100, 101))
+    fill = c.begin_assemble(_keys(100, 1, 101, 2))
+    c.clear()                              # a replay boundary
+    assert len(c) == 0
+    # the hits were copied out at the begin; the new keys go into the
+    # cleared table
+    _check_stub_lanes(_keys(100, 1, 101, 2), c.finish_assemble(fill))
+    assert sorted(c._slot) == _keys(1, 2)
+    _check_stub_lanes(_keys(1, 100), c.assemble(_keys(1, 100)))
+    assert len(c) == 3
 
 
 def test_kes_namespace_lru_and_outcomes():

@@ -254,12 +254,15 @@ def test_every_stage_span_under_its_parent_on_its_thread(traced):
         assert len(by_name[name]) == BLOCKS, name
     assert len(by_name["seq.header"]) == 3 * BLOCKS
     assert len(by_name["seq.body"]) == 2 * BLOCKS
-    for name in ("submit.split", "submit.pack_ed", "pack_ed.challenge",
+    for name in ("submit.split", "pack_ed.challenge",
                  "submit.pack_vrf",
                  "submit.pack_kes", "submit.dispatch", "submit.ed_tiles",
                  "submit.fold",
                  "window.submit", "window.host_seq", "pipeline.drain"):
         assert len(by_name[name]) == N_WINDOWS, name
+    # the Ed25519 packer in two pieces a window: the lanes packed beside
+    # the new keys' fill, then the key tables collected (PR 46)
+    assert len(by_name["submit.pack_ed"]) == 2 * N_WINDOWS
     assert len(by_name["pipeline.beta_prefetch"]) == 1
     # the spans the benchmark already read keep name, cat and thread
     caller = threading.current_thread().name
@@ -301,16 +304,24 @@ def test_a_fill_holds_its_four_stages_in_order(traced):
     """`precompute.fill` is making tables for missed keys, whoever asks
     (a packer of `window.submit`, the beta prefetch): packing the keys
     and storing the tables on the host, dispatch and fetch the device's
-    side, and the counters beside it count keys and lanes."""
+    side, in the two phases of a fill, and the counters beside it count
+    keys and lanes."""
     roots, _stats, _hash, delta = traced
     fills = [sp for r in roots for sp in r.walk()
              if sp.name == "precompute.fill"]
-    assert fills and len(fills) == delta["precompute.device_fills"]
+    # two spans a fill since PR 46, one a phase: the begin packs and
+    # dispatches, the finish fetches and stores, and the caller's own
+    # work may lie between them
+    assert fills and len(fills) == 2 * delta["precompute.device_fills"]
+    halves = {(("fill.pack", "device"), ("fill.dispatch", "device")): 0,
+              (("fill.fetch", "device"), ("fill.store", "device")): 0}
     for sp in fills:
         assert sp.cat == "device" and sp.thread == PRODUCER
-        assert [(c.name, c.cat) for c in sp.children] == [
-            ("fill.pack", "device"), ("fill.dispatch", "device"),
-            ("fill.fetch", "device"), ("fill.store", "device")]
+        halves[tuple((c.name, c.cat) for c in sp.children)] += 1
+    assert set(halves.values()) == {delta["precompute.device_fills"]}
+    # the window's Ed25519 keys are begun ahead of the lanes' hashing
+    assert 0 < delta["precompute.early_fill_keys"] \
+        <= delta["precompute.filled_keys"]
     # `misses` counts the KES hash paths that missed too
     assert 0 < delta["precompute.filled_keys"] <= delta["precompute.misses"]
     assert delta["precompute.filled_keys"] \

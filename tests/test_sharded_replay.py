@@ -277,28 +277,32 @@ def fresh_lines(fresh_chain, lines, mesh_backend, one_chip_backend,
     per-key cache was asked (`assemble` spied on) and what it counted."""
     _listen_for_compiles()
     cache = GLOBAL_PRECOMPUTE_CACHE
-    real = cache.assemble
     out = {"cpp": _validate(fresh_chain, reference_backend)}
     for name, backend in (("mesh", mesh_backend),
                           ("one-chip", one_chip_backend)):
         asked = []     # (keys, which were cached, lanes `hits` rose by)
 
-        def spy(vks):
-            cached = [vk in cache for vk in vks]
-            h0 = cache.hits
-            res = real(vks)
-            asked.append((list(vks), cached, cache.hits - h0))
-            return res
+        def spied(real):
+            def spy(vks):
+                cached = [vk in cache for vk in vks]
+                h0 = cache.hits
+                res = real(vks)
+                asked.append((list(vks), cached, cache.hits - h0))
+                return res
+            return spy
 
         compiles = len(_COMPILES)
         builds = metrics_mod.counter("jax_backend.composite_builds").value
-        cache.assemble = spy
+        # the cache is asked in one go (the VRF packer) or in two phases
+        # (the window's Ed25519 lanes): the lookup is in the first
+        cache.assemble = spied(cache.assemble)
+        cache.begin_assemble = spied(cache.begin_assemble)
         try:
             c0 = _counters()
             out[name] = _validate(fresh_chain, backend)
             c1 = _counters()
         finally:
-            del cache.assemble
+            del cache.assemble, cache.begin_assemble
         out[name + "-facts"] = {
             "asked": asked,
             "delta": {k: c1[k] - c0.get(k, 0) for k in c1},

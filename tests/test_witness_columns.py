@@ -523,8 +523,9 @@ def test_tx_proofs_through_the_mempool_path(window, backends, name):
     assert list(cols) == [Ed25519Req(vk, tx.txid, sig)
                           for vk, sig in tx.witnesses]
     if name == "jax":
-        from_cols, ok_cols = backend._pack_ed(cols, 16)
-        from_objects, ok_objects = backend._pack_ed(list(cols), 16)
+        from_cols, ok_cols = backend._finish_ed(backend._pack_ed(cols, 16))
+        from_objects, ok_objects = backend._finish_ed(
+            backend._pack_ed(list(cols), 16))
         assert all((a == b).all() for a, b in zip(from_cols, from_objects))
         assert (ok_cols == ok_objects).all() and ok_cols[:len(cols)].all()
         return
